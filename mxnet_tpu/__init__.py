@@ -43,6 +43,10 @@ del _sanitizers
 # env is absent; see base.join_distributed_job for the knobs.
 from .base import join_distributed_job as _join
 _join()
+# ... and give jax's persistent compilation cache its directory before
+# anything compiles (see base.place_compile_cache).
+from .base import place_compile_cache as _place_cache
+_place_cache()
 
 from . import base
 from .base import MXNetError

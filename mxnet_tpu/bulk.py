@@ -414,7 +414,6 @@ class Segment:
             _SEG_CACHE.pop(sig, None)
             self._run_sequential(nodes, returns, phs)
             return
-        engine.mark_clean(list(outs))
         for ph, arr in zip(phs, outs):
             ph.value = arr
             engine.track(arr)

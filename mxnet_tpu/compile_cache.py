@@ -322,9 +322,16 @@ class CompileCache:
             self._quarantine(key, "digest")
             return None
         try:
+            import jax
             from jax.experimental import serialize_executable as _se
             payload, in_tree, out_tree = pickle.loads(blob)
-            fn = _se.deserialize_and_load(payload, in_tree, out_tree)
+            # load onto the devices the program was compiled for:
+            # deserialize_and_load otherwise targets EVERY backend
+            # device, and a 1-device program then expects N shards
+            by_id = {d.id: d for d in jax.devices()}
+            fn = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in meta["devices"]])
         except Exception:   # noqa: BLE001 - verified bytes that still
             self._quarantine(key, "deserialize")  # refuse to load
             return None
@@ -393,6 +400,8 @@ class CompileCache:
                 "sha256": digest,
                 "size": len(blob),
                 "surface": surface,
+                "devices": [d.id for d in
+                            compiled.runtime_executable().local_devices()],
                 "fingerprint": _fingerprint(),
                 "created": time.time(),
             }

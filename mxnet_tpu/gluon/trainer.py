@@ -783,17 +783,14 @@ class Trainer:
     _HYPER_CACHE_CAP = 512
 
     def _committed_hypers(self, lrs, wds, rescale, clip):
-        """Value-keyed LRU of committed device hyperparameter arrays.
+        """Value-keyed LRU of device hyperparameter arrays.
 
-        The fused update used to build fresh ``jnp.asarray`` host arrays
-        for lr/wd/rescale/clip EVERY step — on a remote accelerator
-        backend each varying-value host argument pays the slow
-        uncommitted-argument dispatch path per call (the same plateau
-        ``SPMDTrainer._committed_scalar`` exists for).  Hyperparameters
+        Building fresh ``jnp.asarray`` arrays for lr/wd/rescale/clip
+        EVERY step is four host->device transfers per update (the same
+        cost ``SPMDTrainer._committed_scalar`` avoids).  Hyperparameters
         revisit a small value set (constant, or a cyclic schedule), so
         an LRU by value makes the steady state zero-transfer."""
         import jax.numpy as jnp
-        from .. import engine
         key = (tuple(lrs), tuple(wds), float(rescale), float(clip))
         cache = getattr(self, "_hyper_cache", None)
         if cache is None:
@@ -801,10 +798,9 @@ class Trainer:
             cache = self._hyper_cache = OrderedDict()
         hit = cache.get(key)
         if hit is None:
-            hit = tuple(engine.launder(
-                [jnp.asarray(lrs, jnp.float32),
-                 jnp.asarray(wds, jnp.float32),
-                 jnp.float32(rescale), jnp.float32(clip)]))
+            hit = (jnp.asarray(lrs, jnp.float32),
+                   jnp.asarray(wds, jnp.float32),
+                   jnp.float32(rescale), jnp.float32(clip))
             cache[key] = hit
             if len(cache) > self._HYPER_CACHE_CAP:
                 cache.popitem(last=False)
@@ -820,7 +816,6 @@ class Trainer:
         after ``load_states``/rewind (and a skipped update, which never
         calls this, leaves both sides untouched)."""
         import jax.numpy as jnp
-        from .. import engine
         expected = tuple(float(t) for t in ts)
         clock = getattr(self, "_fused_clock", None)
         if clock is None:
@@ -828,7 +823,7 @@ class Trainer:
         hit = clock.get(key)
         if hit is not None and hit[1] == expected:
             return hit[0]
-        return engine.launder([jnp.asarray(ts, jnp.float32)])[0]
+        return jnp.asarray(ts, jnp.float32)
 
     def _fused_update(self, group) -> None:
         """One compiled program applying a group of parameter updates —

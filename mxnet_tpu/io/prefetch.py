@@ -100,21 +100,16 @@ def default_placement(batch: Any) -> Any:
     :meth:`DevicePrefetcher.attach`."""
     import jax
     from ..ndarray.ndarray import NDArray, from_jax
-    from .. import engine as _engine
     dev = jax.devices()[0]
 
     def place(x: Any) -> Any:
         if isinstance(x, (tuple, list)):
             return type(x)(place(v) for v in x)
         if isinstance(x, NDArray):
-            a = jax.device_put(x._data, dev)
-            _engine.mark_clean(a)
-            x._data = a
+            x._data = jax.device_put(x._data, dev)
             return x
         if hasattr(x, "shape") and hasattr(x, "dtype"):
-            a = jax.device_put(x, dev)
-            _engine.mark_clean(a)
-            return from_jax(a)
+            return from_jax(jax.device_put(x, dev))
         return x
 
     return place(batch)

@@ -562,6 +562,11 @@ COMPILE_HITS = counter(
     "mxnet_compile_hits_total",
     "Per-op executable-cache hits on the eager dispatch path (the call "
     "reused a compiled executable instead of tracing).")
+COMPILE_PERSISTENT_HITS = counter(
+    "mxnet_compile_persistent_hits_total",
+    "Programs loaded from jax's persistent compilation cache "
+    "(jax_compilation_cache_dir) instead of compiled — a cold start "
+    "shows misses, a warm restart shows these.")
 COMPILE_SECONDS = histogram(
     "mxnet_compile_seconds",
     "Wall time of XLA backend compilations (jax.monitoring).")
@@ -950,24 +955,33 @@ def inc_backward_segment(reason: str) -> None:
 # ---------------------------------------------------------------------------
 
 _JAX_HOOK = {"installed": False}
+_HOOK_TLS = threading.local()
 
 
 def _install_jax_hooks() -> None:
     if _JAX_HOOK["installed"]:
         return
     _JAX_HOOK["installed"] = True
-    try:
-        from jax import monitoring as _mon
+    from jax import monitoring as _mon
 
-        def _on_duration(event: str, duration: float, **kw: Any) -> None:
-            if event.endswith("backend_compile_duration") or \
-                    event.endswith("backend_compile_time_sec"):
+    def _on_event(event: str, **kw: Any) -> None:
+        # fires inside compile_or_get_cached, before the enclosing
+        # backend_compile duration on the same thread: that "compile"
+        # was a read from jax's persistent cache
+        if event == "/jax/compilation_cache/cache_hits":
+            _HOOK_TLS.cache_hit = True
+
+    def _on_duration(event: str, duration: float, **kw: Any) -> None:
+        if event.endswith("backend_compile_duration"):
+            if getattr(_HOOK_TLS, "cache_hit", False):
+                _HOOK_TLS.cache_hit = False
+                COMPILE_PERSISTENT_HITS.inc()
+            else:
                 COMPILE_MISSES.inc()
                 COMPILE_SECONDS.observe(duration)
 
-        _mon.register_event_duration_secs_listener(_on_duration)
-    except Exception:   # noqa: BLE001 - older jax without monitoring
-        pass
+    _mon.register_event_listener(_on_event)
+    _mon.register_event_duration_secs_listener(_on_duration)
 
 
 _install_jax_hooks()

@@ -293,9 +293,6 @@ def _dispatch(name: str, impl: Callable, arrays, record: bool,
     if not eager_only and _should_use_exec_cache(arrays):
         result = _cached_exec(name, impl, arrays, record)
         if result is not None:
-            outs = result[0] if record else result
-            for o in (outs if isinstance(outs, (tuple, list)) else (outs,)):
-                engine.mark_clean(o)
             if record:
                 return result[0], result[1], True
             return result, None, True

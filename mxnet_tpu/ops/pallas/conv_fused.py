@@ -38,10 +38,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# CompilerParams was named TPUCompilerParams before jax 0.5
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _prec(dtype):
     """bf16 MXU passes for low-precision inputs, exact fp32 for f32 —
@@ -201,7 +197,7 @@ def _fwd(x3, scale2, shift2, w, relu, interpret, bias2=None,
         scratch_shapes=([] if n_ci == 1 else
                         [pltpu.VMEM((block_co, block_m), jnp.float32)]),
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=(
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
     )(*args)
     return y
@@ -291,7 +287,7 @@ def _dgrad(x3, scale2, shift2, w, dy3, relu, interpret,
         scratch_shapes=([] if n_co == 1 else
                         [pltpu.VMEM((block_ci, block_m), jnp.float32)]),
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=(
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
     )(*args)
     return da
@@ -379,7 +375,7 @@ def _wgrad(x3, scale2, shift2, dy3, relu, interpret, out_dtype,
         out_shape=jax.ShapeDtypeStruct((Co, Ci), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_co, block_ci), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=(
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "arbitrary", "arbitrary")),
     )(*args)
     return dw

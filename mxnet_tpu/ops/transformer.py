@@ -116,12 +116,9 @@ def dot_product_attention(query, key, value, mask=None,
                                   scale=sc, causal=cz, bias=bias,
                                   dropout=train_rate, dropout_seed=seed)
         if use_flash and _flash_bias_ok(bias, q, k):
-            from .pallas.attention import flash_attention
-            return flash_attention(
-                q, k, v, scale=sc, causal=cz, bias=bias,
-                block_q=blk_q, block_k=blk_k,
-                dropout=train_rate, dropout_seed=seed,
-                bias_grad=mask_learned)
+            return _flash(q, k, v, bias, seed, scale=sc, causal=cz,
+                          block_q=blk_q, block_k=blk_k,
+                          dropout=train_rate, bias_grad=mask_learned)
         if train_rate > 0.0:
             from .pallas.attention import dense_dropout_attention_bhtd
             import math as _math
@@ -134,6 +131,77 @@ def dot_product_attention(query, key, value, mask=None,
             q, k, v, bias=bias, scale=sc, is_causal=cz)
 
     return invoke("dot_product_attention", impl, inputs)
+
+
+def _flash(q, k, v, bias, seed, *, dropout, **kw):
+    """The flash kernel over (B, T, H, D) operands, under whatever mesh
+    the step is being compiled for.
+
+    GSPMD cannot partition a Mosaic custom call, so on a multi-device
+    mesh (``parallel.mesh.kernel_mesh``, entered by SPMDTrainer) the
+    call is shard_mapped: batch over the data axes and heads over ``tp``
+    where they divide, every other axis replicated.  Attention is
+    independent per (batch, head), so each shard is a whole problem.
+    Inside an enclosing shard_map (ring / pipeline) the operands are
+    already per-device and the kernel is called directly."""
+    from .pallas.attention import flash_attention
+    from ..parallel.mesh import current_kernel_mesh
+
+    def call(q, k, v, bias, seed):
+        return flash_attention(q, k, v, bias=bias, dropout=dropout,
+                               dropout_seed=seed, **kw)
+
+    km = current_kernel_mesh()
+    if km is None or km[0].size == 1 or \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        return call(q, k, v, bias, seed)
+    mesh, batch_axes = km
+    B, H = q.shape[0], q.shape[2]
+
+    def fit(axes, dim):
+        keep = tuple(a for a in axes if a in mesh.axis_names)
+        n = 1
+        for a in keep:
+            n *= mesh.shape[a]
+        return keep if keep and dim % n == 0 else None
+
+    bax, hax = fit(batch_axes, B), fit(("tp",), H)
+    P = jax.sharding.PartitionSpec
+    spec = P(bax, None, hax, None)
+    args, in_specs = [q, k, v], [spec, spec, spec]
+    if bias is not None:            # (B|1, H|1, Tq|1, Tk)
+        args.append(bias)
+        in_specs.append(P(bax if bias.shape[0] > 1 else None,
+                          hax if bias.shape[1] > 1 else None, None, None))
+    has_seed = seed is not None
+    if has_seed:
+        args.append(seed)
+        in_specs.append(P())
+
+    def local(q, k, v, *rest):
+        rest = list(rest)
+        seed = rest.pop() if has_seed else None
+        if has_seed:
+            # the kernel seeds each tile from (seed, b, h, iq, ik) with
+            # LOCAL b/h: shift by this shard's global offsets so shards
+            # draw the masks the unsharded kernel would, not each other's
+            b0 = _shard_offset(mesh, bax, q.shape[0])
+            h0 = _shard_offset(mesh, hax, q.shape[2])
+            from .pallas.attention import dropout_seed_at
+            seed = dropout_seed_at(seed, b0, h0)
+        return call(q, k, v, rest[0] if rest else None, seed)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=spec, check_vma=False)(*args)
+
+
+def _shard_offset(mesh, axes, local_size: int):
+    """Global start index of this shard along a dim sharded over
+    ``axes`` (major-to-minor, as PartitionSpec orders them)."""
+    idx = 0
+    for a in axes or ():
+        idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
+    return idx * local_size
 
 
 def _flash_block(which: str, seq: int = 0) -> int:
@@ -232,14 +300,10 @@ def _flash_threshold() -> int:
 
 
 def _use_pallas_len(seq_len: int) -> bool:
-    import jax as _jax
     if getenv("MXNET_ATTENTION_USE_PALLAS", 0):
         return True
-    try:
-        on_tpu = _jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
-    return on_tpu and seq_len >= _flash_threshold()
+    return jax.default_backend() != "cpu" and \
+        seq_len >= _flash_threshold()
 
 
 def multi_head_attention(query, key, value, num_heads: int, mask=None,
@@ -284,12 +348,9 @@ def multi_head_attention(query, key, value, num_heads: int, mask=None,
                                  scale=sc, causal=cz, bias=bias,
                                  dropout=train_rate, dropout_seed=seed)
         elif use_flash and _flash_bias_ok(bias, qh, kh):
-            from .pallas.attention import flash_attention
-            out = flash_attention(
-                qh, kh, vh, scale=sc, causal=cz, bias=bias,
-                block_q=blk_q, block_k=blk_k,
-                dropout=train_rate, dropout_seed=seed,
-                bias_grad=mask_learned)
+            out = _flash(qh, kh, vh, bias, seed, scale=sc, causal=cz,
+                         block_q=blk_q, block_k=blk_k,
+                         dropout=train_rate, bias_grad=mask_learned)
         elif train_rate > 0.0:
             from .pallas.attention import dense_dropout_attention_bhtd
             import math as _math

@@ -15,6 +15,7 @@ collectives ride ICI. The analog of jax's
 """
 from __future__ import annotations
 
+import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as _np
@@ -23,7 +24,37 @@ import jax
 from ..base import MXNetError
 
 __all__ = ["make_mesh", "mesh_axes", "replicated", "shard_batch",
-           "slice_groups"]
+           "slice_groups", "kernel_mesh", "current_kernel_mesh"]
+
+# (mesh, batch axes) the step THIS thread is tracing compiles for:
+# Mosaic kernels cannot be partitioned by GSPMD, so ops that call one
+# consult this and wrap the call in a shard_map (set by SPMDTrainer).
+_kernel_state = threading.local()
+
+
+class kernel_mesh:
+    """Context manager: while active, ops that lower to a Pallas kernel
+    shard_map it over ``mesh`` — batch over ``batch_axes``, heads over
+    ``tp``.  SPMDTrainer enters this around its traced forward whenever
+    the mesh has more than one device."""
+
+    def __init__(self, mesh: "jax.sharding.Mesh",
+                 batch_axes: Sequence[str] = ("dp",)) -> None:
+        self._active = (mesh, tuple(batch_axes))
+        self._prev = None
+
+    def __enter__(self) -> "kernel_mesh":
+        self._prev = current_kernel_mesh()
+        _kernel_state.active = self._active
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _kernel_state.active = self._prev
+
+
+def current_kernel_mesh():
+    """(mesh, batch_axes) if a kernel_mesh context is active, else None."""
+    return getattr(_kernel_state, "active", None)
 
 
 def slice_groups(devices: Sequence) -> List[List]:

@@ -26,14 +26,8 @@ __all__ = ["pipeline_apply", "pipeline_train_grads", "GPTPipe",
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    try:
-        from jax import shard_map
-        kw = {"check_vma": False}
-    except ImportError:     # jax < 0.8
-        from jax.experimental.shard_map import shard_map
-        kw = {"check_rep": False}
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kw)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def pipeline_apply(stage_fn: Callable, stage_params: Any, x: "jax.Array",
@@ -599,9 +593,7 @@ class GPTPipe(HybridBlock):
             return arr
         arr = jax.device_put(arr, sh)
         nd._data = arr
-        from .. import engine
         from ..ndarray.register import mark_mesh_resident
-        engine.mark_clean(arr)
         if sh.num_devices > 1:
             mark_mesh_resident(nd)   # wrapper outlives per-step buffers
         return arr

@@ -205,12 +205,12 @@ def _flash_ring(q, k, v, bias, axis_name, n_shards, scale, causal):
 def _flash_ring_fwd(q, k, v, bias, axis_name, n_shards, scale, causal):
     """q/k/v: (B, Tl, H, D) local shards; bias: (B|1, Tl|1, H|1, Tk_g)
     row stripe or None. Returns (out, residuals)."""
-    from ..ops.pallas.attention import (_interpret_for, DEFAULT_BLOCK_Q,
+    from ..ops.pallas.attention import (_interpret, DEFAULT_BLOCK_Q,
                                         DEFAULT_BLOCK_K)
     B, Tl, H, D = q.shape
     Tk = k.shape[1]
     my = jax.lax.axis_index(axis_name)
-    interpret = _interpret_for(q)
+    interpret = _interpret()
     # final block legalization happens inside the kernels; this is just
     # the requested upper bound
     blk_cfg = (min(DEFAULT_BLOCK_Q, Tl), min(DEFAULT_BLOCK_K, Tk),
@@ -250,13 +250,13 @@ def _flash_ring_fwd(q, k, v, bias, axis_name, n_shards, scale, causal):
 
 
 def _flash_ring_bwd(axis_name, n_shards, scale, causal, res, g):
-    from ..ops.pallas.attention import (_interpret_for, DEFAULT_BLOCK_Q,
+    from ..ops.pallas.attention import (_interpret, DEFAULT_BLOCK_Q,
                                         DEFAULT_BLOCK_K)
     q, k, v, bias, out, lse = res
     B, Tl, H, D = q.shape
     Tk = k.shape[1]
     my = jax.lax.axis_index(axis_name)
-    interpret = _interpret_for(q)
+    interpret = _interpret()
     blk_cfg = (min(DEFAULT_BLOCK_Q, Tl), min(DEFAULT_BLOCK_K, Tk),
                interpret)
     q_h = jnp.swapaxes(q, 1, 2)
@@ -459,14 +459,8 @@ def ring_attention(q, k, v, mesh: "jax.sharding.Mesh", axis: str = "sp",
             causal=causal, bias=rest[0] if rest else None,
             dropout=dropout, dropout_key=key, use_flash=use_flash)
 
-    try:
-        from jax import shard_map
-        kw = {"check_vma": False}
-    except ImportError:     # jax < 0.8
-        from jax.experimental.shard_map import shard_map
-        kw = {"check_rep": False}
-    return shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=spec, **kw)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=spec, check_vma=False)(*args)
 
 
 def _dense(q, k, v, scale, causal, bias=None, dropout: float = 0.0,

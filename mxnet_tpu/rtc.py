@@ -32,10 +32,7 @@ __all__ = ["PallasModule", "CudaModule"]
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 class PallasModule:

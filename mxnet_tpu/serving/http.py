@@ -198,7 +198,13 @@ class _Handler(BaseHTTPRequestHandler):
             # load shows both)
             self._reply(200, _tracing.export_trace_events())
         elif path == "/v1/model":
+            import jax
+            from .._native import LIB
+            from ..runtime import device_info
             out = (self._ms.describe() if self._ms is not None else {})
+            out["device"] = device_info()
+            out["runtime"] = {"jax": jax.__version__,
+                              "libmxtpu": LIB is not None}
             if self._gs is not None:
                 out["generation"] = self._gs.describe()
             self._reply(200, out)

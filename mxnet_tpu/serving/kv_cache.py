@@ -327,6 +327,9 @@ class PagedKVCache:
             "heads": self.n_heads,
             "head_dim": self.head_dim,
             "dtype": str(self.dtype),
+            # where the buffers actually live, not where they were asked
+            "platforms": sorted({d.platform for b in self._k + self._v
+                                 for d in b.devices()}),
             "prefix_cache": self.prefix.describe(),
         }
 

@@ -865,6 +865,7 @@ class DecodeModel:
         return n
 
     def describe(self) -> Dict[str, Any]:
+        import jax
         with self._seen_lock:
             seen = sorted(self._seen)
         return {
@@ -876,6 +877,10 @@ class DecodeModel:
             "heads": self.num_heads,
             "max_length": self.max_length,
             "dtype": str(self.dtype),
+            "param_platforms": sorted({
+                d.platform
+                for a in jax.tree_util.tree_leaves(self.params)
+                if isinstance(a, jax.Array) for d in a.devices()}),
             "programs_compiled": seen,
         }
 

@@ -462,8 +462,7 @@ _TRAINER = textwrap.dedent("""
                           mesh=make_mesh({{"dp": 1}},
                                          devices=jax.devices()[:1]))
     from mxnet_tpu.ndarray import random as _random
-    from mxnet_tpu import engine as _engine
-    _random.split_key(); _engine.launder([jnp.float32(0.0)])
+    _random.split_key()
     c0 = _m.COMPILE_MISSES.value
     losses = []
     for s in range(4):

@@ -133,6 +133,15 @@ def test_context_placement():
     assert x[0, 0].item() == 1.0  # copy is deep
 
 
+def test_accelerator_context_raises_without_accelerator():
+    """No quiet CPU stand-in: on a CPU-only backend mx.tpu()/mx.gpu()
+    name what jax found instead of resolving to a host device."""
+    for ctx in (mx.tpu(), mx.gpu(), mx.Context("tpu", 1)):
+        with pytest.raises(mx.MXNetError, match="cpu"):
+            ctx.jax_device
+    assert mx.current_context().device_type == "cpu"   # auto still works
+
+
 def test_sync_and_wait():
     x = mx.np.ones((8, 8))
     y = mx.np.dot(x, x)

@@ -67,14 +67,11 @@ def _child_train() -> None:
                           {"learning_rate": 0.05},
                           mesh=make_mesh({"dp": 1},
                                          devices=jax.devices()[:1]))
-    # prime the shape-independent per-step helpers OUTSIDE the window
-    # (split_key / committed-scalar launder compile once per process,
-    # in microseconds — restart cost lives in the step program)
-    import jax.numpy as jnp
-    from mxnet_tpu import engine as _engine
+    # prime the shape-independent per-step helper OUTSIDE the window
+    # (split_key compiles once per process, in microseconds — restart
+    # cost lives in the step program)
     from mxnet_tpu.ndarray import random as _random
     _random.split_key()
-    _engine.launder([jnp.float32(0.0)])
 
     def batch(step):
         rng = onp.random.RandomState(100 + step)

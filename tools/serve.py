@@ -158,6 +158,11 @@ def main(argv=None) -> None:
         jax.config.update("jax_platforms", "cpu")
 
     from mxnet_tpu import serving
+    from mxnet_tpu.runtime import device_info
+
+    dev = device_info()
+    print(f"device: platform={dev['platform']} kind={dev['kind']} "
+          f"count={dev['count']}", flush=True)
 
     if args.generate:
         return _serve_generate(args, serving)
