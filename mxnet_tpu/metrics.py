@@ -715,6 +715,12 @@ GEN_TTFT_SECONDS = histogram(
     "Time-to-first-token per generation request: submit to the first "
     "streamed token (queue wait + prefill).",
     buckets=exponential_buckets(0.001, 2.0, 14))
+GEN_QUEUE_WAIT_SECONDS = histogram(
+    "mxnet_gen_queue_wait_seconds",
+    "Seconds a generation request waited in the admission queue: "
+    "submit to the pop that hands it to prefill (the slot wait; TTFT "
+    "minus this is the admission itself).",
+    buckets=exponential_buckets(0.001, 2.0, 14))
 GEN_ITERATIONS_TOTAL = counter(
     "mxnet_gen_iterations_total",
     "Decode-loop iterations executed (each runs the resident decode "

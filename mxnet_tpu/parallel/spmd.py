@@ -670,6 +670,13 @@ class SPMDTrainer:
         same NDArray (``asnumpy``, eager ops, metrics) must use a separate
         copy of the data.
         """
+        # a real span: a caller's own loop over step() has no trace, so
+        # this roots one (inside fit() it nests under train.step) and
+        # step.place / step.dispatch below are recorded for everyone
+        with _tracing.span("spmd.step", step=self._step_count):
+            return self._step(data, labels)
+
+    def _step(self, data: Any, labels: Any) -> NDArray:
         import time
         from .. import metrics as _metrics
         inputs = data if isinstance(data, (list, tuple)) else [data]

@@ -582,13 +582,16 @@ class SlotScheduler:
                     continue
                 if len(out) < free_slots:
                     out.append(r)
+                    # submit -> admission pop = the slot wait
+                    wait = now - r.enqueue_t
                     tr = getattr(r, "trace", None)
+                    _metrics.GEN_QUEUE_WAIT_SECONDS.observe(
+                        wait, exemplar=tr.trace_id if tr is not None
+                        else None)
                     if tr is not None:
-                        # submit -> admission pop = the slot wait
                         pc = time.perf_counter()
-                        _tracing.record_span(
-                            "queue.wait", pc - (now - r.enqueue_t),
-                            pc, ctx=tr)
+                        _tracing.record_span("queue.wait", pc - wait,
+                                             pc, ctx=tr)
                 else:
                     keep.append(r)
             self._q[:] = keep

@@ -359,7 +359,9 @@ class GenRequest:
         self.recoveries = 0     # resurrections so far (budgeted by the
         #                         server against restart churn)
         # trace context captured at submit; the engine thread attaches
-        # it so queue-wait/prefill spans land in the request's trace
+        # it so queue-wait/prefill spans land in the request's trace.
+        # A request constructed under no trace gets one of its own when
+        # it is submitted (GenerationEngine._enqueue)
         self.trace = _tracing.capture()
 
     # scheduler duck-type
@@ -713,51 +715,100 @@ class GenerationEngine:
                          method=method, temperature=temperature,
                          top_k=top_k, top_p=top_p, seed=seed,
                          speculative=spec)
-        # consumer cancel while still queued -> evict NOW (queue budget
-        # frees immediately; an abandoned-request flood cannot hold
-        # queue_full sheds high until the next admission pass)
-        req.stream._on_cancel = lambda: self.scheduler.discard(req)
-        self.scheduler.submit(req)      # raises OverloadError on shed
+        self._enqueue(req)              # raises OverloadError on shed
         return req.stream
 
     def submit_request(self, req: GenRequest, front: bool = False) -> None:
         """Install an already-accepted request (the recovery path):
         bypasses the queue_full shed — the request was admitted once
         and must complete or fail structurally, never re-shed."""
+        self._enqueue(req, front=front, force=True)
+
+    def _enqueue(self, req: GenRequest, **how: Any) -> None:
+        if req.trace is None:
+            # submitted in-process under no trace: queue.wait and
+            # engine.prefill still need a trace to land in
+            req.trace = _tracing.root_context()
+        # consumer cancel while still queued -> evict NOW (queue budget
+        # frees immediately; an abandoned-request flood cannot hold
+        # queue_full sheds high until the next admission pass)
         req.stream._on_cancel = lambda: self.scheduler.discard(req)
-        self.scheduler.submit(req, front=front, force=True)
+        self.scheduler.submit(req, **how)
 
     # -- the scheduling quantum ---------------------------------------------
     def run_iteration(self) -> bool:
-        """Retire -> admit -> decode, once.  Returns True when any work
-        happened (False = idle: nothing active, nothing admissible)."""
-        from .. import faults as _faults
-        from .. import health as _health
-
+        """Retire -> admit -> decode -> emit, once.  Returns True when
+        any work happened (False = idle: nothing active, nothing
+        admissible)."""
         self._iter += 1
         log: Dict[str, Any] = {"iter": self._iter, "admitted": [],
                                "retired": [], "decoded": []}
+        active: Dict[int, GenRequest] = {}
+        decoding = False
+        # The iteration span covers the whole quantum, so what is left
+        # of it once its children are taken out (retire, the queue pop,
+        # slot-table and sampling-lane bookkeeping, counters) is the
+        # engine's own host time.  It is its own (head-sampled) trace —
+        # one step serves MANY requests, so it cannot be a child of any
+        # one of them; instead it LINKS every resident request's trace
+        # id, and a request's trace finds "its" decode steps by
+        # searching iteration spans that link it.
+        try:
+            with _tracing.span("engine.iteration", iter=self._iter) as isp:
+                self._retire_finished(log)
+                self._admit_pending(log)
+                active = self.scheduler.active()
+                _metrics.GEN_SLOTS_ACTIVE.set(len(active))
+                isp.set_attr(slots=len(active),
+                             admitted=len(log["admitted"]),
+                             retired=len(log["retired"]), tokens=0)
+                if not active:
+                    self.cache.reset_if_empty()
+                    if self._draft is not None:
+                        self._draft.reset_if_empty()
+                    self.iteration_log.append(log)
+                    return bool(log["admitted"] or log["retired"])
+                for req in active.values():
+                    if req.trace is not None:
+                        isp.add_link(req.trace.trace_id)
+                decoding = True
+                next_tok, spec = self._decode(active)
+                decoding = False
+                with _tracing.child_span("engine.emit") as esp:
+                    n_streamed = self._emit(active, next_tok, spec, log)
+                    esp.set_attr(tokens=n_streamed)
+                isp.set_attr(tokens=n_streamed)
+        except Exception as e:   # noqa: BLE001 - a decode fault is the
+            # in-flight sequences' alone; anything else is the worker's
+            if not decoding:
+                raise
+            self._decode_fault(active, e, log)
+            return True
+        self.iteration_log.append(log)
+        return True
 
-        # 1. retire: EOS/max-tokens were marked at the previous decode;
-        #    cancelled consumers release their slot here too.  The
-        #    producer-side `finished` flag, NOT `done`: a finished
-        #    sequence must free its slot even while its consumer is
-        #    still draining buffered tokens
+    def _retire_finished(self, log: Dict[str, Any]) -> None:
+        """EOS/max-tokens were marked at the previous decode; cancelled
+        consumers release their slot here too.  The producer-side
+        `finished` flag, NOT `done`: a finished sequence must free its
+        slot even while its consumer is still draining buffered
+        tokens."""
         for slot, req in self.scheduler.active().items():
             if req.stream.finished or req.is_cancelled():
                 self._retire(slot, req,
                              req.stream.finish_reason or "cancelled")
                 log["retired"].append(slot)
 
-        # 2. admit into free slots (prefill, one compiled program per
-        #    prompt bucket).  Always visit the queue — with zero free
-        #    slots pop_admissions(0) admits nothing but STILL sheds
-        #    queued requests whose deadline passed ("no slot freed
-        #    within the deadline" is the generation overload signal).
-        #    Mid-admission requests ride self._in_admission so a
-        #    worker death during prefill still evacuates them (they
-        #    are in neither the queue nor the slot table), and the
-        #    scheduler's mid-admission count keeps drain polls honest.
+    def _admit_pending(self, log: Dict[str, Any]) -> None:
+        """Admit into free slots (prefill, one compiled program per
+        prompt bucket).  Always visit the queue — with zero free slots
+        pop_admissions(0) admits nothing but STILL sheds queued
+        requests whose deadline passed ("no slot freed within the
+        deadline" is the generation overload signal).  Mid-admission
+        requests ride self._in_admission so a worker death during
+        prefill still evacuates them (they are in neither the queue nor
+        the slot table), and the scheduler's mid-admission count keeps
+        drain polls honest."""
         free = self.cache.free_slots()
         pending = self.scheduler.pop_admissions(len(free))
         self._in_admission = list(pending)
@@ -784,126 +835,109 @@ class GenerationEngine:
             self._in_admission.remove(req)
             self.scheduler.admission_done()
 
-        active = self.scheduler.active()
-        _metrics.GEN_SLOTS_ACTIVE.set(len(active))
-        if not active:
-            self.cache.reset_if_empty()
-            if self._draft is not None:
-                self._draft.reset_if_empty()
-            self.iteration_log.append(log)
-            return bool(log["admitted"] or log["retired"])
-
-        # 3. one resident decode step over EVERY active slot.  The
-        #    iteration span is its own (head-sampled) trace — one step
-        #    serves MANY requests, so it cannot be a child of any one
-        #    of them; instead it LINKS every resident request's trace
-        #    id, and a request's trace finds "its" decode steps by
-        #    searching iteration spans that link it.
-        #    When any resident request speculates, the WHOLE iteration
-        #    rides the draft+verify pair (one program each): the draft
-        #    proposes k tokens per slot, verify scores all k+1
-        #    positions in one target pass, and plain slots simply keep
-        #    only the first verified token — which is bit-identical to
-        #    what the plain step would have produced.
+    def _decode(self, active: Dict[int, "GenRequest"]
+                ) -> Tuple[Any, Optional[Tuple[Any, ...]]]:
+        """One resident decode step over EVERY active slot: returns
+        ``(next_tok, None)``, or ``(None, (verified, candidates,
+        speculating slots, k))`` from a speculative iteration.  When
+        any resident request speculates, the WHOLE iteration rides the
+        draft+verify pair (one program each): the draft proposes k
+        tokens per slot, verify scores all k+1 positions in one target
+        pass, and plain slots simply keep only the first verified token
+        — which is bit-identical to what the plain step would have
+        produced."""
+        from .. import faults as _faults
+        from .. import health as _health
         spec_k = self._draft.k if self._draft is not None else 0
         spec_slots = frozenset(
             s for s, r in active.items()
             if spec_k and getattr(r, "speculative", False))
         use_spec = bool(spec_slots)
-        iter_tid = None
-        try:
-            with _tracing.span("engine.iteration", iter=self._iter,
-                               slots=len(active)) as isp:
-                iter_tid = _tracing.current_trace_id()
-                for _r in active.values():
-                    _tr = getattr(_r, "trace", None)
-                    if _tr is not None:
-                        isp.add_link(_tr.trace_id)
-                _faults.maybe_fault("serving.execute", phase="decode",
-                                    slots=len(active))
-                if use_spec:
-                    # verify scatters k rows past every slot's
-                    # position: grow for the worst case up front,
-                    # capped at the grid top (rows past it belong to
-                    # tokens the submit-time budget check proves are
-                    # never emitted)
-                    self.cache.ensure_capacity(
-                        min(self.cache.needed_capacity() + spec_k,
-                            self.grid[-1]))
-                else:
-                    self.cache.ensure_capacity(
-                        self.cache.needed_capacity())
-                pos = _np.maximum(self.cache.positions,
-                                  0).astype(_np.int32)
-                if self._samp_dev is None:
-                    self._samp_dev = self.model.device_sampling(
-                        self._samp)
-                if use_spec:
-                    with _tracing.child_span(
-                            "engine.draft",
-                            slots=len(spec_slots), k=spec_k):
-                        drafts = self._draft.propose(
-                            self.cache, self._last_tok, pos,
-                            self._samp_dev)
-                    cand = _np.concatenate(
-                        [self._last_tok[:, None],
-                         _np.asarray(drafts, _np.int32)], axis=1)
-                    with _health.watch_section("generation.step",
-                                               slots=len(active)):
-                        with _tracing.child_span(
-                                "engine.verify",
-                                slots=len(active), k=spec_k):
-                            ver = self.model.verify(
-                                self.cache, cand, pos,
-                                self._samp_dev)
-                else:
-                    with _health.watch_section("generation.step",
-                                               slots=len(active)):
-                        next_tok = self.model.step(self.cache,
-                                                   self._last_tok,
-                                                   pos, self._samp_dev)
-        except Exception as e:   # noqa: BLE001 - an iteration fault
-            # hits exactly the sequences IN FLIGHT at this iteration
-            # (their kv rows are suspect); queued requests and the
-            # engine itself are unaffected.  The step consumed the KV
-            # buffers by donation, so a raise AFTER dispatch leaves the
-            # cache holding deleted arrays — reallocate before the next
-            # admission touches them
-            self.cache.reset_buffers()
-            if self._draft is not None:
-                # the draft's own buffers may have been donated to a
-                # dispatch this fault interrupted
-                self._draft.reset()
-            victims: List[GenRequest] = []
-            for slot, req in active.items():
-                if self.recovery_sink is not None \
-                        and not req.stream.finished \
-                        and not req.is_cancelled():
-                    # managed engine: the sequence is resurrected from
-                    # its stream transcript (exactly-once recovery) —
-                    # release the slot WITHOUT closing the stream
-                    self.scheduler.release(slot)
-                    self.cache.free(slot)
-                    if self._draft is not None:
-                        self._draft.release(slot)
-                    if self._samp[5][slot]:
-                        self._samp[5][slot] = 0
-                        self._samp_dev = None
-                    _metrics.GEN_RETIREMENTS_TOTAL.labels(
-                        reason="recovered").inc()
-                    victims.append(req)
-                else:
-                    req.fail(e)          # before close(): the consumer
-                    #                      must observe the fault, not
-                    #                      a clean end-of-stream
-                    self._retire(slot, req, "error")
-                    REQUESTS_TOTAL.labels(status="error").inc()
-                log["retired"].append(slot)
-            self.iteration_log.append(log)
-            if victims:
-                self.recovery_sink(victims, e, "decode")
-            return True
+        _faults.maybe_fault("serving.execute", phase="decode",
+                            slots=len(active))
+        if use_spec:
+            # verify scatters k rows past every slot's position: grow
+            # for the worst case up front, capped at the grid top (rows
+            # past it belong to tokens the submit-time budget check
+            # proves are never emitted)
+            self.cache.ensure_capacity(
+                min(self.cache.needed_capacity() + spec_k, self.grid[-1]))
+        else:
+            self.cache.ensure_capacity(self.cache.needed_capacity())
+        pos = _np.maximum(self.cache.positions, 0).astype(_np.int32)
+        if self._samp_dev is None:
+            self._samp_dev = self.model.device_sampling(self._samp)
+        if not use_spec:
+            with _health.watch_section("generation.step",
+                                       slots=len(active)):
+                return self.model.step(self.cache, self._last_tok, pos,
+                                       self._samp_dev), None
+        with _tracing.child_span("engine.draft", slots=len(spec_slots),
+                                 k=spec_k):
+            drafts = self._draft.propose(self.cache, self._last_tok, pos,
+                                         self._samp_dev)
+        cand = _np.concatenate(
+            [self._last_tok[:, None], _np.asarray(drafts, _np.int32)],
+            axis=1)
+        with _health.watch_section("generation.step", slots=len(active)):
+            with _tracing.child_span("engine.verify", slots=len(active),
+                                     k=spec_k):
+                ver = self.model.verify(self.cache, cand, pos,
+                                        self._samp_dev)
+        return None, (ver, cand, spec_slots, spec_k)
 
+    def _decode_fault(self, active: Dict[int, "GenRequest"],
+                      e: Exception, log: Dict[str, Any]) -> None:
+        """An iteration fault hits exactly the sequences IN FLIGHT at
+        this iteration (their kv rows are suspect); queued requests and
+        the engine itself are unaffected.  The step consumed the KV
+        buffers by donation, so a raise AFTER dispatch leaves the cache
+        holding deleted arrays — reallocate before the next admission
+        touches them."""
+        self.cache.reset_buffers()
+        if self._draft is not None:
+            # the draft's own buffers may have been donated to a
+            # dispatch this fault interrupted
+            self._draft.reset()
+        victims: List[GenRequest] = []
+        for slot, req in active.items():
+            if self.recovery_sink is not None \
+                    and not req.stream.finished \
+                    and not req.is_cancelled():
+                # managed engine: the sequence is resurrected from
+                # its stream transcript (exactly-once recovery) —
+                # release the slot WITHOUT closing the stream
+                self.scheduler.release(slot)
+                self.cache.free(slot)
+                if self._draft is not None:
+                    self._draft.release(slot)
+                if self._samp[5][slot]:
+                    self._samp[5][slot] = 0
+                    self._samp_dev = None
+                _metrics.GEN_RETIREMENTS_TOTAL.labels(
+                    reason="recovered").inc()
+                victims.append(req)
+            else:
+                req.fail(e)          # before close(): the consumer
+                #                      must observe the fault, not
+                #                      a clean end-of-stream
+                self._retire(slot, req, "error")
+                REQUESTS_TOTAL.labels(status="error").inc()
+            log["retired"].append(slot)
+        self.iteration_log.append(log)
+        if victims:
+            self.recovery_sink(victims, e, "decode")
+
+    def _emit(self, active: Dict[int, "GenRequest"], next_tok: Any,
+              spec: Optional[Tuple[Any, ...]], log: Dict[str, Any]
+              ) -> int:
+        """Hand each slot's new token(s) to its stream, mark finished
+        sequences (they retire at the next iteration), count; returns
+        the tokens streamed."""
+        iter_tid = _tracing.current_trace_id()
+        use_spec = spec is not None
+        if use_spec:
+            ver, cand, spec_slots, spec_k = spec
         now = time.monotonic()
         n_streamed = 0
         it_proposed = it_accepted = 0
@@ -1008,8 +1042,7 @@ class GenerationEngine:
                 total = sum(n for _, n in self._tps_window) \
                     - self._tps_window[0][1]
                 _metrics.GEN_TOKENS_PER_SECOND.set(total / span)
-        self.iteration_log.append(log)
-        return True
+        return n_streamed
 
     def _lookup_prefix(self, req: GenRequest) -> Optional[Any]:
         """The longest resident prefix of ``req``'s prompt (pinned —

@@ -34,6 +34,7 @@ import numpy as _np
 
 from ..base import MXNetError, getenv, register_env
 from .. import metrics as _metrics
+from .. import tracing as _tracing
 
 __all__ = ["PagedKVCache", "PrefixCache", "kv_bucket_grid",
            "round_up_bucket"]
@@ -183,18 +184,20 @@ class PagedKVCache:
         the slot's resident-token count after the write.  Grows the
         cache first if the rows exceed the current bucket."""
         Lp = int(ks[0].shape[0])
-        if int(start) + Lp > self.bucket:
-            self.grow(round_up_bucket(int(start) + Lp, self.grid))
-        # ONE dispatch writes every layer's K and V row: per-call
-        # dispatch overhead is what dominates a row copy on small
-        # hosts, so 2L separate writes would bury the prefix cache's
-        # TTFT win under launch latency (see _make_write_rows for why
-        # the write is not donated)
-        out = _write_rows_jit(self._k + self._v,
-                              list(ks) + list(vs),
-                              _np.int32(slot), _np.int32(start))
-        self._k = out[:self.n_layers]
-        self._v = out[self.n_layers:]
+        with _tracing.child_span("kv.write_prompt", rows=Lp,
+                                 bucket=self.bucket):
+            if int(start) + Lp > self.bucket:
+                self.grow(round_up_bucket(int(start) + Lp, self.grid))
+            # ONE dispatch writes every layer's K and V row: per-call
+            # dispatch overhead is what dominates a row copy on small
+            # hosts, so 2L separate writes would bury the prefix
+            # cache's TTFT win under launch latency (see
+            # _make_write_rows for why the write is not donated)
+            out = _write_rows_jit(self._k + self._v,
+                                  list(ks) + list(vs),
+                                  _np.int32(slot), _np.int32(start))
+            self._k = out[:self.n_layers]
+            self._v = out[self.n_layers:]
         self.positions[slot] = int(t0)
 
     # -- rollback -----------------------------------------------------------

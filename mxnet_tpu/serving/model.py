@@ -33,6 +33,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as _np
 
 from ..base import MXNetError
+from .. import metrics as _metrics
+from .. import tracing as _tracing
 from .batching import BUCKET_COMPILES, BucketPolicy, INFER_SECONDS
 
 __all__ = ["ServedModel", "DecodeModel", "load_served"]
@@ -217,7 +219,6 @@ class ServedModel:
             BUCKET_COMPILES.labels(bucket=_sig_str(shapes)).inc()
         t0 = time.perf_counter()
         out = self._fn(arrays)
-        from .. import tracing as _tracing
         INFER_SECONDS.observe(time.perf_counter() - t0,
                               exemplar=_tracing.current_trace_id())
         return out
@@ -637,15 +638,14 @@ class DecodeModel:
         padded = _np.zeros((bucket_len,), _np.int32)
         padded[:t0] = toks
         self._account(f"prefill:{bucket_len}")
-        t = time.perf_counter()
-        logits, ks, vs = self._prefill_fn(
-            self.params, jnp.asarray(padded), _np.int32(t0))
-        out = _np.asarray(logits)
-        from .. import metrics as _metrics
-        from .. import tracing as _tracing
+        with _tracing.child_span("model.prefill", bucket=bucket_len):
+            t = time.perf_counter()
+            logits, ks, vs = self._prefill_fn(
+                self.params, jnp.asarray(padded), _np.int32(t0))
+            out = _np.asarray(logits)
+            dt = time.perf_counter() - t
         _metrics.GEN_STEP_SECONDS.labels(phase="prefill").observe(
-            time.perf_counter() - t,
-            exemplar=_tracing.current_trace_id())
+            dt, exemplar=_tracing.current_trace_id())
         return out, ks, vs
 
     def greedy_sampling(self, n_slots: int) -> Tuple[_np.ndarray, ...]:
@@ -696,19 +696,25 @@ class DecodeModel:
             sampling = self.device_sampling(sampling)
         seeds, bases, temps, topks, topps, methods = sampling
         self._account(f"decode:{S}x{cache.bucket}")
-        t = time.perf_counter()
-        toks, new_ks, new_vs = self._step_fn(
-            self.params, cache._k, cache._v,
-            jnp.asarray(_np.asarray(tokens, _np.int32)),
-            jnp.asarray(_np.asarray(positions, _np.int32)),
-            seeds, bases, temps, topks, topps, methods)
-        cache.replace(new_ks, new_vs)
-        out = _np.asarray(toks)
-        from .. import metrics as _metrics
-        from .. import tracing as _tracing
+        # dispatch (the two uploads, the jitted call returning, the new
+        # buffers installed) and readback (the host waiting for the
+        # tokens) tile the step: the first is host-serial, the second
+        # is the device's time
+        with _tracing.child_span("model.step", slots=S,
+                                 bucket=cache.bucket):
+            t = time.perf_counter()
+            with _tracing.child_span("model.step.dispatch"):
+                toks, new_ks, new_vs = self._step_fn(
+                    self.params, cache._k, cache._v,
+                    jnp.asarray(_np.asarray(tokens, _np.int32)),
+                    jnp.asarray(_np.asarray(positions, _np.int32)),
+                    seeds, bases, temps, topks, topps, methods)
+                cache.replace(new_ks, new_vs)
+            with _tracing.child_span("model.step.readback"):
+                out = _np.asarray(toks)
+            dt = time.perf_counter() - t
         _metrics.GEN_STEP_SECONDS.labels(phase="decode").observe(
-            time.perf_counter() - t,
-            exemplar=_tracing.current_trace_id())
+            dt, exemplar=_tracing.current_trace_id())
         return out
 
     def verify(self, cache: Any, tokens: _np.ndarray,
@@ -740,19 +746,19 @@ class DecodeModel:
             sampling = self.device_sampling(sampling)
         seeds, bases, temps, topks, topps, methods = sampling
         self._account(f"verify:{S}x{cache.bucket}x{toks.shape[1]}")
-        t = time.perf_counter()
-        out_toks, new_ks, new_vs = self._verify_fn(
-            self.params, cache._k, cache._v,
-            jnp.asarray(toks),
-            jnp.asarray(_np.asarray(positions, _np.int32)),
-            seeds, bases, temps, topks, topps, methods)
-        cache.replace(new_ks, new_vs)
-        out = _np.asarray(out_toks)
-        from .. import metrics as _metrics
-        from .. import tracing as _tracing
+        with _tracing.child_span("model.verify", slots=S,
+                                 bucket=cache.bucket):
+            t = time.perf_counter()
+            out_toks, new_ks, new_vs = self._verify_fn(
+                self.params, cache._k, cache._v,
+                jnp.asarray(toks),
+                jnp.asarray(_np.asarray(positions, _np.int32)),
+                seeds, bases, temps, topks, topps, methods)
+            cache.replace(new_ks, new_vs)
+            out = _np.asarray(out_toks)
+            dt = time.perf_counter() - t
         _metrics.GEN_STEP_SECONDS.labels(phase="verify").observe(
-            time.perf_counter() - t,
-            exemplar=_tracing.current_trace_id())
+            dt, exemplar=_tracing.current_trace_id())
         return out
 
     def prefill_suffix(self, tokens: _np.ndarray, prefix_ks: List[Any],
@@ -776,16 +782,15 @@ class DecodeModel:
         padded[:t0] = toks
         Pb = int(prefix_ks[0].shape[0])
         self._account(f"prefill_sfx:{Pb}x{bucket_len}")
-        t = time.perf_counter()
-        logits, ks, vs = self._prefill_sfx_fn(
-            self.params, list(prefix_ks), list(prefix_vs),
-            jnp.asarray(padded), _np.int32(q), _np.int32(t0))
-        out = _np.asarray(logits)
-        from .. import metrics as _metrics
-        from .. import tracing as _tracing
+        with _tracing.child_span("model.prefill", bucket=bucket_len):
+            t = time.perf_counter()
+            logits, ks, vs = self._prefill_sfx_fn(
+                self.params, list(prefix_ks), list(prefix_vs),
+                jnp.asarray(padded), _np.int32(q), _np.int32(t0))
+            out = _np.asarray(logits)
+            dt = time.perf_counter() - t
         _metrics.GEN_STEP_SECONDS.labels(phase="prefill").observe(
-            time.perf_counter() - t,
-            exemplar=_tracing.current_trace_id())
+            dt, exemplar=_tracing.current_trace_id())
         return out, ks, vs
 
     def select(self, logits: _np.ndarray, seed: int, counter: int,
@@ -800,12 +805,13 @@ class DecodeModel:
         import jax.numpy as jnp
         # logits keep the model dtype: the step's sampler sees the
         # same representation, so the two paths stay bit-identical
-        tok = self._select_fn(
-            jnp.asarray(logits),
-            _np.int32(seed), _np.int32(counter),
-            _np.float32(temperature), _np.int32(top_k),
-            _np.float32(top_p), _np.int32(method))
-        return int(tok)
+        with _tracing.child_span("model.select"):
+            tok = self._select_fn(
+                jnp.asarray(logits),
+                _np.int32(seed), _np.int32(counter),
+                _np.float32(temperature), _np.int32(top_k),
+                _np.float32(top_p), _np.int32(method))
+            return int(tok)
 
     def warmup(self, cache: Any, prompt_buckets: Sequence[int],
                suffix_pairs: bool = True) -> int:

@@ -50,6 +50,7 @@ import numpy as _np
 
 from ..base import MXNetError
 from .. import metrics as _metrics
+from .. import tracing as _tracing
 from .kv_cache import PagedKVCache, round_up_bucket
 from .model import DecodeModel, _pure_ln, _sample_tokens, \
     _slot_block_step
@@ -213,7 +214,6 @@ class SelfSpeculativeDraft(DraftModel):
             jnp.asarray(_np.asarray(last_tok, _np.int32)),
             jnp.asarray(_np.asarray(positions, _np.int32)), *sampling)
         out = _np.asarray(outs)
-        from .. import tracing as _tracing
         _metrics.GEN_STEP_SECONDS.labels(phase="draft").observe(
             time.perf_counter() - t,
             exemplar=_tracing.current_trace_id())
@@ -352,7 +352,6 @@ class IndependentDraft(DraftModel):
             jnp.asarray(pos), *sampling)
         self.cache.replace(new_ks, new_vs)
         out = _np.asarray(outs)[:, :self.k]
-        from .. import tracing as _tracing
         _metrics.GEN_STEP_SECONDS.labels(phase="draft").observe(
             time.perf_counter() - t,
             exemplar=_tracing.current_trace_id())

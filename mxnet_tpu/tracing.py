@@ -37,6 +37,14 @@ production:
   straight into its event list (category ``"trace"``) through a direct
   append — never through the op-dispatch layer, so spans cannot fire
   monitor hooks or inflate dispatch metrics.
+* **device trace**: every span that is really opened also holds a
+  ``jax.profiler.TraceAnnotation`` of its name, so any ``jax.profiler``
+  session (``mx.profiler.start_xla_trace``, TensorBoard, chipbench's
+  traced window) shows the program's spans on ``/host:CPU``, on the
+  clock of the device's ``XLA Ops`` lines.  Outside a session the
+  annotation is a flag read in the runtime.  Records keep
+  ``time.perf_counter()`` seconds; retroactive intervals
+  (:func:`record_span`) have no annotation.
 
 Overhead contract: with ``MXNET_TRACE_SAMPLE=0`` tracing is fully off —
 ``span()`` returns a shared no-op after one flag read, and zero spans
@@ -56,12 +64,21 @@ import threading
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
+from . import profiler as _prof
 from .base import getenv, register_env
 
-__all__ = ["span", "child_span", "capture", "attach", "current_context",
-           "current_trace_id", "traceparent", "parse_traceparent",
-           "record_span", "spans", "export_trace_events",
-           "active_spans_tree", "configure", "reset", "SpanContext"]
+__all__ = ["span", "child_span", "capture", "root_context", "attach",
+           "current_context", "current_trace_id", "traceparent",
+           "parse_traceparent", "record_span", "spans",
+           "export_trace_events", "active_spans_tree", "configure",
+           "reset", "SpanContext"]
+
+# ring capacity, a power of two: 90 s of the busiest measured producer
+# stay resident (the serving engine under chipbench's
+# gpt2_774m.serve_doc records 104 spans a second, the trainer 19;
+# PERF.md, Findings PR 26), because chipbench reads the ring when the
+# job has ended
+_BUFFER_SPANS = 16384
 
 register_env(
     "MXNET_TRACE_SAMPLE", 1.0,
@@ -73,10 +90,13 @@ register_env(
     "than MXNET_TRACE_SLOW_MS, which are tail-upgraded and kept "
     "regardless.")
 register_env(
-    "MXNET_TRACE_BUFFER_SPANS", 4096,
+    "MXNET_TRACE_BUFFER_SPANS", _BUFFER_SPANS,
     "Capacity of the in-process finished-span ring buffer. Oldest "
     "spans are overwritten; GET /v1/traces, tools/trace_dump.py and "
-    "tracing.export_trace_events() export whatever is resident.")
+    "tracing.export_trace_events() export whatever is resident. A "
+    "resident record holds about 1 KB (0.8-1.0 KB measured over the "
+    "serving engine's spans), so the default is about 16 MB once the "
+    "ring has filled.")
 register_env(
     "MXNET_TRACE_SLOW_MS", 100.0,
     "Tail-retention threshold for the span runtime: a span that runs "
@@ -96,7 +116,8 @@ _CTX: contextvars.ContextVar[Optional["SpanContext"]] = \
 class _Runtime:
     """Tracing configuration + the ring buffer (rebuilt by configure())."""
 
-    __slots__ = ("sample", "cap", "slow_s", "buf", "seq", "rng")
+    __slots__ = ("sample", "cap", "slow_s", "buf", "seq", "rng",
+                 "randbits")
 
     def __init__(self, sample: Optional[float] = None,
                  buffer_spans: Optional[int] = None,
@@ -104,7 +125,8 @@ class _Runtime:
         if sample is None:
             sample = float(getenv("MXNET_TRACE_SAMPLE", 1.0))
         if buffer_spans is None:
-            buffer_spans = int(getenv("MXNET_TRACE_BUFFER_SPANS", 4096))
+            buffer_spans = int(getenv("MXNET_TRACE_BUFFER_SPANS",
+                                      _BUFFER_SPANS))
         if slow_ms is None:
             slow_ms = float(getenv("MXNET_TRACE_SLOW_MS", 100.0))
         self.sample = max(0.0, min(1.0, float(sample)))
@@ -115,6 +137,7 @@ class _Runtime:
         # list item assignment — the append path takes no lock
         self.seq = itertools.count()
         self.rng = random.Random(os.urandom(8))
+        self.randbits = self.rng.getrandbits
 
 
 _RT = _Runtime()
@@ -194,7 +217,6 @@ def _emit(rec: Dict[str, Any]) -> None:
     i = next(rt.seq)
     rec["seq"] = i
     rt.buf[i % rt.cap] = rec
-    from . import profiler as _prof
     if _prof._active["on"]:
         # direct event append (never via op dispatch: spans must not
         # fire monitor hooks or count as dispatched ops)
@@ -218,8 +240,25 @@ def _upgrade(st: _TraceState) -> None:
         _emit(rec)
 
 
-def _gen_id(nibbles: int) -> str:
-    return f"{_RT.rng.getrandbits(nibbles * 4):0{nibbles}x}"
+def _trace_id() -> str:
+    return "%032x" % _RT.randbits(128)
+
+
+def _span_id() -> str:
+    return "%016x" % _RT.randbits(64)
+
+
+_ANNOTATION: Any = None
+
+
+def _annotation() -> Any:
+    """``jax.profiler.TraceAnnotation``, imported at the first span that
+    is really opened (a process with tracing off never pays it)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 class _NoopSpan:
@@ -254,7 +293,7 @@ class _Span:
 
     __slots__ = ("name", "attrs", "links", "trace_id", "span_id",
                  "parent_id", "state", "t_begin", "error", "_token",
-                 "_root", "_thread")
+                 "_root", "_thread", "_ann")
 
     def __init__(self, name: str, attrs: Dict[str, Any]) -> None:
         self.name = name
@@ -277,7 +316,7 @@ class _Span:
         parent = _CTX.get()
         if parent is None:
             rt = _RT
-            st = _TraceState(_gen_id(32), rt.rng.random() < rt.sample)
+            st = _TraceState(_trace_id(), rt.rng.random() < rt.sample)
             self.parent_id = ""
             self._root = True
         else:
@@ -286,17 +325,22 @@ class _Span:
             self._root = False
         self.state = st
         self.trace_id = st.trace_id
-        self.span_id = _gen_id(16)
+        self.span_id = _span_id()
         self._thread = threading.current_thread().name
         self._token = _CTX.set(
             SpanContext(self.trace_id, self.span_id, st))
-        self.t_begin = time.perf_counter()
         if not st.dead:
             _OPEN[self.span_id] = self
+        # the same interval on the device trace's clock: a flag read
+        # unless a jax.profiler session is running
+        self._ann = _annotation()(self.name, **self.attrs)
+        self._ann.__enter__()
+        self.t_begin = time.perf_counter()
         return self
 
     def __exit__(self, et: Any, ev: Any, tb: Any) -> bool:
         t_end = time.perf_counter()
+        self._ann.__exit__(et, ev, tb)
         _CTX.reset(self._token)
         _OPEN.pop(self.span_id, None)
         st = self.state
@@ -385,7 +429,7 @@ def record_span(name: str, begin: float, end: float,
         return
     rec: Dict[str, Any] = {
         "name": name, "trace_id": ctx.trace_id,
-        "span_id": _gen_id(16), "parent_id": ctx.span_id,
+        "span_id": _span_id(), "parent_id": ctx.span_id,
         "t_begin": begin, "t_end": end,
         "tid": threading.get_ident() % 100000,
         "thread": threading.current_thread().name,
@@ -421,6 +465,20 @@ def capture() -> Optional[SpanContext]:
     """Snapshot the active context for an explicit hand-off (store it
     on the queue item / request object at submit time)."""
     return _CTX.get()
+
+
+def root_context() -> Optional[SpanContext]:
+    """The context of a fresh head-sampled trace that has no span of its
+    own — for work submitted with no active trace whose spans are all
+    recorded where it runs (an in-process generation request: the
+    engine thread records its ``queue.wait`` and ``engine.prefill``
+    under :func:`attach`).  Like a remote parent's, its span id names
+    no record.  None when tracing is off."""
+    rt = _RT
+    if rt.sample <= 0.0:
+        return None
+    st = _TraceState(_trace_id(), rt.rng.random() < rt.sample)
+    return SpanContext(st.trace_id, _span_id(), st)
 
 
 @contextlib.contextmanager
@@ -486,7 +544,6 @@ def export_trace_events() -> Dict[str, Any]:
     profiler's :func:`mxnet_tpu.profiler.dump` payload and on the same
     clock epoch, so one ``chrome://tracing`` / Perfetto load can show a
     profiler dump and this export side by side."""
-    from . import profiler as _prof
     t0 = _prof._P.t0
     events: List[Dict[str, Any]] = [
         {"name": "process_name", "ph": "M", "pid": 0,

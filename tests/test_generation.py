@@ -345,3 +345,124 @@ def test_generation_server_shutdown_fails_inflight(decode_model):
         # drain whatever streamed, then observe the structured error
         while s.next_token(timeout=5) is not None:
             pass
+
+
+# ---------------------------------------------------------------------------
+# the engine's own spans (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def traced():
+    from mxnet_tpu import tracing
+    tracing.configure(sample=1.0)
+    yield tracing
+    tracing.configure()
+
+
+def _inside(child, parent):
+    return parent["t_begin"] <= child["t_begin"] \
+        and child["t_end"] <= parent["t_end"]
+
+
+def test_in_process_submit_gets_a_trace_of_its_own(decode_model, traced):
+    """engine.submit() under no caller trace: one trace holds queue.wait
+    and engine.prefill, and under the latter model.prefill,
+    kv.write_prompt and model.select."""
+    eng = _engine(decode_model)
+    traced.reset()
+    assert traced.current_context() is None
+    n0 = metrics.hist_stats("mxnet_gen_queue_wait_seconds")[1]
+    # the histogram keeps its longest recent wait's exemplar: forget
+    # the ones earlier tests left
+    metrics.GEN_QUEUE_WAIT_SECONDS._default().exemplar = None
+    s = eng.submit(PROMPT_A, max_new_tokens=4)
+    _drain(eng, s)
+    prefill, = [r for r in traced.spans()
+                if r["name"] == "engine.prefill"]
+    trace = traced.spans(prefill["trace_id"])
+    by = {r["name"]: r for r in trace}
+    assert set(by) == {"queue.wait", "engine.prefill", "model.prefill",
+                       "kv.write_prompt", "model.select"}, sorted(by)
+    assert by["queue.wait"]["parent_id"] == prefill["parent_id"]
+    for name in ("model.prefill", "kv.write_prompt", "model.select"):
+        assert by[name]["parent_id"] == prefill["span_id"], name
+        assert _inside(by[name], prefill), name
+    assert by["queue.wait"]["t_end"] <= prefill["t_begin"]
+    assert by["model.prefill"]["attrs"]["bucket"] == 8
+    assert by["kv.write_prompt"]["attrs"]["rows"] == 8
+    # one observation of the queue wait an admission, with the
+    # request's trace as its exemplar
+    assert metrics.hist_stats(
+        "mxnet_gen_queue_wait_seconds")[1] == n0 + 1
+    assert metrics.GEN_QUEUE_WAIT_SECONDS._default().exemplar[0] \
+        == prefill["trace_id"]
+    # the iteration links the request's trace, as it does over HTTP
+    assert any(prefill["trace_id"] in r.get("links", ())
+               for r in traced.spans() if r["name"] == "engine.iteration")
+
+
+def test_iteration_span_covers_its_whole_quantum(decode_model, traced):
+    """By time, on one thread, engine.iteration contains every
+    engine.prefill, model.step and engine.emit of its quantum;
+    model.step.dispatch and model.step.readback tile model.step."""
+    eng = _engine(decode_model)
+    traced.reset()
+    a = eng.submit(PROMPT_A, max_new_tokens=6)
+    eng.run_iteration()
+    b = eng.submit(PROMPT_B, max_new_tokens=3)    # admitted mid-flight
+    _drain(eng, a, b)
+    assert eng.run_iteration()      # the last sequence retires
+    assert not eng.run_iteration()  # an idle pass is a (short) span too
+    recs = traced.spans()
+    iters = [r for r in recs if r["name"] == "engine.iteration"]
+    assert len({r["tid"] for r in recs}) == 1
+    assert len(iters) == len({r["trace_id"] for r in iters})
+    for name, per_iter in (("engine.prefill", None), ("model.step", 1),
+                           ("engine.emit", 1)):
+        found = [r for r in recs if r["name"] == name]
+        assert found, name
+        for r in found:
+            homes = [i for i in iters if _inside(r, i)]
+            assert len(homes) == 1, (name, len(homes))
+        if per_iter:
+            # every iteration that decoded has exactly one
+            decoded = [i for i in iters if i["attrs"]["tokens"]]
+            assert len(found) == per_iter * len(decoded)
+    assert len([r for r in recs if r["name"] == "engine.prefill"]) == 2
+    first = iters[0]["attrs"]
+    assert (first["admitted"], first["slots"], first["tokens"]) == (1, 1, 1)
+    assert sum(i["attrs"]["tokens"] for i in iters) == (6 - 1) + (3 - 1)
+    assert sum(i["attrs"]["retired"] for i in iters) == 2
+    assert iters[-1]["attrs"] == {"iter": iters[-1]["attrs"]["iter"],
+                                  "slots": 0, "admitted": 0, "retired": 0,
+                                  "tokens": 0}
+    by_id = {r["span_id"]: r for r in recs}
+    steps = [r for r in recs if r["name"] == "model.step"]
+    for step in steps:
+        assert by_id[step["parent_id"]]["name"] == "engine.iteration"
+        parts = sorted((r for r in recs if r["parent_id"] == step["span_id"]),
+                       key=lambda r: r["t_begin"])
+        assert [p["name"] for p in parts] == ["model.step.dispatch",
+                                              "model.step.readback"]
+        gaps = (parts[0]["t_begin"] - step["t_begin"],
+                parts[1]["t_begin"] - parts[0]["t_end"],
+                step["t_end"] - parts[1]["t_end"])
+        assert all(0 <= g < 2e-3 for g in gaps), gaps
+        assert step["attrs"] == {"slots": 2, "bucket": eng.cache.bucket}
+    emits = [r for r in recs if r["name"] == "engine.emit"]
+    assert [e["attrs"]["tokens"] for e in emits] \
+        == [i["attrs"]["tokens"] for i in iters if i["attrs"]["tokens"]]
+
+
+def test_tracing_off_constructs_nothing_at_the_engines_sites(
+        decode_model, traced, monkeypatch):
+    traced.configure(sample=0)
+    made = []
+    monkeypatch.setattr(traced, "_Span", lambda *a, **k: made.append(a))
+    monkeypatch.setattr(traced, "_TraceState",
+                        lambda *a, **k: made.append(a))
+    eng = _engine(decode_model)
+    s = eng.submit(PROMPT_A, max_new_tokens=4)
+    _drain(eng, s)
+    assert len(s.result(timeout=10)) == 4
+    assert made == [] and traced.spans() == []

@@ -469,3 +469,62 @@ def test_hybrid_multislice_mesh():
     l1 = float(tr.step(mx.np.array(x), mx.np.array(y)).asnumpy())
     l2 = float(tr.step(mx.np.array(x), mx.np.array(y)).asnumpy())
     assert l2 < l1
+
+
+# ---------------------------------------------------------------------------
+# the trainer step's own spans (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+def _dense_trainer():
+    mx.random.seed(0)
+    net = nn.Dense(4, in_units=8)
+    net.initialize()
+    tr = SPMDTrainer(net, mx.gluon.loss.L2Loss(), "sgd",
+                     {"learning_rate": 0.05},
+                     mesh=make_mesh({"dp": 1}, devices=_devices(1)))
+
+    def batch(step):
+        rng = onp.random.RandomState(step)
+        return (mx.np.array(rng.uniform(-1, 1, (8, 8)).astype("f4")),
+                mx.np.array(rng.uniform(-1, 1, (8, 4)).astype("f4")))
+    return tr, batch
+
+
+def test_step_spans_for_a_bare_loop_and_inside_fit():
+    """A caller's own loop over trainer.step() records spmd.step >
+    step.place, step.dispatch; inside fit() the same three nest under
+    train.step, once."""
+    from mxnet_tpu import tracing
+    tracing.configure(sample=1.0)
+    try:
+        tr, batch = _dense_trainer()
+        for i in range(2):
+            tr.step(*batch(i))
+        roots = [r for r in tracing.spans() if r["name"] == "spmd.step"]
+        assert [r["attrs"]["step"] for r in roots] == [0, 1]
+        for root in roots:
+            assert root["parent_id"] == ""
+            kids = [r for r in tracing.spans(root["trace_id"])
+                    if r is not root]
+            assert sorted(k["name"] for k in kids) == ["step.dispatch",
+                                                       "step.place"]
+            assert all(k["parent_id"] == root["span_id"] for k in kids)
+
+        tracing.reset()
+        tr.fit(batch, 5)
+        steps = [r for r in tracing.spans() if r["name"] == "train.step"]
+        assert len(steps) == 3
+        for step in steps:
+            names = sorted(r["name"]
+                           for r in tracing.spans(step["trace_id"]))
+            assert names == ["spmd.step", "step.dispatch", "step.place",
+                             "train.step"], names
+            inner, = [r for r in tracing.spans(step["trace_id"])
+                      if r["name"] == "spmd.step"]
+            assert inner["parent_id"] == step["span_id"]
+
+        tracing.configure(sample=0)
+        tr.step(*batch(9))
+        assert tracing.spans() == []
+    finally:
+        tracing.configure()

@@ -271,3 +271,94 @@ def test_sample_zero_records_nothing_ever():
     tracing.record_span("off.rec", 0.0, 1.0)
     assert tracing.parse_traceparent(f"00-{'a'*32}-{'b'*16}-01") is None
     assert tracing.spans() == []
+
+
+# ---------------------------------------------------------------------------
+# the device trace's timeline, and traces for work submitted under none
+# ---------------------------------------------------------------------------
+
+def _host_events(trace_dir):
+    """{event name: [(start_ns, duration_ns)]} of the newest profile's
+    /host:CPU plane."""
+    import glob
+    from jax.profiler import ProfileData
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert files, f"the profiler wrote no .xplane.pb under {trace_dir}"
+    data = ProfileData.from_file(max(files, key=os.path.getmtime))
+    events = {}
+    for plane in data.planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for ev in line.events:
+                    events.setdefault(ev.name, []).append(
+                        (int(ev.start_ns), int(ev.duration_ns)))
+    return events
+
+
+def test_spans_show_on_the_host_plane_of_a_jax_profile(tmp_path):
+    """Any jax.profiler session shows the program's spans on /host:CPU:
+    engine.iteration and its children, by name, as long as the
+    records say."""
+    import jax
+    from mxnet_tpu.gluon.model_zoo.gpt import GPTModel
+    from mxnet_tpu.serving import DecodeModel, GenerationEngine
+    mx.random.seed(0)
+    net = GPTModel(vocab_size=97, num_layers=1, units=32, hidden_size=48,
+                   num_heads=4, max_length=64, dropout=0.0)
+    net.initialize()
+    net(mx.np.zeros((1, 4), dtype="int32"))
+    eng = GenerationEngine(DecodeModel.from_block(net), max_slots=2,
+                           kv_buckets=(16,), max_tokens=8)
+    eng.warmup()
+    tracing.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        stream = eng.submit(onp.array([5, 9, 3], "int32"), max_new_tokens=3)
+        while not stream.finished:
+            eng.run_iteration()
+    events = _host_events(str(tmp_path))
+    if not events:
+        pytest.skip("the CPU profiler wrote no /host:CPU plane")
+    recs = tracing.spans()
+    for name in ("engine.iteration", "engine.prefill", "model.prefill",
+                 "kv.write_prompt", "model.select", "model.step",
+                 "model.step.dispatch", "model.step.readback",
+                 "engine.emit"):
+        n = sum(r["name"] == name for r in recs)
+        assert n and len(events.get(name, ())) == n, (name, n)
+    assert "queue.wait" not in events        # retroactive: ring only
+    # the annotation holds the record's interval
+    durs = sorted(d for _, d in events["engine.iteration"])
+    want = sorted(1e9 * (r["t_end"] - r["t_begin"]) for r in recs
+                  if r["name"] == "engine.iteration")
+    for got, rec in zip(durs, want):
+        assert rec <= got + 1e3 and got - rec < 1e6, (got, rec)
+
+
+def test_root_context_gives_untraced_work_a_trace():
+    """root_context() is a fresh head-sampled trace with no span of its
+    own: spans recorded under attach() land in it; off when tracing is
+    off."""
+    assert tracing.current_context() is None
+    ctx = tracing.root_context()
+    assert tracing.current_context() is None      # not activated
+    assert len(ctx.trace_id) == 32 and len(ctx.span_id) == 16
+    with tracing.attach(ctx), tracing.child_span("late.work"):
+        pass
+    tracing.record_span("late.wait", 1.0, 2.0, ctx=ctx)
+    recs = tracing.spans(ctx.trace_id)
+    assert _names(recs) == {"late.work", "late.wait"}
+    assert all(r["parent_id"] == ctx.span_id for r in recs)
+    assert tracing.root_context().trace_id != ctx.trace_id
+    tracing.configure(sample=0.0)
+    assert tracing.root_context() is None
+
+
+def test_ring_default_holds_the_benchmarks_readers_window():
+    """The readers of chipbench run up to 90 s after the spans they read
+    were recorded; the default ring must hold that at the busiest
+    measured rate (PERF.md, Findings PR 26)."""
+    tracing.configure()
+    assert tracing._RT.cap == tracing._BUFFER_SPANS
+    assert tracing._BUFFER_SPANS & (tracing._BUFFER_SPANS - 1) == 0
+    assert tracing._BUFFER_SPANS >= 90 * 160
