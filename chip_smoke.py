@@ -15,6 +15,11 @@ vocab 50257; random weights from a seed):
   (``skipped: <n> device(s)`` otherwise): tp-sharded parameters on 4
   distinct devices, the kernel in the step, first-step loss equal to the
   1-chip run's within a bf16 tolerance.
+* **decode** — the serving engine as ``tools/serve.py`` builds it
+  (``DecodeModel.from_block`` -> ``GenerationEngine``), warmed up, then
+  the compiled decode step at the engine's top KV bucket: its optimized
+  HLO may hold no ``copy`` or ``transpose`` of a whole K or V buffer
+  (the per-token relayout PERF.md's PR 27 entry removed).
 * **serve** — ``tools/serve.py --generate --zoo-gpt gpt2_124m --port 0``
   as a child: streamed concurrent ``/v1/generate`` requests across
   prompt buckets and a KV-bucket growth, contiguous indexes, a done
@@ -37,6 +42,7 @@ programs compiled, apart from step / request seconds).
 import argparse
 import http.client
 import json
+import math
 import os
 import re
 import signal
@@ -145,6 +151,66 @@ def _kernel_parity():
                         - got[0].astype(jnp.float32)).max()) > 0.0,
           "flash with dropout=0.1 equals the undropped output")
     return {k: round(e, 5) for k, e in errs.items()}
+
+
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* (copy|transpose)\(")
+
+
+def cache_sized_relayouts(hlo_text, n_elements):
+    """The ``copy`` / ``transpose`` instructions of an optimized HLO
+    module whose result has ``n_elements`` elements (one whole K or V
+    buffer), fused computations included.  ``copy-start`` / ``copy-done``
+    (a move between memory spaces, same layout) do not count."""
+    found = []
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            continue
+        if math.prod(map(int, filter(None, m.group(1).split(",")))) \
+                == n_elements:
+            found.append(line.strip()[:200])
+    return found
+
+
+def child_decode():
+    t_proc = time.perf_counter()
+    device = _require_tpu()
+    import jax.numpy as jnp
+    import numpy as onp
+    import mxnet_tpu as mx
+    from mxnet_tpu import serving
+    from mxnet_tpu.gluon.model_zoo.gpt import get_gpt
+
+    net = get_gpt(MODEL, dropout=0.0)
+    net.initialize()
+    net(mx.np.zeros((1, 4), dtype="int32"))
+    model = serving.DecodeModel.from_block(net)
+    engine = serving.GenerationEngine(model)
+    warmed = engine.warmup()
+    cache = engine.cache
+    cache.grow(cache.grid[-1])
+    S = cache.max_slots
+    zeros = jnp.asarray(onp.zeros((S,), onp.int32))
+    hlo = model._step_fn.lower(
+        model.params, cache._k, cache._v, zeros, zeros,
+        *model.device_sampling(model.greedy_sampling(S))
+    ).compile().as_text()
+    module = hlo.split("\n", 1)[0]
+    check("jit__step" in module,
+          f"the decode step is not jit__step: {module[:80]}")
+    n_buffer = int(cache.k(0).size)
+    relayouts = cache_sized_relayouts(hlo, n_buffer)
+    check(not relayouts,
+          f"the decode step at {S} x {cache.bucket} relayouts whole KV "
+          f"buffers, {len(relayouts)} instruction(s), the first: "
+          f"{relayouts[:1]}")
+    print(json.dumps({
+        "phase": "decode", "ok": True, **device, "model": MODEL,
+        "programs_warmed": warmed, "kv": cache.describe()["layout"],
+        "step_shape": [S, cache.bucket], "kv_buffer_elements": n_buffer,
+        "cache_sized_relayouts": len(relayouts),
+        "seconds": round(time.perf_counter() - t_proc, 2)}), flush=True)
 
 
 def child_train(phase, mesh_shape, ref_loss):
@@ -436,7 +502,7 @@ def phase_serve(deadline):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--child", choices=("train", "train4"),
+    ap.add_argument("--child", choices=("train", "train4", "decode"),
                     help=argparse.SUPPRESS)
     ap.add_argument("--ref-loss", type=float, help=argparse.SUPPRESS)
     args = ap.parse_args()
@@ -444,6 +510,8 @@ def main():
         return child_train("train", {"dp": 1}, None)
     if args.child == "train4":
         return child_train("train4", {"dp": 2, "tp": 2}, args.ref_loss)
+    if args.child == "decode":
+        return child_decode()
 
     for need in ("mxnet_tpu/__init__.py", "tools/serve.py"):
         if not os.path.isfile(os.path.join(HERE, need)):
@@ -459,6 +527,7 @@ def main():
         train4 = {"phase": "train4",
                   "skipped": f"{train['count']} device(s)"}
     print(json.dumps(train4), flush=True)
+    print(json.dumps(run_child(["--child", "decode"], deadline)), flush=True)
     print(json.dumps(phase_serve(deadline)), flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": train["platform"], "kind": train["kind"],

@@ -5,11 +5,29 @@ stays resident across requests (the Julia->TPU full-compilation lesson,
 PAPERS.md): every tensor the step touches therefore has a fixed shape.
 This cache provides that shape discipline:
 
-* **Slots** — the cache is a fixed ``(S, L, heads, d)`` buffer per
+* **Slots** — the cache is a fixed ``(S, heads * d, L)`` buffer per
   layer, ``S = max_slots``.  A sequence owns one slot row for its whole
   lifetime; admission writes its prefilled keys/values into the row,
   retirement simply frees the slot id (no copy, no compaction — the
   row's stale contents are masked off by the per-slot position mask).
+* **Positions last** — ``L`` is the minor axis because that is the
+  order the device stores the buffer in and the decode attention reads
+  it in.  A TPU keeps an array in the tile-compact layout of its
+  shape (8 x 128 tiles over the two minor axes), so the former
+  ``(S, L, heads, d)`` with ``d`` = 64 was stored ``[S][heads][d][L]``
+  anyway, while the step's scatter wanted ``[S][L][heads][d]``: XLA
+  relayouted every layer's whole K and V buffer on the way into and
+  out of every token (two thirds of a decode step, PERF.md PR 27).
+  With ``(S, heads * d, L)`` the row-major layout of the logical shape
+  IS the stored one (channels on sublanes, ``L`` on lanes, nothing
+  padded for ``L`` a multiple of 128), and the step writes each
+  slot's column with an in-place ``dynamic_update_slice``, so the
+  donated buffers alias its outputs with no copy.  Heads and ``d``
+  share ONE axis because the column write then compiles to half the
+  code (51 against 90 MB a decode program, resident on the device);
+  the step's view ``(S, heads, d, L)`` of it is free.  Callers keep
+  handing :meth:`PagedKVCache.write_prompt` plain ``(Lp, heads, d)``
+  rows.
 * **Capacity buckets** — ``L`` is drawn from a power-of-two-style grid
   (``MXNET_GEN_KV_BUCKETS``).  The decode step compiles once per
   bucket; when any live sequence needs a position ``>= L`` the whole
@@ -78,7 +96,7 @@ def round_up_bucket(n: int, grid: Sequence[int]) -> int:
 
 
 class PagedKVCache:
-    """Per-layer ``(max_slots, L, heads, head_dim)`` K/V buffers plus
+    """Per-layer ``(max_slots, heads * head_dim, L)`` K/V buffers plus
     host-side slot bookkeeping.
 
     ``layers`` buffers live as jax arrays (device-resident); ``k(i)`` /
@@ -121,7 +139,7 @@ class PagedKVCache:
     # -- buffers ------------------------------------------------------------
     def _alloc_buffers(self, L: int) -> None:
         import jax
-        shape = (self.max_slots, L, self.n_heads, self.head_dim)
+        shape = (self.max_slots, self.n_heads * self.head_dim, L)
         # device_put COMMITS the buffers: a jitted call keys its cache
         # on input committed-ness, so fresh uncommitted zeros would
         # make the first post-reset admission recompile the row write
@@ -330,6 +348,8 @@ class PagedKVCache:
             "heads": self.n_heads,
             "head_dim": self.head_dim,
             "dtype": str(self.dtype),
+            # axis order of every resident buffer (module docstring)
+            "layout": "(max_slots, heads*head_dim, bucket)",
             # where the buffers actually live, not where they were asked
             "platforms": sorted({d.platform for b in self._k + self._v
                                  for d in b.devices()}),
@@ -351,8 +371,7 @@ def _grow_rows(buf: Any, new_len: int) -> Any:
         from .. import compile_cache as _cc
 
         def grow(b, _L=int(new_len)):
-            return jnp.pad(
-                b, ((0, 0), (0, _L - b.shape[1]), (0, 0), (0, 0)))
+            return jnp.pad(b, ((0, 0), (0, 0), (0, _L - b.shape[2])))
 
         fn = _grow_jits[int(new_len)] = _cc.persistently_cached(
             jax.jit(grow), surface="serving.kv", pin=True)
@@ -368,9 +387,10 @@ def _make_write_rows():
     from .. import compile_cache as _cc
 
     def write(bufs, rows, slot, start):
-        # bufs: every layer's K then V buffer (S, L, h, d); rows: the
-        # matching (Lp, h, d) rows; slot/start scalars: place each
-        # row-set at [slot, start:start+Lp] in ONE executable (per-
+        # bufs: every layer's K then V buffer (S, h * d, L); rows: the
+        # matching (Lp, h, d) rows, turned to (h * d, Lp) here (small:
+        # one prompt's rows); slot/start scalars: place each row-set
+        # at [slot, :, start:start+Lp] in ONE executable (per-
         # dispatch overhead dominates a row copy, so one call per
         # layer per K/V would bury the admission in launch latency).
         # start is a traced operand (prefix copies write at 0, suffix
@@ -383,8 +403,8 @@ def _make_write_rows():
         # un-donated form matches the pre-prefix-cache write's
         # semantics and keeps warm restarts at 0 compiles
         return [lax.dynamic_update_slice(
-            b, r[None].astype(b.dtype),
-            (slot, start, _np.int32(0), _np.int32(0)))
+            b, r.reshape(r.shape[0], -1).T[None].astype(b.dtype),
+            (slot, _np.int32(0), start))
             for b, r in zip(bufs, rows)]
     return _cc.persistently_cached(
         jax.jit(write), surface="serving.kv",

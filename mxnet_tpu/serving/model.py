@@ -314,26 +314,50 @@ def _sample_tokens(logits, seeds, ctrs, temps, topks, topps, methods):
 
 def _slot_block_step(p, x, ck, cv, pos, nh: int, ga):
     """One decode token for EVERY slot: ``x`` (S, 1, C), caches
-    (S, L, nh, d), ``pos`` (S,) int32 — the per-slot-position variant
-    of ``model_zoo.generation._block_step`` (which shares one scalar
+    (S, C, L) — heads and head dim on one axis, positions last: the
+    order the device stores them in (``kv_cache`` module docstring) —
+    ``pos`` (S,) int32: the per-slot-position variant of
+    ``model_zoo.generation._block_step`` (which shares one scalar
     position across the batch; continuous batching cannot)."""
     import math as _math
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
     gelu_approx, eps = ga
     S, _, C = x.shape
     d = C // nh
-    L = ck.shape[1]
+    L = ck.shape[2]
     h = _pure_ln(x, p["ln1_g"], p["ln1_b"], eps)
     qkv = h @ p["qkv_w"].T + p["qkv_b"]
     q, k, v = jnp.split(qkv, 3, axis=-1)
     qh = q.reshape(S, 1, nh, d)
-    rows = jnp.arange(S)
-    # per-slot scatter: slot i writes its k/v at ITS position pos[i]
-    ck = ck.at[rows, pos].set(k.reshape(S, nh, d))
-    cv = cv.at[rows, pos].set(v.reshape(S, nh, d))
-    scores = jnp.einsum("sqhd,skhd->shqk", qh, ck) / _math.sqrt(d)
+    kcol = k.reshape(S, C, 1)
+    vcol = v.reshape(S, C, 1)
+    # slot i writes its k/v column at ITS position pos[i]: one in-place
+    # dynamic_update_slice a slot, NOT a scatter — a TPU scatter wants
+    # its update window on the minor axes, so XLA would relayout the
+    # whole buffer to [S][L][C] and back around it, every layer, every
+    # token (PERF.md PR 27).  A position past L-1 clamps onto row L-1
+    # where the scatter dropped it; only a verify pass at the grid's
+    # top gets there, for rows no emitted token reads (the submit-time
+    # budget check)
+    for i in range(S):
+        # positions are never negative: without the flag every traced
+        # index gets a wrap-around select, slots x 2 x layers times a
+        # program, a second of tracing on every start
+        at = (i, 0, lax.index_in_dim(pos, i, keepdims=False))
+        ck = lax.dynamic_update_slice(
+            ck, lax.slice_in_dim(kcol, i, i + 1), at,
+            allow_negative_indices=False)
+        cv = lax.dynamic_update_slice(
+            cv, lax.slice_in_dim(vcol, i, i + 1), at,
+            allow_negative_indices=False)
+    # the heads' view of the buffers is free: d (64) is whole sublane
+    # tiles, so splitting C moves nothing
+    kh = ck.reshape(S, nh, d, L)
+    vh = cv.reshape(S, nh, d, L)
+    scores = jnp.einsum("sqhd,shdk->shqk", qh, kh) / _math.sqrt(d)
     # slot i sees cache positions 0..pos[i] (its prompt + its decoded
     # tokens); pad garbage beyond pos[i] stays invisible until the loop
     # overwrites it position by position
@@ -341,7 +365,7 @@ def _slot_block_step(p, x, ck, cv, pos, nh: int, ga):
     scores = jnp.where(visible[:, None, None, :], scores,
                        jnp.float32(-jnp.inf).astype(scores.dtype))
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("shqk,skhd->sqhd", probs, cv).reshape(S, 1, C)
+    out = jnp.einsum("shqk,shdk->sqhd", probs, vh).reshape(S, 1, C)
     x = x + (out @ p["out_w"].T + p["out_b"])
     h = _pure_ln(x, p["ln2_g"], p["ln2_b"], eps)
     ffn = jax.nn.gelu(h @ p["f1_w"].T + p["f1_b"],
@@ -583,7 +607,7 @@ class DecodeModel:
         self._select_fn = _cc.persistently_cached(
             jax.jit(_select_one), surface="serving.decode", pin=True)
         # the KV buffers are DONATED: XLA updates the resident cache in
-        # place instead of allocating a fresh (S, L, h, d) per layer
+        # place instead of allocating a fresh (S, h * d, L) per layer
         # every token
         self._step_fn = _cc.persistently_cached(
             jax.jit(_step, donate_argnums=(1, 2)),

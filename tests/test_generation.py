@@ -102,7 +102,7 @@ def test_kv_cache_slots_and_buckets():
     assert not c.ensure_capacity(8)          # fits the current bucket
     assert c.ensure_capacity(9)              # 9 > 8 -> migrate to 16
     assert c.bucket == 16
-    assert c.k(0).shape == (3, 16, 2, 4)
+    assert c.k(0).shape == (3, 2 * 4, 16)           # positions last
     c.free(s0)
     assert c.free_slots() == [0, 2]
     c.free(s1)
@@ -115,23 +115,128 @@ def test_kv_cache_slots_and_buckets():
         c.ensure_capacity(40)                # past the top bucket
 
 
+def _resident_rows(c, slot):
+    """A slot's rows as callers hand them in: per layer (L, heads, d)
+    K then V, whatever axis order the cache keeps them in."""
+    return [onp.asarray(b)[slot].T.reshape(-1, c.n_heads, c.head_dim)
+            for b in [c.k(i) for i in range(c.n_layers)]
+            + [c.v(i) for i in range(c.n_layers)]]
+
+
+@pytest.mark.parametrize("grown", [False, True],
+                         ids=["same_bucket", "after_grow"])
+@pytest.mark.parametrize("start", [0, 3], ids=["at_0", "at_offset"])
+def test_kv_write_prompt_round_trip(start, grown):
+    """write_prompt takes (Lp, heads, d) rows and the resident buffers
+    keep positions LAST: what was written at ``start`` reads back equal
+    (and nothing else in the slot moved), before and after a grow."""
+    rng = onp.random.RandomState(start + 7 * grown)
+    c = PagedKVCache(n_layers=2, n_heads=2, head_dim=4, max_slots=3,
+                     buckets=(8, 16))
+    assert c.describe()["layout"] == "(max_slots, heads*head_dim, bucket)"
+    assert c.k(0).shape == (3, 2 * 4, 8)
+    c.alloc()
+    slot = c.alloc()
+    rows = [rng.randn(5, 2, 4).astype("float32") for _ in range(4)]
+    c.write_prompt(slot, rows[:2], rows[2:], start + 5, start=start)
+    if grown:
+        c.grow(16)
+        assert c.v(1).shape == (3, 2 * 4, 16)
+    for got, want in zip(_resident_rows(c, slot), rows):
+        assert got.shape == (c.bucket, 2, 4)
+        onp.testing.assert_array_equal(got[start:start + 5], want)
+        assert not got[:start].any() and not got[start + 5:].any()
+    for other in (0, 2):                     # no other slot was touched
+        assert not any(r.any() for r in _resident_rows(c, other))
+    assert c.positions[slot] == start + 5
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (pjit, cond, while)
+    included."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_decode_step_relayouts_no_resident_buffer(decode_model):
+    """The decode step must compute in the order the cache stores: no
+    ``transpose`` of a whole K/V buffer, and no ``scatter`` into one (a
+    TPU scatter wants its update window on the minor axes, so XLA
+    relayouts the whole buffer around it, every layer, every token —
+    two thirds of a decode step before PERF.md's PR 27).  The on-chip
+    form of this guard reads the optimized HLO (chip_smoke.py)."""
+    import jax
+    S = 3
+    c = PagedKVCache(decode_model.n_layers, decode_model.num_heads,
+                     decode_model.head_dim, max_slots=S, buckets=(16,))
+    resident = sorted(c.k(0).shape)     # in any axis order
+    zeros = onp.zeros((S,), "int32")
+    jaxpr = jax.make_jaxpr(decode_model._step_fn._jitted)(
+        decode_model.params, c._k, c._v, zeros, zeros,
+        *decode_model.greedy_sampling(S))
+    seen = set()
+    for e in _eqns(jaxpr.jaxpr):
+        seen.add(e.primitive.name)
+        if e.primitive.name in ("transpose", "scatter", "scatter-add"):
+            assert resident not in [sorted(v.aval.shape)
+                                    for v in e.invars], \
+                f"{e.primitive.name} over a resident KV buffer: {e}"
+    # the walk really went inside the jitted program
+    assert {"dynamic_update_slice", "dot_general"} <= seen
+
+
+def test_chip_smoke_hlo_guard_reads_copies_of_a_kv_buffer():
+    """chip_smoke.cache_sized_relayouts on what the v5e's compiler
+    gave for gpt2_774m at 8 x 1024 (PR 27): the relayouts around a
+    scatter, and the in-place column writes that replaced them."""
+    import chip_smoke
+    n = 8 * 1024 * 20 * 64
+    bad = """\
+  %copy.13 = f32[8,1024,20,64]{3,2,1,0:T(8,128)} copy(%ks_0_.1), sharding={replicated}
+  ROOT %transpose.2 = f32[8,20,64,1024]{3,2,1,0} transpose(%p.1), dimensions={0,2,3,1}
+  %copy.7 = f32[8,20,64]{2,1,0:T(8,128)} copy(%fusion.9)
+"""
+    good = """\
+  %dynamic_update_slice.24 = f32[8,1280,1024]{2,1,0:T(8,128)} dynamic-update-slice(%vs_0_.1, %squeeze.0, %c.1, %c.1, %select_n.38)
+  %copy-start.2 = (f32[8,1280,1024]{2,1,0:T(8,128)S(1)}, f32[8,1280,1024]{2,1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%dynamic_update_slice.31)
+  %copy-done.2 = f32[8,1280,1024]{2,1,0:T(8,128)S(1)} copy-done(%copy-start.2)
+  %copy.15 = f32[1,1280,1]{2,1,0:T(8,128)} copy(%bitcast.4)
+"""
+    assert [l.split(" = ")[0] for l in
+            chip_smoke.cache_sized_relayouts(bad, n)] \
+        == ["%copy.13", "ROOT %transpose.2"]
+    assert chip_smoke.cache_sized_relayouts(good, n) == []
+
+
 # ---------------------------------------------------------------------------
 # greedy parity (incl. a KV-bucket migration mid-decode)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow    # tier-1 time budget (r8): generation-smoke gates greedy parity end-to-end in tier 1
-def test_greedy_parity_vs_uncompiled_reference(gpt, decode_model):
-    eng = _engine(decode_model)
+@pytest.mark.parametrize("buckets, new_tokens, migrations", [
     # 24 new tokens from a 4-token prompt crosses the 16-bucket: the
     # parity window covers prefill, steady decode, AND a live cache
     # migration
+    pytest.param((16, 32, 64), 24, 1, id="16_to_32", marks=pytest.mark.slow),  # tier-1 time budget (r8): generation-smoke gates greedy parity end-to-end in tier 1
+    # one request grows through EVERY bucket of the grid: positions
+    # written before a grow stay where the next bucket's step reads them
+    pytest.param((8, 16, 32), 26, 2, id="through_8_16_32"),
+])
+def test_greedy_parity_vs_uncompiled_reference(gpt, decode_model, buckets,
+                                               new_tokens, migrations):
+    eng = _engine(decode_model, kv_buckets=buckets)
     m0 = metrics.value("mxnet_gen_kv_migrations_total")
-    s = eng.submit(PROMPT_A, max_new_tokens=24)
+    s = eng.submit(PROMPT_A, max_new_tokens=new_tokens)
     _drain(eng, s)
     got = s.result(timeout=10)
-    assert got == _reference_greedy(gpt, PROMPT_A, 24)
+    assert got == _reference_greedy(gpt, PROMPT_A, new_tokens)
     assert s.finish_reason == "length"
-    assert metrics.value("mxnet_gen_kv_migrations_total") == m0 + 1
+    assert metrics.value("mxnet_gen_kv_migrations_total") \
+        == m0 + migrations
 
 
 def test_decode_zero_compiles_after_warmup(gpt, decode_model):
