@@ -745,6 +745,22 @@ GEN_KV_MIGRATIONS_TOTAL = counter(
     "KV-cache capacity-bucket migrations (cache grew to the next "
     "power-of-two length bucket; each switches the engine to that "
     "bucket's pre-compiled decode step).")
+GEN_CACHE_BYTES = gauge(
+    "mxnet_gen_cache_bytes",
+    "Device bytes the generation engine's slot cache has allocated, by "
+    "kind of per-slot state: rows (K/V rows that grow with the "
+    "sequence, in the bucket grid), window (K/V rows capped at the "
+    "attention window), state (recurrent state of fixed size).",
+    labels=("kind",))
+GEN_CACHE_LIVE_BYTES = gauge(
+    "mxnet_gen_cache_live_bytes",
+    "Bytes of mxnet_gen_cache_bytes that the live slots' sequences "
+    "hold, by kind: their resident rows, their window rows (at most "
+    "the window), their state.", labels=("kind",))
+GEN_STATE_INSTALLS_TOTAL = counter(
+    "mxnet_gen_state_installs_total",
+    "Admissions that installed fixed-size state (recurrent state, conv "
+    "tail, window rows) into a slot beside the prompt's K/V rows.")
 GEN_SAMPLED_TOKENS_TOTAL = counter(
     "mxnet_gen_sampled_tokens_total",
     "Tokens emitted by the generation engine, by decode method "

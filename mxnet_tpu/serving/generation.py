@@ -53,8 +53,8 @@ from ..base import MXNetError, getenv, register_env
 from .. import metrics as _metrics
 from .. import tracing as _tracing
 from .batching import REQUESTS_TOTAL, SlotScheduler
-from .kv_cache import (PagedKVCache, PrefixCache, prefix_key,
-                       round_up_bucket, _shrink_rows)
+from .kv_cache import (PrefixCache, prefix_key, round_up_bucket,
+                       _shrink_rows)
 from .model import DecodeModel, METHOD_CODES
 
 __all__ = ["GenerationEngine", "GenRequest", "StreamTimeout",
@@ -481,14 +481,37 @@ class GenerationEngine:
             raise MXNetError(
                 f"no KV bucket <= model max_length {model.max_length} "
                 f"(grid {full})")
-        self.cache = PagedKVCache(
-            model.n_layers, model.num_heads, model.head_dim,
-            self.max_slots, buckets=self.grid, dtype=model.dtype,
-            prefix=prefix_cache, prefix_slots=prefix_slots)
-        # prompt pad grid: powers of two up to the top usable bucket —
+        self.spec_mode = str(
+            spec_mode if spec_mode is not None
+            else getenv("MXNET_GEN_SPEC_MODE", "off"))
+        if not model.supports_rollback:
+            # speculation rewinds a slot and the prefix cache shares
+            # its rows; a family whose slots also hold recurrent state
+            # would need snapshots of it for either.  The prefix
+            # cache's default is the environment's, so only an
+            # explicit request is refused
+            if self.spec_mode != "off" or draft_model is not None:
+                raise MXNetError(
+                    f"spec_mode={self.spec_mode!r} is not available "
+                    f"for the {model.family} family: rejected drafts "
+                    "would have to rewind its recurrent state, of "
+                    "which no snapshot is taken yet")
+            if prefix_slots or (prefix_cache is not None
+                                and prefix_cache.slots):
+                raise MXNetError(
+                    f"prefix_slots > 0 is not available for the "
+                    f"{model.family} family: a shared prefix would "
+                    "have to carry the recurrent state at its end, of "
+                    "which no snapshot is taken yet")
+            prefix_slots, prefix_cache = 0, None
+        self.cache = model.make_cache(
+            self.max_slots, self.grid, prefix_slots=prefix_slots,
+            prefix=prefix_cache)
+        # prompt pad grid: powers of two up to the top usable bucket
+        # (or the longest prompt the family prefills in one program) —
         # mixed prompt lengths land on a handful of prefill programs
-        top = self.grid[-1]
-        pb, b = [], 8
+        top = min(self.grid[-1], model.max_prompt or self.grid[-1])
+        pb, b = [], model.min_prompt_bucket
         while b < top:
             pb.append(b)
             b *= 2
@@ -530,9 +553,6 @@ class GenerationEngine:
         # per-request speculative=False opts out and mixed iterations
         # ride the verify program together (plain slots just keep only
         # the first verified token)
-        self.spec_mode = str(
-            spec_mode if spec_mode is not None
-            else getenv("MXNET_GEN_SPEC_MODE", "off"))
         self.spec_k = int(
             spec_k if spec_k is not None
             else getenv("MXNET_GEN_SPEC_K", 4))
@@ -705,6 +725,11 @@ class GenerationEngine:
                 f"({max_new_tokens}) needs {need} positions; the top "
                 f"KV bucket / model ceiling is {self.grid[-1]} "
                 "(raise MXNET_GEN_KV_BUCKETS or shorten the request)")
+        if toks.size > self.prompt_buckets[-1]:
+            raise MXNetError(
+                f"prompt ({toks.size}) is longer than the "
+                f"{self.prompt_buckets[-1]} positions the "
+                f"{self.model.family} family prefills in one program")
         if deadline_ms is None and self._default_deadline_s > 0:
             deadline_ms = self._default_deadline_s * 1e3
         deadline_t = (time.monotonic() + deadline_ms / 1e3
@@ -763,6 +788,7 @@ class GenerationEngine:
                              admitted=len(log["admitted"]),
                              retired=len(log["retired"]), tokens=0)
                 if not active:
+                    self.cache.publish_live_bytes()
                     self.cache.reset_if_empty()
                     if self._draft is not None:
                         self._draft.reset_if_empty()
@@ -777,6 +803,7 @@ class GenerationEngine:
                 with _tracing.child_span("engine.emit") as esp:
                     n_streamed = self._emit(active, next_tok, spec, log)
                     esp.set_attr(tokens=n_streamed)
+                    self.cache.publish_live_bytes()
                 isp.set_attr(tokens=n_streamed)
         except Exception as e:   # noqa: BLE001 - a decode fault is the
             # in-flight sequences' alone; anything else is the worker's
@@ -1144,8 +1171,11 @@ class GenerationEngine:
                 _metrics.GEN_PREFIX_HITS_TOTAL.inc()
             else:
                 pb = round_up_bucket(t0, self.prompt_buckets)
-                logits, ks, vs = self.model.prefill(req.tokens, pb)
-                self.cache.write_prompt(slot, ks, vs, t0)
+                # a family with fixed-size state hands it back fourth
+                logits, ks, vs, *state = self.model.prefill(req.tokens,
+                                                            pb)
+                self.cache.write_prompt(slot, ks, vs, t0,
+                                        state=state[0] if state else None)
                 if cacheable:
                     _metrics.GEN_PREFIX_MISSES_TOTAL.inc()
                     self._insert_prefix(req, ks, vs, logits)

@@ -38,6 +38,16 @@ This cache provides that shape discipline:
   the decode step's outputs each iteration, so XLA can update the
   cache in place (the buffers are donated to the compiled step).
 
+* **Kinds of per-slot state** — a layer's ``kind`` says what a slot
+  holds of it: ``rows`` (the above: K/V rows that grow, in the bucket
+  grid), ``window`` (K/V rows capped at the attention window,
+  ``(S, heads * d, window)`` whatever the bucket, used as a ring by
+  the family's step), ``state`` (arrays of fixed shape, float32: a
+  recurrence's state) or ``none``.  The GPT family is ``rows`` in
+  every layer; ``serving.hybrid`` mixes all four.  One slot table
+  serves them all: admission installs every kind for its slot
+  (:meth:`PagedKVCache.write_prompt`), only ``rows`` grow.
+
 Positions/occupancy are host-side numpy bookkeeping: the device only
 ever sees the fixed-shape buffers plus an ``(S,)`` position vector.
 """
@@ -95,6 +105,9 @@ def round_up_bucket(n: int, grid: Sequence[int]) -> int:
         "reject the request (or raise MXNET_GEN_KV_BUCKETS)")
 
 
+KINDS = ("rows", "window", "state", "none")
+
+
 class PagedKVCache:
     """Per-layer ``(max_slots, heads * head_dim, L)`` K/V buffers plus
     host-side slot bookkeeping.
@@ -102,6 +115,13 @@ class PagedKVCache:
     ``layers`` buffers live as jax arrays (device-resident); ``k(i)`` /
     ``v(i)`` hand them to the decode step and :meth:`replace` swaps in
     the step's outputs (donation-compatible).
+
+    ``kinds`` names each layer's kind of per-slot state (module
+    docstring; default: ``rows`` everywhere).  ``k(i)`` / ``v(i)``
+    count the ``rows`` layers; the ``window`` layers' K and V rings of
+    ``window`` rows and the ``state`` layers' arrays (``state_shapes``:
+    {name: shape behind the slot axis}, one of each a layer) are the
+    lists of :attr:`state`.
     """
 
     def __init__(self, n_layers: int, n_heads: int, head_dim: int,
@@ -109,10 +129,32 @@ class PagedKVCache:
                  buckets: Optional[Sequence[int]] = None,
                  dtype: Any = None,
                  prefix_slots: Optional[int] = None,
-                 prefix: Optional["PrefixCache"] = None) -> None:
+                 prefix: Optional["PrefixCache"] = None,
+                 kinds: Optional[Sequence[str]] = None,
+                 window: int = 0,
+                 state_shapes: Optional[Dict[str, Tuple[int, ...]]] = None
+                 ) -> None:
         import jax.numpy as jnp
         self.grid = kv_bucket_grid(buckets)
         self.n_layers = int(n_layers)
+        self.kinds = tuple(kinds) if kinds is not None \
+            else ("rows",) * self.n_layers
+        if len(self.kinds) != self.n_layers \
+                or not set(self.kinds) <= set(KINDS):
+            raise MXNetError(
+                f"kinds must name one of {KINDS} for each of the "
+                f"{self.n_layers} layers, got {self.kinds!r}")
+        self.n_rows = self.kinds.count("rows")
+        self.n_window = self.kinds.count("window")
+        self.n_state = self.kinds.count("state")
+        self.window = int(window)
+        self.state_shapes = {name: tuple(int(d) for d in shape)
+                             for name, shape in (state_shapes or {}).items()}
+        if (self.n_window and self.window < 1) \
+                or (self.n_state and not self.state_shapes):
+            raise MXNetError(
+                "window layers need window >= 1 and state layers "
+                "their state_shapes")
         self.n_heads = int(n_heads)
         self.head_dim = int(head_dim)
         self.max_slots = int(max_slots)
@@ -121,9 +163,20 @@ class PagedKVCache:
         self.dtype = jnp.dtype(dtype) if dtype is not None \
             else jnp.float32
         self.bucket = self.grid[0]
+        # bytes one slot holds of each kind: a row (K and V, every
+        # ``rows`` layer), a window row, the whole (float32) state
+        row = 2 * self.n_heads * self.head_dim \
+            * _np.dtype(self.dtype).itemsize
+        self._slot_bytes = {
+            "rows": self.n_rows * row, "window": self.n_window * row,
+            "state": 4 * self.n_state * sum(
+                int(_np.prod(s)) for s in self.state_shapes.values())}
         self._k: List[Any] = []
         self._v: List[Any] = []
+        # the fixed-size kinds, {name: one buffer a layer of the kind}
+        self.state: Dict[str, List[Any]] = {}
         self._alloc_buffers(self.bucket)
+        self._alloc_fixed()
         # host bookkeeping: next write position per slot (== tokens
         # resident in the row), -1 marks a free slot
         self.positions = _np.full((self.max_slots,), -1, _np.int64)
@@ -150,9 +203,61 @@ class PagedKVCache:
         zeros = _np.zeros(shape, self.dtype)
         dev = jax.local_devices()[0]
         self._k = [jax.device_put(zeros, dev)
-                   for _ in range(self.n_layers)]
+                   for _ in range(self.n_rows)]
         self._v = [jax.device_put(zeros, dev)
-                   for _ in range(self.n_layers)]
+                   for _ in range(self.n_rows)]
+        _metrics.GEN_CACHE_BYTES.labels(kind="rows").set(
+            self.bytes_by_kind()["rows"])
+
+    def _fixed_shapes(self) -> Dict[str, Tuple[int, Tuple[int, ...], Any]]:
+        """{name: (buffers, shape behind the slot axis, dtype)} of the
+        kinds whose size does not follow the bucket."""
+        shapes: Dict[str, Tuple[int, Tuple[int, ...], Any]] = {}
+        if self.n_window:
+            ring = (self.n_heads * self.head_dim, self.window)
+            shapes["wk"] = shapes["wv"] = (self.n_window, ring, self.dtype)
+        if self.n_state:
+            for name, shape in self.state_shapes.items():
+                shapes[name] = (self.n_state, shape, _np.float32)
+        return shapes
+
+    def _fixed_zeros(self, lead: Tuple[int, ...]) -> Dict[str, List[Any]]:
+        """The fixed-size kinds zeroed, ``lead`` before each shape
+        (host zeros, committed: see ``_alloc_buffers``)."""
+        import jax
+        dev = jax.local_devices()[0]
+        return {name: [jax.device_put(_np.zeros(lead + shape, dtype), dev)
+                       for _ in range(n)]
+                for name, (n, shape, dtype) in self._fixed_shapes().items()}
+
+    def _alloc_fixed(self) -> None:
+        """(Re)allocate the ``window`` and ``state`` buffers."""
+        self.state = self._fixed_zeros((self.max_slots,))
+        allocated = self.bytes_by_kind()
+        for kind in ("window", "state"):
+            _metrics.GEN_CACHE_BYTES.labels(kind=kind).set(allocated[kind])
+
+    def bytes_by_kind(self) -> Dict[str, int]:
+        """Device bytes allocated, by kind."""
+        per = self._slot_bytes
+        return {"rows": int(self.max_slots * self.bucket * per["rows"]),
+                "window": int(self.max_slots * self.window * per["window"]),
+                "state": int(self.max_slots * per["state"])}
+
+    def live_bytes_by_kind(self) -> Dict[str, int]:
+        """Of :meth:`bytes_by_kind`, what the live slots' sequences
+        hold: their resident rows, at most ``window`` of them in the
+        window layers, and their state."""
+        per = self._slot_bytes
+        live = self.positions[self.positions >= 0]
+        return {"rows": int(live.sum() * per["rows"]),
+                "window": int(_np.minimum(live, self.window).sum()
+                              * per["window"]),
+                "state": int(live.size * per["state"])}
+
+    def publish_live_bytes(self) -> None:
+        for kind, n in self.live_bytes_by_kind().items():
+            _metrics.GEN_CACHE_LIVE_BYTES.labels(kind=kind).set(n)
 
     def k(self, layer: int) -> Any:
         return self._k[layer]
@@ -163,11 +268,21 @@ class PagedKVCache:
     def layers(self) -> List[Tuple[Any, Any]]:
         return list(zip(self._k, self._v))
 
-    def replace(self, new_k: Sequence[Any], new_v: Sequence[Any]) -> None:
+    def buffers(self) -> Tuple[Any, ...]:
+        """What the decode step consumes (donated) and :meth:`replace`
+        takes back: the rows' K and V lists and, where the cache holds
+        them, the fixed-size kinds."""
+        return (self._k, self._v, self.state) if self.state \
+            else (self._k, self._v)
+
+    def replace(self, new_k: Sequence[Any], new_v: Sequence[Any],
+                state: Optional[Dict[str, List[Any]]] = None) -> None:
         """Swap in the decode step's updated buffers (the old ones were
         donated to the compiled call)."""
         self._k = list(new_k)
         self._v = list(new_v)
+        if state is not None:
+            self.state = state
 
     # -- slots --------------------------------------------------------------
     def free_slots(self) -> List[int]:
@@ -190,7 +305,9 @@ class PagedKVCache:
     # -- admission write ----------------------------------------------------
     def write_prompt(self, slot: int, ks: Sequence[Any],
                      vs: Sequence[Any], t0: int,
-                     start: int = 0) -> None:
+                     start: int = 0,
+                     state: Optional[Dict[str, List[Any]]] = None
+                     ) -> None:
         """Install prefilled rows into ``slot``: ``ks[l]``/``vs[l]``
         are ``(Lp, heads, d)`` (padded to a length bucket; the pad rows
         carry garbage KV that stays masked until the decode loop
@@ -200,7 +317,18 @@ class PagedKVCache:
         at the prefix length (``start`` is a traced operand, so every
         offset shares one compiled write per shape pair).  ``t0`` is
         the slot's resident-token count after the write.  Grows the
-        cache first if the rows exceed the current bucket."""
+        cache first if the rows exceed the current bucket.
+
+        ``state`` holds the slot's fixed-size kinds at position ``t0``
+        in the layout of :attr:`state` without the slot axis (the
+        window rings, the recurrence's arrays).  It REPLACES whatever
+        the slot's last owner left, whole, so nothing of a freed
+        slot's request reaches the next one."""
+        if bool(self.state) != (state is not None):
+            raise MXNetError(
+                f"a slot of this cache holds rows and "
+                f"{sorted(self.state) or 'nothing else'}; an admission "
+                "has to install exactly that")
         Lp = int(ks[0].shape[0])
         with _tracing.child_span("kv.write_prompt", rows=Lp,
                                  bucket=self.bucket):
@@ -214,8 +342,14 @@ class PagedKVCache:
             out = _write_rows_jit(self._k + self._v,
                                   list(ks) + list(vs),
                                   _np.int32(slot), _np.int32(start))
-            self._k = out[:self.n_layers]
-            self._v = out[self.n_layers:]
+            self._k = out[:self.n_rows]
+            self._v = out[self.n_rows:]
+        if state is not None:
+            with _tracing.child_span("cache.install_state", slot=int(slot),
+                                     rows=min(int(t0), self.window)):
+                self.state = _install_state_jit(self.state, state,
+                                                _np.int32(slot))
+            _metrics.GEN_STATE_INSTALLS_TOTAL.inc()
         self.positions[slot] = int(t0)
 
     # -- rollback -----------------------------------------------------------
@@ -273,6 +407,8 @@ class PagedKVCache:
         self.bucket = new_bucket
         _metrics.GEN_KV_MIGRATIONS_TOTAL.inc()
         _metrics.GEN_KV_BUCKET_LEN.set(new_bucket)
+        _metrics.GEN_CACHE_BYTES.labels(kind="rows").set(
+            self.bytes_by_kind()["rows"])
 
     def warmup_writes(self, prompt_buckets: Sequence[int]) -> int:
         """Pre-compile every admission/migration executable: the
@@ -292,13 +428,19 @@ class PagedKVCache:
                 rows = [jax.device_put(
                     _np.zeros((int(Lp), self.n_heads, self.head_dim),
                               self.dtype), dev)
-                    for _ in range(2 * self.n_layers)]
+                    for _ in range(2 * self.n_rows)]
                 # one fused write covers every layer's K and V; zeros
                 # into zeros is a no-op in content
                 out = _write_rows_jit(self._k + self._v, rows,
                                       _np.int32(0), _np.int32(0))
-                self._k = out[:self.n_layers]
-                self._v = out[self.n_layers:]
+                self._k = out[:self.n_rows]
+                self._v = out[self.n_rows:]
+                # one write at a time: each holds a second copy of the
+                # buffers it writes, and with every program loaded from
+                # the compile cache the next is dispatched before the
+                # last has run, so the copies pile up (five of 1.3 GB
+                # at 64 x 4096, PERF.md PR 28)
+                out[0].block_until_ready()
                 n += 1
             for L2 in self.grid[i + 1:]:
                 # live migrations may leap buckets (a long-prompt
@@ -314,10 +456,16 @@ class PagedKVCache:
                 rows = [jax.device_put(
                     _np.zeros((Lp, self.n_heads, self.head_dim),
                               self.dtype), dev)
-                    for _ in range(2 * self.n_layers)]
+                    for _ in range(2 * self.n_rows)]
                 for Pb in pbs[:i]:
                     _shrink_rows(rows, Pb)
                     n += 1
+        if self.state:
+            # the fixed-size kinds have one shape whatever the bucket
+            # and the prompt: one install program
+            self.state = _install_state_jit(
+                self.state, self._fixed_zeros(()), _np.int32(0))
+            n += 1
         self.bucket = self.grid[0]
         self._alloc_buffers(self.bucket)
         return n
@@ -329,6 +477,7 @@ class PagedKVCache:
         pointing at deleted arrays — without this, every later
         admission would fail on them forever."""
         self._alloc_buffers(self.bucket)
+        self._alloc_fixed()
 
     def reset_if_empty(self) -> None:
         """Shrink back to the smallest bucket once no sequence is live
@@ -345,14 +494,20 @@ class PagedKVCache:
             "buckets": list(self.grid),
             "occupancy": self.occupancy(),
             "layers": self.n_layers,
+            "kinds": {k: self.kinds.count(k) for k in KINDS
+                      if k in self.kinds},
+            "window": self.window,
+            "bytes": self.bytes_by_kind(),
             "heads": self.n_heads,
             "head_dim": self.head_dim,
             "dtype": str(self.dtype),
             # axis order of every resident buffer (module docstring)
             "layout": "(max_slots, heads*head_dim, bucket)",
             # where the buffers actually live, not where they were asked
-            "platforms": sorted({d.platform for b in self._k + self._v
-                                 for d in b.devices()}),
+            "platforms": sorted({
+                d.platform
+                for b in self._k + self._v + sum(self.state.values(), [])
+                for d in b.devices()}),
             "prefix_cache": self.prefix.describe(),
         }
 
@@ -411,20 +566,44 @@ def _make_write_rows():
         pin=True)
 
 
-class _LazyWrite:
+class _Lazy:
     """Defer the jax import to first use (the serving package must stay
     importable without touching the backend)."""
 
-    def __init__(self) -> None:
-        self._fn = None
+    def __init__(self, make) -> None:
+        self._make, self._fn = make, None
 
-    def __call__(self, bufs, rows, slot, start):
+    def __call__(self, *args):
         if self._fn is None:
-            self._fn = _make_write_rows()
-        return self._fn(bufs, rows, slot, start)
+            self._fn = self._make()
+        return self._fn(*args)
 
 
-_write_rows_jit = _LazyWrite()
+_write_rows_jit = _Lazy(_make_write_rows)
+
+
+def _make_install_state():
+    import jax
+    from jax import lax
+
+    def install(bufs, new, slot):
+        # bufs: {name: [(S, ...) a layer]}; new: the same without the
+        # slot axis.  The whole of the slot is replaced.  DONATED: the
+        # window rings are as large as a bucket's rows and an
+        # un-donated write would hold them twice.  A plain jax.jit on
+        # purpose: the donated multi-buffer write that mis-aliased
+        # (_make_write_rows) did so when deserialized by
+        # compile_cache, which this program therefore never enters;
+        # jax's own cache keeps it across restarts as it keeps the
+        # donated decode step
+        return jax.tree_util.tree_map(
+            lambda b, r: lax.dynamic_update_slice(
+                b, r[None].astype(b.dtype), (slot,) + (0,) * r.ndim),
+            bufs, new)
+    return jax.jit(install, donate_argnums=(0,))
+
+
+_install_state_jit = _Lazy(_make_install_state)
 
 
 def _shrink_rows(rows: List[Any], new_len: int) -> List[Any]:

@@ -312,6 +312,16 @@ def _sample_tokens(logits, seeds, ctrs, temps, topks, topps, methods):
     return jnp.where(methods == 0, greedy, sampled)
 
 
+def _select_one(logits, seed, ctr, temp, topk, topp, method):
+    """The first-token selector (prefill logits -> token): the SAME
+    fused sampler on one row, so host-emitted first tokens and
+    step-emitted tokens share one code path and one key-stream
+    discipline."""
+    return _sample_tokens(
+        logits[None], seed[None], ctr[None], temp[None],
+        topk[None], topp[None], method[None])[0]
+
+
 def _slot_block_step(p, x, ck, cv, pos, nh: int, ga):
     """One decode token for EVERY slot: ``x`` (S, 1, C), caches
     (S, C, L) — heads and head dim on one axis, positions last: the
@@ -439,7 +449,21 @@ class DecodeModel:
     the one-shot path (``mxnet_serving_bucket_compiles_total``, labels
     ``decode:SxL`` / ``prefill:Lp``), so warmup moves every compile to
     startup and the smoke gate can pin "0 after warmup".
+
+    A zoo family with another kind of layer is a subclass
+    (``serving.hybrid.HybridDecodeModel``) that ``from_block`` picks by
+    the block's type; the class attributes below are what the engine
+    reads of a family.
     """
+
+    family = "gpt"
+    # longest prompt one prefill program takes (None: the top KV bucket)
+    # and the smallest bucket prompts are padded to
+    max_prompt: Optional[int] = None
+    min_prompt_bucket = 8
+    # whether a slot can be rewound (speculation) and its rows shared
+    # (prefix cache): true where rows are all a slot holds
+    supports_rollback = True
 
     def __init__(self, params: Any, num_heads: int, ga: Tuple[Any, Any],
                  max_length: int, name: str) -> None:
@@ -454,6 +478,7 @@ class DecodeModel:
         self.head_dim = self.units // self.num_heads
         self.n_layers = len(params["blocks"])
         self.dtype = params["blocks"][0]["qkv_w"].dtype
+        self.logits_dtype = self.dtype
         self._seen_lock = threading.Lock()
         self._seen: set = set()
         nh, ga_s = self.num_heads, self.ga
@@ -587,15 +612,6 @@ class DecodeModel:
             h = lax.dynamic_slice_in_dim(x[0], t0 - 1, 1, axis=0)[0]
             return h @ params["embed"].T, ks_o, vs_o
 
-        def _select_one(logits, seed, ctr, temp, topk, topp, method):
-            # the first-token selector (prefill logits -> token): the
-            # SAME fused sampler on one row, so host-emitted first
-            # tokens and step-emitted tokens share one code path and
-            # one key-stream discipline
-            return _sample_tokens(
-                logits[None], seed[None], ctr[None], temp[None],
-                topk[None], topp[None], method[None])[0]
-
         # all programs persist through the compile cache (pinned: a
         # live server's decode grid is never evicted) so a restarted
         # replica re-warms its whole bucket grid with zero XLA compiles
@@ -622,20 +638,35 @@ class DecodeModel:
     # -- constructors -------------------------------------------------------
     @staticmethod
     def from_block(block: Any) -> "DecodeModel":
-        """Build from a live zoo ``GPTModel`` (weights as currently
-        initialized/loaded; MoE decode is not supported yet — same
-        restriction as ``model_zoo.generation``)."""
+        """Build from a live zoo LM (weights as currently
+        initialized/loaded): a ``GPTModel`` (MoE decode is not
+        supported yet — same restriction as ``model_zoo.generation``)
+        or a ``Phi4FlashModel``, which gets its own subclass."""
         from ..gluon.model_zoo.generation import _collect
+        from ..gluon.model_zoo.phi4flash import Phi4FlashModel
+        if isinstance(block, Phi4FlashModel):
+            from .hybrid import HybridDecodeModel
+            return HybridDecodeModel.from_phi4flash(block)
         if not hasattr(block, "blocks") or not hasattr(block,
                                                        "word_embed"):
             raise MXNetError(
-                f"DecodeModel serves decoder-only zoo LMs (GPTModel); "
-                f"got {type(block).__name__}")
+                f"DecodeModel serves decoder-only zoo LMs (GPTModel, "
+                f"Phi4FlashModel); got {type(block).__name__}")
         params = _collect(block)
         ga = (params.pop("gelu_approx"), params.pop("ln_eps"))
         nh = next(iter(block.blocks._children.values()))._num_heads
         return DecodeModel(params, nh, ga, block._max_length,
                            type(block).__name__)
+
+    def make_cache(self, max_slots: int, buckets: Sequence[int],
+                   prefix_slots: Optional[int] = None,
+                   prefix: Any = None) -> Any:
+        """The slot cache this family decodes over."""
+        from .kv_cache import PagedKVCache
+        return PagedKVCache(
+            self.n_layers, self.num_heads, self.head_dim, max_slots,
+            buckets=buckets, dtype=self.dtype, prefix=prefix,
+            prefix_slots=prefix_slots)
 
     # -- execution ----------------------------------------------------------
     def _account(self, tag: str) -> None:
@@ -647,10 +678,12 @@ class DecodeModel:
             BUCKET_COMPILES.labels(bucket=tag).inc()
 
     def prefill(self, tokens: _np.ndarray, bucket_len: int
-                ) -> Tuple[_np.ndarray, List[Any], List[Any]]:
+                ) -> Tuple[Any, ...]:
         """Run the prompt pass padded to ``bucket_len``; returns
         (last-token logits (V,) numpy, per-layer ks/vs device arrays
-        (bucket_len, nh, d))."""
+        (bucket_len, nh, d)) and, for a family whose slots hold more
+        than rows, fourth what ``PagedKVCache.write_prompt(state=)``
+        installs beside them."""
         import jax.numpy as jnp
         toks = _np.asarray(tokens, _np.int32).reshape(-1)
         t0 = toks.shape[0]
@@ -662,15 +695,16 @@ class DecodeModel:
         padded = _np.zeros((bucket_len,), _np.int32)
         padded[:t0] = toks
         self._account(f"prefill:{bucket_len}")
-        with _tracing.child_span("model.prefill", bucket=bucket_len):
+        with _tracing.child_span("model.prefill", bucket=bucket_len,
+                                 family=self.family):
             t = time.perf_counter()
-            logits, ks, vs = self._prefill_fn(
+            logits, *held = self._prefill_fn(
                 self.params, jnp.asarray(padded), _np.int32(t0))
             out = _np.asarray(logits)
             dt = time.perf_counter() - t
         _metrics.GEN_STEP_SECONDS.labels(phase="prefill").observe(
             dt, exemplar=_tracing.current_trace_id())
-        return out, ks, vs
+        return (out, *held)
 
     def greedy_sampling(self, n_slots: int) -> Tuple[_np.ndarray, ...]:
         """All-greedy per-slot sampling vectors (seed, counter base,
@@ -725,15 +759,17 @@ class DecodeModel:
         # tokens) tile the step: the first is host-serial, the second
         # is the device's time
         with _tracing.child_span("model.step", slots=S,
-                                 bucket=cache.bucket):
+                                 bucket=cache.bucket, family=self.family):
             t = time.perf_counter()
             with _tracing.child_span("model.step.dispatch"):
-                toks, new_ks, new_vs = self._step_fn(
-                    self.params, cache._k, cache._v,
+                # every kind of buffer the cache holds is donated and
+                # comes back updated
+                toks, *new = self._step_fn(
+                    self.params, *cache.buffers(),
                     jnp.asarray(_np.asarray(tokens, _np.int32)),
                     jnp.asarray(_np.asarray(positions, _np.int32)),
                     seeds, bases, temps, topks, topps, methods)
-                cache.replace(new_ks, new_vs)
+                cache.replace(*new)
             with _tracing.child_span("model.step.readback"):
                 out = _np.asarray(toks)
             dt = time.perf_counter() - t
@@ -806,7 +842,8 @@ class DecodeModel:
         padded[:t0] = toks
         Pb = int(prefix_ks[0].shape[0])
         self._account(f"prefill_sfx:{Pb}x{bucket_len}")
-        with _tracing.child_span("model.prefill", bucket=bucket_len):
+        with _tracing.child_span("model.prefill", bucket=bucket_len,
+                                 family=self.family):
             t = time.perf_counter()
             logits, ks, vs = self._prefill_sfx_fn(
                 self.params, list(prefix_ks), list(prefix_vs),
@@ -853,7 +890,7 @@ class DecodeModel:
             n += 1
         # one call warms the selector for every method (the method is
         # a traced operand — a single executable)
-        self.select(_np.zeros((self.vocab_size,), self.dtype),
+        self.select(_np.zeros((self.vocab_size,), self.logits_dtype),
                     seed=0, counter=0, temperature=1.0, top_k=1,
                     top_p=1.0, method=0)
         n += 1
@@ -901,6 +938,7 @@ class DecodeModel:
         return {
             "name": self.name,
             "kind": "decode",
+            "family": self.family,
             "vocab_size": int(self.vocab_size),
             "units": int(self.units),
             "layers": self.n_layers,

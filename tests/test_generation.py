@@ -553,7 +553,8 @@ def test_iteration_span_covers_its_whole_quantum(decode_model, traced):
                 parts[1]["t_begin"] - parts[0]["t_end"],
                 step["t_end"] - parts[1]["t_end"])
         assert all(0 <= g < 2e-3 for g in gaps), gaps
-        assert step["attrs"] == {"slots": 2, "bucket": eng.cache.bucket}
+        assert step["attrs"] == {"slots": 2, "bucket": eng.cache.bucket,
+                                 "family": "gpt"}
     emits = [r for r in recs if r["name"] == "engine.emit"]
     assert [e["attrs"]["tokens"] for e in emits] \
         == [i["attrs"]["tokens"] for i in iters if i["attrs"]["tokens"]]

@@ -12,6 +12,8 @@ bucket, and answers on a stdlib HTTP server:
         --batch-timeout-ms 3 --queue-limit 512
     python tools/serve.py --generate --zoo-gpt gpt2_124m   # decoder LM:
         # continuous-batching /v1/generate with per-token streaming
+    python tools/serve.py --generate --zoo-phi4flash phi4_mini_flash \
+        --max-slots 64 --kv-buckets 1024,2048,4096    # hybrid LM, bfloat16
 
     curl -s localhost:8080/v1/inference -d '{"instances": [[...]]}'
     curl -sN localhost:8080/v1/generate \
@@ -22,8 +24,10 @@ bucket, and answers on a stdlib HTTP server:
 Knobs default from the MXNET_SERVING_* env tier, plus MXNET_GEN_* for
 --generate (docs/serving.md).  Static exports serve exactly their
 traced batch size; export with ``dynamic_batch=True`` for the full
-bucket grid.  --generate serves a LIVE decoder LM (zoo GPT, optionally
-with --gpt-params weights) through the resident decode loop.
+bucket grid.  --generate serves a LIVE decoder LM (zoo GPT in float32,
+or with --zoo-phi4flash the Phi-4-mini-flash hybrid in bfloat16, for
+which speculation and the prefix cache are refused; optionally with
+--gpt-params weights) through the resident decode loop.
 
 Resilience (docs/serving.md#resilience): --replicas N hosts N worker
 replicas (dead workers requeue/recover their requests and restart with
@@ -102,7 +106,16 @@ def main(argv=None) -> None:
                          "random unless --gpt-params is given)")
     ap.add_argument("--gpt-params", default=None,
                     help="a .params file to load into the --zoo-gpt "
-                         "model before serving")
+                         "or --zoo-phi4flash model before serving")
+    ap.add_argument("--zoo-phi4flash", default=None,
+                    choices=("phi4_mini_flash", "tiny"),
+                    help="serve the Phi-4-mini-flash hybrid family "
+                         "(Mamba state, windowed and full differential "
+                         "attention, Gated Memory Units) for --generate "
+                         "instead of --zoo-gpt: phi4_mini_flash is the "
+                         "published 3.85 B model in bfloat16, 'tiny' an "
+                         "8-layer float32 one for the CPU.  Speculation "
+                         "and the prefix cache are refused for it")
     ap.add_argument("--max-slots", type=int, default=None,
                     help="decode slots for --generate "
                          "(MXNET_GEN_MAX_SLOTS)")
@@ -240,16 +253,25 @@ def _serve_generate(args, serving) -> None:
     engine (resident decode loop, paged KV cache, token streaming)."""
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo.gpt import GPTModel, get_gpt
+    from mxnet_tpu.gluon.model_zoo.phi4flash import get_phi4flash
 
     mx.random.seed(0)
-    if args.zoo_gpt == "tiny":       # CPU tire-kicking: boots fast
+    if args.zoo_phi4flash:
+        net = get_phi4flash(
+            args.zoo_phi4flash,
+            dtype="float32" if args.zoo_phi4flash == "tiny" else "bfloat16")
+        # inference only: a gradient buffer beside each of 3.85 B
+        # bfloat16 weights would not fit one chip
+        net.collect_params().setattr("grad_req", "null")
+    elif args.zoo_gpt == "tiny":     # CPU tire-kicking: boots fast
         net = GPTModel(vocab_size=503, num_layers=2, units=64,
                        hidden_size=128, num_heads=4, max_length=256,
                        dropout=0.0)
     else:
         net = get_gpt(args.zoo_gpt, dropout=0.0)
     net.initialize()
-    net(mx.np.zeros((1, 4), dtype="int32"))
+    if not args.zoo_phi4flash:       # finishes the deferred shapes
+        net(mx.np.zeros((1, 4), dtype="int32"))
     if args.gpt_params:
         net.load_parameters(args.gpt_params)
         print(f"loaded weights: {args.gpt_params}")
@@ -263,7 +285,11 @@ def _serve_generate(args, serving) -> None:
     # ONE shared prefix store across replicas (same device, same
     # DecodeModel): a prefix any replica prefilled is hot for all of
     # them, and a resurrected sequence lands on warm rows
-    prefix = serving.PrefixCache(args.prefix_cache_slots)
+    # (a family that cannot share prefixes gets none unless slots were
+    # asked for, which the engine then refuses by name)
+    prefix = None \
+        if not (model.supports_rollback or args.prefix_cache_slots) \
+        else serving.PrefixCache(args.prefix_cache_slots)
 
     def engine_factory():
         # one engine per worker replica; the shared DecodeModel means
