@@ -26,7 +26,8 @@
 #                           #   one-shot-per-token, 0 decode compiles
 #                           #   after warmup, clean shed under a
 #                           #   2x-slot flood; PLUS the speculative
-#                           #   leg: draft/verify >=1.3x tokens/sec,
+#                           #   leg: draft/verify >=1.3x tokens/sec
+#                           #   over the plain engine in serial order,
 #                           #   accepted/step >1.0, byte-identical
 #                           #   streams, rollback + worker-kill legs
 #   ci/run.sh resilience-smoke # serving resilience gate: seeded
@@ -158,7 +159,8 @@ run_generation_smoke() {
   JAX_PLATFORMS=cpu timeout 900 python tools/serve_bench.py \
     --generate --smoke
   echo "== generation-smoke (speculative): draft/verify decoding"
-  echo "   >=1.3x tokens/sec over the non-speculative engine,"
+  echo "   >=1.3x tokens/sec over the non-speculative engine run in"
+  echo "   serial order (vs. one step in flight: reported),"
   echo "   accepted-tokens/step >1.0, greedy AND sampled streams"
   echo "   byte-identical at the same seeds, truncated-draft leg"
   echo "   rejects+rolls back KV rows without changing a byte, seeded"

@@ -706,8 +706,11 @@ GEN_TOKENS_TOTAL = counter(
 GEN_STEP_SECONDS = histogram(
     "mxnet_gen_step_seconds",
     "Wall time of one generation-engine model execution, by phase "
-    "(prefill = one prompt admitted; decode = one iteration over ALL "
-    "active slots) — the prefill/decode split of engine time.",
+    "(prefill = one prompt admitted; decode = one step over ALL "
+    "active slots, from the later of its dispatch and the previous "
+    "step's tokens reaching the host to its own tokens reaching the "
+    "host: what the step cost the loop) — the prefill/decode split of "
+    "engine time.",
     labels=("phase",),
     buckets=exponential_buckets(0.0005, 2.0, 14))
 GEN_TTFT_SECONDS = histogram(
@@ -725,6 +728,26 @@ GEN_ITERATIONS_TOTAL = counter(
     "mxnet_gen_iterations_total",
     "Decode-loop iterations executed (each runs the resident decode "
     "step once over every active slot).")
+GEN_STEPS_AHEAD_TOTAL = counter(
+    "mxnet_gen_steps_ahead_total",
+    "Decode steps launched before the previous step's tokens were "
+    "read back (fed by its token array on the device): the host's "
+    "dispatch, emit and bookkeeping ran under the device's step.")
+GEN_STEP_FALLBACKS_TOTAL = counter(
+    "mxnet_gen_step_fallbacks_total",
+    "Decode steps launched the serial way (previous tokens read back "
+    "first), by what stood in the way of launching ahead: idle "
+    "(nothing was in flight), finish (a resident sequence ended or "
+    "was about to), admit (a free slot and a waiting request), cancel, "
+    "spec (a speculating iteration). With "
+    "mxnet_gen_steps_ahead_total it counts every step.",
+    labels=("reason",))
+GEN_DISCARDED_TOKENS_TOTAL = counter(
+    "mxnet_gen_discarded_tokens_total",
+    "Decode-step tokens computed for a slot whose stream had already "
+    "ended when they were read (the step was launched before the host "
+    "read the EOS of the one before it, or the consumer cancelled): "
+    "never streamed, in no other token counter.")
 GEN_ADMISSIONS_TOTAL = counter(
     "mxnet_gen_admissions_total",
     "Generation requests admitted into a decode slot (prefill ran).")

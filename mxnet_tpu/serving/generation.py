@@ -24,9 +24,14 @@ PAPERS.md) on top of the pieces PRs 2-5 built:
   written into free slots, and the very next iteration decodes old and
   new sequences together — no resident sequence ever stalls or changes
   its tokens because of an arrival;
-* **retirement is per-step**: a sequence that emits EOS or reaches its
-  max-tokens budget frees its slot at the END of that iteration, and
-  the slot is admissible on the next one;
+* **retirement is per-step**: a sequence that reaches its max-tokens
+  budget frees its slot in the iteration that reads its last token,
+  one that emits EOS an iteration later (the next step was launched
+  before the EOS was read; its token for the slot is discarded);
+* **one step is in flight**: the loop launches step N+1 from step N's
+  token array on the device before it reads N's tokens back, wherever
+  the host knows N+1's inputs without them, so dispatch, emit and
+  bookkeeping run under the device's step and not between steps;
 * **tokens stream out as they exist**: each iteration's (S,) token
   readback is pushed into per-request :class:`TokenStream` queues the
   HTTP layer drains as chunked responses.
@@ -37,7 +42,8 @@ its deadline sheds with the same structured
 :class:`~mxnet_tpu.serving.batching.OverloadError` the one-shot path
 raises.  Faults at the PR-3 ``serving.execute`` site fail only the
 sequences in flight at that iteration; the engine survives and keeps
-serving.  Each iteration runs under the PR-5 hang watchdog.
+serving.  Each wait for a step's tokens runs under the PR-5 hang
+watchdog.
 """
 from __future__ import annotations
 
@@ -408,6 +414,24 @@ def make_recovery_request(req: GenRequest) -> GenRequest:
     return r
 
 
+class _InFlight:
+    """The plain decode step the engine has launched and not read
+    back: its (S,) token array, still on the device; the slot table it
+    was launched over (which no retirement or admission changes before
+    it is read); the positions it wrote at; and the moment ``since``
+    which the loop has been paying for it (its dispatch, or, for a
+    step launched ahead, the readback of the step before it)."""
+
+    __slots__ = ("tokens", "active", "pos", "since")
+
+    def __init__(self, tokens: Any, active: Dict[int, "GenRequest"],
+                 pos: _np.ndarray, since: float) -> None:
+        self.tokens = tokens
+        self.active = active
+        self.pos = pos
+        self.since = since
+
+
 class GenerationEngine:
     """The resident decode loop over a slot table.
 
@@ -421,13 +445,33 @@ class GenerationEngine:
             pass
         print(stream.result())
 
-    ``run_iteration`` is ONE scheduling quantum: retire finished
-    sequences, admit newcomers into freed slots (prefill), then execute
-    one decode step over every active slot.  Everything the iteration
-    does is recorded in :attr:`iteration_log` (bounded ring) — the
-    continuous-batching invariant ("admission changes no resident
-    sequence's tokens") is asserted against these per-iteration slot
-    logs in CI.
+    ``run_iteration`` is ONE scheduling quantum, and the engine keeps
+    ONE plain decode step in flight between quanta.  Where the host
+    already knows the next step's inputs, the quantum launches step N+1
+    (fed by the token array step N returns, still on the device, at
+    its positions plus one) and only then reads step N's tokens back,
+    emits them and bookkeeps, all under the device's N+1.  Otherwise it
+    falls back to the serial order: read N back, emit, retire finished
+    sequences, admit newcomers into freed slots (prefill), launch N+1
+    from the host's tokens.  It falls back for the quantum in which a
+    resident sequence ends or is about to (budget, top bucket, an EOS
+    read last time), a consumer cancelled, a slot is free and a request
+    waits, any resident request speculates, or nothing is in flight;
+    it decides that from its slot table, with no knob.
+
+    An EOS therefore LAGS by one step: the host reads it after the
+    next step was launched, discards that step's token for the slot
+    (never streamed, never counted as generated) and retires the slot
+    one iteration later.  Every stream's tokens, finish reason and
+    indexes are those of the serial order, greedy and sampled.
+    ``cache.positions`` advances when a step is LAUNCHED (it is then
+    the next write index whether or not the token has been read).
+
+    Everything the iteration does is recorded in :attr:`iteration_log`
+    (bounded ring): ``decoded`` lists the slots whose tokens were
+    EMITTED in it.  The continuous-batching invariant ("admission
+    changes no resident sequence's tokens") is asserted against these
+    per-iteration slot logs in CI.
     """
 
     LOG_KEEP = 4096
@@ -537,6 +581,8 @@ class GenerationEngine:
         self._samp = model.greedy_sampling(self.max_slots)
         self._samp_dev: Optional[Any] = None
         self._in_admission: List[GenRequest] = []
+        # the one plain decode step launched and not read back
+        self._flight: Optional[_InFlight] = None
         self.iteration_log: Deque[Dict[str, Any]] = collections.deque(
             maxlen=self.LOG_KEEP)
         self._iter = 0
@@ -608,6 +654,7 @@ class GenerationEngine:
     def close(self) -> None:
         """Fail everything in flight and stop admissions."""
         self.scheduler.close()
+        self._flight = None     # its tokens are nobody's any more
         for slot, req in self.scheduler.active().items():
             self.scheduler.release(slot)
             self.cache.free(slot)
@@ -642,6 +689,9 @@ class GenerationEngine:
                     and not req.is_cancelled():
                 resident.append(req)
         self._in_admission = []
+        # the step in flight is dropped un-read: its tokens are in no
+        # transcript, so the resurrection computes them again
+        self._flight = None
         self.cache.reset_buffers()
         if self._draft is not None:
             self._draft.evacuate()
@@ -762,13 +812,27 @@ class GenerationEngine:
 
     # -- the scheduling quantum ---------------------------------------------
     def run_iteration(self) -> bool:
-        """Retire -> admit -> decode -> emit, once.  Returns True when
-        any work happened (False = idle: nothing active, nothing
-        admissible)."""
+        """One scheduling quantum.  Returns True when any work happened
+        or a step is in flight (False = idle: nothing active, nothing
+        admissible).
+
+        The engine keeps ONE plain decode step in flight between
+        quanta.  Where the host already knows the next step's inputs
+        (the token array the step in flight will return, still on the
+        device; its positions plus one) the quantum RUNS AHEAD: launch
+        step N+1, then read step N's tokens back, emit them and
+        bookkeep, all under the device's N+1.  Otherwise it falls back
+        to the serial order, which is the one every quantum had before:
+        read N back, emit, retire, admit, launch N+1 from the host's
+        ``_last_tok``.  :meth:`_fallback_reason` decides, each quantum,
+        from the slot table alone."""
         self._iter += 1
         log: Dict[str, Any] = {"iter": self._iter, "admitted": [],
                                "retired": [], "decoded": []}
-        active: Dict[int, GenRequest] = {}
+        flight, self._flight = self._flight, None
+        # whom a decode fault hits: the sequences of the step(s) in
+        # flight when it surfaces
+        victims: Dict[int, GenRequest] = flight.active if flight else {}
         decoding = False
         # The iteration span covers the whole quantum, so what is left
         # of it once its children are taken out (retire, the queue pop,
@@ -778,41 +842,200 @@ class GenerationEngine:
         # one of them; instead it LINKS every resident request's trace
         # id, and a request's trace finds "its" decode steps by
         # searching iteration spans that link it.
+        worked = True
         try:
-            with _tracing.span("engine.iteration", iter=self._iter) as isp:
-                self._retire_finished(log)
-                self._admit_pending(log)
-                active = self.scheduler.active()
+            with _tracing.span("engine.iteration", iter=self._iter,
+                               tokens=0) as isp:
+                reason = self._fallback_reason(flight)
+                ahead = None
+                if flight is not None:
+                    decoding = True
+                    if reason is None:
+                        ahead = self._launch(flight.active, flight, None)
+                    next_tok = self._collect(flight)
+                    decoding = False
+                    if ahead is not None:
+                        # it ran under the wait above: the loop pays
+                        # for it from here
+                        ahead.since = time.perf_counter()
+                    self._flight = ahead
+                    self._emit_step(isp, flight.active, log,
+                                    next_tok=next_tok, wrote_at=flight.pos)
+                if ahead is not None:
+                    active = flight.active
+                    # the queue is still visited: with no slot to give,
+                    # it sheds what waited past its deadline
+                    self.scheduler.pop_admissions(0)
+                else:
+                    self._retire_finished(log)
+                    self._admit_pending(log)
+                    active = victims = self.scheduler.active()
+                    if not active:
+                        worked = bool(flight is not None or log["admitted"]
+                                      or log["retired"])
+                        self.cache.publish_live_bytes()
+                        self.cache.reset_if_empty()
+                        if self._draft is not None:
+                            self._draft.reset_if_empty()
+                    elif not self._speculating(active):
+                        decoding = True
+                        self._flight = self._launch(active, None, reason)
+                    elif flight is None:
+                        # a speculating quantum is wholly serial:
+                        # accepted lengths, hence positions, depend on
+                        # its tokens
+                        decoding = True
+                        spec = self._decode_spec(active)
+                        decoding = False
+                        self._emit_step(isp, active, log, spec=spec)
+                        self.cache.publish_live_bytes()
+                    # else: this quantum emitted a plain step and then
+                    # admitted a speculating request; the next drafts
+                    decoding = False
                 _metrics.GEN_SLOTS_ACTIVE.set(len(active))
                 isp.set_attr(slots=len(active),
                              admitted=len(log["admitted"]),
-                             retired=len(log["retired"]), tokens=0)
-                if not active:
-                    self.cache.publish_live_bytes()
-                    self.cache.reset_if_empty()
-                    if self._draft is not None:
-                        self._draft.reset_if_empty()
-                    self.iteration_log.append(log)
-                    return bool(log["admitted"] or log["retired"])
+                             retired=len(log["retired"]))
                 for req in active.values():
                     if req.trace is not None:
                         isp.add_link(req.trace.trace_id)
-                decoding = True
-                next_tok, spec = self._decode(active)
-                decoding = False
-                with _tracing.child_span("engine.emit") as esp:
-                    n_streamed = self._emit(active, next_tok, spec, log)
-                    esp.set_attr(tokens=n_streamed)
-                    self.cache.publish_live_bytes()
-                isp.set_attr(tokens=n_streamed)
         except Exception as e:   # noqa: BLE001 - a decode fault is the
             # in-flight sequences' alone; anything else is the worker's
             if not decoding:
                 raise
-            self._decode_fault(active, e, log)
+            self._decode_fault(victims, e, log)
             return True
         self.iteration_log.append(log)
-        return True
+        return worked
+
+    def _emit_step(self, isp: Any, active: Dict[int, "GenRequest"],
+                   log: Dict[str, Any], **step: Any) -> None:
+        with _tracing.child_span("engine.emit") as esp:
+            n_streamed = self._emit(active, log, **step)
+            esp.set_attr(tokens=n_streamed)
+        isp.set_attr(tokens=n_streamed)
+
+    def _fallback_reason(self, flight: Optional["_InFlight"]
+                         ) -> Optional[str]:
+        """Why this quantum cannot launch the next step before it reads
+        the last one back, or None where it can.  Everything here the
+        host knows without the tokens in flight: ``idle`` nothing is in
+        flight (the first step over a batch, or the one after a decode
+        fault, whose victims the retirement counter has); ``cancel`` a
+        resident consumer gave up; ``finish`` a resident stream has
+        ended (an EOS read one step late, a failure) or ends with the
+        token in flight (its budget, or the last row of the top
+        bucket); ``admit`` a slot is free and the queue is not empty.
+        A speculating quantum leaves nothing in flight, so it reads
+        ``idle`` here and is counted ``spec`` where it is launched.
+        Growing the rows is no reason: ``_launch`` queues the
+        migration behind the step in flight like any other program."""
+        if flight is None:
+            return "idle"
+        top = self.grid[-1]
+        for slot, req in flight.active.items():
+            if req.stream.finished:
+                return "cancel" if req.is_cancelled() else "finish"
+            if req.emitted + 1 >= req.max_new_tokens \
+                    or int(flight.pos[slot]) + 1 >= top:
+                return "finish"
+        if len(self.scheduler) and len(flight.active) < self.max_slots:
+            return "admit"
+        return None
+
+    def _speculating(self, active: Dict[int, "GenRequest"]) -> bool:
+        return self._draft is not None and any(
+            getattr(r, "speculative", False) for r in active.values())
+
+    def _launch(self, active: Dict[int, "GenRequest"],
+                after: Optional["_InFlight"], reason: Optional[str]
+                ) -> "_InFlight":
+        """Dispatch one plain decode step over every slot and leave it
+        in flight.  ``after`` is the step in flight whose un-read
+        tokens feed this one (running ahead); without it the host's
+        ``_last_tok`` is uploaded, and ``reason`` says why.
+        ``cache.positions`` advances HERE, at dispatch: the step writes
+        each live slot's column at its position, so the next write
+        index is one on whether or not the token has been read (the
+        capacity check and a launch ahead both need that)."""
+        self.cache.ensure_capacity(self.cache.needed_capacity())
+        pos = _np.maximum(self.cache.positions, 0).astype(_np.int32)
+        if self._samp_dev is None:
+            self._samp_dev = self.model.device_sampling(self._samp)
+        t = time.perf_counter()
+        toks = self.model.dispatch(
+            self.cache, after.tokens if after is not None
+            else self._last_tok, pos, self._samp_dev)
+        self.cache.positions[list(active)] += 1
+        self.cache.publish_live_bytes()
+        if after is not None:
+            _metrics.GEN_STEPS_AHEAD_TOTAL.inc()
+        else:
+            _metrics.GEN_STEP_FALLBACKS_TOTAL.labels(reason=reason).inc()
+        return _InFlight(toks, active, pos, t)
+
+    def _collect(self, flight: "_InFlight") -> _np.ndarray:
+        """Wait for the step in flight; its (S,) tokens on the host.
+        The ``serving.execute`` fault site and the hang watchdog sit
+        here, where the host waits on the device: a fault found now
+        hits the sequences of every step launched and not yet read.
+        Observes ``mxnet_gen_step_seconds{phase="decode"}`` once a
+        step: what the step cost the loop, from the later of its own
+        dispatch and the previous step's tokens reaching the host
+        (``flight.since``) to its own tokens reaching the host."""
+        from .. import faults as _faults
+        from .. import health as _health
+        _faults.maybe_fault("serving.execute", phase="decode",
+                            slots=len(flight.active))
+        with _health.watch_section("generation.step",
+                                   slots=len(flight.active)):
+            out = self.model.collect(flight.tokens)
+        _metrics.GEN_STEP_SECONDS.labels(phase="decode").observe(
+            time.perf_counter() - flight.since,
+            exemplar=_tracing.current_trace_id())
+        return out
+
+    def _decode_spec(self, active: Dict[int, "GenRequest"]
+                     ) -> Tuple[Any, ...]:
+        """One speculative iteration over EVERY active slot, start to
+        end: returns ``(verified, candidates, speculating slots, k)``.
+        When any resident request speculates, the WHOLE iteration rides
+        the draft+verify pair (one program each): the draft proposes k
+        tokens per slot, verify scores all k+1 positions in one target
+        pass, and plain slots simply keep only the first verified token
+        — which is bit-identical to what the plain step would have
+        produced."""
+        from .. import faults as _faults
+        from .. import health as _health
+        spec_k = self._draft.k
+        spec_slots = frozenset(
+            s for s, r in active.items()
+            if getattr(r, "speculative", False))
+        _faults.maybe_fault("serving.execute", phase="decode",
+                            slots=len(active))
+        # verify scatters k rows past every slot's position: grow for
+        # the worst case up front, capped at the grid top (rows past it
+        # belong to tokens the submit-time budget check proves are
+        # never emitted)
+        self.cache.ensure_capacity(
+            min(self.cache.needed_capacity() + spec_k, self.grid[-1]))
+        pos = _np.maximum(self.cache.positions, 0).astype(_np.int32)
+        if self._samp_dev is None:
+            self._samp_dev = self.model.device_sampling(self._samp)
+        _metrics.GEN_STEP_FALLBACKS_TOTAL.labels(reason="spec").inc()
+        with _tracing.child_span("engine.draft", slots=len(spec_slots),
+                                 k=spec_k):
+            drafts = self._draft.propose(self.cache, self._last_tok, pos,
+                                         self._samp_dev)
+        cand = _np.concatenate(
+            [self._last_tok[:, None], _np.asarray(drafts, _np.int32)],
+            axis=1)
+        with _health.watch_section("generation.step", slots=len(active)):
+            with _tracing.child_span("engine.verify", slots=len(active),
+                                     k=spec_k):
+                ver = self.model.verify(self.cache, cand, pos,
+                                        self._samp_dev)
+        return ver, cand, spec_slots, spec_k
 
     def _retire_finished(self, log: Dict[str, Any]) -> None:
         """EOS/max-tokens were marked at the previous decode; cancelled
@@ -862,57 +1085,6 @@ class GenerationEngine:
             self._in_admission.remove(req)
             self.scheduler.admission_done()
 
-    def _decode(self, active: Dict[int, "GenRequest"]
-                ) -> Tuple[Any, Optional[Tuple[Any, ...]]]:
-        """One resident decode step over EVERY active slot: returns
-        ``(next_tok, None)``, or ``(None, (verified, candidates,
-        speculating slots, k))`` from a speculative iteration.  When
-        any resident request speculates, the WHOLE iteration rides the
-        draft+verify pair (one program each): the draft proposes k
-        tokens per slot, verify scores all k+1 positions in one target
-        pass, and plain slots simply keep only the first verified token
-        — which is bit-identical to what the plain step would have
-        produced."""
-        from .. import faults as _faults
-        from .. import health as _health
-        spec_k = self._draft.k if self._draft is not None else 0
-        spec_slots = frozenset(
-            s for s, r in active.items()
-            if spec_k and getattr(r, "speculative", False))
-        use_spec = bool(spec_slots)
-        _faults.maybe_fault("serving.execute", phase="decode",
-                            slots=len(active))
-        if use_spec:
-            # verify scatters k rows past every slot's position: grow
-            # for the worst case up front, capped at the grid top (rows
-            # past it belong to tokens the submit-time budget check
-            # proves are never emitted)
-            self.cache.ensure_capacity(
-                min(self.cache.needed_capacity() + spec_k, self.grid[-1]))
-        else:
-            self.cache.ensure_capacity(self.cache.needed_capacity())
-        pos = _np.maximum(self.cache.positions, 0).astype(_np.int32)
-        if self._samp_dev is None:
-            self._samp_dev = self.model.device_sampling(self._samp)
-        if not use_spec:
-            with _health.watch_section("generation.step",
-                                       slots=len(active)):
-                return self.model.step(self.cache, self._last_tok, pos,
-                                       self._samp_dev), None
-        with _tracing.child_span("engine.draft", slots=len(spec_slots),
-                                 k=spec_k):
-            drafts = self._draft.propose(self.cache, self._last_tok, pos,
-                                         self._samp_dev)
-        cand = _np.concatenate(
-            [self._last_tok[:, None], _np.asarray(drafts, _np.int32)],
-            axis=1)
-        with _health.watch_section("generation.step", slots=len(active)):
-            with _tracing.child_span("engine.verify", slots=len(active),
-                                     k=spec_k):
-                ver = self.model.verify(self.cache, cand, pos,
-                                        self._samp_dev)
-        return None, (ver, cand, spec_slots, spec_k)
-
     def _decode_fault(self, active: Dict[int, "GenRequest"],
                       e: Exception, log: Dict[str, Any]) -> None:
         """An iteration fault hits exactly the sequences IN FLIGHT at
@@ -920,7 +1092,11 @@ class GenerationEngine:
         the engine itself are unaffected.  The step consumed the KV
         buffers by donation, so a raise AFTER dispatch leaves the cache
         holding deleted arrays — reallocate before the next admission
-        touches them."""
+        touches them.  A fault found at the late readback of step N
+        also drops step N+1, launched over the same sequences from
+        N's buffers: neither step's tokens reached a stream, so a
+        recovery replays from transcripts that hold neither."""
+        self._flight = None
         self.cache.reset_buffers()
         if self._draft is not None:
             # the draft's own buffers may have been donated to a
@@ -955,18 +1131,27 @@ class GenerationEngine:
         if victims:
             self.recovery_sink(victims, e, "decode")
 
-    def _emit(self, active: Dict[int, "GenRequest"], next_tok: Any,
-              spec: Optional[Tuple[Any, ...]], log: Dict[str, Any]
-              ) -> int:
+    def _emit(self, active: Dict[int, "GenRequest"], log: Dict[str, Any],
+              next_tok: Any = None, wrote_at: Any = None,
+              spec: Optional[Tuple[Any, ...]] = None) -> int:
         """Hand each slot's new token(s) to its stream, mark finished
-        sequences (they retire at the next iteration), count; returns
-        the tokens streamed."""
+        sequences (they retire at the next retire phase), count;
+        returns the tokens streamed.  A plain step brings ``next_tok``
+        and the positions it ``wrote_at``; a speculative iteration
+        ``spec`` = ``(verified, candidates, speculating slots, k)``.
+
+        A plain step's token for a stream that had already ended when
+        it was read is DISCARDED: the step was launched before the host
+        had read the EOS of the one before it (or the consumer gave up
+        meanwhile).  It reaches no stream and no token counter but
+        ``mxnet_gen_discarded_tokens_total``; the slot retires in this
+        quantum and is installed anew, whole, at its next admission."""
         iter_tid = _tracing.current_trace_id()
         use_spec = spec is not None
         if use_spec:
             ver, cand, spec_slots, spec_k = spec
         now = time.monotonic()
-        n_streamed = 0
+        n_streamed = n_discarded = 0
         it_proposed = it_accepted = 0
         for slot, req in active.items():
             if use_spec:
@@ -1032,8 +1217,10 @@ class GenerationEngine:
                     _metrics.GEN_SPEC_ACCEPTED_PER_STEP.observe(
                         float(m), exemplar=iter_tid)
             else:
+                if req.stream.finished:
+                    n_discarded += 1
+                    continue
                 tok = int(next_tok[slot])
-                self.cache.positions[slot] += 1
                 self._last_tok[slot] = tok
                 _metrics.GEN_SAMPLED_TOKENS_TOTAL.labels(
                     method=req.method).inc()
@@ -1048,7 +1235,8 @@ class GenerationEngine:
                 finished = "eos"
             elif req.emitted >= req.max_new_tokens:
                 finished = "length"
-            elif int(self.cache.positions[slot]) >= self.grid[-1]:
+            elif (int(self.cache.positions[slot]) if use_spec
+                  else int(wrote_at[slot]) + 1) >= self.grid[-1]:
                 finished = "length"
             if finished:
                 # mark done now; the slot frees at the next iteration's
@@ -1059,6 +1247,8 @@ class GenerationEngine:
             self._spec_accepted += it_accepted
             _metrics.GEN_SPEC_ACCEPT_RATE.set(
                 self._spec_accepted / self._spec_proposed)
+        if n_discarded:
+            _metrics.GEN_DISCARDED_TOKENS_TOTAL.inc(n_discarded)
         _metrics.GEN_TOKENS_TOTAL.labels(phase="decode").inc(n_streamed)
         _metrics.GEN_ITERATIONS_TOTAL.inc()
         self._tps_window.append((now, n_streamed))

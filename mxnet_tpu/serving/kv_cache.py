@@ -134,6 +134,7 @@ class PagedKVCache:
                  window: int = 0,
                  state_shapes: Optional[Dict[str, Tuple[int, ...]]] = None
                  ) -> None:
+        import jax
         import jax.numpy as jnp
         self.grid = kv_bucket_grid(buckets)
         self.n_layers = int(n_layers)
@@ -171,6 +172,11 @@ class PagedKVCache:
             "rows": self.n_rows * row, "window": self.n_window * row,
             "state": 4 * self.n_state * sum(
                 int(_np.prod(s)) for s in self.state_shapes.values())}
+        # the one device every buffer here is committed to, and every
+        # host operand of a step over them (DecodeModel.dispatch): a
+        # jitted call keys its executable on whether and where its
+        # inputs are committed, so the choice is made once, here
+        self.device = jax.local_devices()[0]
         self._k: List[Any] = []
         self._v: List[Any] = []
         # the fixed-size kinds, {name: one buffer a layer of the kind}
@@ -178,7 +184,9 @@ class PagedKVCache:
         self._alloc_buffers(self.bucket)
         self._alloc_fixed()
         # host bookkeeping: next write position per slot (== tokens
-        # resident in the row), -1 marks a free slot
+        # resident in the row once the steps launched have run: the
+        # engine advances it when it LAUNCHES a step, not when it reads
+        # the token back), -1 marks a free slot
         self.positions = _np.full((self.max_slots,), -1, _np.int64)
         # the pinned shared-prefix region: hot prompt-prefix K/V rows
         # resident beside the slot buffers, copied (never re-prefilled)
@@ -201,10 +209,9 @@ class PagedKVCache:
         # transfer keeps restart warmup (which walks every bucket
         # shape) at zero XLA compiles
         zeros = _np.zeros(shape, self.dtype)
-        dev = jax.local_devices()[0]
-        self._k = [jax.device_put(zeros, dev)
+        self._k = [jax.device_put(zeros, self.device)
                    for _ in range(self.n_rows)]
-        self._v = [jax.device_put(zeros, dev)
+        self._v = [jax.device_put(zeros, self.device)
                    for _ in range(self.n_rows)]
         _metrics.GEN_CACHE_BYTES.labels(kind="rows").set(
             self.bytes_by_kind()["rows"])
@@ -225,8 +232,8 @@ class PagedKVCache:
         """The fixed-size kinds zeroed, ``lead`` before each shape
         (host zeros, committed: see ``_alloc_buffers``)."""
         import jax
-        dev = jax.local_devices()[0]
-        return {name: [jax.device_put(_np.zeros(lead + shape, dtype), dev)
+        return {name: [jax.device_put(_np.zeros(lead + shape, dtype),
+                                      self.device)
                        for _ in range(n)]
                 for name, (n, shape, dtype) in self._fixed_shapes().items()}
 
@@ -417,7 +424,7 @@ class PagedKVCache:
         prefix-row shrink per (prompt bucket -> smaller prompt bucket)
         pair — so steady-state traffic never compiles them."""
         import jax
-        dev = jax.local_devices()[0]
+        dev = self.device
         n = 0
         for i, L in enumerate(self.grid):
             self.bucket = int(L)

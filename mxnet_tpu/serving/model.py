@@ -733,18 +733,24 @@ class DecodeModel:
                 jnp.asarray(_np.asarray(topps, _np.float32)),
                 jnp.asarray(_np.asarray(methods, _np.int32)))
 
-    def step(self, cache: Any, tokens: _np.ndarray,
-             positions: _np.ndarray,
-             sampling: Optional[Sequence[Any]] = None
-             ) -> _np.ndarray:
-        """One resident decode iteration over every slot: consumes the
-        cache's buffers (donated), installs the updated ones, returns
-        the (S,) int32 next-token vector (greedy or sampled per slot —
-        ``sampling`` is the (seeds, counter bases, temperatures,
-        top_ks, top_ps, methods) vectors, host or device
-        (:meth:`device_sampling`); None means all-greedy)."""
+    def dispatch(self, cache: Any, tokens: Any,
+                 positions: _np.ndarray,
+                 sampling: Optional[Sequence[Any]] = None) -> Any:
+        """Launch one resident decode iteration over every slot and
+        return without waiting for it: consumes the cache's buffers
+        (donated), installs the updated ones, and hands back the step's
+        (S,) int32 next-token array UN-READ, still on the device, for
+        :meth:`collect`.
+
+        ``tokens`` is the host vector of each slot's last token, or the
+        un-read array the step before this one returned: it then feeds
+        this step where it lies, so the launch needs nothing the host
+        has not got (the span says ``ahead=1``).  Either way the
+        compiled program is the same one: host vectors are uploaded
+        COMMITTED to the cache's device, as a step's own results are (a
+        jitted call keys its executable on that).  ``sampling`` as in
+        :meth:`step`."""
         import jax
-        import jax.numpy as jnp
         S = cache.max_slots
         if sampling is None:
             sampling = self.greedy_sampling(S)
@@ -752,30 +758,56 @@ class DecodeModel:
             # host vectors: one-shot callers; the engine hands in its
             # cached device mirrors instead
             sampling = self.device_sampling(sampling)
-        seeds, bases, temps, topks, topps, methods = sampling
         self._account(f"decode:{S}x{cache.bucket}")
-        # dispatch (the two uploads, the jitted call returning, the new
-        # buffers installed) and readback (the host waiting for the
-        # tokens) tile the step: the first is host-serial, the second
-        # is the device's time
-        with _tracing.child_span("model.step", slots=S,
+        ahead = isinstance(tokens, jax.Array)
+        # the host-serial part of a step: the uploads, the jitted call
+        # returning, the new buffers installed
+        with _tracing.child_span("model.step.dispatch", slots=S,
+                                 bucket=cache.bucket, family=self.family,
+                                 ahead=int(ahead)):
+            if not ahead:
+                tokens = jax.device_put(_np.asarray(tokens, _np.int32),
+                                        cache.device)
+            # every kind of buffer the cache holds is donated and
+            # comes back updated
+            toks, *new = self._step_fn(
+                self.params, *cache.buffers(), tokens,
+                jax.device_put(_np.asarray(positions, _np.int32),
+                               cache.device), *sampling)
+            cache.replace(*new)
+        return toks
+
+    def collect(self, toks: Any) -> _np.ndarray:
+        """Wait for a dispatched step: its (S,) int32 tokens on the
+        host."""
+        with _tracing.child_span("model.step.readback"):
+            return _np.asarray(toks)
+
+    def step(self, cache: Any, tokens: _np.ndarray,
+             positions: _np.ndarray,
+             sampling: Optional[Sequence[Any]] = None
+             ) -> _np.ndarray:
+        """One resident decode iteration over every slot, synchronous:
+        :meth:`dispatch` then :meth:`collect`.  Consumes the cache's
+        buffers (donated), installs the updated ones, returns the (S,)
+        int32 next-token vector on the host (greedy or sampled per slot
+        — ``sampling`` is the (seeds, counter bases, temperatures,
+        top_ks, top_ps, methods) vectors, host or device
+        (:meth:`device_sampling`); None means all-greedy).  Warm-up and
+        one-shot callers use this; the engine calls the two halves
+        itself, so that it can launch the next step before it reads
+        this one's tokens (and observes
+        ``mxnet_gen_step_seconds{phase="decode"}`` itself, as what the
+        step cost its loop; here that is the whole call)."""
+        with _tracing.child_span("model.step", slots=cache.max_slots,
                                  bucket=cache.bucket, family=self.family):
             t = time.perf_counter()
-            with _tracing.child_span("model.step.dispatch"):
-                # every kind of buffer the cache holds is donated and
-                # comes back updated
-                toks, *new = self._step_fn(
-                    self.params, *cache.buffers(),
-                    jnp.asarray(_np.asarray(tokens, _np.int32)),
-                    jnp.asarray(_np.asarray(positions, _np.int32)),
-                    seeds, bases, temps, topks, topps, methods)
-                cache.replace(*new)
-            with _tracing.child_span("model.step.readback"):
-                out = _np.asarray(toks)
-            dt = time.perf_counter() - t
-        _metrics.GEN_STEP_SECONDS.labels(phase="decode").observe(
-            dt, exemplar=_tracing.current_trace_id())
-        return out
+            out = self.collect(
+                self.dispatch(cache, tokens, positions, sampling))
+            _metrics.GEN_STEP_SECONDS.labels(phase="decode").observe(
+                time.perf_counter() - t,
+                exemplar=_tracing.current_trace_id())
+            return out
 
     def verify(self, cache: Any, tokens: _np.ndarray,
                positions: _np.ndarray,
@@ -895,7 +927,7 @@ class DecodeModel:
                     top_p=1.0, method=0)
         n += 1
         if suffix_pairs:
-            dev = jax.local_devices()[0]
+            dev = cache.device
             top = max(int(pb) for pb in prompt_buckets)
             rows = {int(pb): [jax.device_put(
                 _np.zeros((int(pb), self.num_heads, self.head_dim),

@@ -30,7 +30,7 @@ TINY = (
      "device_idle_pct.serve", "queue_wait_p95_ms", "admission_ms",
      "decode_dispatch_ms", "engine_host_ms", "idle_pct.decode_call",
      "idle_pct.admission", "idle_pct.engine_host", "cache_bytes_per_slot",
-     "state_install_ms", "decode_hbm_pct"],
+     "state_install_ms", "decode_hbm_pct", "decode_ahead_pct"],
     {"arch": {"vocab": 503, "width": 64, "kv_heads": 2, "head_dim": 16,
               "window": 8, "d_inner": 128, "d_state": 16, "d_conv": 4,
               "mamba_layers": 3, "window_layers": 2, "full_layers": 1,
@@ -96,6 +96,9 @@ def test_new_job_end_to_end_at_a_tiny_size(tmp_path, monkeypatch, trace):
     value = {k: m["value"] for k, m in line["metrics"].items()}
     assert 0 < value["decode_hbm_pct"]
     assert value["state_install_ms"] > 0
+    # outputs of 4-16 tokens: some steps are launched ahead, the ones
+    # after a finish or an admission are not
+    assert 0 < value["decode_ahead_pct"] < 100
     # rows at some bucket while the trace was open, 2 window layers,
     # 3 state layers
     per_slot = value["cache_bytes_per_slot"]

@@ -34,6 +34,12 @@ from mxnet_tpu.serving import (DecodeModel, GenerationEngine,
                                GenerationServer, PrefixCache)
 from mxnet_tpu.serving.kv_cache import prefix_key
 
+import os
+import sys
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from serial_decode import (StepCounters, run_staggered,     # noqa: E402
+                           serial_transcript)
+
 VOCAB = 97
 PROMPT_A = onp.array([5, 9, 3, 17], dtype="int32")
 PROMPT_B = onp.array([1, 2], dtype="int32")
@@ -118,6 +124,38 @@ def test_sampled_parity_vs_zoo_select(gpt, decode_model, method, kw):
     _drain(eng, s)
     assert s.result(timeout=10) == want, \
         f"{method} decode diverged from the zoo _select oracle"
+
+
+@pytest.mark.parametrize("lanes", [
+    [dict(method="sample", temperature=0.9, seed=5),
+     dict(method="top_k", top_k=5, seed=6),
+     dict(method="top_p", top_p=0.8, temperature=1.2, seed=2 ** 31 - 9),
+     dict(method="top_k", top_k=2, temperature=0.7, seed=8)],
+    [dict(method="greedy"), dict(method="top_p", top_p=0.6, seed=21),
+     dict(method="greedy"), dict(method="sample", seed=22)],
+], ids=["all_sampled", "mixed_with_greedy"])
+def test_sampled_transcripts_equal_the_serial_step_loop(decode_model, lanes):
+    """The in-program sampler's counter comes from the position
+    operand, which the engine sends the same whether a step is launched
+    from the host's tokens or from the last step's array on the device:
+    sampled streams equal the request decoded alone by
+    ``DecodeModel.step``, launch-wait-read, token for token."""
+    mix = [dict(prompt=p, max_new_tokens=n, at=at, **lane)
+           for (p, n, at), lane in zip(
+               [(PROMPT_A, 26, 0), (PROMPT_B, 6, 0),
+                (onp.arange(3, 12, dtype="int32"), 10, 2),
+                (onp.array([9, 9, 4], "int32"), 8, 20)], lanes)]
+    eng = _engine(decode_model)
+    counted = StepCounters()
+    streams = run_staggered(eng, mix)
+    moved = counted.moved()
+    for s, r in zip(streams, mix):
+        kw = {k: v for k, v in r.items() if k not in ("at", "prompt")}
+        assert (s.result(timeout=5), s.finish_reason) == serial_transcript(
+            decode_model, eng, r["prompt"], **kw)
+    assert moved["ahead"] >= 20 and moved["admit"] >= 1
+    assert moved["ahead"] + counted.fallbacks() == moved["iterations"]
+    assert moved["sampled"] == sum(len(s.tokens) for s in streams)
 
 
 def test_sampling_defaults_and_validation(decode_model):

@@ -25,6 +25,12 @@ from mxnet_tpu.serving import (DecodeModel, GenerationEngine,
                                PagedKVCache)
 from mxnet_tpu.serving.kv_cache import round_up_bucket
 
+import os
+import sys
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from serial_decode import (StepCounters, check_log,         # noqa: E402
+                           run_staggered, serial_transcript)
+
 VOCAB = 97
 PROMPT_A = onp.array([5, 9, 3, 17], dtype="int32")
 PROMPT_B = onp.array([1, 2], dtype="int32")
@@ -310,6 +316,135 @@ def test_eos_and_max_token_retirement_free_slots(gpt, decode_model):
 
 
 # ---------------------------------------------------------------------------
+# one step in flight: the loop launches step N+1 before it reads step N
+# ---------------------------------------------------------------------------
+
+# mixed lengths over two slots: two arrive together, two more while
+# those decode (each waits for a slot), the last into a slot that has
+# stood free; the long one grows the rows twice with a step in flight.
+# Admissions and finishes fall mid-run and most other quanta run ahead
+STAGGERED = [
+    {"prompt": PROMPT_A, "max_new_tokens": 30, "at": 0},
+    {"prompt": PROMPT_B, "max_new_tokens": 5, "at": 0},
+    {"prompt": onp.arange(1, 8, dtype="int32"), "max_new_tokens": 9,
+     "at": 3},
+    {"prompt": onp.array([11, 3, 8], "int32"), "max_new_tokens": 3,
+     "at": 6},
+    {"prompt": onp.array([2, 40, 7, 7, 19], "int32"), "max_new_tokens": 11,
+     "at": 22},
+]
+
+
+def test_transcripts_equal_the_serial_step_loop(decode_model):
+    """Greedy: every stream's tokens and finish reason equal the same
+    request decoded alone by ``DecodeModel.step``, launch-wait-read; the
+    run really ran ahead, and really fell back for its admissions and
+    finishes.  (Sampled lanes: test_gen_sampling; the hybrid family:
+    test_phi4flash.)"""
+    eng = _engine(decode_model)
+    counted = StepCounters()
+    m0 = metrics.value("mxnet_gen_kv_migrations_total")
+    streams = run_staggered(eng, STAGGERED)
+    assert metrics.value("mxnet_gen_kv_migrations_total") == m0 + 2
+    for s, r in zip(streams, STAGGERED):
+        want = serial_transcript(decode_model, eng, r["prompt"],
+                                 r["max_new_tokens"])
+        assert (s.result(timeout=5), s.finish_reason) == want
+    moved = counted.moved()
+    assert moved["ahead"] >= 25
+    assert moved["finish"] >= 2 and moved["admit"] >= 1 \
+        and moved["idle"] >= 1
+    assert moved["discarded"] == 0          # nothing ended on an EOS
+    check_log(eng, streams)
+
+
+def test_every_step_is_counted_ahead_or_fallen_back(decode_model, traced):
+    """``steps_ahead`` + the fall-backs count every decode step, which
+    is every quantum that emitted a step's tokens; every
+    ``model.step.dispatch`` span says which it was."""
+    eng = _engine(decode_model)
+    traced.reset()
+    counted = StepCounters()
+    steps0 = metrics.hist_stats("mxnet_gen_step_seconds", phase="decode")[1]
+    streams = run_staggered(eng, STAGGERED)
+    moved = counted.moved()
+    assert moved["ahead"] + counted.fallbacks() == moved["iterations"]
+    assert moved["iterations"] == sum(
+        1 for e in eng.iteration_log if e["decoded"])
+    launches = [r for r in traced.spans()
+                if r["name"] == "model.step.dispatch"]
+    assert len(launches) == moved["iterations"]
+    assert all(r["attrs"]["ahead"] in (0, 1) for r in launches)
+    assert sum(r["attrs"]["ahead"] for r in launches) == moved["ahead"]
+    # what the token counters say is what the clients got
+    delivered = sum(len(s.result(timeout=5)) for s in streams)
+    assert moved["decode_tokens"] + moved["prefill_tokens"] == delivered
+    assert moved["sampled"] == delivered
+    # one observation of its cost a step, and none left in flight
+    assert metrics.hist_stats("mxnet_gen_step_seconds",
+                              phase="decode")[1] - steps0 \
+        == moved["iterations"]
+    assert eng._flight is None
+
+
+def test_eos_is_read_one_step_late_and_nothing_leaks(decode_model):
+    """With EOS at step N the host learns of it after N+1 was launched:
+    N+1's token for that slot reaches no stream and no token counter,
+    the stream ends AT the eos, and the slot's next owner decodes as if
+    alone."""
+    eng = _engine(decode_model, max_slots=1)
+    base, _ = serial_transcript(decode_model, eng, PROMPT_A, 12)
+    eos = base[3]
+    cut = base[:base.index(eos) + 1]
+    assert 2 <= len(cut) < 10, "fixture: the eos must fall mid-decode"
+    counted = StepCounters()
+    sa = eng.submit(PROMPT_A, max_new_tokens=12, eos_token=eos)
+    sb = eng.submit(PROMPT_B, max_new_tokens=6)      # waits for the slot
+    _drain(eng, sa, sb)
+    assert not eng.run_iteration()
+    assert (sa.result(timeout=5), sa.finish_reason) == (cut, "eos")
+    assert (sb.result(timeout=5), sb.finish_reason) \
+        == serial_transcript(decode_model, eng, PROMPT_B, 6)
+    moved = counted.moved()
+    assert moved["discarded"] == 1
+    delivered = len(cut) + 6
+    assert moved["decode_tokens"] + moved["prefill_tokens"] == delivered
+    assert moved["sampled"] == delivered
+    check_log(eng, [sa, sb])
+    assert eng.cache.free_slots() == [0]
+
+
+def test_cancel_with_a_step_in_flight(decode_model):
+    """A consumer gives up between two quanta, with a step launched
+    over its slot: the next quantum falls back, the token in flight is
+    discarded, the slot frees, and neither its neighbour nor the slot's
+    next owner sees any of it."""
+    eng = _engine(decode_model)
+    sa = eng.submit(PROMPT_A, max_new_tokens=30)
+    sb = eng.submit(onp.arange(1, 8, dtype="int32"), max_new_tokens=12)
+    for _ in range(4):
+        eng.run_iteration()
+    assert eng._flight is not None
+    counted = StepCounters()
+    had = list(sa.tokens)
+    sa.cancel()
+    eng.run_iteration()
+    moved = counted.moved()
+    assert moved["cancel"] == 1 and moved["ahead"] == 0
+    assert moved["discarded"] == 1
+    assert sa.tokens == had and sa.finished
+    assert eng.cache.free_slots() == [0]
+    assert metrics.value("mxnet_gen_retirements_total",
+                         reason="cancelled") >= 1
+    sc = eng.submit(PROMPT_B, max_new_tokens=5)      # takes the freed slot
+    _drain(eng, sb, sc)
+    assert sb.result(timeout=5) == serial_transcript(
+        decode_model, eng, onp.arange(1, 8, dtype="int32"), 12)[0]
+    assert sc.result(timeout=5) == serial_transcript(
+        decode_model, eng, PROMPT_B, 5)[0]
+
+
+# ---------------------------------------------------------------------------
 # overload
 # ---------------------------------------------------------------------------
 
@@ -508,53 +643,76 @@ def test_in_process_submit_gets_a_trace_of_its_own(decode_model, traced):
 
 def test_iteration_span_covers_its_whole_quantum(decode_model, traced):
     """By time, on one thread, engine.iteration contains every
-    engine.prefill, model.step and engine.emit of its quantum;
-    model.step.dispatch and model.step.readback tile model.step."""
+    engine.prefill, model.step.dispatch, model.step.readback and
+    engine.emit of its quantum.  A quantum that runs ahead launches the
+    next step (``ahead=1``) BEFORE it reads the last one back; one that
+    falls back reads first and launches last (``ahead=0``)."""
     eng = _engine(decode_model)
     traced.reset()
     a = eng.submit(PROMPT_A, max_new_tokens=6)
     eng.run_iteration()
     b = eng.submit(PROMPT_B, max_new_tokens=3)    # admitted mid-flight
     _drain(eng, a, b)
-    assert eng.run_iteration()      # the last sequence retires
+    # the last sequence retired in the quantum that read its last token
+    assert eng.cache.free_slots() == [0, 1]
     assert not eng.run_iteration()  # an idle pass is a (short) span too
     recs = traced.spans()
     iters = [r for r in recs if r["name"] == "engine.iteration"]
     assert len({r["tid"] for r in recs}) == 1
     assert len(iters) == len({r["trace_id"] for r in iters})
-    for name, per_iter in (("engine.prefill", None), ("model.step", 1),
-                           ("engine.emit", 1)):
+    inside = {}
+    for name in ("engine.prefill", "model.step.dispatch",
+                 "model.step.readback", "engine.emit"):
         found = [r for r in recs if r["name"] == name]
         assert found, name
         for r in found:
             homes = [i for i in iters if _inside(r, i)]
             assert len(homes) == 1, (name, len(homes))
-        if per_iter:
-            # every iteration that decoded has exactly one
-            decoded = [i for i in iters if i["attrs"]["tokens"]]
-            assert len(found) == per_iter * len(decoded)
+            inside.setdefault(homes[0]["span_id"], []).append(r)
+    assert not [r for r in recs if r["name"] == "model.step"]
+    # every iteration that emitted has exactly one readback and one
+    # emit; every step launched was read (nothing is left in flight)
+    emitted = [i for i in iters if i["attrs"]["tokens"]]
+    for name in ("model.step.readback", "engine.emit",
+                 "model.step.dispatch"):
+        assert sum(r["name"] == name for r in recs) == len(emitted), name
     assert len([r for r in recs if r["name"] == "engine.prefill"]) == 2
     first = iters[0]["attrs"]
-    assert (first["admitted"], first["slots"], first["tokens"]) == (1, 1, 1)
+    # the first quantum admits and launches; its step is read next time
+    assert (first["admitted"], first["slots"], first["tokens"]) == (1, 1, 0)
     assert sum(i["attrs"]["tokens"] for i in iters) == (6 - 1) + (3 - 1)
     assert sum(i["attrs"]["retired"] for i in iters) == 2
     assert iters[-1]["attrs"] == {"iter": iters[-1]["attrs"]["iter"],
                                   "slots": 0, "admitted": 0, "retired": 0,
                                   "tokens": 0}
     by_id = {r["span_id"]: r for r in recs}
-    steps = [r for r in recs if r["name"] == "model.step"]
-    for step in steps:
-        assert by_id[step["parent_id"]]["name"] == "engine.iteration"
-        parts = sorted((r for r in recs if r["parent_id"] == step["span_id"]),
+    aheads = []
+    for it in iters:
+        parts = sorted(inside.get(it["span_id"], ()),
                        key=lambda r: r["t_begin"])
-        assert [p["name"] for p in parts] == ["model.step.dispatch",
-                                              "model.step.readback"]
-        gaps = (parts[0]["t_begin"] - step["t_begin"],
-                parts[1]["t_begin"] - parts[0]["t_end"],
-                step["t_end"] - parts[1]["t_end"])
-        assert all(0 <= g < 2e-3 for g in gaps), gaps
-        assert step["attrs"] == {"slots": 2, "bucket": eng.cache.bucket,
-                                 "family": "gpt"}
+        names = [p["name"] for p in parts if p["name"] != "engine.prefill"]
+        launch = [p for p in parts if p["name"] == "model.step.dispatch"]
+        for p in parts:
+            if p["name"] != "engine.prefill":   # that one is the request's
+                assert by_id[p["parent_id"]]["name"] == "engine.iteration"
+        if not launch:
+            assert names in ([], ["model.step.readback", "engine.emit"])
+            continue
+        assert launch[0]["attrs"] == {
+            "slots": 2, "bucket": launch[0]["attrs"]["bucket"],
+            "family": "gpt", "ahead": launch[0]["attrs"]["ahead"]}
+        aheads.append(launch[0]["attrs"]["ahead"])
+        if aheads[-1]:
+            assert names == ["model.step.dispatch", "model.step.readback",
+                             "engine.emit"]
+        else:
+            assert names in (["model.step.dispatch"],
+                             ["model.step.readback", "engine.emit",
+                              "model.step.dispatch"])
+    # a (6 tokens) and b (3): the first launch and the one after b's
+    # admission are serial, b's and a's last tokens end their steps'
+    # successors: some of each kind
+    assert 0 in aheads and 1 in aheads
     emits = [r for r in recs if r["name"] == "engine.emit"]
     assert [e["attrs"]["tokens"] for e in emits] \
         == [i["attrs"]["tokens"] for i in iters if i["attrs"]["tokens"]]

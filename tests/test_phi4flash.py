@@ -21,6 +21,8 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 import reference_phi4flash as ref                           # noqa: E402
+from serial_decode import (StepCounters, check_log,         # noqa: E402
+                           run_staggered, serial_transcript)
 
 VOCAB, WINDOW = 503, 8
 TOL = 2e-5      # float32 system against the float32 reference
@@ -231,6 +233,72 @@ def test_a_freed_slot_leaks_nothing_into_the_next_request(model):
     assert metrics.value("mxnet_gen_kv_migrations_total") == m0 + 1
 
 
+# two slots; prompts on both sides of the window of 8, answers that
+# cross it; the last arrives into a slot that has stood free and grows
+# the full layer's rows with a step in flight
+HYBRID_MIX = [
+    dict(prompt=prompt(11, 50), max_new_tokens=30, at=0),
+    dict(prompt=prompt(3, 51), max_new_tokens=6, at=0),
+    dict(prompt=prompt(20, 52), max_new_tokens=12, at=2),
+    dict(prompt=prompt(5, 53), max_new_tokens=4, at=5),
+    dict(prompt=prompt(40, 54), max_new_tokens=30, at=26),
+]
+LANES = [dict(method="top_k", top_k=7, temperature=0.8, seed=11),
+         dict(method="greedy"),
+         dict(method="sample", temperature=1.3, seed=2 ** 31 - 5),
+         dict(method="top_p", top_p=0.85, seed=3),
+         dict(method="top_k", top_k=3, seed=4)]
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+def test_one_step_in_flight_changes_no_transcript(model, sampled):
+    """The engine launches step N+1 from step N's token array before it
+    reads N back; every stream still equals the request decoded alone
+    by ``model.step``, launch-wait-read: state, rings and rows, greedy
+    and with sampling lanes in the same batch."""
+    mix = [dict(r, **(lane if sampled else {}))
+           for r, lane in zip(HYBRID_MIX, LANES)]
+    engine = new_engine(model, max_slots=2)
+    counted = StepCounters()
+    m0 = metrics.value("mxnet_gen_kv_migrations_total")
+    streams = run_staggered(engine, mix)
+    moved = counted.moved()
+    assert metrics.value("mxnet_gen_kv_migrations_total") == m0 + 1
+    for s, r in zip(streams, mix):
+        kw = {k: v for k, v in r.items() if k not in ("at", "prompt")}
+        assert (s.result(), s.finish_reason) == serial_transcript(
+            model, engine, r["prompt"], **kw)
+    assert moved["ahead"] >= 25 and moved["discarded"] == 0
+    assert moved["finish"] >= 2 and moved["admit"] >= 1
+    assert moved["ahead"] + counted.fallbacks() == moved["iterations"]
+    check_log(engine, streams)
+
+
+def test_a_late_eos_leaks_nothing_and_the_slot_is_installed_anew(model):
+    """EOS at step N is read after N+1 was launched: N+1's token is
+    discarded, and its column, state update and ring write land in a
+    slot whose next owner has state, rings and rows installed whole."""
+    engine = new_engine(model, max_slots=1)
+    long_p, short_p = prompt(50, 42), prompt(6, 41)
+    base, _ = serial_transcript(model, engine, long_p, 40)
+    at = next(i for i in range(3, 30) if base[i] not in base[:i])
+    want_second = serial_transcript(model, engine, short_p, 12)
+    counted = StepCounters()
+    n0 = metrics.value("mxnet_gen_state_installs_total")
+    first = engine.submit(long_p, max_new_tokens=40, eos_token=base[at])
+    second = engine.submit(short_p, max_new_tokens=12)
+    out = run_all(engine, first, second)
+    assert (out[0], first.finish_reason) == (base[:at + 1], "eos")
+    assert (out[1], second.finish_reason) == want_second
+    moved = counted.moved()
+    assert moved["discarded"] == 1
+    delivered = at + 1 + 12
+    assert moved["decode_tokens"] + moved["prefill_tokens"] == delivered
+    assert moved["sampled"] == delivered
+    assert metrics.value("mxnet_gen_state_installs_total") == n0 + 2
+    check_log(engine, [first, second])
+
+
 def test_cache_accounting_by_kind(model):
     engine = new_engine(model)
     cache = engine.cache
@@ -354,7 +422,7 @@ def test_spans_and_counters_of_an_admission(model):
     assert install["attrs"]["rows"] == WINDOW
     assert install["attrs"]["slot"] == 0
     assert by_name["model.prefill"]["attrs"]["family"] == "phi4flash"
-    assert by_name["model.step"]["attrs"]["family"] == "phi4flash"
+    assert by_name["model.step.dispatch"]["attrs"]["family"] == "phi4flash"
     # the install sits beside the row write, inside the admission
     admission = by_name["engine.prefill"]
     assert admission["t_begin"] <= by_name["kv.write_prompt"]["t_begin"] \

@@ -41,6 +41,9 @@ PROMPT_A = onp.array([5, 9, 3, 17], dtype="int32")
 PROMPT_B = onp.array([1, 2], dtype="int32")
 PROMPT_C = onp.array([7, 4, 11], dtype="int32")
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from serial_decode import StepCounters, serial_transcript   # noqa: E402
+
 
 # ---------------------------------------------------------------------------
 # fixtures
@@ -248,6 +251,61 @@ def test_worker_death_recovers_on_surviving_replica(gpt, decode_model):
         assert recs > rec0, "the kill recovered nothing (did it fire?)"
     finally:
         gs.stop()
+
+
+@pytest.mark.parametrize("moment", ["running_ahead", "at_a_fall_back"])
+def test_fault_at_the_late_readback_hits_exactly_the_steps_in_flight(
+        decode_model, moment):
+    """A fault at ``serving.execute`` surfaces where the host waits for
+    a step's tokens.  Running ahead, step N+1 is already launched then:
+    the victims are exactly the sequences of the steps in flight (not
+    the queued one), neither step's tokens reached a stream, the step
+    in flight is dropped, and the recovery replays from the transcripts
+    exactly once: every final stream equals the fault-free one."""
+    from mxnet_tpu.serving.generation import make_recovery_request
+    eng = _engine(decode_model, max_slots=2)
+    asks = [(PROMPT_A, 20), (PROMPT_B, 16), (PROMPT_C, 6)]
+    wants = [serial_transcript(decode_model, eng, p, n) for p, n in asks]
+    hit = []
+    eng.recovery_sink = lambda victims, exc, site: hit.append(
+        (list(victims), exc, site))
+    streams = [eng.submit(p, max_new_tokens=n) for p, n in asks]
+    for _ in range(5):
+        eng.run_iteration()
+    if moment == "at_a_fall_back":
+        # B's budget ends with the token in flight, which the host
+        # counts itself: the next quantum reads before it launches
+        for req in eng.scheduler.active().values():
+            if req.stream is streams[1]:
+                req.max_new_tokens = req.emitted + 1
+    assert eng._flight is not None and len(eng.scheduler) == 1
+    had = [list(s.tokens) for s in streams]
+    counted = StepCounters()
+    dupes0 = metrics.value("mxnet_serving_stream_dupes_dropped_total")
+    with faults.fault_plan("serving.execute:p=1:times=1"):
+        assert eng.run_iteration()
+    moved = counted.moved()
+    # the step after N was launched before the fault surfaced, or not
+    assert moved["ahead"] == (1 if moment == "running_ahead" else 0)
+    assert moved["iterations"] == 0
+    (victims, exc, site), = hit
+    assert site == "decode" and "injected" in str(exc)
+    assert [v.stream for v in victims] == streams[:2]
+    assert [list(s.tokens) for s in streams] == had      # nothing leaked
+    assert eng._flight is None
+    assert eng.cache.free_slots() == [0, 1] and len(eng.scheduler) == 1
+    assert not any(s.finished for s in streams)
+    # resurrect from the transcripts, as GenerationServer._recover does
+    for v in victims:
+        eng.submit_request(make_recovery_request(v), front=True)
+    while eng.run_iteration():
+        pass
+    assert [(s.result(timeout=5), s.finish_reason) for s in streams] \
+        == wants
+    # the first step after it is launched from the host, nothing ahead
+    assert counted.moved()["idle"] == 1
+    assert metrics.value("mxnet_serving_stream_dupes_dropped_total") \
+        == dupes0
 
 
 def test_recovery_budget_exhausted_fails_structurally(decode_model):

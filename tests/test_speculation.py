@@ -275,6 +275,45 @@ def test_independent_draft_streams_identical(decode_model, draft_gpt):
     assert eng.describe()["speculation"]["mode"] == "draft"
 
 
+@pytest.mark.parametrize("mode,kw", [
+    ("self", dict(spec_draft_layers=1)),
+    ("draft", dict()),
+])
+def test_a_speculating_engine_never_runs_ahead(decode_model, draft_gpt,
+                                               mode, kw):
+    """Accepted lengths, hence the next positions, depend on the tokens:
+    while any resident request speculates every quantum is serial
+    (counted ``spec``), and no step is launched before the last was
+    read.  Once only plain requests are resident the same engine runs
+    ahead."""
+    from mxnet_tpu import metrics
+    if mode == "draft":
+        kw = dict(kw, draft_model=DecodeModel.from_block(draft_gpt))
+    eng = _engine(decode_model, spec_mode=mode, spec_k=3, **kw)
+
+    def read():
+        return {k: metrics.value(name, **lab) for k, (name, lab) in {
+            "ahead": ("mxnet_gen_steps_ahead_total", {}),
+            "spec": ("mxnet_gen_step_fallbacks_total", {"reason": "spec"}),
+            "iterations": ("mxnet_gen_iterations_total", {})}.items()}
+
+    before = read()
+    want = _run_mix(_engine(decode_model))
+    plain_run = read()
+    assert plain_run["ahead"] > before["ahead"]       # the plain engine did
+    assert _run_mix(eng) == want
+    after = read()
+    assert after["ahead"] == plain_run["ahead"]
+    assert after["spec"] - plain_run["spec"] \
+        == after["iterations"] - plain_run["iterations"] > 0
+    assert eng._flight is None
+    # requests that opt out leave nobody speculating: the loop runs ahead
+    s = eng.submit(PROMPT_A, max_new_tokens=10, speculative=False)
+    _drain(eng, s)
+    assert s.result(timeout=10) == want[0][:10]
+    assert read()["ahead"] > after["ahead"]
+
+
 def test_mixed_spec_and_plain_slots(decode_model):
     """A per-request ``speculative=False`` opt-out rides the same
     iterations as speculating neighbors; both must match plain."""
