@@ -46,14 +46,6 @@
 #                           #   budget exhaustion degrades (exit 70)
 #   ci/run.sh chaos-smoke   # bounded fault-injection/preemption proof
 #                           #   (tests/test_faults.py -k smoke)
-#   ci/run.sh cache-smoke   # persistent compile cache warm-restart
-#                           #   gate: cold run compiles N + persists,
-#                           #   restarted training job and serving
-#                           #   replica compile 0 with bit-identical
-#                           #   losses/tokens, a fully poisoned cache
-#                           #   + seeded read/write fault plan
-#                           #   degrades to quarantine+recompile with
-#                           #   0 caller-visible errors
 #   ci/run.sh health-smoke  # training health guard acceptance: seeded
 #                           #   NaN plan -> exactly one skip + loss
 #                           #   recovery + budget; watchdog stack dump
@@ -80,16 +72,8 @@
 #                           #   ps.handle remote child across the PS
 #                           #   frame; 1%-sampling steps/sec >=0.97x
 #                           #   tracing-off, 0 compiles after warmup
-#   ci/run.sh bench-check   # bench regression gate (bench.py --check):
-#                           #   deterministic metrics (compiles after
-#                           #   warmup, flush growth, stall fraction)
-#                           #   FAIL; wall-clock vs ROUND_BASELINES
-#                           #   only WARNS (rig noise is +/-25-40%)
 #   ci/run.sh chaos         # full chaos suite incl. SIGKILL/SIGTERM
 #                           #   subprocess resume proofs
-#   ci/run.sh bulk-smoke    # lazy-bulking acceptance: lstm micro-run
-#                           #   (dispatch reduction / steady cache /
-#                           #   loss parity)
 #   ci/run.sh bulk-off      # core suite with MXNET_BULK_MAX_OPS=1
 #                           #   (per-op dispatch sanitizer)
 #   ci/run.sh unit          # full Python suite on the 8-dev virtual mesh
@@ -203,22 +187,6 @@ run_chaos_smoke() {
     -k smoke -q -p no:cacheprovider
 }
 
-run_cache_smoke() {
-  echo "== cache-smoke: persistent compile cache — cold compiles N +"
-  echo "   durable writes, restarted training job and serving replica"
-  echo "   compile 0 with bit-identical losses/tokens, poisoned cache"
-  echo "   + seeded fault plan degrades to recompile with 0 errors"
-  JAX_PLATFORMS=cpu timeout 600 python tools/cache_smoke.py
-}
-
-run_bulk_smoke() {
-  echo "== bulk-smoke: lazy eager-op bulking acceptance — lstm micro-run"
-  echo "   asserting >=1.3x eager->bulked dispatch reduction, 0 segment"
-  echo "   compiles after warmup, and loss parity"
-  JAX_PLATFORMS=cpu MXNET_BENCH_MODEL=bulk_smoke timeout 600 \
-    python bench.py
-}
-
 run_bulk_off() {
   echo "== bulk-off: core suite with bulking DISABLED (per-op dispatch)"
   echo "   — flushes out bulked-vs-eager divergence, the bulking analog"
@@ -250,7 +218,7 @@ run_dist_comm_smoke() {
   echo "   segmentation + grad-ready streaming >=1.5x serialized AND"
   echo "   strictly faster than optimizer-only overlap, bit-identical"
   echo "   losses, 0 steady-state compiles incl. a warm restart via"
-  echo "   the persistent compile cache"
+  echo "   jax's persistent compilation cache"
   # 900s: the backward-overlap + warm-restart legs roughly tripled
   # the smoke's work (~4min on the reference rig; 2x slow-host margin)
   JAX_PLATFORMS=cpu timeout 900 python tools/dist_comm_smoke.py
@@ -267,13 +235,6 @@ run_trace_smoke() {
   JAX_PLATFORMS=cpu timeout 600 python tools/trace_smoke.py
 }
 
-run_bench_check() {
-  echo "== bench-check: deterministic bench regressions fail (compiles"
-  echo "   after warmup / flush growth / stall fraction); wall-clock"
-  echo "   deltas vs ROUND_BASELINES only warn (rig noise +/-25-40%)"
-  JAX_PLATFORMS=cpu timeout 600 python bench.py --check BENCH_r0*.json
-}
-
 run_chaos() {
   echo "== chaos: the full fault-tolerance suite, including the"
   echo "   SIGKILL/SIGTERM subprocess resume proofs"
@@ -285,22 +246,18 @@ run_tier1() {
   echo "== tier1: mxlint (concurrency/invariant analyzer, subsumes the"
   echo "   old envdoc+faultdoc gates) + serving smoke + generation"
   echo "   smoke + resilience smoke + dist-resilience smoke + chaos"
-  echo "   smoke + cache smoke + health smoke + bulking smoke +"
-  echo "   input-pipeline smoke + dist-comm smoke + trace smoke +"
-  echo "   bench regression check + the tier-1 pytest selection"
+  echo "   smoke + health smoke + input-pipeline smoke + dist-comm"
+  echo "   smoke + trace smoke + the tier-1 pytest selection"
   run_mxlint
   run_serving_smoke
   run_generation_smoke
   run_resilience_smoke
   run_dist_resilience_smoke
   run_chaos_smoke
-  run_cache_smoke
   run_health_smoke
-  run_bulk_smoke
   run_input_pipeline_smoke
   run_dist_comm_smoke
   run_trace_smoke
-  run_bench_check
   JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
     --continue-on-collection-errors -p no:cacheprovider
 }
@@ -367,14 +324,11 @@ case "$variant" in
   resilience-smoke) run_resilience_smoke ;;
   dist-resilience-smoke) run_dist_resilience_smoke ;;
   chaos-smoke)  run_chaos_smoke ;;
-  cache-smoke)  run_cache_smoke ;;
   health-smoke) run_health_smoke ;;
   input-pipeline-smoke) run_input_pipeline_smoke ;;
   dist-comm-smoke) run_dist_comm_smoke ;;
   trace-smoke)  run_trace_smoke ;;
-  bench-check)  run_bench_check ;;
   chaos)        run_chaos ;;
-  bulk-smoke)   run_bulk_smoke ;;
   bulk-off)     run_bulk_off ;;
   unit)         run_unit ;;
   dist)         run_dist ;;

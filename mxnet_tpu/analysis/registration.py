@@ -38,7 +38,7 @@ _ENV_DOC_MODULES = (
     "mxnet_tpu.gluon.data.dataloader", "mxnet_tpu.serving",
     "mxnet_tpu.faults", "mxnet_tpu.retry", "mxnet_tpu.kvstore_async",
     "mxnet_tpu.kvstore_sched", "mxnet_tpu.tracing",
-    "mxnet_tpu.health", "mxnet_tpu.io", "mxnet_tpu.compile_cache",
+    "mxnet_tpu.health", "mxnet_tpu.io",
     "mxnet_tpu._native", "mxnet_tpu.recordio", "mxnet_tpu.test_utils",
 )
 

@@ -25,12 +25,7 @@ On flush the segment's nodes (appended in program order, which IS a
 topological order of the segment DAG) are traced once as a single
 function, jitted, and the compiled callable is cached by *segment
 signature* (op sequence + attr tokens + input binding structure + output
-liveness; ``jax.jit`` keys input avals internally).  With
-``MXNET_COMPILE_CACHE_DIR`` set, un-recorded segment executables are
-additionally persisted AOT through :mod:`mxnet_tpu.compile_cache`, so a
-restarted process replays them with zero XLA compiles (recorded
-segments keep the in-memory path — their vjp closures do not
-serialize).  Steady-state
+liveness; ``jax.jit`` keys input avals internally).  Steady-state
 training replays one fused executable per segment instead of N per-op
 dispatches, and XLA fuses elementwise chains (optimizer updates, loss
 arithmetic, LSTM cell math) that previously crossed executable
@@ -373,14 +368,9 @@ class Segment:
             seg_fn = _make_seg_fn(
                 [(n.impl, n.ins, n.single) for n in nodes], returns)
             if any_tainted:
-                # recorded segments stay on the in-memory jit path:
-                # their vjp closure (a tree_util.Partial over local
-                # functions) cannot be serialized to disk
                 fn = jax.jit(lambda *xs: jax.vjp(seg_fn, *xs))
             else:
-                from . import compile_cache as _cc
-                fn = _cc.persistently_cached(jax.jit(seg_fn),
-                                             surface="bulk")
+                fn = jax.jit(seg_fn)
             _SEG_CACHE[sig] = fn
             if len(_SEG_CACHE) > _SEG_CACHE_CAP:
                 _SEG_CACHE.popitem(last=False)

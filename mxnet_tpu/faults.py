@@ -29,10 +29,6 @@ documented in docs/fault_tolerance.md):
 * ``worker.heartbeat``  — the dist_async worker heartbeat thread (an
   error here suppresses the beat: the wedged-not-dead rank simulation)
 * ``dispatch.op``       — the imperative op dispatch path, per op
-* ``compile_cache.read``  — persistent compile-cache lookup (an error
-  degrades to a miss + recompile, never a step failure)
-* ``compile_cache.write`` — persistent compile-cache write-back (an
-  error abandons the write; memory still serves)
 * ``trainer.step``      — the optimizer-step boundary, per step (the
   tensor-corrupting site: ``kind=nan`` plants a NaN via
   :func:`maybe_corrupt`)
@@ -159,16 +155,6 @@ _SITES: Dict[str, str] = {
     "dispatch.op":
         "the imperative op dispatch path (ndarray.register.invoke), "
         "per op call",
-    "compile_cache.read":
-        "persistent compile-cache lookup (CompileCache.load), before "
-        "the entry manifest is opened — an injected error degrades "
-        "the lookup to a miss (the program recompiles); never a step "
-        "or request failure",
-    "compile_cache.write":
-        "persistent compile-cache write-back (CompileCache.store), "
-        "before serialization/staging — an injected error abandons "
-        "the write; the freshly compiled executable still serves this "
-        "process from memory",
     "trainer.step":
         "the optimizer-step boundary (gluon Trainer.step before the "
         "gradient reduction, SPMDTrainer.step before the compiled "

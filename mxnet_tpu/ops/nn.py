@@ -651,7 +651,7 @@ register_env("MXNET_FUSE_BN_CONV", "0",
              "forces on (CPU runs the kernels in interpret mode). "
              "Numerically invisible (tests/test_fused_conv.py); default-off "
              "until the kernels beat XLA's convs at the gated shapes "
-             "(benchmark/fused_conv_probe.py).")
+             "(the probe is in git history before PR 30).")
 
 _FUSE_BN_CONV_LAST: list = [None]
 

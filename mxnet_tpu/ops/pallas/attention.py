@@ -863,7 +863,7 @@ def _flash2_bwd(rate, scale, causal, block_q, block_k, bias_grad, res, g):
 _flash2.defvjp(_flash2_fwd, _flash2_bwd)
 
 
-# measured optimum on v5e (benchmark/attn_probe.py sweep, r3): tall
+# measured optimum on v5e (attn_probe sweep, r3; git history < PR 30): tall
 # q-blocks over full-width k-blocks, clamped to T per call. Single source
 # of truth — ops/transformer.py's env-var defaults read these too.
 DEFAULT_BLOCK_Q = 256

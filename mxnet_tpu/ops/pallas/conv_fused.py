@@ -5,8 +5,8 @@ Reference parity (leezu/mxnet): the reference materializes every
 ``Convolution -> BatchNorm -> Activation`` junction through HBM
 (``src/operator/nn/convolution.cc`` dispatches cuDNN per op;
 ``MXNET_SUBGRAPH_BACKEND`` fusion only covers pointwise chains).  On TPU
-the ResNet-50 step is HBM-bound (BASELINE.md bandwidth roofline;
-``benchmark/resnet_layer_probe.py``): every pass over an activation
+the ResNet-50 step is HBM-bound (BASELINE.md bandwidth roofline; the
+layer probe is in git history before PR 30): every pass over an activation
 tensor costs ~1/850 GB/s, and XLA cannot fuse producers into a
 convolution's operand.  A 1x1 stride-1 convolution IS a GEMM, so Pallas
 can: these kernels compute ``y = w @ f(x)`` where ``f`` (per-channel

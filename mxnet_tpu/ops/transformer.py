@@ -38,7 +38,8 @@ register_env("MXNET_FLASH_BLOCK_Q", 0,
              "shape-aware auto: the FULL sequence as one block at "
              "T<=512 (one grid row per head — measured +5.5% BERT-base "
              "step throughput vs 256-row blocks at T=512), 256-row "
-             "blocks (the attn_probe sweep's pick) from T=1024 up.")
+             "blocks (the r3 attn_probe sweep's pick; the probe is in "
+             "git history before PR 30) from T=1024 up.")
 register_env("MXNET_FLASH_BLOCK_K", 1024,
              "Flash-attention key-block rows (v5e-tuned default; "
              "clamped to the sequence length per call).")
@@ -214,7 +215,7 @@ def _flash_block(which: str, seq: int = 0) -> int:
         # 138.6k tok/s with a full-T block vs 131.4k with 256): at
         # T<=512 one query block per (B,H) head removes per-block grid
         # overhead; at 1024+ the 256-row blocks from the attn_probe
-        # sweep win.
+        # sweep (in git history before PR 30) win.
         if 0 < seq <= 512:
             return seq
         return DEFAULT_BLOCK_Q
@@ -278,8 +279,9 @@ def _flash_threshold() -> int:
     r5: the backward IS now a fused single pass whenever Tk fits one
     k-block (every T <= MXNET_FLASH_BLOCK_K=1024 — all headline
     shapes), halving kernel launches/q-k-v reads/probability
-    recomputes.  Measured effect (attn_probe, b32 h12 d64, 60-iter
-    scan, fwdbwd ms/step, flash uses 256x1024 blocks clamped to T):
+    recomputes.  Measured effect (attn_probe, in git history before
+    PR 30; b32 h12 d64, 60-iter scan, fwdbwd ms/step, flash uses
+    256x1024 blocks clamped to T):
 
         T      xla    flash(fused)   flash(two-pass, bk=T/2)
         128    1.79      2.26              —

@@ -302,12 +302,9 @@ class SPMDTrainer:
             return body(param_arrays, opt_states, rng, lr, wd, t,
                         *batch) + (t + 1.0,)
 
-        from .. import compile_cache as _cc
         donate = (0, 1) if self._donate else ()
         if not self._donate_inputs:
-            return _cc.persistently_cached(
-                jax.jit(step, donate_argnums=donate),
-                surface="spmd.step")
+            return jax.jit(step, donate_argnums=donate)
         # batch args start at position 6; n_inputs data arrays plus
         # the label array.  Batch buffers rarely alias an output shape
         # (params/states/loss) — the donation win is the EARLY release
@@ -320,8 +317,7 @@ class SPMDTrainer:
         _warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
         donate = donate + tuple(range(6, 6 + n_inputs + 1))
-        return _cc.persistently_cached(
-            jax.jit(step, donate_argnums=donate), surface="spmd.step")
+        return jax.jit(step, donate_argnums=donate)
 
     def _build_step_body(self, n_inputs: int,
                          health_gate: bool = False) -> Callable:
@@ -483,10 +479,8 @@ class SPMDTrainer:
                 (keys, lrs, wds) + tuple(xs) + (ys,))
             return params, states, losses
 
-        from .. import compile_cache as _cc
         donate = (0, 1) if self._donate else ()
-        return _cc.persistently_cached(
-            jax.jit(multi, donate_argnums=donate), surface="spmd.multi")
+        return jax.jit(multi, donate_argnums=donate)
 
     def _raw_step(self, n_inputs: int) -> Callable:
         """The unjitted single-step body (shared by step and multi-step)."""

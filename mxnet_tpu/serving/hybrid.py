@@ -240,14 +240,9 @@ class HybridDecodeModel(DecodeModel):
                                 logits)
             return next_tok, ks, vs, new
 
-        from .. import compile_cache as _cc
-        self._prefill_fn = _cc.persistently_cached(
-            jax.jit(_prefill), surface="serving.decode", pin=True)
-        self._select_fn = _cc.persistently_cached(
-            jax.jit(_select_one), surface="serving.decode", pin=True)
-        self._step_fn = _cc.persistently_cached(
-            jax.jit(_step, donate_argnums=(1, 2, 3)),
-            surface="serving.decode", pin=True)
+        self._prefill_fn = jax.jit(_prefill)
+        self._select_fn = jax.jit(_select_one)
+        self._step_fn = jax.jit(_step, donate_argnums=(1, 2, 3))
 
     @staticmethod
     def from_phi4flash(block: Any) -> "HybridDecodeModel":

@@ -520,23 +520,18 @@ class PagedKVCache:
 
 
 # jitted helpers — one executable per (cache shape, prompt shape) pair,
-# all drawn from the bucket grid (warmable, bounded).  Both persist
-# through the compile cache (surface serving.kv, pinned) so a restarted
-# replica's warmup re-loads the whole admission/migration grid from
-# disk instead of recompiling it.
+# all drawn from the bucket grid (warmable, bounded).
 
 def _grow_rows(buf: Any, new_len: int) -> Any:
     fn = _grow_jits.get(int(new_len))
     if fn is None:
         import jax
         import jax.numpy as jnp
-        from .. import compile_cache as _cc
 
         def grow(b, _L=int(new_len)):
             return jnp.pad(b, ((0, 0), (0, 0), (0, _L - b.shape[2])))
 
-        fn = _grow_jits[int(new_len)] = _cc.persistently_cached(
-            jax.jit(grow), surface="serving.kv", pin=True)
+        fn = _grow_jits[int(new_len)] = jax.jit(grow)
     return fn(buf)
 
 
@@ -546,7 +541,6 @@ _grow_jits: dict = {}
 def _make_write_rows():
     import jax
     from jax import lax
-    from .. import compile_cache as _cc
 
     def write(bufs, rows, slot, start):
         # bufs: every layer's K then V buffer (S, h * d, L); rows: the
@@ -557,20 +551,19 @@ def _make_write_rows():
         # layer per K/V would bury the admission in launch latency).
         # start is a traced operand (prefix copies write at 0, suffix
         # prefills at the prefix length) so every offset shares this
-        # one executable per shape pair.  NOT donated: a donated
-        # multi-buffer write deserialized from the persistent compile
-        # cache mis-aliases on this jax/XLA version — a warm-restarted
-        # replica then decodes corrupted KV rows and double-frees at
-        # teardown (observed live; the in-process jit was fine).  The
-        # un-donated form matches the pre-prefix-cache write's
-        # semantics and keeps warm restarts at 0 compiles
+        # one executable per shape pair.  NOT donated: a write holds
+        # the cache twice while it runs (PERF.md PR 24).  Donating it
+        # moves admission_ms, memory_peak_bytes and the slots a cell
+        # can hold, so it is a change to measure on the chip (ROADMAP
+        # Speed 3 (3)).  Nothing is known against it: the mis-aliasing
+        # once cited here was the deleted compile_cache module's, and
+        # jax's persistent cache loads the donated decode step and the
+        # donated install below in every warm chip run
         return [lax.dynamic_update_slice(
             b, r.reshape(r.shape[0], -1).T[None].astype(b.dtype),
             (slot, _np.int32(0), start))
             for b, r in zip(bufs, rows)]
-    return _cc.persistently_cached(
-        jax.jit(write), surface="serving.kv",
-        pin=True)
+    return jax.jit(write)
 
 
 class _Lazy:
@@ -597,12 +590,7 @@ def _make_install_state():
         # bufs: {name: [(S, ...) a layer]}; new: the same without the
         # slot axis.  The whole of the slot is replaced.  DONATED: the
         # window rings are as large as a bucket's rows and an
-        # un-donated write would hold them twice.  A plain jax.jit on
-        # purpose: the donated multi-buffer write that mis-aliased
-        # (_make_write_rows) did so when deserialized by
-        # compile_cache, which this program therefore never enters;
-        # jax's own cache keeps it across restarts as it keeps the
-        # donated decode step
+        # un-donated write would hold them twice
         return jax.tree_util.tree_map(
             lambda b, r: lax.dynamic_update_slice(
                 b, r[None].astype(b.dtype), (slot,) + (0,) * r.ndim),
@@ -622,13 +610,11 @@ def _shrink_rows(rows: List[Any], new_len: int) -> List[Any]:
     fn = _shrink_jits.get(int(new_len))
     if fn is None:
         import jax
-        from .. import compile_cache as _cc
 
         def shrink(bs, _n=int(new_len)):
             return [b[:_n] for b in bs]
 
-        fn = _shrink_jits[int(new_len)] = _cc.persistently_cached(
-            jax.jit(shrink), surface="serving.kv", pin=True)
+        fn = _shrink_jits[int(new_len)] = jax.jit(shrink)
     return fn(list(rows))
 
 

@@ -77,16 +77,12 @@ class ServedModel:
         bit-flipped program/params file raises a structured error
         naming the artifact and the expected/actual digests, instead
         of an opaque deserializer crash (or, worse, a model that loads
-        and serves garbage).  Per-bucket executables go through the
-        persistent compile cache (pinned — a live server's grid is
-        never evicted), so a restarted replica re-warms from disk with
-        zero XLA compiles."""
+        and serves garbage)."""
         import base64
 
         import jax
         import jax.numpy as jnp
         from jax import export as jax_export
-        from .. import compile_cache as _cc
         from .._durable import sha256_bytes, sha256_file
 
         with open(symbol_file) as f:
@@ -142,12 +138,10 @@ class ServedModel:
         fixed = None if dynamic else int(meta["inputs"][0]["shape"][0])
 
         # params ride as ARGUMENTS (not closure constants): the lowered
-        # program — and so the persistent-cache key and the serialized
-        # executable — is weight-independent, shared across re-exports
-        # of the same architecture
-        aot = _cc.persistently_cached(
-            jax.jit(lambda ps, *xs: exp.call(key, list(ps), *xs)),
-            surface="serving.export", pin=True)
+        # program — and so its key in jax's persistent cache — is
+        # weight-independent, shared across re-exports of the same
+        # architecture
+        aot = jax.jit(lambda ps, *xs: exp.call(key, list(ps), *xs))
         params_t = tuple(params)
 
         def fn(arrays: Sequence[_np.ndarray]) -> List[_np.ndarray]:
@@ -612,28 +606,17 @@ class DecodeModel:
             h = lax.dynamic_slice_in_dim(x[0], t0 - 1, 1, axis=0)[0]
             return h @ params["embed"].T, ks_o, vs_o
 
-        # all programs persist through the compile cache (pinned: a
-        # live server's decode grid is never evicted) so a restarted
-        # replica re-warms its whole bucket grid with zero XLA compiles
-        from .. import compile_cache as _cc
-        self._prefill_fn = _cc.persistently_cached(
-            jax.jit(_prefill), surface="serving.decode", pin=True)
-        self._prefill_sfx_fn = _cc.persistently_cached(
-            jax.jit(_prefill_sfx), surface="serving.decode", pin=True)
-        self._select_fn = _cc.persistently_cached(
-            jax.jit(_select_one), surface="serving.decode", pin=True)
+        self._prefill_fn = jax.jit(_prefill)
+        self._prefill_sfx_fn = jax.jit(_prefill_sfx)
+        self._select_fn = jax.jit(_select_one)
         # the KV buffers are DONATED: XLA updates the resident cache in
         # place instead of allocating a fresh (S, h * d, L) per layer
         # every token
-        self._step_fn = _cc.persistently_cached(
-            jax.jit(_step, donate_argnums=(1, 2)),
-            surface="serving.decode", pin=True)
+        self._step_fn = jax.jit(_step, donate_argnums=(1, 2))
         # same donation contract as _step: verify scatters k+1 rows
         # into the resident buffers in place; rejected rows are
         # invisible (visibility mask <= pos) until overwritten
-        self._verify_fn = _cc.persistently_cached(
-            jax.jit(_verify, donate_argnums=(1, 2)),
-            surface="serving.decode", pin=True)
+        self._verify_fn = jax.jit(_verify, donate_argnums=(1, 2))
 
     # -- constructors -------------------------------------------------------
     @staticmethod
@@ -822,8 +805,7 @@ class DecodeModel:
         lanes).  The cache's buffers gain k+1 rows per slot starting
         at ``positions``; the caller owns acceptance and rolls back
         rejected rows via ``cache.truncate``.  One compiled program
-        per (S, bucket, k+1) triple, persistently cached like the
-        decode grid."""
+        per (S, bucket, k+1) triple."""
         import jax
         import jax.numpy as jnp
         S = cache.max_slots

@@ -59,14 +59,14 @@ _LOG = logging.getLogger("mxnet_tpu.serving")
 
 
 def _compile_cache_stats() -> Dict[str, Any]:
-    """Persistent compile-cache stats for /v1/model ({} when the cache
-    is disabled) — operators see at a glance whether a restarted
-    replica's warmup came from disk."""
-    from .. import compile_cache as _cc
-    try:
-        return _cc.cache_stats()
-    except Exception:   # noqa: BLE001 - introspection must never fail
-        return {}
+    """Where jax's persistent compilation cache is (None: off) and what
+    this boot compiled and loaded from it, for /v1/model — operators
+    see at a glance whether a restarted replica's warmup came from
+    disk."""
+    import jax
+    return {"dir": jax.config.jax_compilation_cache_dir,
+            "compiled": int(_metrics.COMPILE_MISSES.value),
+            "loaded": int(_metrics.COMPILE_PERSISTENT_HITS.value)}
 
 
 class DegradedError(MXNetError):

@@ -162,7 +162,6 @@ class SelfSpeculativeDraft(DraftModel):
 
     def __init__(self, model: Any, k: int, layers: int = 0) -> None:
         import jax
-        from .. import compile_cache as _cc
         if not isinstance(model, DecodeModel):
             model = DecodeModel.from_block(model)
         self.model = model
@@ -187,8 +186,7 @@ class SelfSpeculativeDraft(DraftModel):
                                       topps, methods, n_sub, nh, ga_s)
             return outs
 
-        self._fn = _cc.persistently_cached(
-            jax.jit(_propose), surface="serving.decode", pin=True)
+        self._fn = jax.jit(_propose)
 
     def _sub_params(self) -> dict:
         p = self.model.params
@@ -241,7 +239,6 @@ class IndependentDraft(DraftModel):
     def __init__(self, model: Any, k: int, max_slots: int,
                  buckets: Optional[Sequence[int]] = None) -> None:
         import jax
-        from .. import compile_cache as _cc
         if not isinstance(model, DecodeModel):
             model = DecodeModel.from_block(model)
         self.model = model
@@ -270,9 +267,7 @@ class IndependentDraft(DraftModel):
 
         # the draft cache's buffers are donated exactly like the
         # target step's: the chain updates them in place
-        self._fn = _cc.persistently_cached(
-            jax.jit(_propose, donate_argnums=(1, 2)),
-            surface="serving.decode", pin=True)
+        self._fn = jax.jit(_propose, donate_argnums=(1, 2))
 
     def admit(self, slot: int, tokens: _np.ndarray,
               prompt_buckets: Sequence[int]) -> None:

@@ -240,8 +240,8 @@ def _jax_center2(img, label):
 def test_dataloader_workers_jax_touching_dataset():
     """Regression (VERDICT r5 weak 1): multi-worker loading over a
     dataset whose __getitem__/transform touch jax must COMPLETE — the
-    old fork-context pool deadlocked here (benchmark/decode_scaling.py
-    at workers>=1) because jax's dispatch threads don't survive fork.
+    old fork-context pool deadlocked here (decode_scaling.py, in git
+    history before PR 30, at workers>=1) because jax's dispatch threads don't survive fork.
     Workers spawn by default now; this pins both completion and
     numerical equality with the in-process path."""
     # the parent's jax runtime must be live before the pool exists —

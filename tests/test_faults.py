@@ -104,8 +104,7 @@ def test_smoke_plan_parse_and_env(monkeypatch):
     assert set(faults.known_sites()) == {
         "checkpoint.write", "kvstore.send", "kvstore.recv",
         "dataloader.worker", "serving.execute", "serving.worker",
-        "ps.server", "worker.heartbeat", "dispatch.op",
-        "compile_cache.read", "compile_cache.write", "trainer.step"}
+        "ps.server", "worker.heartbeat", "dispatch.op", "trainer.step"}
 
 
 def test_smoke_nan_kind_corrupts_tensor_sites_only():

@@ -182,7 +182,7 @@ def test_decode_step_relayouts_no_resident_buffer(decode_model):
                      decode_model.head_dim, max_slots=S, buckets=(16,))
     resident = sorted(c.k(0).shape)     # in any axis order
     zeros = onp.zeros((S,), "int32")
-    jaxpr = jax.make_jaxpr(decode_model._step_fn._jitted)(
+    jaxpr = jax.make_jaxpr(decode_model._step_fn)(
         decode_model.params, c._k, c._v, zeros, zeros,
         *decode_model.greedy_sampling(S))
     seen = set()

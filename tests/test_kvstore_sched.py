@@ -287,8 +287,8 @@ def test_2bit_residual_survives_bucket_recomposition():
 
 def test_convergence_parity_2bit_vs_none_lstm_micro():
     """Compressed training tracks uncompressed on the lstm micro
-    config (the bulk-smoke LM shape): loss decreases and lands within
-    a band of the lossless run."""
+    config: loss decreases and lands within a band of the lossless
+    run."""
     vocab, embed, hidden, batch, seq = 120, 16, 16, 4, 6
 
     def build():

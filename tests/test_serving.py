@@ -8,6 +8,7 @@ parity; everything above that (batching, bucketing, backpressure) is
 beyond-reference serving behavior specified by ISSUE 2.
 """
 import json
+import os
 import threading
 import time
 import urllib.error
@@ -599,3 +600,41 @@ def test_serving_metrics_account_every_request():
     assert metrics.hist_stats(
         "mxnet_serving_inference_seconds")[1] > inf0[1]
     assert metrics.value("mxnet_serving_queue_depth") == 0.0
+
+
+# ---------------------------------------------------------------------------
+# export artifact digest verification (serving load path)
+# ---------------------------------------------------------------------------
+
+def test_export_digest_verified_on_load(tmp_path):
+    import mxnet_tpu as mx
+    from mxnet_tpu import serving
+    from mxnet_tpu.base import MXNetError
+
+    mx.random.seed(0)
+    net = mx.gluon.nn.Dense(3)
+    net.initialize()
+    net.hybridize()
+    net(mx.np.zeros((1, 6), dtype="float32"))
+    sym, params = net.export(str(tmp_path / "m"))
+    with open(sym) as f:
+        meta = json.load(f)
+    assert "stablehlo_sha256" in meta and "params_sha256" in meta
+    serving.load_served(str(tmp_path / "m"))        # intact: loads
+
+    # garbled program: structured error naming the artifact, BEFORE
+    # any deserializer runs
+    bad = json.loads(json.dumps(meta))
+    bad["stablehlo"] = bad["stablehlo"][:-8] + "AAAAAAA="
+    with open(sym, "w") as f:
+        json.dump(bad, f)
+    with pytest.raises(MXNetError, match="program checksum"):
+        serving.load_served(str(tmp_path / "m"))
+
+    # garbled weights: named too
+    with open(sym, "w") as f:
+        json.dump(meta, f)
+    with open(params, "r+b") as f:
+        f.truncate(max(0, os.path.getsize(params) - 7))
+    with pytest.raises(MXNetError, match="params_sha256|checksum"):
+        serving.load_served(str(tmp_path / "m"))

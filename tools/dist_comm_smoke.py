@@ -43,8 +43,8 @@ the same calibrated slow wire must reach
    (per-layer segments are steady-state cache hits, not per-step
    recompiles);
 8. **warm restart**: the same streamed workload run twice as fresh
-   processes sharing a persistent compile cache
-   (``MXNET_COMPILE_CACHE_DIR``) produces bit-identical losses, and
+   processes sharing jax's persistent compilation cache
+   (``JAX_COMPILATION_CACHE_DIR``) produces bit-identical losses, and
    the restarted process still reports 0 steady-state compiles after
    its warmup.
 
@@ -168,8 +168,8 @@ def _run_bwd(steps=BWD_STEPS, seed=0, n_params=BWD_PARAMS,
         metrics.value("mxnet_compile_misses_total") - c0
 
 
-# every env knob the measurement legs mutate — save/restored
-# symmetrically so library callers (bench.py) see no leakage
+# every env knob the backward legs mutate — saved and restored
+# symmetrically so main()'s later legs see no leakage
 _LEG_ENV_KEYS = ("MXNET_KV_OVERLAP", "MXNET_BULK_BACKWARD_SEGMENTS",
                  "MXNET_KV_BACKWARD_STREAM", "MXNET_KV_SYNTH_WIRE_GBPS",
                  "MXNET_KV_BUCKET_BYTES")
@@ -191,40 +191,10 @@ def _bwd_env(overlap, segments, stream, gbps):
     os.environ["MXNET_KV_BUCKET_BYTES"] = str(BWD_BUCKET)
 
 
-def optimizer_leg_ratio() -> dict:
-    """One calibrate + serialized + overlapped measurement of the
-    PR-14 update-heavy leg, with streaming AND segmentation pinned OFF
-    so the ratio isolates the optimizer-phase scheduler (bench.py's
-    ``dist_comm`` config trends it as ``dist_comm_overlap_ratio``;
-    the streamed path has its own metric via :func:`backward_leg`).
-    Single-shot — the gating main() keeps its own min-of-2 + retry
-    orchestration."""
-    push_bytes = N_PARAMS * PARAM_ELEMS * 4
-    saved = {k: os.environ.get(k) for k in _LEG_ENV_KEYS}
-    try:
-        os.environ["MXNET_KV_BUCKET_BYTES"] = str(BUCKET_BYTES)
-        os.environ["MXNET_KV_BACKWARD_STREAM"] = "0"
-        os.environ["MXNET_BULK_BACKWARD_SEGMENTS"] = "off"
-        os.environ["MXNET_KV_OVERLAP"] = "0"
-        os.environ["MXNET_KV_SYNTH_WIRE_GBPS"] = "0"
-        t_nowire, _, _ = _run()
-        step_s = max(t_nowire / STEPS, 0.004)
-        os.environ["MXNET_KV_SYNTH_WIRE_GBPS"] = \
-            f"{push_bytes / (0.8 * step_s * 1e9):.9f}"
-        serial_s, _, _ = _run()
-        os.environ["MXNET_KV_OVERLAP"] = "1"
-        overlap_s, _, _ = _run()
-    finally:
-        _restore_env(saved)
-    return {"ratio": serial_s / overlap_s if overlap_s > 0 else 0.0,
-            "serial_s": serial_s, "overlap_s": overlap_s,
-            "wire_ms": 0.8 * step_s * 1e3}
-
-
 def backward_leg(failures) -> dict:
     """Legs 5-7: serialized vs optimizer-only overlap vs streamed-
     during-backward, all on one calibrated slow wire.  Env knobs the
-    legs flip are restored on return (bench.py imports this)."""
+    legs flip are restored on return."""
     saved = {k: os.environ.get(k) for k in _LEG_ENV_KEYS}
     try:
         return _backward_leg_inner(failures)
@@ -315,21 +285,19 @@ def _backward_leg_inner(failures) -> dict:
 
 
 def restart_leg(failures) -> dict:
-    """Leg 8: two fresh processes share a persistent compile cache
-    (MXNET_COMPILE_CACHE_DIR); the restarted one must replay
+    """Leg 8: two fresh processes share jax's persistent compilation
+    cache (JAX_COMPILATION_CACHE_DIR); the restarted one must replay
     bit-identical losses with 0 steady-state compiles after its
-    warmup.  What this leg does NOT gate: warmup-compile savings from
-    the cache — this workload's programs are all RECORDED segments and
-    their pullbacks, which stay on the in-memory path by design (their
-    vjp closures do not serialize, PR 10), so both processes report
-    the same warmup compile count; the cache's own hit contract is
-    cache-smoke's gate.  The counts are returned for visibility."""
+    warmup, and compile no more in its warmup than the cold one (what
+    it loads instead is tests/test_warm_restart.py's gate).  The
+    counts are returned for visibility."""
     reports = []
     with tempfile.TemporaryDirectory(prefix="dist-comm-cache-") as d:
         for _ in range(2):
             env = dict(os.environ,
                        JAX_PLATFORMS="cpu",
-                       MXNET_COMPILE_CACHE_DIR=os.path.join(d, "cc"))
+                       JAX_COMPILATION_CACHE_DIR=os.path.join(d, "cc"),
+                       JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
             out = subprocess.run(
                 [sys.executable, os.path.abspath(__file__),
                  "--restart-child"],

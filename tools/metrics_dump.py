@@ -43,12 +43,6 @@ Workloads:
                ``exemplar`` field in the JSON exposition links a bad
                histogram straight to the trace that caused it (use
                ``--format json``; the Prometheus text is unchanged).
-  compile-cache  SPMD steps against a fresh persistent compile cache:
-               miss + durable write, a second trainer replaying the
-               same program from disk (hit), a truncated entry
-               quarantined + recompiled (corrupt counter), and a
-               seeded compile_cache.read fault degrading to a miss —
-               the mxnet_compile_cache_* families end-to-end.
 
 Runs on the CPU backend by default so it works anywhere (pass
 ``--platform ambient`` to keep the environment's backend, e.g. the TPU
@@ -389,54 +383,6 @@ def _workload_dist_resilience(steps: int) -> None:
     th2.join(10)
 
 
-def _workload_compile_cache(steps: int) -> None:
-    """Persistent compile-cache families end-to-end in one process:
-    miss/write (first trainer), hit (second trainer replays the same
-    program from disk), corrupt/quarantine (truncated entry), and the
-    compile_cache.read fault site degrading to a miss."""
-    import glob
-    import tempfile
-    import numpy as onp
-    import jax
-    if not os.environ.get("MXNET_COMPILE_CACHE_DIR"):
-        # CPU demo only (main() refuses the ambient platform without a
-        # directory the caller chose): a throw-away cache to show a miss
-        os.environ["MXNET_COMPILE_CACHE_DIR"] = tempfile.mkdtemp(
-            prefix="mxcc-dump-")
-    import mxnet_tpu as mx
-    from mxnet_tpu import faults
-    from mxnet_tpu.parallel import SPMDTrainer, make_mesh
-
-    def fresh_trainer():
-        net = mx.gluon.nn.Dense(4)
-        net.initialize()
-        net(mx.np.zeros((2, 8)))
-        return SPMDTrainer(net, mx.gluon.loss.L2Loss(), "sgd",
-                           {"learning_rate": 0.05},
-                           mesh=make_mesh({"dp": 1},
-                                          devices=jax.devices()[:1]))
-
-    mx.random.seed(0)
-    rng = onp.random.RandomState(0)
-    x = mx.np.array(rng.uniform(-1, 1, (8, 8)).astype("f4"))
-    y = mx.np.array(rng.uniform(-1, 1, (8, 4)).astype("f4"))
-    t1 = fresh_trainer()                    # miss + durable write
-    for _ in range(max(steps, 2)):
-        t1.step(x, y)
-    t2 = fresh_trainer()                    # same program: disk hit
-    t2.step(x, y)
-    d = os.environ["MXNET_COMPILE_CACHE_DIR"]
-    for exe in glob.glob(os.path.join(d, "cc-*.exe")):
-        with open(exe, "r+b") as f:
-            f.truncate(16)                  # -> quarantine + recompile
-    t3 = fresh_trainer()
-    t3.step(x, y)
-    with faults.fault_plan("compile_cache.read:times=1"):
-        t4 = fresh_trainer()                # read fault -> miss
-        t4.step(x, y)
-    mx.waitall()
-
-
 def _workload_dist_comm(steps: int) -> None:
     """Overlapped gradient reduction on a synthetic-slow wire: a
     16-parameter adam micro-fit through the bucketed comm-thread
@@ -523,7 +469,6 @@ WORKLOADS = {
     "resilience": _workload_resilience,
     "generation": _workload_generation,
     "dist-resilience": _workload_dist_resilience,
-    "compile-cache": _workload_compile_cache,
     "dist-comm": _workload_dist_comm,
     "trace": _workload_trace,
 }
@@ -541,12 +486,6 @@ def main(argv=None) -> None:
                     help="force the CPU backend (default) or keep the "
                          "environment's")
     args = ap.parse_args(argv)
-    if args.workload == "compile-cache" and args.platform == "ambient" \
-            and not os.environ.get("MXNET_COMPILE_CACHE_DIR"):
-        ap.error("the compile-cache workload on the ambient platform "
-                 "needs MXNET_COMPILE_CACHE_DIR set to a directory you "
-                 "chose (no temp-named cache is invented there)")
-
     if args.platform == "cpu":
         import jax
         jax.config.update("jax_platforms", "cpu")

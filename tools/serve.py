@@ -36,11 +36,12 @@ graceful drain — admissions shed 429, resident work finishes inside
 MXNET_SERVING_DRAIN_DEADLINE_S, readiness 503 / liveness 200
 throughout, exit 0.
 
-Warm restarts (docs/performance.md#persistent-compile-cache): with
-MXNET_COMPILE_CACHE_DIR set, --prewarm populates every bucket grid
-from the persistent compile cache BEFORE /healthz flips ready (zero
-XLA compiles on a restart) and /v1/model reports warmup_seconds +
-cache stats.
+Warm restarts (docs/performance.md#persistent-compile-cache): on an
+accelerator jax's persistent compilation cache is on
+(JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache), so a restarted
+server loads every bucket grid from it BEFORE /healthz flips ready
+(zero XLA compiles) and /v1/model reports warmup_seconds and what this
+boot compiled and loaded.
 """
 import argparse
 import os
@@ -82,9 +83,9 @@ def main(argv=None) -> None:
                     help="populate the bucket grids BEFORE /healthz "
                          "flips ready (the default behavior, made "
                          "explicit for launch scripts) and print the "
-                         "warmup report — with MXNET_COMPILE_CACHE_DIR "
-                         "set, a restarted server re-warms from the "
-                         "persistent compile cache with zero XLA "
+                         "warmup report — a restarted server loads "
+                         "its programs from jax's persistent cache "
+                         "(JAX_COMPILATION_CACHE_DIR) with zero XLA "
                          "compiles; warmup seconds are also reported "
                          "in /v1/model")
     ap.add_argument("--replicas", type=int, default=None,
@@ -239,13 +240,11 @@ def main(argv=None) -> None:
 
 def _cache_note() -> str:
     """One-line persistent-cache summary for the startup banner."""
-    from mxnet_tpu import compile_cache
-    stats = compile_cache.cache_stats()
-    if not stats:
-        return ""
-    return (f"  [compile cache: {stats['entries']} entries, "
-            f"{stats['bytes'] / 1e6:.1f} MB, {int(stats['hits'])} hits "
-            f"/ {int(stats['misses'])} misses this boot]")
+    from mxnet_tpu.serving.server import _compile_cache_stats
+    stats = _compile_cache_stats()
+    return (f"  [compile cache: {stats['dir'] or 'off'}, "
+            f"{stats['compiled']} compiled / {stats['loaded']} loaded "
+            "this boot]")
 
 
 def _serve_generate(args, serving) -> None:
