@@ -742,6 +742,19 @@ GEN_STEP_FALLBACKS_TOTAL = counter(
     "spec (a speculating iteration). With "
     "mxnet_gen_steps_ahead_total it counts every step.",
     labels=("reason",))
+GEN_ROW_BLOCKS_READ_TOTAL = counter(
+    "mxnet_gen_row_blocks_read_total",
+    "Position blocks of the cache's rows that decode steps fetched: a "
+    "launch adds each slot's pos // block + 1 (a free slot rides at 0), "
+    "from the host's own position vector. Only a family whose step "
+    "reads by extent moves it (ops.pallas.decode_attention); over "
+    "mxnet_gen_row_blocks_total it is the share of the bucket a step "
+    "reads.")
+GEN_ROW_BLOCKS_TOTAL = counter(
+    "mxnet_gen_row_blocks_total",
+    "Position blocks the same launches would have fetched reading "
+    "every slot's whole capacity bucket: slots x bucket / block a "
+    "launch.")
 GEN_DISCARDED_TOKENS_TOTAL = counter(
     "mxnet_gen_discarded_tokens_total",
     "Decode-step tokens computed for a slot whose stream had already "

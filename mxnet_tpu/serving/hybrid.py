@@ -22,7 +22,9 @@ Prefill is one program a prompt bucket, built from the zoo's sequence
 functions; it hands back every kind AT THE PROMPT'S REAL LENGTH: the
 recurrence stops there (``dt`` is zeroed past it), the conv tail and
 the ring's columns are gathered from there.  The decode step is one
-donated program a KV bucket over every slot.  Matrices and activations
+donated program a KV bucket over every slot; it reads the rings in full
+and the ``rows`` kind by extent, each slot's position blocks up to its
+own position (``_rows_attention``).  Matrices and activations
 are the block's dtype (bfloat16 as published); the recurrence, its
 state, the softmax and the logits are float32.
 
@@ -35,7 +37,7 @@ from __future__ import annotations
 
 import math
 import threading
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as _np
 
@@ -90,6 +92,20 @@ def _slot_attention(p, q, ck, cv, pos, depth, cfg):
     out = _pf._diff_combine(p, a, depth, cfg["layer_norm_eps"])
     return _pf._mm(out.reshape(S, -1).astype(q.dtype), p["out_w"]) \
         + _pf._f32(p["out_b"])
+
+
+def _rows_attention(p, q, ck, cv, pos, depth, cfg):
+    """:func:`_slot_attention` over the ``rows`` kind by the ragged
+    kernel (``ops.pallas.decode_attention``): of slot i's columns the
+    position blocks up to ``pos[i]``'s are read and no other.  The
+    window rings stay with the dense code: a ring is live in full from
+    ``window - 1`` on."""
+    from ..gluon.model_zoo import phi4flash as _pf
+    from ..ops.pallas import decode_attention as _da
+    a = _da.paired_decode_attention(q, ck, cv, pos, cfg["head_dim"])
+    out = _pf._diff_combine(p, a, depth, cfg["layer_norm_eps"])
+    return _pf._mm(out.reshape(q.shape[0], -1).astype(q.dtype),
+                   p["out_w"]) + _pf._f32(p["out_b"])
 
 
 def _slot_mamba(p, x, conv, ssm, cfg):
@@ -206,7 +222,7 @@ class HybridDecodeModel(DecodeModel):
                 elif kind == "cross":
                     q = (_pf._mm(h, p["q_w"])
                          + _pf._f32(p["q_b"])).astype(h.dtype)
-                    y = _slot_attention(p, q, ks[0], vs[0], pos, depth,
+                    y = _rows_attention(p, q, ks[0], vs[0], pos, depth,
                                         cfg)
                 else:
                     q, k, v = _pf._qkv(p, h, cfg)
@@ -222,7 +238,7 @@ class HybridDecodeModel(DecodeModel):
                                                     pos)
                         cv = vs[i] = _write_columns(vs[i], v[:, :, None],
                                                     pos)
-                        y = _slot_attention(p, q, ck, cv, pos, depth, cfg)
+                        y = _rows_attention(p, q, ck, cv, pos, depth, cfg)
                 x = x + y.astype(x.dtype)
                 x = x + _pf._mlp(p, _pf._ln(x, p["ln2_g"], p["ln2_b"],
                                             eps))
@@ -263,6 +279,11 @@ class HybridDecodeModel(DecodeModel):
             window=cfg["window"],
             state_shapes={"conv": (cfg["d_inner"], cfg["d_conv"] - 1),
                           "ssm": (cfg["d_inner"], cfg["d_state"])})
+
+    def row_blocks(self, positions: _np.ndarray,
+                   bucket: int) -> Tuple[int, int]:
+        from ..ops.pallas import decode_attention as _da
+        return _da.blocks_read(_np.asarray(positions), bucket)
 
     # -- execution: DecodeModel's prefill and step, which hand a family's
     # extra results through (the fixed-size kinds ride fourth) ----------

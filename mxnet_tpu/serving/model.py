@@ -716,6 +716,15 @@ class DecodeModel:
                 jnp.asarray(_np.asarray(topps, _np.float32)),
                 jnp.asarray(_np.asarray(methods, _np.int32)))
 
+    def row_blocks(self, positions: _np.ndarray,
+                   bucket: int) -> Optional[Tuple[int, int]]:
+        """``(read, all)`` for a family whose decode step reads the
+        cache's rows by extent: the position blocks one step at these
+        per-slot positions fetches, and those a bucket of ``bucket``
+        rows holds for every slot.  None where the step reads the whole
+        bucket whatever the positions, as this family's does."""
+        return None
+
     def dispatch(self, cache: Any, tokens: Any,
                  positions: _np.ndarray,
                  sampling: Optional[Sequence[Any]] = None) -> Any:
@@ -728,7 +737,10 @@ class DecodeModel:
         ``tokens`` is the host vector of each slot's last token, or the
         un-read array the step before this one returned: it then feeds
         this step where it lies, so the launch needs nothing the host
-        has not got (the span says ``ahead=1``).  Either way the
+        has not got (the span says ``ahead=1``; for a family that reads
+        by extent it also says ``row_blocks`` of ``row_blocks_all``,
+        :meth:`row_blocks`, and the two ``mxnet_gen_row_blocks_*``
+        counters move by them).  Either way the
         compiled program is the same one: host vectors are uploaded
         COMMITTED to the cache's device, as a step's own results are (a
         jitted call keys its executable on that).  ``sampling`` as in
@@ -743,11 +755,17 @@ class DecodeModel:
             sampling = self.device_sampling(sampling)
         self._account(f"decode:{S}x{cache.bucket}")
         ahead = isinstance(tokens, jax.Array)
+        extent = {}
+        blocks = self.row_blocks(positions, cache.bucket)
+        if blocks is not None:
+            extent = {"row_blocks": blocks[0], "row_blocks_all": blocks[1]}
+            _metrics.GEN_ROW_BLOCKS_READ_TOTAL.inc(blocks[0])
+            _metrics.GEN_ROW_BLOCKS_TOTAL.inc(blocks[1])
         # the host-serial part of a step: the uploads, the jitted call
         # returning, the new buffers installed
         with _tracing.child_span("model.step.dispatch", slots=S,
                                  bucket=cache.bucket, family=self.family,
-                                 ahead=int(ahead)):
+                                 ahead=int(ahead), **extent):
             if not ahead:
                 tokens = jax.device_put(_np.asarray(tokens, _np.int32),
                                         cache.device)

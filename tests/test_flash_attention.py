@@ -371,3 +371,75 @@ def test_flash_shard_mapped_matches_dense():
     for a, b in zip(g, gref):
         onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
                                     rtol=2e-4, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the ragged decode-attention kernel (ops/pallas/decode_attention.py) under
+# the same described v5e; its mathematics is tests/test_decode_attention.py
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def one_v5e(monkeypatch):
+    try:
+        mesh = _v5e_mesh(monkeypatch, (1,), ("dp",))
+    except Exception as e:      # noqa: BLE001 - whatever stops the describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+
+
+def test_decode_attention_compiles_for_v5e_at_the_serving_cells_shapes(
+        one_v5e):
+    """64 slots, 1280 K/V channels, the 4096 bucket, bfloat16, the
+    module's own block: Mosaic takes the kernel within the scoped VMEM,
+    and the rows reach it as they are (no copy of a whole buffer)."""
+    import chip_smoke
+    from mxnet_tpu.ops.pallas import decode_attention as da
+    S, kv, L, d = 64, 1280, 4096, 64
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(      # noqa: E731
+        shape, dt, sharding=one_v5e)
+
+    def call(q, ck, cv, pos):
+        return da.paired_decode_attention(q, ck, cv, pos, d)
+
+    hlo = jax.jit(call).lower(
+        arg((S, 2 * kv), jnp.bfloat16), arg((S, kv, L), jnp.bfloat16),
+        arg((S, kv, L), jnp.bfloat16), arg((S,), jnp.int32)
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert chip_smoke.cache_sized_relayouts(hlo, S * kv * L) == []
+
+
+@pytest.mark.slow
+def test_hybrid_step_compiles_for_v5e_and_reads_the_rows_in_place(one_v5e):
+    """The whole decode step of the published Phi-4-mini-flash, 64
+    slots on the 4096 bucket (about a minute): eight kernel calls, and
+    no copy or transpose the size of layer 17's K or V rows on the way
+    into them (``chip_smoke.cache_sized_relayouts``, PR 27's check)."""
+    import chip_smoke
+    from mxnet_tpu.gluon.model_zoo import phi4flash as pf
+    from mxnet_tpu.serving.hybrid import CACHE_KIND, HybridDecodeModel
+    S, L = 64, 4096
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(      # noqa: E731
+        tuple(shape), dt, sharding=one_v5e)
+    net = pf.get_phi4flash("phi4_mini_flash", dtype="bfloat16")
+    cfg = dict(net.config)
+    params = pf._tree({name: arg(p.shape, jnp.dtype(str(p.dtype)))
+                       for name, p in net.collect_params().items()},
+                      cfg["kinds"])
+    model = HybridDecodeModel(params, cfg, net._max_length, "aot")
+    kv = cfg["num_kv_heads"] * cfg["head_dim"]
+    kinds = [CACHE_KIND[k] for k in cfg["kinds"]]
+    rows = [arg((S, kv, L), jnp.bfloat16)] * kinds.count("rows")
+    ring = [arg((S, kv, cfg["window"]), jnp.bfloat16)] \
+        * kinds.count("window")
+    n_state = kinds.count("state")
+    state = {"wk": ring, "wv": ring,
+             "conv": [arg((S, cfg["d_inner"], cfg["d_conv"] - 1),
+                          jnp.float32)] * n_state,
+             "ssm": [arg((S, cfg["d_inner"], cfg["d_state"]),
+                         jnp.float32)] * n_state}
+    i32, f32 = arg((S,), jnp.int32), arg((S,), jnp.float32)
+    hlo = model._step_fn.lower(params, rows, rows, state, i32, i32, i32,
+                               i32, f32, i32, f32, i32).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 8
+    assert chip_smoke.cache_sized_relayouts(hlo, S * kv * L) == []
