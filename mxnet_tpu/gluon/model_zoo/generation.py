@@ -48,8 +48,12 @@ def _collect(model) -> Dict[str, Any]:
     for blk in model.blocks._children.values():
         if blk.moe is not None:
             raise MXNetError(
-                "generate() does not support MoE blocks yet — decode "
-                "routing is not implemented (train-time MoE is)")
+                "generate() does not support MoE blocks of the GPT "
+                "family: MoEDense routes against a capacity mask that "
+                "drops tokens, and no decode step is built over it. "
+                "The dropless expert layer (parallel.moe.route / "
+                "expert_product) is decoded for the cohere2moe family "
+                "(serving.moe)")
         blocks.append({
             "ln1_g": _j(blk.ln1.gamma), "ln1_b": _j(blk.ln1.beta),
             "qkv_w": _j(blk.attn_qkv.weight),

@@ -755,6 +755,27 @@ GEN_ROW_BLOCKS_TOTAL = counter(
     "Position blocks the same launches would have fetched reading "
     "every slot's whole capacity bucket: slots x bucket / block a "
     "launch.")
+GEN_EXPERT_ASSIGNMENTS_TOTAL = counter(
+    "mxnet_gen_expert_assignments_total",
+    "(slot, choice) pairs that decode steps routed to an expert this "
+    "model HOLDS, summed over layers: what the held experts' grouped "
+    "product computed. Read back with each step's tokens. Only a family "
+    "with routed experts moves it and the three counters below "
+    "(serving.moe); over mxnet_gen_expert_slots_total it is the mean "
+    "tokens a held expert a layer a step.")
+GEN_EXPERT_OFFERED_TOTAL = counter(
+    "mxnet_gen_expert_offered_total",
+    "(slot, choice) pairs the same steps routed over ALL the experts: "
+    "slots x experts per token x layers a step. Assignments over it is "
+    "the share of the routing that fell on this model's share of the "
+    "experts.")
+GEN_EXPERTS_HIT_TOTAL = counter(
+    "mxnet_gen_experts_hit_total",
+    "Held experts with at least one token, summed over layers and "
+    "steps: each is one expert's matrices a step has to read.")
+GEN_EXPERT_SLOTS_TOTAL = counter(
+    "mxnet_gen_expert_slots_total",
+    "Held experts x layers a step: the base of the two ratios above.")
 GEN_DISCARDED_TOKENS_TOTAL = counter(
     "mxnet_gen_discarded_tokens_total",
     "Decode-step tokens computed for a slot whose stream had already "

@@ -6,6 +6,16 @@ ABSENT).  Design (tpu-first): experts are ONE set of stacked parameters
 them; token dispatch/combine are dense einsums against a capacity-bucketed
 one-hot mask (Shazeer/GShard style), which GSPMD turns into all-to-all
 over ICI when the expert dim is sharded — no manual collective calls.
+
+Beside it, the DROPLESS top-k expert layer as pure functions
+(:func:`route`, then :func:`grouped_experts` or :func:`dense_experts`): every token's ``top_k`` choices
+among all the experts are honoured, and the caller says which range of
+the experts it HOLDS (one chip's share of an expert-parallel
+deployment).  The held experts' part of the result is computed for the
+tokens routed to them and nothing is added for an absent expert.  The
+zoo's ``cohere2moe`` family and ``serving.moe`` both call these;
+``MoEDense`` above keeps its capacity mask until it is retired onto them
+(ROADMAP).
 """
 from __future__ import annotations
 
@@ -25,7 +35,8 @@ from ..ndarray.ndarray import NDArray
 from .spmd import DEFAULT_TRANSFORMER_RULES, PartitionRules
 
 __all__ = ["MoEDense", "MOE_RULES", "MOE_TRANSFORMER_RULES",
-           "collect_aux_losses"]
+           "collect_aux_losses", "route", "dense_experts",
+           "grouped_experts"]
 
 
 # Active aux-loss collector (trace-safe channel from MoE layers to the
@@ -199,3 +210,111 @@ class MoEDense(HybridBlock):
 
         units = out.shape[-1]
         return out.reshape(tuple(shape[:-1]) + (units,))
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer: pure functions over (T, d) tokens
+# ---------------------------------------------------------------------------
+
+def route(h, router_w, top_k: int, held, valid=None):
+    """Sigmoid top-k routing over ALL the experts, for a caller that
+    holds the experts ``held = (lo, hi)`` of them.
+
+    ``h (T, d)``, ``router_w (E, d)``.  Scores ``sigmoid(h Wr)`` are
+    float32; a token's weights are its ``top_k`` scores over their sum.
+    Returns ``(local, weights, load, scores)``: ``local (T, k)`` int32 is
+    the choice's index among the held experts, or ``hi - lo`` where the
+    chosen expert is absent (or the token is not ``valid``: a prompt's
+    padding); ``weights (T, k)`` float32; ``load (hi - lo,)`` int32 the
+    choices that fell on each held expert; ``scores (T, E)``."""
+    import jax.numpy as jnp
+    from jax import lax
+    lo, hi = held
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "td,ed->te", h, router_w, preferred_element_type=jnp.float32))
+    top, ids = lax.top_k(scores, top_k)
+    weights = top / jnp.sum(top, axis=-1, keepdims=True)
+    here = (ids >= lo) & (ids < hi)
+    if valid is not None:
+        here &= valid[:, None]
+    local = jnp.where(here, ids - lo, hi - lo).astype(jnp.int32)
+    load = jnp.sum(local[..., None] == jnp.arange(hi - lo), axis=(0, 1),
+                   dtype=jnp.int32)
+    return local, weights, load, scores
+
+
+def _swiglu(gate_up, dtype):
+    import jax.numpy as jnp
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    return (jax.nn.silu(gate) * up).astype(dtype)
+
+
+def dense_experts(h, local, weights, w_in, w_out):
+    """The held experts' part of the output as ONE batched product over
+    all of them, every token through every held expert and weighted 0
+    where it was not routed: ``n`` times the needed FLOPs, but each
+    expert's matrices are read once, which is all a decode step's few
+    tokens cost.  ``w_in (n, d, 2 f)`` (gate then up), ``w_out (n, f,
+    d)``; returns ``(T, d)`` float32."""
+    import jax.numpy as jnp
+    n = w_in.shape[0]
+    combine = jnp.sum((local[..., None] == jnp.arange(n))
+                      * weights[..., None], axis=1)             # (T, n)
+    x = jnp.broadcast_to(h, (n,) + h.shape)
+    act = _swiglu(jnp.einsum("etd,edf->etf", x, w_in,
+                             preferred_element_type=jnp.float32), h.dtype)
+    y = jnp.einsum("etf,efd->etd", act, w_out,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("etd,te->td", y, combine)
+
+
+# megablox's tile: rows of (token, choice) pairs, contraction, output
+# columns.  From chip measurements, PERF.md section 6 (PR 32).
+GMM_TILING = (128, 1024, 1024)
+
+
+def _gmm(rows, w, sizes):
+    """``rows[segment e] @ w[e]`` for the segments ``sizes`` names, in
+    float32: the ``megablox`` grouped matmul that ships inside jax, a
+    Pallas kernel whose grid visits the row tiles the segments touch
+    and no other (interpret mode on the CPU, as every kernel here)."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu import megablox
+    from ..ops.pallas.attention import _interpret
+    tm, tk, tn = GMM_TILING
+    return megablox.gmm(
+        rows, w, sizes, jnp.float32,
+        (min(tm, rows.shape[0]), min(tk, w.shape[1]), min(tn, w.shape[2])),
+        interpret=_interpret())
+
+
+def grouped_experts(h, local, weights, load, w_in, w_out):
+    """The same part by segments: the (token, choice) pairs sorted by
+    held expert, the absent ones last, and one grouped product over the
+    segments ``load`` names.  It reads each hit expert's matrices once
+    and computes the routed rows alone, where the batched form's FLOPs
+    grow 16-fold with a prompt's tokens; the sort, the gather and the
+    way back cost a decode step more than they save it (PERF.md section
+    6, PR 32: this, the batched form and ``jax.lax.ragged_dot``, which
+    on a v5e takes 3.8 ms for ``w_in`` whatever the rows).  Returns
+    ``(T, d)`` float32."""
+    import jax.numpy as jnp
+    T, k = local.shape
+    n = w_in.shape[0]
+    pairs = T * k
+    # whole row tiles for the kernel
+    pad = -pairs % min(GMM_TILING[0], -(-pairs // 8) * 8)
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    # rows past the segments belong to no expert and the kernel leaves
+    # them unwritten, forward and backward: selected away on the way in
+    # and after each product, so that nothing of them reaches the
+    # result or a gradient
+    live = jnp.pad(flat[order] < n, (0, pad))[:, None]
+    rows = jnp.where(live, jnp.pad(h[order // k], ((0, pad), (0, 0))), 0)
+    act = _swiglu(jnp.where(live, _gmm(rows, w_in, load), 0.0), h.dtype)
+    y = jnp.where(live, _gmm(act, w_out, load), 0.0)[:pairs] \
+        * weights.reshape(-1)[order][:, None]
+    back = jnp.zeros((pairs,), jnp.int32).at[order].set(
+        jnp.arange(pairs, dtype=jnp.int32))
+    return jnp.sum(y[back].reshape(T, k, -1), axis=1)

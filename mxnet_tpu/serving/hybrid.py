@@ -12,6 +12,9 @@ What a slot holds, by layer kind (``PagedKVCache`` allocates it):
   position embedding and softmax does not care for the order of its
   keys, so the ring is never unrolled; a slot at position ``p`` sees
   the columns ``<= p``, which is all of them from ``window - 1`` on.
+  (It also holds for keys stored ALREADY ROTATED at their absolute
+  position, as ``serving.moe`` stores them: a score then depends on
+  the two positions' difference alone.)
 * ``full``   -> ``rows``: K and V rows ``(S, kv, L)`` in the bucket
   grid, written and grown exactly as the GPT family's.
 * ``gmu``, ``cross`` -> nothing: a GMU layer reads the memory the last

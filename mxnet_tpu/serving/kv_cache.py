@@ -42,9 +42,14 @@ This cache provides that shape discipline:
   holds of it: ``rows`` (the above: K/V rows that grow, in the bucket
   grid), ``window`` (K/V rows capped at the attention window,
   ``(S, heads * d, window)`` whatever the bucket, used as a ring by
-  the family's step), ``state`` (arrays of fixed shape, float32: a
-  recurrence's state) or ``none``.  The GPT family is ``rows`` in
-  every layer; ``serving.hybrid`` mixes all four.  One slot table
+  the family's step: position ``p`` in column ``p % window``, never
+  unrolled, because softmax does not care for the order of its keys;
+  that holds without a position embedding and also for keys stored
+  already rotated at their absolute position), ``state`` (arrays of
+  fixed shape, float32: a recurrence's state) or ``none``.  The GPT
+  family is ``rows`` in every layer; ``serving.hybrid`` mixes all
+  four, ``serving.moe`` three rings of 4096 and one ``rows`` a period.
+  One slot table
   serves them all: admission installs every kind for its slot
   (:meth:`PagedKVCache.write_prompt`), only ``rows`` grow.
 

@@ -622,19 +622,28 @@ class DecodeModel:
     @staticmethod
     def from_block(block: Any) -> "DecodeModel":
         """Build from a live zoo LM (weights as currently
-        initialized/loaded): a ``GPTModel`` (MoE decode is not
-        supported yet — same restriction as ``model_zoo.generation``)
-        or a ``Phi4FlashModel``, which gets its own subclass."""
+        initialized/loaded).  Three families are served: a ``GPTModel``
+        with dense FFNs (one built with ``moe_experts`` keeps
+        ``MoEDense``'s capacity mask, which drops tokens, and is
+        refused), a ``Phi4FlashModel`` (``serving.hybrid``) and a
+        ``Cohere2MoEModel`` (``serving.moe``: dropless top-k experts, of
+        which the model holds a share), each of the last two through its
+        own subclass."""
+        from ..gluon.model_zoo.cohere2moe import Cohere2MoEModel
         from ..gluon.model_zoo.generation import _collect
         from ..gluon.model_zoo.phi4flash import Phi4FlashModel
         if isinstance(block, Phi4FlashModel):
             from .hybrid import HybridDecodeModel
             return HybridDecodeModel.from_phi4flash(block)
+        if isinstance(block, Cohere2MoEModel):
+            from .moe import MoEDecodeModel
+            return MoEDecodeModel.from_cohere2moe(block)
         if not hasattr(block, "blocks") or not hasattr(block,
                                                        "word_embed"):
             raise MXNetError(
                 f"DecodeModel serves decoder-only zoo LMs (GPTModel, "
-                f"Phi4FlashModel); got {type(block).__name__}")
+                f"Phi4FlashModel, Cohere2MoEModel); got "
+                f"{type(block).__name__}")
         params = _collect(block)
         ga = (params.pop("gelu_approx"), params.pop("ln_eps"))
         nh = next(iter(block.blocks._children.values()))._num_heads
@@ -679,15 +688,32 @@ class DecodeModel:
         padded[:t0] = toks
         self._account(f"prefill:{bucket_len}")
         with _tracing.child_span("model.prefill", bucket=bucket_len,
-                                 family=self.family):
+                                 family=self.family) as span:
             t = time.perf_counter()
             logits, *held = self._prefill_fn(
                 self.params, jnp.asarray(padded), _np.int32(t0))
+            held = self._prefill_extras(span, held)
             out = _np.asarray(logits)
             dt = time.perf_counter() - t
         _metrics.GEN_STEP_SECONDS.labels(phase="prefill").observe(
             dt, exemplar=_tracing.current_trace_id())
         return (out, *held)
+
+    # what a family's programs hand back or take beside the GPT family's,
+    # where it has any (serving.moe: the held experts' load)
+    def _prefill_extras(self, span: Any, held: List[Any]) -> List[Any]:
+        """``held``, what the prefill program returned after the logits,
+        without what is said on ``span`` and not handed on."""
+        return held
+
+    def _step_tokens(self, tokens: _np.ndarray) -> _np.ndarray:
+        """The host's (S,) last tokens as the step program takes them."""
+        return tokens
+
+    def _read_step(self, span: Any, out: _np.ndarray) -> _np.ndarray:
+        """The (S,) tokens of what a step handed back, read to the
+        host; the rest is said on ``span``."""
+        return out
 
     def greedy_sampling(self, n_slots: int) -> Tuple[_np.ndarray, ...]:
         """All-greedy per-slot sampling vectors (seed, counter base,
@@ -767,8 +793,9 @@ class DecodeModel:
                                  bucket=cache.bucket, family=self.family,
                                  ahead=int(ahead), **extent):
             if not ahead:
-                tokens = jax.device_put(_np.asarray(tokens, _np.int32),
-                                        cache.device)
+                tokens = jax.device_put(
+                    self._step_tokens(_np.asarray(tokens, _np.int32)),
+                    cache.device)
             # every kind of buffer the cache holds is donated and
             # comes back updated
             toks, *new = self._step_fn(
@@ -781,8 +808,8 @@ class DecodeModel:
     def collect(self, toks: Any) -> _np.ndarray:
         """Wait for a dispatched step: its (S,) int32 tokens on the
         host."""
-        with _tracing.child_span("model.step.readback"):
-            return _np.asarray(toks)
+        with _tracing.child_span("model.step.readback") as span:
+            return self._read_step(span, _np.asarray(toks))
 
     def step(self, cache: Any, tokens: _np.ndarray,
              positions: _np.ndarray,

@@ -5,6 +5,7 @@ file is run by hand; these count in tier-1).  They check control flow,
 file lookup and the last line; no number they see is a statement about
 speed."""
 import json
+import math
 import os
 import sys
 import time
@@ -55,9 +56,46 @@ TINY = (
      "trace_at_s": 0.2, "trace_window_s": 0.5})
 
 
-def tiny_root(tmp_path, monkeypatch):
-    monkeypatch.setitem(rehearsal.TINY, CELL, TINY)
-    return rehearsal.tiny_root(tmp_path, CELL)
+MOE_CELL = "tiny_moe.serve_agent"
+MOE_TINY = (
+    ["setup_s", "serve_tokens_per_s", "warmup_s", "decode_batch_mean",
+     "kv_migrations", "decode_step_ms", "prefill_ms",
+     "device_idle_pct.serve", "queue_wait_p95_ms", "admission_ms",
+     "decode_dispatch_ms", "engine_host_ms", "idle_pct.decode_call",
+     "idle_pct.admission", "idle_pct.engine_host", "cache_bytes_per_slot",
+     "state_install_ms", "decode_hbm_pct", "decode_ahead_pct",
+     "rows_read_pct", "expert_tokens_mean", "experts_hit_pct",
+     "expert_load_max_over_mean", "moe_gmm_roofline_pct"],
+    {"arch": {"layers": 4, "width": 64, "heads": 8, "kv_heads": 2,
+              "head_dim": 16, "expert_width": 32, "experts": 16,
+              "experts_held": 4, "experts_per_token": 4,
+              "shared_experts": 2, "vocab": 512, "window": 8,
+              "window_layers": 3, "full_layers": 1},
+     "zoo": "mxnet_tpu.gluon.model_zoo.cohere2moe:get_cohere2moe",
+     "zoo_args": ["tiny"], "zoo_kwargs": {"dtype": "float32"},
+     "serve_dtype": "float32"},
+    {"job": "serve_moe",
+     "engine": {"max_slots": 4, "kv_buckets": [64, 128, 256],
+                "prefix_slots": 0, "queue_limit": 1000, "max_tokens": 64},
+     "traffic": {"rate_per_s": 20.0, "ramp_s": 0.5,
+                 "prompt": {"median": 24, "sigma": 0.5, "min": 8, "max": 64},
+                 "output": {"median": 8, "sigma": 0.5, "min": 4, "max": 16},
+                 "at_window_end": "drain", "drain_s": 20.0},
+     # forced: a slot that crosses the window of 8 and one installed from
+     # the reference past the longest prompt the model prefills (256),
+     # which takes the rows past the engine's last bucket
+     "check": {"prompt_lengths": [5, 40],
+               "forced": {"prompts": [3, 260], "copies": 1, "steps": 6,
+                          "min_decisive": 3},
+               "decode_prompt": 4, "new_tokens": 12,
+               "batch_prompts": [8, 60]},
+     "trace_at_s": 0.2, "trace_window_s": 0.5})
+TINIES = {CELL: TINY, MOE_CELL: MOE_TINY}
+
+
+def tiny_root(tmp_path, monkeypatch, cell=CELL):
+    monkeypatch.setitem(rehearsal.TINY, cell, TINIES[cell])
+    return rehearsal.tiny_root(tmp_path, cell)
 
 
 def synthetic_devices(monkeypatch):
@@ -69,7 +107,9 @@ def synthetic_devices(monkeypatch):
         _, (lo, hi) = real(path)
         q = (hi - lo) // 8
         return {"/device:TPU:0": {
-            trace_reduce.OPS_LINE: [("fusion.7", lo + 2 * q, 2 * q)],
+            trace_reduce.OPS_LINE: [
+                ("fusion.7", lo + 2 * q, 2 * q),
+                ("gmm.3[tpu_custom_call]", lo + 5 * q, q)],
             trace_reduce.MODULES_LINE: [("jit__step(1)", lo + q, 4 * q)],
         }}, (lo, hi)
     monkeypatch.setattr(trace_reduce, "read_xplane", fake)
@@ -77,23 +117,36 @@ def synthetic_devices(monkeypatch):
     monkeypatch.setattr(flops, "peaks", lambda kind: v5e)
 
 
-@pytest.mark.parametrize("trace", [0, 1])
-def test_new_job_end_to_end_at_a_tiny_size(tmp_path, monkeypatch, trace):
+def _tiny_margin(monkeypatch, job):
+    """A tiny router's 16 scores lie within 0.04 of each other, so the
+    chip's margin for an unsettled choice would leave no token settled;
+    float32 against float32 needs none to speak of."""
+    if hasattr(job, "MARGIN"):
+        monkeypatch.setattr(job, "MARGIN", 1e-5)
+
+
+def _end_to_end(tmp_path, monkeypatch, cell, trace):
     import jax
-    root, bench = tiny_root(tmp_path, monkeypatch)
+    root, bench = tiny_root(tmp_path, monkeypatch, cell)
     if trace:
         synthetic_devices(monkeypatch)
-    found = run.resolve(root, CELL)
-    line, units = run.measure(found, CELL, 3_000_000_001, 1.5, trace,
+    found = run.resolve(root, cell)
+    _tiny_margin(monkeypatch, found["job"])
+    line, units = run.measure(found, cell, 3_000_000_001, 1.5, trace,
                               jax.devices()[:1], time.perf_counter())
     assert line["correct"] is True and line["failed"] == 0
-    assert units == lastline.cell_metrics(bench, CELL, trace)
+    assert units == lastline.cell_metrics(bench, cell, trace)
     assert set(line["metrics"]) == set(units)
     line["device"].update(platform="tpu", memory_peak_bytes=1)
     lastline.validate(line, units, 1, trace)
+    return {k: m["value"] for k, m in line["metrics"].items()}
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_new_job_end_to_end_at_a_tiny_size(tmp_path, monkeypatch, trace):
+    value = _end_to_end(tmp_path, monkeypatch, CELL, trace)
     if not trace:
         return
-    value = {k: m["value"] for k, m in line["metrics"].items()}
     assert 0 < value["decode_hbm_pct"]
     assert value["state_install_ms"] > 0
     # outputs of 4-16 tokens: some steps are launched ahead, the ones
@@ -106,9 +159,10 @@ def test_new_job_end_to_end_at_a_tiny_size(tmp_path, monkeypatch, trace):
     assert (per_slot - fixed) / (2 * 32 * 4) in (64, 128, 256)
 
 
-def _check_on_a_tiny_model(tmp_path, monkeypatch):
-    found = run.resolve(tiny_root(tmp_path, monkeypatch)[0], CELL)
+def _check_on_a_tiny_model(tmp_path, monkeypatch, cell=CELL):
+    found = run.resolve(tiny_root(tmp_path, monkeypatch, cell)[0], cell)
     job, config = found["job"], found["config"]
+    _tiny_margin(monkeypatch, job)
     return job, job.build_model(config, 7), found["cell"]["check"], \
         config["arch"]["vocab"]
 
@@ -252,3 +306,242 @@ def test_hybrid_step_bytes_by_hand():
     assert hybrid_bytes.live_row_equivalents([[5], [5, 20]], arch, 4) == \
         (hybrid_bytes.slot_bytes([5], arch, 4)
          + hybrid_bytes.slot_bytes([5, 20], arch, 4)) / 2 / row
+
+
+# ---------------------------------------------------------------------------
+# job kind serve_moe (the Command A+ family), the same rehearsals
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_the_moe_job_end_to_end_at_a_tiny_size(tmp_path, monkeypatch,
+                                               trace):
+    value = _end_to_end(tmp_path, monkeypatch, MOE_CELL, trace)
+    if not trace:
+        return
+    # 4 slots, 4 of 16 experts held, 4 choices a token: a quarter of
+    # the choices fall here, spread over 4 layers x 4 experts
+    assert 0 < value["expert_tokens_mean"] <= 4
+    assert 0 < value["experts_hit_pct"] <= 100
+    assert value["expert_load_max_over_mean"] >= 1
+    assert 0 < value["decode_hbm_pct"] and 0 < value["rows_read_pct"] <= 100
+    assert 0 < value["moe_gmm_roofline_pct"]
+    assert 0 < value["decode_ahead_pct"] < 100
+    # three rings of 8 and the rows at some bucket, 32 channels, float32
+    per_slot = value["cache_bytes_per_slot"]
+    assert (per_slot - 3 * 2 * 32 * 8 * 4) / (2 * 32 * 4) in (64, 128, 256)
+
+
+def test_the_float8_control_is_refused_by_the_moe_jobs_verdict(
+        tmp_path, monkeypatch):
+    """``chipbench/precision.py`` works on a ``serve_moe`` cell as it
+    is: the reference on rounded weights goes through the job's
+    ``verdict`` and is refused by its limits; against itself it is
+    correct."""
+    import types
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from chipbench import precision
+    job, model, spec, vocab = _check_on_a_tiny_model(tmp_path, monkeypatch,
+                                                     MOE_CELL)
+    # 4 layers of width 64 gather less of a rounding than 4 of 4096:
+    # the tiny control rounds to float8_e5m2 (as the hybrid family's)
+    low = types.SimpleNamespace(cfg=model.cfg, params=dict(
+        model.params, layers=jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.float8_e5m2) if a.ndim >= 2 else a,
+            model.params["layers"])))
+    n_min = spec["forced"]["min_decisive"]
+    control = precision.control_readings(
+        job, model, low, spec, np.random.default_rng(3), vocab)
+    ok, refused = job.verdict(control, n_min)
+    assert not ok and set(refused) & set(job.LIMITS)
+    same = precision.control_readings(
+        job, model, model, spec, np.random.default_rng(3), vocab)
+    assert job.verdict(same, n_min) == (True, [])
+    assert same["decisive_positions"] == control["decisive_positions"] >= n_min
+
+
+@pytest.mark.parametrize("fault", ["ring_column", "rows_shifted",
+                                   "load_shifted", "too_few_decisive"])
+def test_the_moe_program_check_refuses_a_planted_fault(
+        tmp_path, monkeypatch, fault):
+    """The programs driven directly: a ring left one column off, the
+    rows one position off, the load counted on the wrong experts, and a
+    run with nothing decisive to compare each come out as not correct,
+    by the limit that is for it."""
+    import jax.numpy as jnp
+    import numpy as np
+    job, model, spec, vocab = _check_on_a_tiny_model(tmp_path, monkeypatch,
+                                                     MOE_CELL)
+    cell = {"check": dict(spec)}
+    drive = job.drive_decode_program
+
+    def faulty(model, cache, forced):
+        answers, loads = drive(model, cache, forced)
+        if fault == "ring_column":
+            cache.state["wk"] = [jnp.roll(a, 1, axis=2)
+                                 for a in cache.state["wk"]]
+        elif fault == "rows_shifted":
+            cache._v = [jnp.roll(a, 1, axis=2) for a in cache._v]
+        elif fault == "load_shifted":
+            loads = [np.roll(a, 1, axis=1) for a in loads]
+        return answers, loads
+    if fault == "too_few_decisive":
+        cell["check"]["forced"] = dict(spec["forced"], min_decisive=10 ** 6)
+    else:
+        monkeypatch.setattr(job, "drive_decode_program", faulty)
+    readings = job.check_programs(
+        model, (4, (64, 128, 256), (64, 128, 256)), cell,
+        np.random.default_rng(5), vocab)
+    assert readings["crossed_window_at"] == [3 + 6, 260 + 6]
+    ok, refused = job.verdict(readings, cell["check"]["forced"]["min_decisive"])
+    assert not ok and refused == {
+        "ring_column": ["ring_err"], "rows_shifted": ["rows_err"],
+        "load_shifted": ["route_excess"],
+        "too_few_decisive": ["decisive_positions"]}[fault]
+
+
+def test_a_sound_moe_program_check_moves_no_choice(tmp_path, monkeypatch):
+    """float32 against the float32 reference: every step's load is the
+    reference's, every decisive token its argmax, and the slot installed
+    from the reference crossed the window and the engine's last
+    bucket."""
+    import numpy as np
+    job, model, spec, vocab = _check_on_a_tiny_model(tmp_path, monkeypatch,
+                                                     MOE_CELL)
+    readings = job.check_programs(
+        model, (4, (64, 128, 256), (64, 128, 256)), {"check": spec},
+        np.random.default_rng(6), vocab)
+    assert job.verdict(readings, spec["forced"]["min_decisive"]) == (True, [])
+    assert readings["route_moved"] == readings["route_excess"] == 0
+    assert max(readings["ring_err"] + readings["rows_err"]
+               + readings["prefill_logit_err"]) < 2e-5
+    assert readings["crossed_window_at"] == [9, 266]
+
+
+def test_route_reading_by_hand(monkeypatch):
+    import numpy as np
+    from chipbench.jobs import serve_moe as job
+    monkeypatch.setattr(job, "MARGIN", 0.02)
+    cfg = {"experts_held": (1, 3), "top_k": 2}
+    # two tokens, four experts, two layers; the threshold lies midway
+    # between a row's second and third score
+    first = np.array([[0.9, 0.8, 0.1, 0.2],         # chooses 0, 1
+                      [0.1, 0.5, 0.51, 0.9]])       # chooses 2, 3; 1 near
+    second = np.array([[0.9, 0.1, 0.8, 0.2],        # chooses 0, 2
+                       [0.9, 0.8, 0.1, 0.2]])       # chooses 0, 1
+    chosen, near = job.held_choices(first, cfg)
+    assert chosen.tolist() == [[True, False], [False, True]]
+    assert near.tolist() == [0, 2]
+    tainted = job.tainted_at([first, second], cfg)
+    assert tainted.tolist() == [[False, False], [False, True],
+                                [False, True]]
+    assert job.settled_rows([first, second], cfg).tolist() == [True, False]
+    want = np.array([[1, 1], [1, 1]])
+    # the reference's own load: nothing moved; the room is the first
+    # layer's two unsettled choices and, for the token they taint, top_k
+    # in the second
+    assert job.route_reading(want, [first, second], cfg) == (0, 4, 0)
+    # the near choice went the other way, and its token anywhere after
+    assert job.route_reading(np.array([[2, 0], [0, 2]]),
+                             [first, second], cfg) == (4, 4, 0)
+    # loads no unsettled choice explains
+    assert job.route_reading(np.array([[0, 4], [1, 1]]),
+                             [first, second], cfg) == (4, 4, 2)
+    assert job.route_reading(np.array([[1, 1], [4, 1]]),
+                             [first, second], cfg) == (3, 4, 1)
+    # K and V are compared where the layer's input cannot have moved
+    held = {"kv": [(np.ones((2, 1, 2)),) * 2] * 2,
+            "scores": [first, second]}
+    cfg = dict(cfg, kinds=["window", "full"], window=8)
+    want = job.reference_holding(held, 2, cfg)
+    assert want["ring_ok"].tolist() == [True, True]
+    assert want["rows_ok"].tolist() == [True, False]
+    got = {"ring": [np.ones((2, 2))] * 2,
+           "rows": [np.array([[1.0, 1.0], [9.0, 9.0]])] * 2}
+    assert job.holding_errs(got, want) == {"ring_err": [0.0, 0.0],
+                                           "rows_err": [0.0, 0.0]}
+
+
+def test_the_moe_cell_resolves_from_the_real_benchmark():
+    cell = "command_a_plus.serve_agent"
+    found = run.resolve(ROOT, cell)
+    bench = found["bench"]
+    listed = lastline.cell_metrics(bench, cell, 1)
+    assert set(found["readers"]) == set(listed)
+    for name, reader in found["readers"].items():
+        entry = next(m for m in bench["per_layer"] if m["name"] == name)
+        assert (reader.LAYER, reader.MOVES, reader.UNIT, reader.SOURCE) == (
+            entry["layer"], entry["moves"], entry["unit"], entry["source"])
+    assert found["chips"] == 1 and found["cell"]["engine"] == {
+        "max_slots": 48, "kv_buckets": [1024, 2048, 4096],
+        "prefix_slots": 0, "queue_limit": 100000, "max_tokens": 3000}
+    assert {"decode_hbm_pct", "rows_read_pct", "expert_tokens_mean",
+            "experts_hit_pct", "expert_load_max_over_mean",
+            "moe_gmm_roofline_pct", "cache_bytes_per_slot"} <= set(listed)
+    assert lastline.cell_metrics(bench, cell, 0) == {
+        "setup_s": "s", "serve_tokens_per_s": "tokens/s"}
+    # the configuration: every published number but the three that are
+    # cut, and the share the zoo's spec holds
+    config, entry = found["config"], next(
+        c for c in bench["configs"] if c["name"] == "command_a_plus")
+    assert entry["reduced"] == config["reduced"] == [
+        "num_hidden_layers", "num_experts", "vocab_size"]
+    assert (config["hidden_size"], config["intermediate_size"],
+            config["num_attention_heads"], config["num_key_value_heads"],
+            config["head_dim"], config["num_experts_per_tok"],
+            config["num_shared_experts"], config["sliding_window"]) == (
+        4096, 4096, 128, 8, 128, 8, 4, 4096)
+    assert len(config["layer_types"]) == 32
+    from mxnet_tpu.gluon.model_zoo import cohere2moe as c2
+    net = c2.get_cohere2moe(*config["zoo_args"])
+    arch, cfg = config["arch"], net.config
+    assert (cfg["num_layers"], cfg["experts_held"], cfg["vocab_rows"]) == (
+        config["num_hidden_layers"], (0, config["num_experts"]),
+        config["vocab_size"]) == (arch["layers"], (0, arch["experts_held"]),
+                                  arch["vocab"])
+    assert cfg["kinds"] == ["window" if t == "sliding_attention" else "full"
+                            for t in config["layer_types"][:4]]
+    assert (cfg["num_experts"], cfg["top_k"], cfg["window"]) == (
+        config["published"]["num_experts"], config["num_experts_per_tok"],
+        config["sliding_window"])
+    # the two slots installed from the reference cross the window
+    # inside lengths the reference is compiled for
+    check, job = found["cell"]["check"], found["job"]
+    ends = [n + check["forced"]["steps"] for n in check["forced"]["prompts"]]
+    assert max(ends) > config["sliding_window"] > max(
+        check["forced"]["prompts"]) and max(ends) <= max(job.REF_LENGTHS)
+
+
+def test_moe_step_bytes_by_hand():
+    from chipbench.harness import moe_bytes
+    from mxnet_tpu.gluon.model_zoo import cohere2moe as c2
+    arch = MOE_TINY[1]["arch"]
+    expert = 3 * 64 * 32 * 4
+    assert moe_bytes.expert_bytes(arch, 4) == expert
+    assert moe_bytes.row_bytes(arch, 4) == 2 * 2 * 16 * 4
+    # every weight outside the routed experts: from the zoo's shapes
+    net = c2.get_cohere2moe("tiny")
+    outside = sum(math.prod(p.shape)
+                  for name, p in net.collect_params().items()
+                  if "expert_in" not in name and "expert_out" not in name)
+    assert moe_bytes.fixed_bytes(arch, 4) == 4 * outside
+    assert moe_bytes.step_weight_bytes(2.5, arch, 4) == \
+        4 * outside + 2.5 * expert
+    # a slot at 5 (inside the window of 8) and one at 20 (past it):
+    # three rings capped, the rows to the position
+    assert moe_bytes.slot_rows([5, 20], arch) == 3 * (5 + 8) + 25
+    assert moe_bytes.live_row_equivalents([], arch) is None
+    assert moe_bytes.live_row_equivalents([[5], [5, 20]], arch) == \
+        (3 * 5 + 5 + 3 * 13 + 25) / 2
+    # the published share: 344.5 M outside the experts a layer, 50.3 M an
+    # expert, 4096 B a K and V row
+    real = json.load(open(os.path.join(
+        ROOT, "chipbench", "configs", "command_a_plus.json")))["arch"]
+    assert moe_bytes.expert_bytes(real, 2) == 2 * 50_331_648
+    assert moe_bytes.row_bytes(real, 2) == 4096
+    assert moe_bytes.fixed_bytes(real, 2) == 2 * (
+        4_733_292_544 - 4 * 16 * 50_331_648)
+    flops, nbytes = moe_bytes.gmm_flops_and_bytes(1000, 60, real, 2)
+    assert flops == 6 * 1000 * 4096 * 4096
+    assert nbytes == 60 * 2 * 50_331_648 + 1000 * (8192 * 2 + 12288 * 4)

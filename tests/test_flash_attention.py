@@ -443,3 +443,82 @@ def test_hybrid_step_compiles_for_v5e_and_reads_the_rows_in_place(one_v5e):
                                i32, f32, i32, f32, i32).compile().as_text()
     assert hlo.count("tpu_custom_call") >= 8
     assert chip_smoke.cache_sized_relayouts(hlo, S * kv * L) == []
+
+
+# ---------------------------------------------------------------------------
+# the Command A+ family (serving/moe.py) under the same described v5e: the
+# ragged kernel at its packing, and its whole decode step
+# ---------------------------------------------------------------------------
+
+def test_decode_attention_compiles_for_v5e_at_the_moe_cells_shapes(one_v5e):
+    """48 slots, 8 K/V heads of 128 as the kernel's groups with their 16
+    query heads as rows, a 4096-wide ring or bucket, bfloat16: Mosaic
+    takes the kernel, and the rows reach it as they are."""
+    import chip_smoke
+    from mxnet_tpu.ops.pallas import decode_attention as da
+    S, G, R, C, L = 48, 8, 16, 128, 4096
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(      # noqa: E731
+        shape, dt, sharding=one_v5e)
+
+    def call(q, ck, cv, pos):
+        return da.ragged_attention(q, ck, cv, pos, 1.0 / C ** 0.5)
+
+    hlo = jax.jit(call).lower(
+        arg((S, G, R, C), jnp.bfloat16), arg((S, G * C, L), jnp.bfloat16),
+        arg((S, G * C, L), jnp.bfloat16), arg((S,), jnp.int32)
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert chip_smoke.cache_sized_relayouts(hlo, S * G * C * L) == []
+
+
+def _described_moe_model(one_v5e):
+    from mxnet_tpu.gluon.model_zoo import cohere2moe as c2
+    from mxnet_tpu.serving.moe import MoEDecodeModel
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(      # noqa: E731
+        tuple(shape), dt, sharding=one_v5e)
+    net = c2.get_cohere2moe("command_a_plus_ep8", dtype="bfloat16")
+    cfg = dict(net.config)
+    params = c2._tree({name: arg(p.shape, jnp.dtype(str(p.dtype)))
+                       for name, p in net.collect_params().items()},
+                      cfg["num_layers"])
+    return MoEDecodeModel(params, cfg, net._max_length, "aot"), params, \
+        cfg, arg
+
+
+@pytest.mark.slow
+def test_moe_step_compiles_for_v5e_and_reads_every_cache_in_place(one_v5e):
+    """The whole decode step of one chip's share of Command A+, 48
+    slots on the 4096 bucket (about half a minute): four kernel calls
+    (three rings, one rows cache), no copy or transpose the size of a
+    cache on the way into them, and temporaries far under a cache."""
+    import chip_smoke
+    model, params, cfg, arg = _described_moe_model(one_v5e)
+    S, L, W = 48, 4096, cfg["window"]
+    kv = cfg["num_kv_heads"] * cfg["head_dim"]
+    rows = [arg((S, kv, L), jnp.bfloat16)]
+    ring = [arg((S, kv, W), jnp.bfloat16)] * 3
+    i32, f32 = arg((S,), jnp.int32), arg((S,), jnp.float32)
+    toks = arg((S + model.n_layers * model.held,), jnp.int32)
+    compiled = model._step_fn.lower(
+        params, rows, rows, {"wk": ring, "wv": ring}, toks, i32, i32, i32,
+        f32, i32, f32, i32).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 4
+    assert chip_smoke.cache_sized_relayouts(hlo, S * kv * L) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+
+
+@pytest.mark.slow
+def test_moe_prefill_compiles_for_v5e_with_flash_and_the_grouped_matmul(
+        one_v5e, monkeypatch):
+    """A 1024-token prefill: the flash kernel at head dim 128 over K/V
+    heads repeated to the 128 query heads, and two megablox grouped
+    matmuls a layer over the held experts' segments."""
+    from mxnet_tpu.ops import transformer
+    monkeypatch.setattr(transformer, "_use_pallas_len", lambda T: T >= 512)
+    model, params, cfg, arg = _described_moe_model(one_v5e)
+    compiled = model._prefill_fn.lower(
+        params, arg((1024,), jnp.int32), arg((), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 4 * 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30

@@ -14,6 +14,8 @@ bucket, and answers on a stdlib HTTP server:
         # continuous-batching /v1/generate with per-token streaming
     python tools/serve.py --generate --zoo-phi4flash phi4_mini_flash \
         --max-slots 64 --kv-buckets 1024,2048,4096    # hybrid LM, bfloat16
+    python tools/serve.py --generate --zoo-cohere2moe command_a_plus_ep8 \
+        --max-slots 48 --kv-buckets 1024,2048,4096    # sparse experts, bfloat16
 
     curl -s localhost:8080/v1/inference -d '{"instances": [[...]]}'
     curl -sN localhost:8080/v1/generate \
@@ -25,7 +27,8 @@ Knobs default from the MXNET_SERVING_* env tier, plus MXNET_GEN_* for
 --generate (docs/serving.md).  Static exports serve exactly their
 traced batch size; export with ``dynamic_batch=True`` for the full
 bucket grid.  --generate serves a LIVE decoder LM (zoo GPT in float32,
-or with --zoo-phi4flash the Phi-4-mini-flash hybrid in bfloat16, for
+with --zoo-phi4flash the Phi-4-mini-flash hybrid or with
+--zoo-cohere2moe one chip's share of Command A+ in bfloat16, for both of
 which speculation and the prefix cache are refused; optionally with
 --gpt-params weights) through the resident decode loop.
 
@@ -106,8 +109,9 @@ def main(argv=None) -> None:
                          "that boots in seconds on CPU; weights are "
                          "random unless --gpt-params is given)")
     ap.add_argument("--gpt-params", default=None,
-                    help="a .params file to load into the --zoo-gpt "
-                         "or --zoo-phi4flash model before serving")
+                    help="a .params file to load into the --zoo-gpt, "
+                         "--zoo-phi4flash or --zoo-cohere2moe model "
+                         "before serving")
     ap.add_argument("--zoo-phi4flash", default=None,
                     choices=("phi4_mini_flash", "tiny"),
                     help="serve the Phi-4-mini-flash hybrid family "
@@ -117,6 +121,20 @@ def main(argv=None) -> None:
                          "published 3.85 B model in bfloat16, 'tiny' an "
                          "8-layer float32 one for the CPU.  Speculation "
                          "and the prefix cache are refused for it")
+    ap.add_argument("--zoo-cohere2moe", default=None,
+                    choices=("command_a_plus_ep8", "tiny"),
+                    help="serve the Command A+ (cohere2_moe) family for "
+                         "--generate instead of --zoo-gpt: a parallel "
+                         "block with sigmoid top-8 routing over 128 "
+                         "experts, 4 averaged shared experts, window "
+                         "layers with RoPE and full layers without.  "
+                         "command_a_plus_ep8 is ONE CHIP'S SHARE of the "
+                         "published 218 B model where 8 chips share each "
+                         "layer (16 of the 128 routed experts held, one "
+                         "period of 4 layers, an eighth of the "
+                         "vocabulary; 4.7 B in bfloat16), 'tiny' a "
+                         "float32 one for the CPU.  Speculation and the "
+                         "prefix cache are refused for it")
     ap.add_argument("--max-slots", type=int, default=None,
                     help="decode slots for --generate "
                          "(MXNET_GEN_MAX_SLOTS)")
@@ -252,14 +270,18 @@ def _serve_generate(args, serving) -> None:
     engine (resident decode loop, paged KV cache, token streaming)."""
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo.gpt import GPTModel, get_gpt
+    from mxnet_tpu.gluon.model_zoo.cohere2moe import get_cohere2moe
     from mxnet_tpu.gluon.model_zoo.phi4flash import get_phi4flash
 
     mx.random.seed(0)
-    if args.zoo_phi4flash:
-        net = get_phi4flash(
-            args.zoo_phi4flash,
-            dtype="float32" if args.zoo_phi4flash == "tiny" else "bfloat16")
-        # inference only: a gradient buffer beside each of 3.85 B
+    declared = args.zoo_phi4flash or args.zoo_cohere2moe
+    if declared:
+        # the two families that declare every shape; bfloat16 as
+        # published but for the CPU size
+        get = get_phi4flash if args.zoo_phi4flash else get_cohere2moe
+        net = get(declared,
+                  dtype="float32" if declared == "tiny" else "bfloat16")
+        # inference only: a gradient buffer beside each of billions of
         # bfloat16 weights would not fit one chip
         net.collect_params().setattr("grad_req", "null")
     elif args.zoo_gpt == "tiny":     # CPU tire-kicking: boots fast
@@ -269,7 +291,7 @@ def _serve_generate(args, serving) -> None:
     else:
         net = get_gpt(args.zoo_gpt, dropout=0.0)
     net.initialize()
-    if not args.zoo_phi4flash:       # finishes the deferred shapes
+    if not declared:                 # finishes the deferred shapes
         net(mx.np.zeros((1, 4), dtype="int32"))
     if args.gpt_params:
         net.load_parameters(args.gpt_params)
