@@ -25,9 +25,11 @@ Prefill is one program a prompt bucket, built from the zoo's sequence
 functions; it hands back every kind AT THE PROMPT'S REAL LENGTH: the
 recurrence stops there (``dt`` is zeroed past it), the conv tail and
 the ring's columns are gathered from there.  The decode step is one
-donated program a KV bucket over every slot; it reads the rings in full
-and the ``rows`` kind by extent, each slot's position blocks up to its
-own position (``_rows_attention``).  Matrices and activations
+donated program a KV bucket over every slot; it writes the token's K/V
+column of every slot with one kernel call a layer
+(``ops.pallas.column_write``), reads the rings in full and the ``rows``
+kind by extent, each slot's position blocks up to its own position
+(``_rows_attention``).  Matrices and activations
 are the block's dtype (bfloat16 as published); the recurrence, its
 state, the softmax and the logits are float32.
 
@@ -58,19 +60,6 @@ CACHE_KIND = {"mamba": "state", "window": "window", "full": "rows",
 # can take beside the weights.  Chunked prefill lifts it (ROADMAP).
 MAX_PROMPT = 1024
 MIN_PROMPT_BUCKET = 64
-
-
-def _write_columns(buf, cols, at):
-    """Slot i's column ``cols[i]`` (S, C, 1) into ``buf`` (S, C, L) at
-    position ``at[i]``: one in-place dynamic_update_slice a slot, as
-    ``model._slot_block_step`` does and for its reasons."""
-    from jax import lax
-    for i in range(buf.shape[0]):
-        buf = lax.dynamic_update_slice(
-            buf, lax.slice_in_dim(cols, i, i + 1),
-            (i, 0, lax.index_in_dim(at, i, keepdims=False)),
-            allow_negative_indices=False)
-    return buf
 
 
 def _slot_attention(p, q, ck, cv, pos, depth, cfg):
@@ -148,6 +137,7 @@ class HybridDecodeModel(DecodeModel):
         import jax
         import jax.numpy as jnp
         from ..gluon.model_zoo import phi4flash as _pf
+        from ..ops.pallas import column_write as _cw
         self.params = params
         self.cfg = cfg
         self.kinds = list(cfg["kinds"])
@@ -229,18 +219,19 @@ class HybridDecodeModel(DecodeModel):
                                         cfg)
                 else:
                     q, k, v = _pf._qkv(p, h, cfg)
+                    # the token's K and V column of every slot, one
+                    # in-place kernel call a layer: a ring's at pos % W,
+                    # a row's at pos
                     if kind == "window":
-                        ck = new["wk"][i] = _write_columns(
-                            new["wk"][i], k[:, :, None], ring)
-                        cv = new["wv"][i] = _write_columns(
-                            new["wv"][i], v[:, :, None], ring)
+                        ck, cv = _cw.write_columns(
+                            (new["wk"][i], new["wv"][i]), (k, v), ring)
+                        new["wk"][i], new["wv"][i] = ck, cv
                         y = _slot_attention(p, q, ck, cv, seen_ring,
                                             depth, cfg)
                     else:
-                        ck = ks[i] = _write_columns(ks[i], k[:, :, None],
-                                                    pos)
-                        cv = vs[i] = _write_columns(vs[i], v[:, :, None],
-                                                    pos)
+                        ck, cv = _cw.write_columns((ks[i], vs[i]), (k, v),
+                                                   pos)
+                        ks[i], vs[i] = ck, cv
                         y = _rows_attention(p, q, ck, cv, pos, depth, cfg)
                 x = x + y.astype(x.dtype)
                 x = x + _pf._mlp(p, _pf._ln(x, p["ln2_g"], p["ln2_b"],

@@ -21,13 +21,19 @@ This cache provides that shape discipline:
   With ``(S, heads * d, L)`` the row-major layout of the logical shape
   IS the stored one (channels on sublanes, ``L`` on lanes, nothing
   padded for ``L`` a multiple of 128), and the step writes each
-  slot's column with an in-place ``dynamic_update_slice``, so the
-  donated buffers alias its outputs with no copy.  Heads and ``d``
-  share ONE axis because the column write then compiles to half the
-  code (51 against 90 MB a decode program, resident on the device);
-  the step's view ``(S, heads, d, L)`` of it is free.  Callers keep
-  handing :meth:`PagedKVCache.write_prompt` plain ``(Lp, heads, d)``
-  rows.
+  slot's column in place, so the donated buffers alias its outputs
+  with no copy: the hybrid and the sparse-expert family with ONE
+  kernel call a layer's K and V over all slots
+  (``ops.pallas.column_write``, PR 33: the 128-position tile column
+  that holds the slot's position is read, one lane of it replaced,
+  and written back), the GPT family's step
+  (``model._slot_block_step``) still with one
+  ``dynamic_update_slice`` a slot and buffer, ~3.5 us each whatever
+  it moves.  Heads and ``d`` share ONE axis: a column is then whole
+  sublane tiles of one 2-D block, and the per-slot updates compile to
+  half the code; the step's view ``(S, heads, d, L)`` of it is free.
+  Callers keep handing :meth:`PagedKVCache.write_prompt` plain
+  ``(Lp, heads, d)`` rows.
 * **Capacity buckets** — ``L`` is drawn from a power-of-two-style grid
   (``MXNET_GEN_KV_BUCKETS``).  The decode step compiles once per
   bucket; when any live sequence needs a position ``>= L`` the whole

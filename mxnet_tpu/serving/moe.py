@@ -75,8 +75,8 @@ class MoEDecodeModel(DecodeModel):
         import jax
         import jax.numpy as jnp
         from ..gluon.model_zoo import cohere2moe as _c2
+        from ..ops.pallas import column_write as _cw
         from ..ops.pallas import decode_attention as _da
-        from .hybrid import _write_columns
         self.params = params
         self.cfg = cfg
         self.kinds = list(cfg["kinds"])
@@ -149,17 +149,17 @@ class MoEDecodeModel(DecodeModel):
             for kind, i, p in zip(kinds, nth, params["layers"]):
                 h = _c2._ln(x, p["ln_g"], eps)
                 q, k, v = _c2.qkv(p, h, pos, kind, cfg)
-                kcol = k.reshape(S, nkv * d, 1)
-                vcol = v.reshape(S, nkv * d, 1)
+                cols = (k.reshape(S, nkv * d), v.reshape(S, nkv * d))
+                # the token's K and V column of every slot, one in-place
+                # kernel call a layer: a ring's at pos % W, a row's at pos
                 if kind == "window":
-                    ck = new["wk"][i] = _write_columns(new["wk"][i], kcol,
-                                                       ring)
-                    cv = new["wv"][i] = _write_columns(new["wv"][i], vcol,
-                                                       ring)
+                    ck, cv = _cw.write_columns(
+                        (new["wk"][i], new["wv"][i]), cols, ring)
+                    new["wk"][i], new["wv"][i] = ck, cv
                     seen = seen_ring
                 else:
-                    ck = ks[i] = _write_columns(ks[i], kcol, pos)
-                    cv = vs[i] = _write_columns(vs[i], vcol, pos)
+                    ck, cv = _cw.write_columns((ks[i], vs[i]), cols, pos)
+                    ks[i], vs[i] = ck, cv
                     seen = pos
                 # query head n reads K/V head n // g: the kernel's
                 # groups are the K/V heads, their g queries its rows

@@ -1,0 +1,133 @@
+"""The column-write kernel (ops/pallas/column_write.py) against the
+per-slot ``dynamic_update_slice`` loop it replaced in the hybrid and the
+sparse-expert decode steps: bitwise, over the whole buffer.  Interpret
+mode on the CPU; its v5e compiles are in tests/test_flash_attention.py."""
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+from jax import lax
+
+from mxnet_tpu.ops.pallas import column_write as cw
+
+CHANNELS = 16
+
+
+def loop_of_updates(buf, cols, at):
+    """The plain reference: one in-place update a slot, as the decode
+    steps wrote their columns before the kernel (and as the GPT
+    family's ``model._slot_block_step`` still does)."""
+    for i in range(buf.shape[0]):
+        buf = lax.dynamic_update_slice(
+            buf, lax.slice_in_dim(cols, i, i + 1),
+            (i, 0, lax.index_in_dim(at, i, keepdims=False)),
+            allow_negative_indices=False)
+    return buf
+
+
+def _bits(a):
+    a = onp.asarray(a)
+    return a.view({2: onp.uint16, 4: onp.uint32}[a.dtype.itemsize])
+
+
+def _random_bits(rng, shape, dtype):
+    """Every bit pattern of the dtype, NaNs, infinities and subnormals
+    among them: a write is a move and must not look at the values."""
+    word = {2: onp.uint16, 4: onp.uint32}[jnp.dtype(dtype).itemsize]
+    raw = rng.integers(0, onp.iinfo(word).max, size=shape, dtype=word,
+                       endpoint=True)
+    return lax.bitcast_convert_type(jnp.asarray(raw), dtype)
+
+
+def _positions(which, S, L, rng):
+    if which == "edges":
+        # the first and the last lane of a tile, the buffer's last
+        # column, and past it: a position beyond L - 1 lands on L - 1
+        edges = [0, 127, 128, L - 1, L, L + 7, 1, L // 2]
+        return onp.array([edges[i % len(edges)] for i in range(S)])
+    if which == "ring":
+        # a ring of L columns after it has wrapped: position p lives in
+        # column p % L
+        return (L + rng.integers(0, 3 * L, size=S)) % L
+    if which == "same":
+        return onp.full(S, 129 % L)
+    return rng.integers(0, L, size=S)
+
+
+@pytest.mark.parametrize("which", ["edges", "ring", "same", "random"])
+@pytest.mark.parametrize("S", [3, 48, 64])
+@pytest.mark.parametrize("L", [64, 512, 4096])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_kernel_is_the_loop_of_updates_bit_for_bit(dtype, L, S, which):
+    """K and V through one call; every element of both buffers equal to
+    what one ``dynamic_update_slice`` a slot leaves."""
+    rng = onp.random.default_rng(S * L + len(which))
+    at = jnp.asarray(_positions(which, S, L, rng), jnp.int32)
+    shape = (S, CHANNELS, L)
+    kb, vb = _random_bits(rng, shape, dtype), _random_bits(rng, shape, dtype)
+    kc = _random_bits(rng, (S, CHANNELS, 1), dtype)
+    vc = _random_bits(rng, (S, CHANNELS, 1), dtype)
+    got_k, got_v = jax.jit(cw.write_columns)((kb, vb), (kc, vc), at)
+    want = jax.jit(loop_of_updates)
+    assert got_k.dtype == kb.dtype and got_k.shape == kb.shape
+    onp.testing.assert_array_equal(_bits(got_k), _bits(want(kb, kc, at)))
+    onp.testing.assert_array_equal(_bits(got_v), _bits(want(vb, vc, at)))
+
+
+@pytest.mark.parametrize("S,C,L", [(3, 16, 64), (5, 32, 512), (8, 16, 4096)])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_only_one_column_a_slot_changes(dtype, S, C, L):
+    """A buffer full of a sentinel: after the write exactly ``S``
+    columns differ from it, slot s's at ``at[s]``, and they hold the
+    new values; buffers of different widths share a call."""
+    rng = onp.random.default_rng(L)
+    at = rng.integers(0, L, size=S)
+    sentinel = jnp.full((S, C, L), -7.0, dtype)
+    wide = jnp.full((S, 2 * C, L), -7.0, dtype)
+    cols = jnp.asarray(rng.standard_normal((S, C, 1)) + 10.0, dtype)
+    wide_cols = jnp.asarray(rng.standard_normal((S, 2 * C, 1)) + 10.0,
+                            dtype)
+    out, wide_out = cw.write_columns((sentinel, wide), (cols, wide_cols),
+                                     jnp.asarray(at, jnp.int32))
+    for got, new in ((out, cols), (wide_out, wide_cols)):
+        changed = onp.asarray(got != -7.0)
+        assert changed.sum() == S * new.shape[1]
+        for s in range(S):
+            assert changed[s, :, at[s]].all()
+            onp.testing.assert_array_equal(
+                onp.asarray(got[s, :, at[s]], onp.float32),
+                onp.asarray(new[s, :, 0], onp.float32))
+
+
+def test_one_buffer_and_columns_without_the_unit_axis():
+    """One buffer alone is a call too, and ``(S, C)`` columns are taken
+    as ``(S, C, 1)`` are."""
+    rng = onp.random.default_rng(0)
+    buf = _random_bits(rng, (4, 16, 256), jnp.bfloat16)
+    cols = _random_bits(rng, (4, 16), jnp.bfloat16)
+    at = jnp.asarray([0, 255, 128, 127], jnp.int32)
+    (got,) = cw.write_columns((buf,), (cols,), at)
+    onp.testing.assert_array_equal(
+        _bits(got), _bits(loop_of_updates(buf, cols[:, :, None], at)))
+
+
+@pytest.mark.parametrize("bufs,cols", [
+    (((4, 16, 256), (4, 16, 512)), ((4, 16, 1), (4, 16, 1))),   # two lengths
+    (((4, 16, 256),), ((4, 8, 1),)),                            # channels
+    (((4, 16, 256),), ((3, 16, 1),)),                           # slots
+    (((4, 16, 200),), ((4, 16, 1),)),                           # part block
+], ids=["lengths", "channels", "slots", "part-block"])
+def test_operands_that_do_not_fit_are_refused_by_name(bufs, cols):
+    at = jnp.zeros((4,), jnp.int32)
+    with pytest.raises(ValueError, match="does not fit|whole blocks"):
+        cw.write_columns([jnp.zeros(s, jnp.float32) for s in bufs],
+                         [jnp.zeros(s, jnp.float32) for s in cols], at)
+
+
+def test_a_dtype_that_does_not_pack_is_refused():
+    with pytest.raises(ValueError, match="do not pack"):
+        cw.write_columns((jnp.zeros((2, 3, 128), jnp.bfloat16),),
+                         (jnp.zeros((2, 3, 1), jnp.bfloat16),),
+                         jnp.zeros((2,), jnp.int32))

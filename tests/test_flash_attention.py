@@ -1,5 +1,7 @@
 """Pallas flash-attention kernels vs the dense reference (interpret mode
 on CPU — the kernels themselves, not just the dispatch heuristics)."""
+import re
+
 import numpy as onp
 import jax
 import jax.numpy as jnp
@@ -409,12 +411,63 @@ def test_decode_attention_compiles_for_v5e_at_the_serving_cells_shapes(
     assert chip_smoke.cache_sized_relayouts(hlo, S * kv * L) == []
 
 
+def _kernel_calls(hlo):
+    """The names of an optimized HLO module's Mosaic calls, their
+    numbering cut off: ``["write_columns", ..., "_step", ...]``."""
+    return [re.sub(r"\.\d+$", "", m.group(1)) for m in re.finditer(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)]
+
+
+def _column_updates(hlo, S, C):
+    """The ``dynamic-update-slice`` instructions of an optimized HLO
+    module (fused computations included) whose result is an
+    ``(S, C, L)`` buffer: what the per-slot write of a ``(1, C, 1)``
+    column compiled to (1152 of them in the hybrid step before the
+    column-write kernel)."""
+    result = re.compile(rf"= \w+\[{S},{C},\d+\][^ ]* dynamic-update-slice\(")
+    return [line.strip()[:160] for line in hlo.splitlines()
+            if result.search(line)]
+
+
+@pytest.mark.parametrize("S,C,L", [(64, 1280, 512), (64, 1280, 4096),
+                                   (48, 1024, 4096)])
+def test_column_write_compiles_for_v5e_in_place_at_the_cells_shapes(
+        one_v5e, S, C, L):
+    """A layer's K and V through one call, bfloat16, the hybrid cell's
+    ring and rows and the sparse-expert cell's: Mosaic takes the kernel
+    (the word view of a tile, the 32-bit transpose of a slot's row),
+    the donated buffers ARE the results (aliased whole, nothing held
+    beside them) and nothing the size of one is copied or relaid."""
+    import chip_smoke
+    from mxnet_tpu.ops.pallas import column_write as cw
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(      # noqa: E731
+        shape, dt, sharding=one_v5e)
+
+    def call(kb, vb, kc, vc, at):
+        return cw.write_columns((kb, vb), (kc[:, :, None], vc[:, :, None]),
+                                at)
+
+    compiled = jax.jit(call, donate_argnums=(0, 1)).lower(
+        arg((S, C, L), jnp.bfloat16), arg((S, C, L), jnp.bfloat16),
+        arg((S, C), jnp.bfloat16), arg((S, C), jnp.bfloat16),
+        arg((S,), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert _kernel_calls(hlo) == ["write_columns"]
+    assert chip_smoke.cache_sized_relayouts(hlo, S * C * L) == []
+    assert _column_updates(hlo, S, C) == []
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 2 * S * C * L * 2
+    assert memory.temp_size_in_bytes < 2 ** 20
+
+
 @pytest.mark.slow
 def test_hybrid_step_compiles_for_v5e_and_reads_the_rows_in_place(one_v5e):
     """The whole decode step of the published Phi-4-mini-flash, 64
-    slots on the 4096 bucket (about a minute): eight kernel calls, and
-    no copy or transpose the size of layer 17's K or V rows on the way
-    into them (``chip_smoke.cache_sized_relayouts``, PR 27's check)."""
+    slots on the 4096 bucket (about a minute): eight ragged reads and
+    nine column writes (a K/V pair a call: 8 rings, layer 17's rows),
+    no per-slot column update left, and no copy or transpose the size
+    of a ring or of layer 17's K or V rows on the way into or out of
+    them (``chip_smoke.cache_sized_relayouts``, PR 27's check)."""
     import chip_smoke
     from mxnet_tpu.gluon.model_zoo import phi4flash as pf
     from mxnet_tpu.serving.hybrid import CACHE_KIND, HybridDecodeModel
@@ -441,8 +494,12 @@ def test_hybrid_step_compiles_for_v5e_and_reads_the_rows_in_place(one_v5e):
     i32, f32 = arg((S,), jnp.int32), arg((S,), jnp.float32)
     hlo = model._step_fn.lower(params, rows, rows, state, i32, i32, i32,
                                i32, f32, i32, f32, i32).compile().as_text()
-    assert hlo.count("tpu_custom_call") >= 8
+    calls = _kernel_calls(hlo)
+    assert calls.count("write_columns") == 9 and len(calls) == 8 + 9
+    assert _column_updates(hlo, S, kv) == []
     assert chip_smoke.cache_sized_relayouts(hlo, S * kv * L) == []
+    assert chip_smoke.cache_sized_relayouts(
+        hlo, S * kv * cfg["window"]) == []
 
 
 # ---------------------------------------------------------------------------
@@ -488,9 +545,11 @@ def _described_moe_model(one_v5e):
 @pytest.mark.slow
 def test_moe_step_compiles_for_v5e_and_reads_every_cache_in_place(one_v5e):
     """The whole decode step of one chip's share of Command A+, 48
-    slots on the 4096 bucket (about half a minute): four kernel calls
-    (three rings, one rows cache), no copy or transpose the size of a
-    cache on the way into them, and temporaries far under a cache."""
+    slots on the 4096 bucket (about half a minute): four ragged reads
+    and four column writes (three rings, one rows cache, a K/V pair a
+    call), no per-slot column update left, no copy or transpose the
+    size of a cache on the way into or out of them, and temporaries far
+    under a cache."""
     import chip_smoke
     model, params, cfg, arg = _described_moe_model(one_v5e)
     S, L, W = 48, 4096, cfg["window"]
@@ -503,7 +562,9 @@ def test_moe_step_compiles_for_v5e_and_reads_every_cache_in_place(one_v5e):
         params, rows, rows, {"wk": ring, "wv": ring}, toks, i32, i32, i32,
         f32, i32, f32, i32).compile()
     hlo = compiled.as_text()
-    assert hlo.count("tpu_custom_call") >= 4
+    calls = _kernel_calls(hlo)
+    assert calls.count("write_columns") == 4 and len(calls) == 4 + 4
+    assert _column_updates(hlo, S, kv) == []
     assert chip_smoke.cache_sized_relayouts(hlo, S * kv * L) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
 
