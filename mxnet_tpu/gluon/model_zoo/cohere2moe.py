@@ -227,18 +227,30 @@ def _mm(x, w):
                       preferred_element_type=jnp.float32)
 
 
-def rope(x, pos, theta: float):
-    """Interleaved RoPE: channels ``(2 j, 2 j + 1)`` of every head of
-    ``x (T, heads, d)`` turn by ``pos[t] theta^(-2 j / d)``, in float32.
+def rope(x, pos, theta: float, pairing: str = "interleaved"):
+    """RoPE over every head of ``x (T, heads, d)``, in float32: the
+    channels of pair ``j`` turn by ``pos[t] theta^(-2 j / d)``.
+    ``pairing`` says which two channels are pair ``j``: ``interleaved``
+    ``(2 j, 2 j + 1)`` (this family; ``rope_gptj``), ``half``
+    ``(j, j + d / 2)`` (the NeoX convention; ``model_zoo.ouro``).
     The pair's partner is fetched by a shift along the head's channels
     (a (.., d / 2, 2) view would put 2 on the lanes)."""
     d = x.shape[-1]
     freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = jnp.repeat(_f32(pos)[:, None] * freq, 2, axis=-1)[:, None, :]
+    angle = _f32(pos)[:, None] * freq
     h = _f32(x)
-    even = (jnp.arange(d) % 2 == 0)
-    partner = jnp.where(even, -jnp.roll(h, -1, axis=-1),
-                        jnp.roll(h, 1, axis=-1))
+    if pairing == "interleaved":
+        angle = jnp.repeat(angle, 2, axis=-1)
+        first = jnp.arange(d) % 2 == 0
+        partner = jnp.where(first, -jnp.roll(h, -1, axis=-1),
+                            jnp.roll(h, 1, axis=-1))
+    elif pairing == "half":
+        angle = jnp.concatenate([angle, angle], axis=-1)
+        swapped = jnp.roll(h, d // 2, axis=-1)
+        partner = jnp.where(jnp.arange(d) < d // 2, -swapped, swapped)
+    else:
+        raise ValueError(f"unknown RoPE pairing {pairing!r}")
+    angle = angle[:, None, :]
     return (h * jnp.cos(angle) + partner * jnp.sin(angle)).astype(x.dtype)
 
 

@@ -776,6 +776,12 @@ GEN_EXPERTS_HIT_TOTAL = counter(
 GEN_EXPERT_SLOTS_TOTAL = counter(
     "mxnet_gen_expert_slots_total",
     "Held experts x layers a step: the base of the two ratios above.")
+GEN_LOOP_STEPS_TOTAL = counter(
+    "mxnet_gen_loop_steps_total",
+    "Loop steps the decode steps launched made (a looped family, "
+    "serving.loop: its whole stack of layers once is one loop step; "
+    "over mxnet_gen_iterations_total it is the loop steps a token "
+    "costs).")
 GEN_DISCARDED_TOKENS_TOTAL = counter(
     "mxnet_gen_discarded_tokens_total",
     "Decode-step tokens computed for a slot whose stream had already "

@@ -783,14 +783,20 @@ def layer_norm(data, gamma, beta, axis: int = -1, eps: float = 1e-5):
                   (_as_nd(data), _as_nd(gamma), _as_nd(beta)))
 
 
+def rms_norm_impl(x, g, axis: int = -1, eps: float = 1e-6):
+    """:func:`rms_norm` over raw arrays: what a model family's pure
+    functions (``gluon.model_zoo.ouro``) call inside their programs."""
+    ms = jnp.mean(jnp.square(x), axis=axis, keepdims=True)
+    shape = [1] * x.ndim
+    shape[axis] = x.shape[axis]
+    return x * lax.rsqrt(ms + eps) * g.reshape(shape)
+
+
 def rms_norm(data, gamma, axis: int = -1, eps: float = 1e-6):
     """RMSNorm (beyond-reference; standard in modern LLM blocks)."""
     ax, ep = axis, eps
     def impl(x, g):
-        ms = jnp.mean(jnp.square(x), axis=ax, keepdims=True)
-        shape = [1] * x.ndim
-        shape[ax] = x.shape[ax]
-        return x * lax.rsqrt(ms + ep) * g.reshape(shape)
+        return rms_norm_impl(x, g, ax, ep)
     return invoke("rms_norm", impl, (_as_nd(data), _as_nd(gamma)))
 
 

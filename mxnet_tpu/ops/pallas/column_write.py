@@ -49,8 +49,10 @@ LANES = 128
 _WORD = jnp.uint32
 
 
-def _kernel(at_ref, *refs, lanes):
-    n = len(refs) // 3
+def _kernel(at_ref, *refs, lanes, n):
+    # behind ``at``: the entry where the buffers are stacked, then the
+    # n columns, the n buffers and the n results
+    refs = refs[-3 * n:]
     cols, bufs, outs = refs[:n], refs[n:2 * n], refs[2 * n:]
     lane = at_ref[pl.program_id(0)] % lanes
     for col_ref, buf_ref, out_ref in zip(cols, bufs, outs):
@@ -76,7 +78,7 @@ def _words(cols):
 
 
 @jax.jit
-def write_columns(bufs, cols, at):
+def write_columns(bufs, cols, at, entry=None):
     """Each ``buf (S, C, L)`` of ``bufs`` with column ``at[s]`` of slot
     ``s`` replaced by row ``s`` of its ``cols`` (``(S, C)``, or the
     ``(S, C, 1)`` a per-slot update takes) and every other element what
@@ -85,15 +87,27 @@ def write_columns(bufs, cols, at):
     must agree in ``S`` and ``L``.  Returns the tuple of written
     buffers, each aliased to its operand.  (Jitted so that a step's
     layers of one shape are traced once; the caller's program inlines
-    it.)"""
+    it.)
+
+    With ``entry`` (an int32 scalar, traced) the buffers are STACKED,
+    ``(E, S, C, L)``, and the columns go into entry ``entry`` of each:
+    it is scalar-prefetched beside ``at`` and picks the leading block
+    index, so a caller inside a loop carries the whole stack through,
+    aliased, and every other entry stays what it was."""
     bufs, cols = tuple(bufs), tuple(cols)
-    S, _, L = bufs[0].shape
+    lead = () if entry is None else (None,)
+    if bufs[0].ndim != 3 + len(lead):
+        raise ValueError(
+            f"buffer {bufs[0].shape}: (S, C, L), or stacked "
+            "(E, S, C, L) with the entry to write")
+    S, _, L = bufs[0].shape[-3:]
     lanes = min(LANES, L)
     if L % lanes:
         raise ValueError(f"rows of {L} positions are not whole blocks "
                          f"of {lanes}")
     for buf, col in zip(bufs, cols):
-        if buf.shape[::2] != (S, L) or col.shape[:2] != buf.shape[:2] \
+        if buf.shape[-3::2] != (S, L) or buf.shape[:-3] != \
+                bufs[0].shape[:-3] or col.shape[:2] != buf.shape[-3:-1] \
                 or col.dtype != buf.dtype:
             raise ValueError(f"column {col.shape} {col.dtype} does not "
                              f"fit buffer {buf.shape} {buf.dtype}")
@@ -101,27 +115,30 @@ def write_columns(bufs, cols, at):
     words = [_words(col) for col in cols]
     n = len(bufs)
 
-    def tile_at(s, at_ref):
-        return (s, 0, at_ref[s] // lanes)
+    prefetch = (at,) if entry is None else (
+        at, jnp.asarray(entry, jnp.int32).reshape(1))
 
-    tiles = [pl.BlockSpec((1, buf.shape[1], lanes), tile_at)
+    def tile_at(s, at_ref, *entry_ref):
+        return tuple(e[0] for e in entry_ref) + (s, 0, at_ref[s] // lanes)
+
+    tiles = [pl.BlockSpec(lead + (1, buf.shape[-2], lanes), tile_at)
              for buf in bufs]
     out = pl.pallas_call(
-        functools.partial(_kernel, lanes=lanes),
+        functools.partial(_kernel, lanes=lanes, n=n),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=(S,),
             in_specs=[pl.BlockSpec((1, 1, w.shape[2]),
-                                   lambda s, at_ref: (s, 0, 0))
+                                   lambda s, *p: (s, 0, 0))
                       for w in words] + tiles,
             out_specs=tiles),
         out_shape=[jax.ShapeDtypeStruct(buf.shape, buf.dtype)
                    for buf in bufs],
-        # operand 0 is ``at``, then the n columns, then the n buffers
-        input_output_aliases={1 + n + i: i for i in range(n)},
+        # the prefetched scalars, then the n columns, then the n buffers
+        input_output_aliases={len(prefetch) + n + i: i for i in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=_attention._interpret(),
         name="write_columns",
-    )(at, *words, *bufs)
+    )(*prefetch, *words, *bufs)
     return tuple(out)

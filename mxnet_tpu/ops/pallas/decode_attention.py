@@ -63,8 +63,11 @@ def blocks_read(pos, length: int):
             int(pos.shape[0]) * (int(length) // block))
 
 
-def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
-            block, scale):
+def _kernel(*refs, block, scale):
+    # refs: pos, the entry where the rows are stacked, then q, k, v, o
+    # and the scratch
+    pos_ref = refs[0]
+    q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc = refs[-7:]
     s, i = pl.program_id(0), pl.program_id(1)
     pos = pos_ref[s]
     final = pl.num_programs(1) - 1
@@ -114,13 +117,24 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         o_ref[0] = acc_sc[...] / l_sc[...]
 
 
-def ragged_attention(q, k_rows, v_rows, pos, scale: float):
+def ragged_attention(q, k_rows, v_rows, pos, scale: float, entry=None):
     """``softmax(scale q K[:, :pos + 1]) V[:, :pos + 1]`` a slot and a
     group: ``q (S, G, R, C)``, ``k_rows`` / ``v_rows (S, G C, L)``,
     ``pos (S,)`` int32 (a free slot rides at 0).  Returns
-    ``(S, G, R, C)`` float32."""
+    ``(S, G, R, C)`` float32.
+
+    With ``entry`` (an int32 scalar, traced) the rows are STACKED,
+    ``(E, S, G C, L)``, and entry ``entry`` of them is read: it is
+    scalar-prefetched beside ``pos`` and picks the leading block index,
+    so a caller inside a loop hands the whole stack through and no
+    entry of it is sliced out (a copy of ``S G C L`` elements a call)."""
     S, G, R, C = q.shape
-    L = k_rows.shape[2]
+    L = k_rows.shape[-1]
+    lead = () if entry is None else (None,)
+    if k_rows.ndim != 3 + len(lead):
+        raise ValueError(
+            f"rows {k_rows.shape}: (S, channels, L), or stacked "
+            "(E, S, channels, L) with the entry to read")
     block = row_block(L)
     if L % block:
         raise ValueError(f"rows of {L} positions are not whole blocks "
@@ -131,21 +145,25 @@ def ragged_attention(q, k_rows, v_rows, pos, scale: float):
     Rp = R + pad
     steps = L // block
 
-    def rows_at(s, i, pos_ref):
-        return (s, 0, jnp.maximum(i - (steps - 1 - pos_ref[s] // block), 0))
+    def rows_at(s, i, pos_ref, *entry_ref):
+        return tuple(e[0] for e in entry_ref) + (
+            s, 0, jnp.maximum(i - (steps - 1 - pos_ref[s] // block), 0))
+
+    prefetch = (pos.astype(jnp.int32),) if entry is None else (
+        pos.astype(jnp.int32), jnp.asarray(entry, jnp.int32).reshape(1))
 
     out = pl.pallas_call(
         functools.partial(_kernel, block=block, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=(S, steps),
             in_specs=[
-                pl.BlockSpec((1, G, Rp, C), lambda s, i, p: (s, 0, 0, 0)),
-                pl.BlockSpec((1, G * C, block), rows_at),
-                pl.BlockSpec((1, G * C, block), rows_at),
+                pl.BlockSpec((1, G, Rp, C), lambda s, i, *p: (s, 0, 0, 0)),
+                pl.BlockSpec(lead + (1, G * C, block), rows_at),
+                pl.BlockSpec(lead + (1, G * C, block), rows_at),
             ],
             out_specs=pl.BlockSpec((1, G, Rp, C),
-                                   lambda s, i, p: (s, 0, 0, 0)),
+                                   lambda s, i, *p: (s, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((G, Rp, 1), jnp.float32),
                 pltpu.VMEM((G, Rp, 1), jnp.float32),
@@ -155,7 +173,8 @@ def ragged_attention(q, k_rows, v_rows, pos, scale: float):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_attention._interpret(),
-    )(pos.astype(jnp.int32), q, k_rows, v_rows)
+        name="ragged_attention",
+    )(*prefetch, q, k_rows, v_rows)
     return out[:, :, :R]
 
 

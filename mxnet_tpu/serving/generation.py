@@ -530,25 +530,14 @@ class GenerationEngine:
             else getenv("MXNET_GEN_SPEC_MODE", "off"))
         if not model.supports_rollback:
             # speculation rewinds a slot and the prefix cache shares
-            # its rows; a family whose slots also hold recurrent state
-            # or window rings would need snapshots of them for either.
-            # The prefix cache's default is the environment's, so only
-            # an explicit request is refused
+            # its rows; the family says why it can do neither.  The
+            # prefix cache's default is the environment's, so only an
+            # explicit request is refused
             if self.spec_mode != "off" or draft_model is not None:
-                raise MXNetError(
-                    f"spec_mode={self.spec_mode!r} is not available "
-                    f"for the {model.family} family: rejected drafts "
-                    "would have to rewind what its slots hold beside "
-                    "rows (recurrent state, window rings), of which no "
-                    "snapshot is taken yet")
+                raise model.no_rollback(f"spec_mode={self.spec_mode!r}")
             if prefix_slots or (prefix_cache is not None
                                 and prefix_cache.slots):
-                raise MXNetError(
-                    f"prefix_slots > 0 is not available for the "
-                    f"{model.family} family: a shared prefix would "
-                    "have to carry what a slot holds beside rows "
-                    "(recurrent state, window rings) at its end, of "
-                    "which no snapshot is taken yet")
+                raise model.no_rollback("prefix_slots > 0")
             prefix_slots, prefix_cache = 0, None
         self.cache = model.make_cache(
             self.max_slots, self.grid, prefix_slots=prefix_slots,

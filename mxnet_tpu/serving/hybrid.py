@@ -46,7 +46,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as _np
 
-from ..base import MXNetError
 from .kv_cache import PagedKVCache
 from .model import DecodeModel, _sample_tokens, _select_one
 
@@ -130,6 +129,10 @@ class HybridDecodeModel(DecodeModel):
     max_prompt = MAX_PROMPT
     min_prompt_bucket = MIN_PROMPT_BUCKET
     supports_rollback = False
+    no_rollback_why = (
+        "it rewinds or shares a slot's rows, and this family's slots "
+        "also hold recurrent state and window rings, of which no "
+        "snapshot is taken yet")
 
     def __init__(self, params: Any, cfg: Dict[str, Any], max_length: int,
                  name: str) -> None:
@@ -281,18 +284,11 @@ class HybridDecodeModel(DecodeModel):
 
     # -- execution: DecodeModel's prefill and step, which hand a family's
     # extra results through (the fixed-size kinds ride fourth) ----------
-    def _no_snapshots(self, what: str) -> MXNetError:
-        return MXNetError(
-            f"{what} is not available for the {self.family} family: it "
-            "rewinds or shares a slot's rows, and this family's slots "
-            "also hold recurrent state, of which no snapshot is taken "
-            "yet")
-
     def verify(self, *args: Any, **kwargs: Any) -> _np.ndarray:
-        raise self._no_snapshots("speculative verification")
+        raise self.no_rollback("speculative verification")
 
     def prefill_suffix(self, *args: Any, **kwargs: Any) -> Any:
-        raise self._no_snapshots("suffix prefill over a shared prefix")
+        raise self.no_rollback("suffix prefill over a shared prefix")
 
     def describe(self) -> Dict[str, Any]:
         out = super().describe()

@@ -133,6 +133,12 @@ class PagedKVCache:
     ``window`` rows and the ``state`` layers' arrays (``state_shapes``:
     {name: shape behind the slot axis}, one of each a layer) are the
     lists of :attr:`state`.
+
+    ``stacked`` puts the ``rows`` layers on a leading axis of ONE K and
+    ONE V buffer ``(layers, max_slots, heads * head_dim, L)``: what a
+    family whose programs loop over its layers indexes from inside the
+    loop (``serving.loop``: an entry a (loop step, layer)).  Admission
+    then takes one ``(layers, Lp, heads, d)`` array a side.
     """
 
     def __init__(self, n_layers: int, n_heads: int, head_dim: int,
@@ -143,8 +149,8 @@ class PagedKVCache:
                  prefix: Optional["PrefixCache"] = None,
                  kinds: Optional[Sequence[str]] = None,
                  window: int = 0,
-                 state_shapes: Optional[Dict[str, Tuple[int, ...]]] = None
-                 ) -> None:
+                 state_shapes: Optional[Dict[str, Tuple[int, ...]]] = None,
+                 stacked: bool = False) -> None:
         import jax
         import jax.numpy as jnp
         self.grid = kv_bucket_grid(buckets)
@@ -159,6 +165,9 @@ class PagedKVCache:
         self.n_rows = self.kinds.count("rows")
         self.n_window = self.kinds.count("window")
         self.n_state = self.kinds.count("state")
+        # the rows' leading axes: none (a buffer a ``rows`` layer), or
+        # the layers themselves (``stacked``: ONE K and one V buffer)
+        self._lead = (self.n_rows,) if stacked else ()
         self.window = int(window)
         self.state_shapes = {name: tuple(int(d) for d in shape)
                              for name, shape in (state_shapes or {}).items()}
@@ -211,7 +220,20 @@ class PagedKVCache:
     # -- buffers ------------------------------------------------------------
     def _alloc_buffers(self, L: int) -> None:
         import jax
-        shape = (self.max_slots, self.n_heads * self.head_dim, L)
+        shape = self._lead + (self.max_slots,
+                              self.n_heads * self.head_dim, L)
+        n = 1 if self._lead else self.n_rows
+        held = self._k + self._v
+        if len(held) == 2 * n and all(
+                b.shape == shape and not b.is_deleted() for b in held):
+            # already at this bucket and alive (warm-up walks a grid of
+            # one bucket several times): kept as they are.  What a slot
+            # held before stays invisible behind its position, as after
+            # a retirement; stacked rows are 8 GB to zero and upload
+            return
+        # what is held goes before what replaces it is made: stacked
+        # rows are most of a chip
+        self._k = self._v = []
         # device_put COMMITS the buffers: a jitted call keys its cache
         # on input committed-ness, so fresh uncommitted zeros would
         # make the first post-reset admission recompile the row write
@@ -220,10 +242,8 @@ class PagedKVCache:
         # transfer keeps restart warmup (which walks every bucket
         # shape) at zero XLA compiles
         zeros = _np.zeros(shape, self.dtype)
-        self._k = [jax.device_put(zeros, self.device)
-                   for _ in range(self.n_rows)]
-        self._v = [jax.device_put(zeros, self.device)
-                   for _ in range(self.n_rows)]
+        self._k = [jax.device_put(zeros, self.device) for _ in range(n)]
+        self._v = [jax.device_put(zeros, self.device) for _ in range(n)]
         _metrics.GEN_CACHE_BYTES.labels(kind="rows").set(
             self.bytes_by_kind()["rows"])
 
@@ -278,10 +298,10 @@ class PagedKVCache:
             _metrics.GEN_CACHE_LIVE_BYTES.labels(kind=kind).set(n)
 
     def k(self, layer: int) -> Any:
-        return self._k[layer]
+        return self._k[0][layer] if self._lead else self._k[layer]
 
     def v(self, layer: int) -> Any:
-        return self._v[layer]
+        return self._v[0][layer] if self._lead else self._v[layer]
 
     def layers(self) -> List[Tuple[Any, Any]]:
         return list(zip(self._k, self._v))
@@ -327,7 +347,8 @@ class PagedKVCache:
                      state: Optional[Dict[str, List[Any]]] = None
                      ) -> None:
         """Install prefilled rows into ``slot``: ``ks[l]``/``vs[l]``
-        are ``(Lp, heads, d)`` (padded to a length bucket; the pad rows
+        are ``(Lp, heads, d)`` (one array ``(layers, Lp, heads, d)`` each
+        where the rows are stacked; padded to a length bucket; the pad rows
         carry garbage KV that stays masked until the decode loop
         overwrites them position by position).  ``start`` places the
         rows at positions ``start..start+Lp`` — the shared-prefix
@@ -347,7 +368,7 @@ class PagedKVCache:
                 f"a slot of this cache holds rows and "
                 f"{sorted(self.state) or 'nothing else'}; an admission "
                 "has to install exactly that")
-        Lp = int(ks[0].shape[0])
+        Lp = int(ks[0].shape[-3])
         with _tracing.child_span("kv.write_prompt", rows=Lp,
                                  bucket=self.bucket):
             if int(start) + Lp > self.bucket:
@@ -355,13 +376,9 @@ class PagedKVCache:
             # ONE dispatch writes every layer's K and V row: per-call
             # dispatch overhead is what dominates a row copy on small
             # hosts, so 2L separate writes would bury the prefix
-            # cache's TTFT win under launch latency (see
-            # _make_write_rows for why the write is not donated)
-            out = _write_rows_jit(self._k + self._v,
-                                  list(ks) + list(vs),
-                                  _np.int32(slot), _np.int32(start))
-            self._k = out[:self.n_rows]
-            self._v = out[self.n_rows:]
+            # cache's TTFT win under launch latency.  The buffers are
+            # donated and come back written in place
+            self._write_rows(list(ks) + list(vs), slot, start)
         if state is not None:
             with _tracing.child_span("cache.install_state", slot=int(slot),
                                      rows=min(int(t0), self.window)):
@@ -369,6 +386,11 @@ class PagedKVCache:
                                                 _np.int32(slot))
             _metrics.GEN_STATE_INSTALLS_TOTAL.inc()
         self.positions[slot] = int(t0)
+
+    def _write_rows(self, rows: List[Any], slot: int, start: int) -> None:
+        out = _write_rows_jit(self._k + self._v, rows, _np.int32(slot),
+                              _np.int32(start))
+        self._k, self._v = out[:len(out) // 2], out[len(out) // 2:]
 
     # -- rollback -----------------------------------------------------------
     def truncate(self, slot: int, position: int) -> int:
@@ -444,21 +466,12 @@ class PagedKVCache:
                 if Lp > L:
                     continue
                 rows = [jax.device_put(
-                    _np.zeros((int(Lp), self.n_heads, self.head_dim),
-                              self.dtype), dev)
-                    for _ in range(2 * self.n_rows)]
+                    _np.zeros(self._lead + (int(Lp), self.n_heads,
+                                            self.head_dim), self.dtype),
+                    dev) for _ in range(2 * len(self._k))]
                 # one fused write covers every layer's K and V; zeros
                 # into zeros is a no-op in content
-                out = _write_rows_jit(self._k + self._v, rows,
-                                      _np.int32(0), _np.int32(0))
-                self._k = out[:self.n_rows]
-                self._v = out[self.n_rows:]
-                # one write at a time: each holds a second copy of the
-                # buffers it writes, and with every program loaded from
-                # the compile cache the next is dispatched before the
-                # last has run, so the copies pile up (five of 1.3 GB
-                # at 64 x 4096, PERF.md PR 28)
-                out[0].block_until_ready()
+                self._write_rows(rows, 0, 0)
                 n += 1
             for L2 in self.grid[i + 1:]:
                 # live migrations may leap buckets (a long-prompt
@@ -520,7 +533,8 @@ class PagedKVCache:
             "head_dim": self.head_dim,
             "dtype": str(self.dtype),
             # axis order of every resident buffer (module docstring)
-            "layout": "(max_slots, heads*head_dim, bucket)",
+            "layout": ("(layers, " if self._lead else "(")
+            + "max_slots, heads*head_dim, bucket)",
             # where the buffers actually live, not where they were asked
             "platforms": sorted({
                 d.platform
@@ -540,7 +554,8 @@ def _grow_rows(buf: Any, new_len: int) -> Any:
         import jax.numpy as jnp
 
         def grow(b, _L=int(new_len)):
-            return jnp.pad(b, ((0, 0), (0, 0), (0, _L - b.shape[2])))
+            return jnp.pad(b, ((0, 0),) * (b.ndim - 1)
+                           + ((0, _L - b.shape[-1]),))
 
         fn = _grow_jits[int(new_len)] = jax.jit(grow)
     return fn(buf)
@@ -551,30 +566,31 @@ _grow_jits: dict = {}
 
 def _make_write_rows():
     import jax
+    import jax.numpy as jnp
     from jax import lax
 
     def write(bufs, rows, slot, start):
-        # bufs: every layer's K then V buffer (S, h * d, L); rows: the
-        # matching (Lp, h, d) rows, turned to (h * d, Lp) here (small:
-        # one prompt's rows); slot/start scalars: place each row-set
-        # at [slot, :, start:start+Lp] in ONE executable (per-
-        # dispatch overhead dominates a row copy, so one call per
-        # layer per K/V would bury the admission in launch latency).
-        # start is a traced operand (prefix copies write at 0, suffix
-        # prefills at the prefix length) so every offset shares this
-        # one executable per shape pair.  NOT donated: a write holds
-        # the cache twice while it runs (PERF.md PR 24).  Donating it
-        # moves admission_ms, memory_peak_bytes and the slots a cell
-        # can hold, so it is a change to measure on the chip (ROADMAP
-        # Speed 3 (3)).  Nothing is known against it: the mis-aliasing
-        # once cited here was the deleted compile_cache module's, and
-        # jax's persistent cache loads the donated decode step and the
-        # donated install below in every warm chip run
-        return [lax.dynamic_update_slice(
-            b, r.reshape(r.shape[0], -1).T[None].astype(b.dtype),
-            (slot, _np.int32(0), start))
-            for b, r in zip(bufs, rows)]
-    return jax.jit(write)
+        # bufs: every layer's K then V buffer (S, h * d, L), or the one
+        # stacked K and V (layers, S, h * d, L); rows: the matching
+        # (Lp, h, d) rows, layers before them where stacked, turned to
+        # (h * d, Lp) here (small: one prompt's rows); slot/start
+        # scalars: place each row-set at [slot, :, start:start+Lp] in
+        # ONE executable (per-dispatch overhead dominates a row copy,
+        # so one call per layer per K/V would bury the admission in
+        # launch latency).  start is a traced operand (prefix copies
+        # write at 0, suffix prefills at the prefix length) so every
+        # offset shares this one executable per shape pair.  The
+        # buffers are DONATED and written in place: an un-donated write
+        # holds the cache twice while it runs, and stacked rows are
+        # most of a chip
+        def place(b, r):
+            r = r.reshape(r.shape[:-2] + (-1,)).swapaxes(-1, -2)
+            lead = (_np.int32(0),) * (b.ndim - 3)
+            return lax.dynamic_update_slice(
+                b, jnp.expand_dims(r, -3).astype(b.dtype),
+                lead + (slot, _np.int32(0), start))
+        return [place(b, r) for b, r in zip(bufs, rows)]
+    return jax.jit(write, donate_argnums=(0,))
 
 
 class _Lazy:

@@ -456,8 +456,12 @@ class DecodeModel:
     max_prompt: Optional[int] = None
     min_prompt_bucket = 8
     # whether a slot can be rewound (speculation) and its rows shared
-    # (prefix cache): true where rows are all a slot holds
+    # (prefix cache), and where not, why: the end of every refusal
     supports_rollback = True
+    no_rollback_why = ""
+    # what a family says of its programs on the ``model.prefill`` and
+    # ``model.step.dispatch`` spans beside what every family says
+    span_attrs: Dict[str, Any] = {}
 
     def __init__(self, params: Any, num_heads: int, ga: Tuple[Any, Any],
                  max_length: int, name: str) -> None:
@@ -622,16 +626,21 @@ class DecodeModel:
     @staticmethod
     def from_block(block: Any) -> "DecodeModel":
         """Build from a live zoo LM (weights as currently
-        initialized/loaded).  Three families are served: a ``GPTModel``
+        initialized/loaded).  Four families are served: a ``GPTModel``
         with dense FFNs (one built with ``moe_experts`` keeps
         ``MoEDense``'s capacity mask, which drops tokens, and is
-        refused), a ``Phi4FlashModel`` (``serving.hybrid``) and a
+        refused), a ``Phi4FlashModel`` (``serving.hybrid``), a
         ``Cohere2MoEModel`` (``serving.moe``: dropless top-k experts, of
-        which the model holds a share), each of the last two through its
-        own subclass."""
+        which the model holds a share) and an ``OuroModel``
+        (``serving.loop``: one stack of layers run several times a
+        token), each of the last three through its own subclass."""
         from ..gluon.model_zoo.cohere2moe import Cohere2MoEModel
         from ..gluon.model_zoo.generation import _collect
+        from ..gluon.model_zoo.ouro import OuroModel
         from ..gluon.model_zoo.phi4flash import Phi4FlashModel
+        if isinstance(block, OuroModel):
+            from .loop import LoopDecodeModel
+            return LoopDecodeModel.from_ouro(block)
         if isinstance(block, Phi4FlashModel):
             from .hybrid import HybridDecodeModel
             return HybridDecodeModel.from_phi4flash(block)
@@ -642,7 +651,7 @@ class DecodeModel:
                                                        "word_embed"):
             raise MXNetError(
                 f"DecodeModel serves decoder-only zoo LMs (GPTModel, "
-                f"Phi4FlashModel, Cohere2MoEModel); got "
+                f"Phi4FlashModel, Cohere2MoEModel, OuroModel); got "
                 f"{type(block).__name__}")
         params = _collect(block)
         ga = (params.pop("gelu_approx"), params.pop("ln_eps"))
@@ -659,6 +668,12 @@ class DecodeModel:
             self.n_layers, self.num_heads, self.head_dim, max_slots,
             buckets=buckets, dtype=self.dtype, prefix=prefix,
             prefix_slots=prefix_slots)
+
+    def no_rollback(self, what: str) -> MXNetError:
+        """The refusal of ``what`` for a family that cannot rewind or
+        share a slot."""
+        return MXNetError(f"{what} is not available for the "
+                          f"{self.family} family: {self.no_rollback_why}")
 
     # -- execution ----------------------------------------------------------
     def _account(self, tag: str) -> None:
@@ -688,7 +703,8 @@ class DecodeModel:
         padded[:t0] = toks
         self._account(f"prefill:{bucket_len}")
         with _tracing.child_span("model.prefill", bucket=bucket_len,
-                                 family=self.family) as span:
+                                 family=self.family,
+                                 **self.span_attrs) as span:
             t = time.perf_counter()
             logits, *held = self._prefill_fn(
                 self.params, jnp.asarray(padded), _np.int32(t0))
@@ -791,7 +807,8 @@ class DecodeModel:
         # returning, the new buffers installed
         with _tracing.child_span("model.step.dispatch", slots=S,
                                  bucket=cache.bucket, family=self.family,
-                                 ahead=int(ahead), **extent):
+                                 ahead=int(ahead), **extent,
+                                 **self.span_attrs):
             if not ahead:
                 tokens = jax.device_put(
                     self._step_tokens(_np.asarray(tokens, _np.int32)),

@@ -44,7 +44,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as _np
 
-from ..base import MXNetError
 from .. import metrics as _metrics
 from .kv_cache import PagedKVCache
 from .model import DecodeModel, _sample_tokens, _select_one
@@ -68,6 +67,10 @@ class MoEDecodeModel(DecodeModel):
     max_prompt = MAX_PROMPT
     min_prompt_bucket = MIN_PROMPT_BUCKET
     supports_rollback = False
+    no_rollback_why = (
+        "it rewinds or shares a slot's rows, and this family's slots "
+        "also hold window rings, which cannot be rewound and of which "
+        "no snapshot is taken yet")
 
     def __init__(self, params: Any, cfg: Dict[str, Any], max_length: int,
                  name: str) -> None:
@@ -250,18 +253,11 @@ class MoEDecodeModel(DecodeModel):
         _metrics.GEN_EXPERT_SLOTS_TOTAL.inc(int(load.size))
         return out[:S]
 
-    def _no_rewind(self, what: str) -> MXNetError:
-        return MXNetError(
-            f"{what} is not available for the {self.family} family: it "
-            "rewinds or shares a slot's rows, and this family's slots "
-            "also hold window rings, which cannot be rewound and of "
-            "which no snapshot is taken yet")
-
     def verify(self, *args: Any, **kwargs: Any) -> _np.ndarray:
-        raise self._no_rewind("speculative verification")
+        raise self.no_rollback("speculative verification")
 
     def prefill_suffix(self, *args: Any, **kwargs: Any) -> Any:
-        raise self._no_rewind("suffix prefill over a shared prefix")
+        raise self.no_rollback("suffix prefill over a shared prefix")
 
     def describe(self) -> Dict[str, Any]:
         out = super().describe()

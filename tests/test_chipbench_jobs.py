@@ -90,7 +90,39 @@ MOE_TINY = (
                "decode_prompt": 4, "new_tokens": 12,
                "batch_prompts": [8, 60]},
      "trace_at_s": 0.2, "trace_window_s": 0.5})
-TINIES = {CELL: TINY, MOE_CELL: MOE_TINY}
+LOOP_CELL = "tiny_ouro.serve_math"
+LOOP_TINY = (
+    ["setup_s", "serve_tokens_per_s", "warmup_s", "decode_batch_mean",
+     "kv_migrations", "decode_step_ms", "prefill_ms",
+     "device_idle_pct.serve", "queue_wait_p95_ms", "admission_ms",
+     "decode_dispatch_ms", "engine_host_ms", "idle_pct.decode_call",
+     "idle_pct.admission", "idle_pct.engine_host", "cache_bytes_per_slot",
+     "decode_hbm_pct", "decode_ahead_pct", "rows_read_pct",
+     "loop_passes_per_token", "decode_attn_roofline_pct"],
+    {"arch": {"layers": 3, "loop_steps": 3, "width": 64, "heads": 4,
+              "kv_heads": 4, "head_dim": 16, "ffn": 96, "vocab": 512},
+     "zoo": "mxnet_tpu.gluon.model_zoo.ouro:get_ouro",
+     "zoo_args": ["tiny"], "zoo_kwargs": {"dtype": "float32"},
+     "serve_dtype": "float32"},
+    {"job": "serve_loop",
+     # one bucket, as the real cell; its block is 512, so the slot
+     # installed at 500 crosses it
+     "engine": {"max_slots": 4, "kv_buckets": [1024], "prefix_slots": 0,
+                "queue_limit": 1000, "max_tokens": 64},
+     "traffic": {"rate_per_s": 20.0, "ramp_s": 0.5,
+                 "prompt": {"median": 24, "sigma": 0.5, "min": 8, "max": 64},
+                 "output": {"median": 8, "sigma": 0.5, "min": 4, "max": 16},
+                 "at_window_end": "drain", "drain_s": 20.0},
+     # forced: a slot from a short prompt and one installed from the
+     # reference (longer than the traffic's longest prompt) that crosses
+     # the ragged kernel's 512-position block
+     "check": {"prompt_lengths": [5, 40],
+               "forced": {"prompts": [3, 508], "copies": 1, "steps": 6,
+                          "min_decisive": 3},
+               "decode_prompt": 4, "new_tokens": 12,
+               "batch_prompts": [8, 60]},
+     "trace_at_s": 0.2, "trace_window_s": 0.5})
+TINIES = {CELL: TINY, MOE_CELL: MOE_TINY, LOOP_CELL: LOOP_TINY}
 
 
 def tiny_root(tmp_path, monkeypatch, cell=CELL):
@@ -109,7 +141,8 @@ def synthetic_devices(monkeypatch):
         return {"/device:TPU:0": {
             trace_reduce.OPS_LINE: [
                 ("fusion.7", lo + 2 * q, 2 * q),
-                ("gmm.3[tpu_custom_call]", lo + 5 * q, q)],
+                ("gmm.3[tpu_custom_call]", lo + 5 * q, q),
+                ("ragged_attention.9[tpu_custom_call]", lo + 6 * q, q)],
             trace_reduce.MODULES_LINE: [("jit__step(1)", lo + q, 4 * q)],
         }}, (lo, hi)
     monkeypatch.setattr(trace_reduce, "read_xplane", fake)
@@ -545,3 +578,176 @@ def test_moe_step_bytes_by_hand():
     flops, nbytes = moe_bytes.gmm_flops_and_bytes(1000, 60, real, 2)
     assert flops == 6 * 1000 * 4096 * 4096
     assert nbytes == 60 * 2 * 50_331_648 + 1000 * (8192 * 2 + 12288 * 4)
+
+
+# ---------------------------------------------------------------------------
+# job kind serve_loop (the Ouro family), the same rehearsals
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_the_loop_job_end_to_end_at_a_tiny_size(tmp_path, monkeypatch,
+                                                trace):
+    value = _end_to_end(tmp_path, monkeypatch, LOOP_CELL, trace)
+    if not trace:
+        return
+    # 3 loop steps on every launch of the tiny model
+    assert value["loop_passes_per_token"] == 3.0
+    assert 0 < value["decode_hbm_pct"] and 0 < value["decode_attn_roofline_pct"]
+    # 9 entries of 4 heads x 16, K and V, float32, one bucket of 1024
+    assert value["cache_bytes_per_slot"] == 9 * 2 * 64 * 4 * 1024
+    # every slot sits in the first of the bucket's two blocks
+    assert value["rows_read_pct"] == 50.0
+
+
+def test_the_float8_control_is_refused_by_the_loop_jobs_verdict(
+        tmp_path, monkeypatch):
+    """``chipbench/precision.py`` works on a ``serve_loop`` cell as it
+    is: the reference on rounded weights goes through the job's
+    ``verdict`` and is refused by its limits; against itself it is
+    correct."""
+    import types
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from chipbench import precision
+    job, model, spec, vocab = _check_on_a_tiny_model(tmp_path, monkeypatch,
+                                                     LOOP_CELL)
+    # 9 passes of width 64 gather less of a rounding than 192 of 2048:
+    # the tiny control rounds to float8_e5m2 (as the other families')
+    low = types.SimpleNamespace(cfg=model.cfg, params=dict(
+        model.params, layers=jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.float8_e5m2) if a.ndim >= 2 else a,
+            model.params["layers"])))
+    n_min = spec["forced"]["min_decisive"]
+    control = precision.control_readings(
+        job, model, low, spec, np.random.default_rng(3), vocab)
+    ok, refused = job.verdict(control, n_min)
+    assert not ok and set(refused) & set(job.LIMITS)
+    same = precision.control_readings(
+        job, model, model, spec, np.random.default_rng(3), vocab)
+    assert job.verdict(same, n_min) == (True, [])
+    assert same["decisive_positions"] == control["decisive_positions"] >= n_min
+
+
+@pytest.mark.parametrize("fault", ["entry_swapped", "rows_shifted",
+                                   "a_pass_dropped", "too_few_decisive"])
+def test_the_loop_program_check_refuses_a_planted_fault(
+        tmp_path, monkeypatch, fault):
+    """The programs driven directly: two entries of the cache swapped
+    (loop steps 0 and 1 of layer 0: what a wrong entry index aliases),
+    the rows one position off, a program that makes one loop step fewer
+    than it says, and a run with nothing decisive to compare each come
+    out as not correct, by the limit that is for it."""
+    import jax.numpy as jnp
+    import numpy as np
+    job, model, spec, vocab = _check_on_a_tiny_model(tmp_path, monkeypatch,
+                                                     LOOP_CELL)
+    cell = {"check": dict(spec), "traffic": {"prompt": {"max": 64}}}
+    drive = job.drive_decode_program
+
+    def faulty(model, cache, forced):
+        answers = drive(model, cache, forced)
+        if fault == "entry_swapped":
+            n = model.cfg["num_layers"]
+            cache._k = [cache._k[0].at[jnp.array([0, n])].set(
+                cache._k[0][jnp.array([n, 0])])]
+        elif fault == "rows_shifted":
+            cache._v = [jnp.roll(cache._v[0], 1, axis=3)]
+        return answers
+    if fault == "too_few_decisive":
+        cell["check"]["forced"] = dict(spec["forced"], min_decisive=10 ** 6)
+    elif fault == "a_pass_dropped":
+        from mxnet_tpu.serving.loop import LoopDecodeModel
+        short = dict(model.cfg, loop_steps=model.cfg["loop_steps"] - 1)
+        dropped = LoopDecodeModel(model.params, short, model.max_length,
+                                  "faulty")
+        # it still says, and holds the cache of, every loop step
+        dropped.cfg, dropped.entries = model.cfg, model.entries
+        model = dropped
+    else:
+        monkeypatch.setattr(job, "drive_decode_program", faulty)
+    readings = job.check_programs(
+        model, (4, (1024,), (64, 128, 256, 512, 1024)), cell,
+        np.random.default_rng(5), vocab)
+    assert readings["crossed_block_at"] == [508 + 6]
+    ok, refused = job.verdict(readings, cell["check"]["forced"]["min_decisive"])
+    assert not ok and set(refused) >= {
+        "entry_swapped": {"rows_err"}, "rows_shifted": {"rows_err"},
+        # the first two entries are sound; every position of the last
+        # has parted, and no prompt's last position is settled
+        "a_pass_dropped": {"prefill_logit_err", "unsettled_share"},
+        "too_few_decisive": {"decisive_positions"}}[fault]
+
+
+def test_a_sound_loop_program_check_is_correct(tmp_path, monkeypatch):
+    import numpy as np
+    job, model, spec, vocab = _check_on_a_tiny_model(tmp_path, monkeypatch,
+                                                     LOOP_CELL)
+    cell = {"check": spec, "traffic": {"prompt": {"max": 64}}}
+    readings = job.check_programs(
+        model, (4, (1024,), (64, 128, 256, 512, 1024)), cell,
+        np.random.default_rng(5), vocab)
+    assert job.verdict(readings, spec["forced"]["min_decisive"]) == (True, [])
+    assert max(readings["rows_err"]) < 2e-5
+    assert max(readings["prefill_logit_err"]) < 2e-5
+    # 2 prefills and 4 slots after the steps: K and V of the first two
+    # compared entries over all positions; of the last entry, the share
+    # of positions that have parted: none, float32 against float32
+    assert len(readings["rows_err"]) == (2 + 4) * 2 * 2
+    assert readings["unsettled_by_array"] == [0.0] * (2 + 4)
+    # a 0.0 a compared position: the two prefills' and the four slots'
+    assert set(readings["unsettled_share"]) == {0.0}
+    assert len(readings["unsettled_share"]) > 5 + 40 + 4 * 6
+    assert readings["prompts_redrawn"] == 0
+
+
+def test_the_loop_cell_resolves_from_the_real_benchmark():
+    cell = "ouro_2_6b.serve_math"
+    found = run.resolve(ROOT, cell)
+    bench = found["bench"]
+    listed = lastline.cell_metrics(bench, cell, 1)
+    assert set(found["readers"]) == set(listed)
+    for name, reader in found["readers"].items():
+        entry = next(m for m in bench["per_layer"] if m["name"] == name)
+        assert (reader.LAYER, reader.MOVES, reader.UNIT, reader.SOURCE) == (
+            entry["layer"], entry["moves"], entry["unit"], entry["source"])
+    assert found["chips"] == 1 and found["cell"]["engine"] == {
+        "max_slots": 5, "kv_buckets": [1024], "prefix_slots": 0,
+        "queue_limit": 100000, "max_tokens": 752}
+    # the sixteen serving readers ISSUE 35 lists, the two it adds, and
+    # warmup_s, which every cell reports; not state_install_ms: a slot
+    # of this family holds rows alone
+    assert len(listed) == 16 + 2 + 1 and "state_install_ms" not in listed
+    assert {"loop_passes_per_token", "decode_attn_roofline_pct",
+            "decode_hbm_pct", "rows_read_pct", "cache_bytes_per_slot"} \
+        <= set(listed)
+    assert lastline.cell_metrics(bench, cell, 0) == {
+        "setup_s": "s", "serve_tokens_per_s": "tokens/s"}
+    assert len(bench["workloads"]) == 5 \
+        and all(w["chips"] == 1 for w in bench["workloads"])
+    mix = found["cell"]["traffic"]
+    assert (mix["prompt"], mix["output"], mix["ramp_s"],
+            mix["at_window_end"]) == (
+        {"median": 96, "sigma": 0.5, "min": 32, "max": 256},
+        {"median": 384, "sigma": 0.35, "min": 192, "max": 752}, 20.0,
+        "cancel")
+    # the configuration: every number of the published config, nothing cut
+    config, entry = found["config"], next(
+        c for c in bench["configs"] if c["name"] == "ouro_2_6b")
+    assert entry["reduced"] == config["reduced"] == []
+    assert (config["hidden_size"], config["intermediate_size"],
+            config["num_attention_heads"], config["num_key_value_heads"],
+            config["head_dim"], config["num_hidden_layers"],
+            config["total_ut_steps"], config["early_exit_threshold"],
+            config["vocab_size"], config["rope_theta"],
+            config["rms_norm_eps"], config["tie_word_embeddings"]) == (
+        2048, 5632, 16, 16, 128, 48, 4, 1, 49152, 1000000, 1e-06, False)
+    assert len(config["layer_types"]) == 48
+    from mxnet_tpu.gluon.model_zoo import ouro
+    net = ouro.get_ouro(*config["zoo_args"])
+    assert (net.config["num_layers"], net.config["loop_steps"],
+            net.config["units"], net.config["hidden_size"]) == (
+        48, 4, 2048, 5632)
+    # the longest request of the mix fits the one bucket
+    assert mix["prompt"]["max"] + mix["output"]["max"] \
+        <= found["cell"]["engine"]["kv_buckets"][0]

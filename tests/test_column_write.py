@@ -131,3 +131,68 @@ def test_a_dtype_that_does_not_pack_is_refused():
         cw.write_columns((jnp.zeros((2, 3, 128), jnp.bfloat16),),
                          (jnp.zeros((2, 3, 1), jnp.bfloat16),),
                          jnp.zeros((2,), jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# the stacked form (a looped family's cache: one entry a pass, written
+# from inside a loop with the entry's index scalar-prefetched)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("entry", [0, 2, 4])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_the_stacked_form_equals_the_plain_form_entry_by_entry(dtype, entry):
+    """Entry ``entry`` of the stack comes out as the plain form leaves
+    that entry alone, bit for bit; every other entry is untouched."""
+    E, S, L = 5, 3, 256
+    rng = onp.random.default_rng(entry)
+    at = jnp.asarray([0, 130, L - 1], jnp.int32)
+    # plain values in the buffers: interpreted on the CPU, the
+    # unvisited entries of a bfloat16 stack come back with their
+    # subnormal bit patterns changed (0.4 % of random bits, the
+    # subnormals' share; float32 keeps every bit).  The chip aliases the
+    # stack and moves nothing of them
+    kb = jnp.asarray(rng.normal(size=(E, S, CHANNELS, L)), dtype)
+    vb = jnp.asarray(rng.normal(size=(E, S, CHANNELS, L)), dtype)
+    kc = _random_bits(rng, (S, CHANNELS), dtype)
+    vc = _random_bits(rng, (S, CHANNELS), dtype)
+    got_k, got_v = jax.jit(cw.write_columns)((kb, vb), (kc, vc), at,
+                                             jnp.int32(entry))
+    want_k, want_v = cw.write_columns((kb[entry], vb[entry]), (kc, vc), at)
+    for got, want, before in ((got_k, want_k, kb), (got_v, want_v, vb)):
+        assert got.shape == before.shape and got.dtype == before.dtype
+        onp.testing.assert_array_equal(_bits(got[entry]), _bits(want))
+        others = [e for e in range(E) if e != entry]
+        onp.testing.assert_array_equal(_bits(got)[others],
+                                       _bits(before)[others])
+
+
+def test_the_stacked_form_inside_a_loop_writes_every_entry():
+    """The entry is a traced loop index: each pass writes its own entry
+    of the carried stack, as the looped family's decode step does."""
+    E, S, L = 4, 2, 128
+    rng = onp.random.default_rng(1)
+    buf = _random_bits(rng, (E, S, CHANNELS, L), jnp.float32)
+    cols = jnp.asarray(rng.normal(size=(E, S, CHANNELS)), jnp.float32)
+    at = jnp.asarray([5, 127], jnp.int32)
+
+    def program(buf):
+        return lax.fori_loop(
+            0, E, lambda e, b: cw.write_columns((b,), (cols[e],), at, e)[0],
+            buf)
+
+    got = jax.jit(program)(buf)
+    want = onp.asarray(buf).copy()
+    for s in range(S):
+        want[:, s, :, int(at[s])] = onp.asarray(cols)[:, s]
+    onp.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+def test_a_stack_without_its_entry_is_refused():
+    buf = jnp.zeros((2, 3, CHANNELS, 128))
+    col = jnp.zeros((3, CHANNELS))
+    with pytest.raises(ValueError, match="stacked"):
+        cw.write_columns((buf,), (col,), jnp.zeros(3, jnp.int32))
+    with pytest.raises(ValueError, match="stacked"):
+        cw.write_columns((buf[0],), (col,), jnp.zeros(3, jnp.int32),
+                         jnp.int32(0))

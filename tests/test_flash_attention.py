@@ -583,3 +583,132 @@ def test_moe_prefill_compiles_for_v5e_with_flash_and_the_grouped_matmul(
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") >= 4 * 3
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+# ---------------------------------------------------------------------------
+# the looped (Ouro) family (serving/loop.py): both kernels on a STACKED
+# cache, the entry scalar-prefetched, from inside a loop
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("entry", [0, 1, 3])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                       (jnp.bfloat16, 1e-6)])
+def test_stacked_decode_attention_equals_the_plain_form_entry_by_entry(
+        dtype, tol, entry):
+    """Interpret mode: entry ``entry`` of the stacked rows through the
+    leading-axis form is the plain form on that entry alone, the same
+    arithmetic in the same order."""
+    from mxnet_tpu.ops.pallas import decode_attention as da
+    E, S, G, R, C, L = 4, 3, 2, 1, 16, 1024
+    k = jax.random.split(jax.random.PRNGKey(entry), 3)
+    q = jax.random.normal(k[0], (S, G, R, C)).astype(dtype)
+    ck = jax.random.normal(k[1], (E, S, G * C, L)).astype(dtype)
+    cv = jax.random.normal(k[2], (E, S, G * C, L)).astype(dtype)
+    pos = jnp.asarray([0, 511, 700], jnp.int32)
+    got = jax.jit(da.ragged_attention, static_argnums=4)(
+        q, ck, cv, pos, 0.25, jnp.int32(entry))
+    want = da.ragged_attention(q, ck[entry], cv[entry], pos, 0.25)
+    assert got.shape == (S, G, R, C) and got.dtype == jnp.float32
+    assert float(jnp.abs(got - want).max()) <= tol
+    with pytest.raises(ValueError, match="stacked"):
+        da.ragged_attention(q, ck, cv, pos, 0.25)
+
+
+def _loop_of_passes(E, S, G, C, L):
+    """Both kernels as the looped family's decode step calls them: a
+    ``fori_loop`` over the entries with the stacked K and V carried."""
+    from mxnet_tpu.ops.pallas import column_write as cw
+    from mxnet_tpu.ops.pallas import decode_attention as da
+
+    def step(K, V, x, pos):
+        def body(e, carry):
+            K, V, x = carry
+            K, V = cw.write_columns((K, V), (x, x), pos, entry=e)
+            a = da.ragged_attention(x.reshape(S, G, 1, C), K, V, pos,
+                                    C ** -0.5, entry=e)
+            return K, V, a.reshape(S, G * C).astype(x.dtype)
+        return jax.lax.fori_loop(0, E, body, (K, V, x))
+    return step
+
+
+def test_the_stacked_kernels_compile_for_v5e_in_a_loop_at_the_cells_shapes(
+        one_v5e):
+    """192 entries, 5 slots, 16 heads of 128, the 1024 bucket, bfloat16
+    (ouro_2_6b.serve_math): Mosaic takes both leading-axis forms, the
+    program holds ONE call of each inside one ``while``, the two stacks
+    (8.05 GB) are aliased whole to the results, and nothing the size of
+    an entry (or of the stack) is copied, sliced out or relaid."""
+    import chip_smoke
+    E, S, G, C, L = 192, 5, 16, 128, 1024
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(      # noqa: E731
+        shape, dt, sharding=one_v5e)
+    stack = arg((E, S, G * C, L), jnp.bfloat16)
+    compiled = jax.jit(_loop_of_passes(E, S, G, C, L),
+                       donate_argnums=(0, 1)).lower(
+        stack, stack, arg((S, G * C), jnp.bfloat16),
+        arg((S,), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert sorted(_kernel_calls(hlo)) == ["ragged_attention",
+                                          "write_columns"]
+    assert len(re.findall(r" while\(", hlo)) == 1
+    for size in (S * G * C * L, E * S * G * C * L):
+        assert chip_smoke.cache_sized_relayouts(hlo, size) == []
+    assert not re.search(
+        rf"= bf16\[{S},{G * C},{L}\][^ ]* (dynamic-slice|copy)\(", hlo)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * E * S * G * C * L * 2
+    assert memory.temp_size_in_bytes < 2 ** 24
+
+
+def _described_loop_model(one_v5e):
+    from mxnet_tpu.gluon.model_zoo import ouro
+    from mxnet_tpu.serving.loop import LoopDecodeModel
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(      # noqa: E731
+        tuple(shape), dt, sharding=one_v5e)
+    net = ouro.get_ouro("ouro_2_6b", dtype="bfloat16")
+    params = ouro._tree({name: arg(p.shape, jnp.dtype(str(p.dtype)))
+                         for name, p in net.collect_params().items()})
+    return LoopDecodeModel(params, dict(net.config), net._max_length,
+                           "aot"), params, arg
+
+
+@pytest.mark.slow
+def test_loop_step_compiles_for_v5e_as_a_loop_with_the_cache_in_place(
+        one_v5e):
+    """The whole decode step of the published Ouro-2.6B, 5 slots on the
+    1024 bucket (about half a minute): two Mosaic calls and two
+    ``while`` loops for 192 layer passes, the stacked K and V aliased to
+    the results, no copy of an entry or of a layer's weights (their
+    ``dynamic-slice`` is fused into the product that reads them), and
+    temporaries of a few MB."""
+    import chip_smoke
+    model, params, arg = _described_loop_model(one_v5e)
+    S, L, E, C = 5, 1024, 192, 2048
+    rows = [arg((E, S, C, L), jnp.bfloat16)]
+    i32, f32 = arg((S,), jnp.int32), arg((S,), jnp.float32)
+    compiled = model._step_fn.lower(
+        params, rows, rows, i32, i32, i32, i32, f32, i32, f32,
+        i32).compile()
+    hlo = compiled.as_text()
+    assert sorted(_kernel_calls(hlo)) == ["ragged_attention",
+                                          "write_columns"]
+    assert len(re.findall(r" while\(", hlo)) == 2
+    for size in (S * C * L, E * S * C * L):
+        assert chip_smoke.cache_sized_relayouts(hlo, size) == []
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * E * S * C * L * 2
+    assert memory.temp_size_in_bytes < 2 ** 26
+
+
+@pytest.mark.slow
+def test_loop_prefill_compiles_for_v5e_as_a_loop_with_flash_at_1024(
+        one_v5e, monkeypatch):
+    from mxnet_tpu.ops import transformer
+    monkeypatch.setattr(transformer, "_use_pallas_len", lambda T: T >= 512)
+    model, params, arg = _described_loop_model(one_v5e)
+    compiled = model._prefill_fn.lower(
+        params, arg((1024,), jnp.int32), arg((), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 1
+    assert len(re.findall(r" while\(", hlo)) == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30

@@ -16,6 +16,8 @@ bucket, and answers on a stdlib HTTP server:
         --max-slots 64 --kv-buckets 1024,2048,4096    # hybrid LM, bfloat16
     python tools/serve.py --generate --zoo-cohere2moe command_a_plus_ep8 \
         --max-slots 48 --kv-buckets 1024,2048,4096    # sparse experts, bfloat16
+    python tools/serve.py --generate --zoo-ouro ouro_2_6b \
+        --max-slots 5 --kv-buckets 1024       # looped LM (48 layers x 4), bfloat16
 
     curl -s localhost:8080/v1/inference -d '{"instances": [[...]]}'
     curl -sN localhost:8080/v1/generate \
@@ -27,10 +29,11 @@ Knobs default from the MXNET_SERVING_* env tier, plus MXNET_GEN_* for
 --generate (docs/serving.md).  Static exports serve exactly their
 traced batch size; export with ``dynamic_batch=True`` for the full
 bucket grid.  --generate serves a LIVE decoder LM (zoo GPT in float32,
-with --zoo-phi4flash the Phi-4-mini-flash hybrid or with
---zoo-cohere2moe one chip's share of Command A+ in bfloat16, for both of
-which speculation and the prefix cache are refused; optionally with
---gpt-params weights) through the resident decode loop.
+with --zoo-phi4flash the Phi-4-mini-flash hybrid, with
+--zoo-cohere2moe one chip's share of Command A+ or with --zoo-ouro the
+looped Ouro-2.6B in bfloat16, for all three of which speculation and
+the prefix cache are refused; optionally with --gpt-params weights)
+through the resident decode loop.
 
 Resilience (docs/serving.md#resilience): --replicas N hosts N worker
 replicas (dead workers requeue/recover their requests and restart with
@@ -110,8 +113,8 @@ def main(argv=None) -> None:
                          "random unless --gpt-params is given)")
     ap.add_argument("--gpt-params", default=None,
                     help="a .params file to load into the --zoo-gpt, "
-                         "--zoo-phi4flash or --zoo-cohere2moe model "
-                         "before serving")
+                         "--zoo-phi4flash, --zoo-cohere2moe or "
+                         "--zoo-ouro model before serving")
     ap.add_argument("--zoo-phi4flash", default=None,
                     choices=("phi4_mini_flash", "tiny"),
                     help="serve the Phi-4-mini-flash hybrid family "
@@ -135,6 +138,21 @@ def main(argv=None) -> None:
                          "vocabulary; 4.7 B in bfloat16), 'tiny' a "
                          "float32 one for the CPU.  Speculation and the "
                          "prefix cache are refused for it")
+    ap.add_argument("--zoo-ouro", default=None,
+                    choices=("ouro_2_6b", "tiny"),
+                    help="serve the Ouro (LoopLM) family for --generate "
+                         "instead of --zoo-gpt: ONE stack of sandwich-"
+                         "norm layers (RMSNorm before and after each "
+                         "branch, half-split RoPE, SwiGLU) applied "
+                         "several times a token with the same weights, "
+                         "a K/V cache entry for every (loop step, "
+                         "layer).  ouro_2_6b is the published 2.67 B "
+                         "model whole (48 layers x 4 loop steps; "
+                         "1.5 MiB of cache a position, so 5 slots of "
+                         "1024 on a 16 GB chip) in bfloat16, 'tiny' a "
+                         "3-layer x 3-step float32 one for the CPU.  "
+                         "Speculation and the prefix cache are refused "
+                         "for it")
     ap.add_argument("--max-slots", type=int, default=None,
                     help="decode slots for --generate "
                          "(MXNET_GEN_MAX_SLOTS)")
@@ -271,14 +289,16 @@ def _serve_generate(args, serving) -> None:
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo.gpt import GPTModel, get_gpt
     from mxnet_tpu.gluon.model_zoo.cohere2moe import get_cohere2moe
+    from mxnet_tpu.gluon.model_zoo.ouro import get_ouro
     from mxnet_tpu.gluon.model_zoo.phi4flash import get_phi4flash
 
     mx.random.seed(0)
-    declared = args.zoo_phi4flash or args.zoo_cohere2moe
+    declared = args.zoo_phi4flash or args.zoo_cohere2moe or args.zoo_ouro
     if declared:
-        # the two families that declare every shape; bfloat16 as
-        # published but for the CPU size
-        get = get_phi4flash if args.zoo_phi4flash else get_cohere2moe
+        # the families that declare every shape; bfloat16 as published
+        # but for the CPU size
+        get = get_phi4flash if args.zoo_phi4flash else \
+            get_cohere2moe if args.zoo_cohere2moe else get_ouro
         net = get(declared,
                   dtype="float32" if declared == "tiny" else "bfloat16")
         # inference only: a gradient buffer beside each of billions of
