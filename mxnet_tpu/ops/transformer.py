@@ -142,7 +142,9 @@ def _flash(q, k, v, bias, seed, *, dropout, **kw):
     mesh (``parallel.mesh.kernel_mesh``, entered by SPMDTrainer) the
     call is shard_mapped: batch over the data axes and heads over ``tp``
     where they divide, every other axis replicated.  Attention is
-    independent per (batch, head), so each shard is a whole problem.
+    independent per (batch, head), so each shard is a whole problem:
+    the kernel forms its head groups (``attention.head_group``) from the
+    heads its shard holds, so a ``tp`` shard always holds whole groups.
     Inside an enclosing shard_map (ring / pipeline) the operands are
     already per-device and the kernel is called directly."""
     from .pallas.attention import flash_attention
@@ -211,11 +213,11 @@ def _flash_block(which: str, seq: int = 0) -> int:
     if env:
         return env
     if which == "Q":
-        # shape-aware default (r4 measured, BERT-base b64xT=512:
-        # 138.6k tok/s with a full-T block vs 131.4k with 256): at
-        # T<=512 one query block per (B,H) head removes per-block grid
-        # overhead; at 1024+ the 256-row blocks from the attn_probe
-        # sweep (in git history before PR 30) win.
+        # shape-aware default: at T<=512 one query block per (batch,
+        # head group) removes per-block grid overhead; at 1024+ 256-row
+        # blocks win (measured again in PR 36, BERT-large b16xT=512
+        # forward + backward: 0.890 ms a layer whole, 0.998 at 256; the
+        # sweep is beside attention.DEFAULT_BLOCK_Q).
         if 0 < seq <= 512:
             return seq
         return DEFAULT_BLOCK_Q

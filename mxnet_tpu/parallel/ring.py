@@ -133,33 +133,32 @@ def _block_update(q, k, v, m, l, acc, scale, row0, col0, causal, kv_len,
 # K/V blocks.
 # ---------------------------------------------------------------------------
 
-def _ring_flash_block(q_h, k_h, v_h, bias_h, scale, case, blk_cfg):
-    """One ring block's flash forward: returns (o f32 (B,H,Tl,D),
-    lse (B,H,Tl,1)). case: 0 = fully visible, 1 = aligned causal
-    diagonal, 2 = fully masked (skip compute)."""
+def _ring_flash_block(q, k, v, bias_h, scale, case, blk_cfg, H):
+    """One ring block's flash forward over (B, Tl, H·D) operands: returns
+    (o float32 (B, Tl, H·D), lse in the kernels' own layout). case: 0 =
+    fully visible, 1 = aligned causal diagonal, 2 = fully masked (skip
+    compute)."""
     from ..ops.pallas.attention import _flash_forward
     bq, bk, interpret = blk_cfg
-    B, H, Tl, D = q_h.shape
 
-    def before(_):
-        o, lse = _flash_forward(q_h, k_h, v_h, bias_h, None, scale,
-                                False, bq, bk, 0.0, interpret)
-        return o.astype(jnp.float32), lse
+    def run(causal_flag):
+        def f(_):
+            o, lse = _flash_forward(q, k, v, bias_h, None, scale,
+                                    causal_flag, bq, bk, 0.0, interpret, H)
+            return o.astype(jnp.float32), lse
+        return f
 
-    def diag(_):
-        o, lse = _flash_forward(q_h, k_h, v_h, bias_h, None, scale,
-                                True, bq, bk, 0.0, interpret)
-        return o.astype(jnp.float32), lse
+    o_s, lse_s = jax.eval_shape(run(False), None)
 
     def after(_):
-        return (jnp.zeros((B, H, Tl, D), jnp.float32),
-                jnp.full((B, H, Tl, 1), _NEG_INF, jnp.float32))
+        return (jnp.zeros(o_s.shape, jnp.float32),
+                jnp.full(lse_s.shape, _NEG_INF, jnp.float32))
 
-    return jax.lax.switch(case, [before, diag, after], None)
+    return jax.lax.switch(case, [run(False), run(True), after], None)
 
 
-def _ring_flash_bwd_block(q_h, k_h, v_h, bias_h, o_h, lse_h, g_h, scale,
-                          case, blk_cfg, want_dbias):
+def _ring_flash_bwd_block(q, k, v, bias_h, o, lse, g, scale, case, blk_cfg,
+                          want_dbias, H):
     """One ring block's flash backward with GLOBAL (o, lse): returns
     (dq, dk, dv, dbias) partial grads for this block."""
     from ..ops.pallas.attention import _flash_backward
@@ -168,9 +167,8 @@ def _ring_flash_bwd_block(q_h, k_h, v_h, bias_h, o_h, lse_h, g_h, scale,
     def run(causal_flag):
         def f(_):
             dq, dk, dv, db = _flash_backward(
-                q_h, k_h, v_h, bias_h, None, o_h, lse_h, g_h, scale,
-                causal_flag, bq, bk, 0.0, interpret,
-                bias_grad=want_dbias)
+                q, k, v, bias_h, None, o, lse, g, scale, causal_flag, bq,
+                bk, 0.0, interpret, H, bias_grad=want_dbias)
             if db is None:
                 db = jnp.zeros((1, 1, 1, 1), jnp.float32)
             return (dq.astype(jnp.float32), dk.astype(jnp.float32),
@@ -180,9 +178,9 @@ def _ring_flash_bwd_block(q_h, k_h, v_h, bias_h, o_h, lse_h, g_h, scale,
     def after(_):
         db_shape = bias_h.shape if (bias_h is not None and want_dbias) \
             else (1, 1, 1, 1)
-        return (jnp.zeros(q_h.shape, jnp.float32),
-                jnp.zeros(k_h.shape, jnp.float32),
-                jnp.zeros(v_h.shape, jnp.float32),
+        return (jnp.zeros(q.shape, jnp.float32),
+                jnp.zeros(k.shape, jnp.float32),
+                jnp.zeros(v.shape, jnp.float32),
                 jnp.zeros(db_shape, jnp.float32))
 
     return jax.lax.switch(case, [run(False), run(True), after], None)
@@ -202,86 +200,91 @@ def _flash_ring(q, k, v, bias, axis_name, n_shards, scale, causal):
     return out
 
 
-def _flash_ring_fwd(q, k, v, bias, axis_name, n_shards, scale, causal):
-    """q/k/v: (B, Tl, H, D) local shards; bias: (B|1, Tl|1, H|1, Tk_g)
-    row stripe or None. Returns (out, residuals)."""
+def _ring_blocks(Tl, Tk):
+    """The requested upper bound of the kernels' blocks (they legalize
+    it themselves) and whether they run interpreted."""
     from ..ops.pallas.attention import (_interpret, DEFAULT_BLOCK_Q,
                                         DEFAULT_BLOCK_K)
+    return (min(DEFAULT_BLOCK_Q, Tl), min(DEFAULT_BLOCK_K, Tk),
+            _interpret())
+
+
+def _bias_stripe(bias, src, Tk):
+    """The held block's columns of the (B|1, Tl|1, H|1, Tk_g) row stripe,
+    in the kernels' (B|1, H|1, Tl|1, Tk) order."""
+    if bias is None:
+        return None
+    stripe = jax.lax.dynamic_slice_in_dim(bias, src * Tk, Tk, axis=3)
+    return jnp.swapaxes(stripe, 1, 2)
+
+
+def _flash_ring_fwd(q, k, v, bias, axis_name, n_shards, scale, causal):
+    """q/k/v: (B, Tl, H, D) local shards; bias: (B|1, Tl|1, H|1, Tk_g)
+    row stripe or None. Returns (out, residuals).  The kernels index the
+    (B, Tl, H·D) array the shards are a free reshape of, so no block is
+    transposed on its way in or out; ``lse`` stays in their layout and
+    only the (B, Tl, H, 1) weights of the combine are reordered."""
+    from ..ops.pallas.attention import lse_weights
     B, Tl, H, D = q.shape
     Tk = k.shape[1]
     my = jax.lax.axis_index(axis_name)
-    interpret = _interpret()
-    # final block legalization happens inside the kernels; this is just
-    # the requested upper bound
-    blk_cfg = (min(DEFAULT_BLOCK_Q, Tl), min(DEFAULT_BLOCK_K, Tk),
-               interpret)
-    q_h = jnp.swapaxes(q, 1, 2)                       # (B,H,Tl,D)
+    blk_cfg = _ring_blocks(Tl, Tk)
+    q3 = q.reshape(B, Tl, H * D)
     perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
+
+    def block(k_blk, v_blk, src):
+        return _ring_flash_block(
+            q3, k_blk.reshape(B, Tk, H * D), v_blk.reshape(B, Tk, H * D),
+            _bias_stripe(bias, src, Tk), scale, _case_of(src, my, causal),
+            blk_cfg, H)
 
     def body(carry, step):
         k_blk, v_blk, acc, lse = carry
         src = (my - step) % n_shards
-        case = _case_of(src, my, causal)
-        bias_h = None
-        if bias is not None:
-            stripe = jax.lax.dynamic_slice_in_dim(
-                bias, src * Tk, Tk, axis=3)           # (B|1,Tl|1,H|1,Tk)
-            bias_h = jnp.swapaxes(stripe, 1, 2)       # (B|1,H|1,Tl|1,Tk)
-        o_blk, lse_blk = _ring_flash_block(
-            q_h, jnp.swapaxes(k_blk, 1, 2), jnp.swapaxes(v_blk, 1, 2),
-            bias_h, scale, case, blk_cfg)
+        o_blk, lse_blk = block(k_blk, v_blk, src)
         lse_new = jnp.logaddexp(lse, lse_blk)
         # avoid exp(-inf - -inf) NaNs before any block contributed
         w_old = jnp.where(jnp.isfinite(lse_new), jnp.exp(lse - lse_new),
                           0.0)
         w_new = jnp.where(jnp.isfinite(lse_new),
                           jnp.exp(lse_blk - lse_new), 0.0)
-        acc = acc * w_old + o_blk * w_new
+        acc = acc * lse_weights(w_old, H, Tl) + \
+            o_blk.reshape(B, Tl, H, D) * lse_weights(w_new, H, Tl)
         k_blk = jax.lax.ppermute(k_blk, axis_name, perm)
         v_blk = jax.lax.ppermute(v_blk, axis_name, perm)
         return (k_blk, v_blk, acc, lse_new), None
 
-    acc0 = jnp.zeros((B, H, Tl, D), jnp.float32)
-    lse0 = jnp.full((B, H, Tl, 1), _NEG_INF, jnp.float32)
+    lse_s = jax.eval_shape(block, k, v, my)[1]
+    acc0 = jnp.zeros((B, Tl, H, D), jnp.float32)
+    lse0 = jnp.full(lse_s.shape, _NEG_INF, jnp.float32)
     (_, _, acc, lse), _ = jax.lax.scan(
         body, (k, v, acc0, lse0), jnp.arange(n_shards))
-    out = jnp.swapaxes(acc, 1, 2).astype(q.dtype)     # (B,Tl,H,D)
+    out = acc.astype(q.dtype)
     return out, (q, k, v, bias, out, lse)
 
 
 def _flash_ring_bwd(axis_name, n_shards, scale, causal, res, g):
-    from ..ops.pallas.attention import (_interpret, DEFAULT_BLOCK_Q,
-                                        DEFAULT_BLOCK_K)
     q, k, v, bias, out, lse = res
     B, Tl, H, D = q.shape
     Tk = k.shape[1]
     my = jax.lax.axis_index(axis_name)
-    interpret = _interpret()
-    blk_cfg = (min(DEFAULT_BLOCK_Q, Tl), min(DEFAULT_BLOCK_K, Tk),
-               interpret)
-    q_h = jnp.swapaxes(q, 1, 2)
-    o_h = jnp.swapaxes(out, 1, 2)
-    g_h = jnp.swapaxes(g, 1, 2)
+    blk_cfg = _ring_blocks(Tl, Tk)
+    q3, o3, g3 = (a.reshape(B, Tl, H * D) for a in (q, out, g))
     want_dbias = bias is not None
     perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
 
     def body(carry, step):
         k_blk, v_blk, dk_blk, dv_blk, dq_acc, db_acc = carry
         src = (my - step) % n_shards
-        case = _case_of(src, my, causal)
-        bias_h = None
-        if bias is not None:
-            stripe = jax.lax.dynamic_slice_in_dim(bias, src * Tk, Tk,
-                                                  axis=3)
-            bias_h = jnp.swapaxes(stripe, 1, 2)
         dq_i, dk_i, dv_i, db_i = _ring_flash_bwd_block(
-            q_h, jnp.swapaxes(k_blk, 1, 2), jnp.swapaxes(v_blk, 1, 2),
-            bias_h, o_h, lse, g_h, scale, case, blk_cfg, want_dbias)
+            q3, k_blk.reshape(B, Tk, H * D), v_blk.reshape(B, Tk, H * D),
+            _bias_stripe(bias, src, Tk), o3, lse, g3, scale,
+            _case_of(src, my, causal), blk_cfg, want_dbias, H)
         dq_acc = dq_acc + dq_i
-        # (B,H,Tk,D) -> the ring layout, accumulated onto THIS block's
-        # rotating gradient slot — it ppermutes home with the block
-        dk_blk = dk_blk + jnp.swapaxes(dk_i, 1, 2)
-        dv_blk = dv_blk + jnp.swapaxes(dv_i, 1, 2)
+        # accumulated onto THIS block's rotating gradient slot — it
+        # ppermutes home with the block
+        dk_blk = dk_blk + dk_i.reshape(k.shape)
+        dv_blk = dv_blk + dv_i.reshape(v.shape)
         if want_dbias:
             db_stripe = jnp.swapaxes(db_i, 1, 2)      # (B|1,Tl|1,H|1,Tk)
             db_acc = jax.lax.dynamic_update_slice_in_dim(
@@ -294,12 +297,12 @@ def _flash_ring_bwd(axis_name, n_shards, scale, causal, res, g):
 
     dk0 = jnp.zeros_like(k, jnp.float32)
     dv0 = jnp.zeros_like(v, jnp.float32)
-    dq0 = jnp.zeros((B, H, Tl, D), jnp.float32)
+    dq0 = jnp.zeros(q3.shape, jnp.float32)
     db0 = (jnp.zeros(bias.shape, jnp.float32) if want_dbias
            else jnp.zeros((1,), jnp.float32))
     (_, _, dk_f, dv_f, dq_f, db_f), _ = jax.lax.scan(
         body, (k, v, dk0, dv0, dq0, db0), jnp.arange(n_shards))
-    dq = jnp.swapaxes(dq_f, 1, 2).astype(q.dtype)
+    dq = dq_f.reshape(q.shape).astype(q.dtype)
     d_bias = db_f.astype(bias.dtype) if want_dbias else None
     return dq, dk_f.astype(k.dtype), dv_f.astype(v.dtype), d_bias
 

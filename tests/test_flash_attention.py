@@ -8,7 +8,22 @@ import jax.numpy as jnp
 import pytest
 
 from mxnet_tpu.ops.pallas.attention import (_dense_reference, _flash2,
-                                            flash_attention)
+                                            flash_attention, head_group)
+
+
+def _flash_bhtd(q, k, v, bias, seed, rate, scale, causal, block_q, block_k,
+                bias_grad=True):
+    """The kernels with explicit blocks over (B, H, T, D) operands, the
+    layout ``_dense_reference`` and most cases of this file state theirs
+    in (the kernels' own is (B, T, H·D))."""
+    H, D = q.shape[1], q.shape[3]
+
+    def fold(a):
+        return jnp.swapaxes(a, 1, 2).reshape(a.shape[0], a.shape[2], H * D)
+
+    out = _flash2(fold(q), fold(k), fold(v), bias, seed, rate, scale,
+                  causal, block_q, block_k, bias_grad, H)
+    return jnp.swapaxes(out.reshape(out.shape[0], out.shape[1], H, D), 1, 2)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -20,7 +35,7 @@ def test_flash_forward_matches_dense(causal, shape):
     k = jnp.asarray(rng.normal(0, 1, shape).astype("float32"))
     v = jnp.asarray(rng.normal(0, 1, shape).astype("float32"))
     scale = 1.0 / D ** 0.5
-    out = _flash2(q, k, v, None, None, 0.0, scale, causal, 128, 128)
+    out = _flash_bhtd(q, k, v, None, None, 0.0, scale, causal, 128, 128)
     ref = _dense_reference(q, k, v, scale, causal)
     onp.testing.assert_allclose(onp.asarray(out), onp.asarray(ref),
                                 rtol=2e-4, atol=2e-5)
@@ -36,7 +51,7 @@ def test_flash_backward_matches_dense(causal):
     scale = 1.0 / D ** 0.5
 
     def loss_flash(q, k, v):
-        return jnp.sum(_flash2(q, k, v, None, None, 0.0, scale,
+        return jnp.sum(_flash_bhtd(q, k, v, None, None, 0.0, scale,
                        causal, 128, 128) ** 2)
 
     def loss_dense(q, k, v):
@@ -81,14 +96,14 @@ def test_flash_bias_matches_dense():
     scale = 1.0 / onp.sqrt(D)
 
     def loss_flash(q, k, v, bias):
-        return jnp.sum(_flash2(q, k, v, bias, None, 0.0, scale, False,
+        return jnp.sum(_flash_bhtd(q, k, v, bias, None, 0.0, scale, False,
                                32, 32) ** 2)
 
     def loss_dense(q, k, v, bias):
         return jnp.sum(_dense_reference(q, k, v, scale, False,
                                         bias=bias) ** 2)
 
-    out_f = _flash2(q, k, v, bias, None, 0.0, scale, False, 32, 32)
+    out_f = _flash_bhtd(q, k, v, bias, None, 0.0, scale, False, 32, 32)
     out_d = _dense_reference(q, k, v, scale, False, bias=bias)
     onp.testing.assert_allclose(onp.asarray(out_f), onp.asarray(out_d),
                                 rtol=2e-4, atol=2e-5)
@@ -108,7 +123,7 @@ def test_flash_broadcast_bias_grad():
     scale = 1.0 / onp.sqrt(D)
 
     def loss_flash(bias):
-        return jnp.sum(_flash2(q, q, q, bias, None, 0.0, scale, True,
+        return jnp.sum(_flash_bhtd(q, q, q, bias, None, 0.0, scale, True,
                                16, 16) ** 2)
 
     def loss_dense(bias):
@@ -149,8 +164,8 @@ def test_flash_tunable_blocks():
     rng = onp.random.RandomState(6)
     q = jnp.asarray(rng.uniform(-1, 1, (1, 2, 96, 16)).astype("float32"))
     scale = 0.25
-    o1 = _flash2(q, q, q, None, None, 0.0, scale, False, 32, 48)
-    o2 = _flash2(q, q, q, None, None, 0.0, scale, False, 96, 96)
+    o1 = _flash_bhtd(q, q, q, None, None, 0.0, scale, False, 32, 48)
+    o2 = _flash_bhtd(q, q, q, None, None, 0.0, scale, False, 96, 96)
     onp.testing.assert_allclose(onp.asarray(o1), onp.asarray(o2),
                                 rtol=2e-4, atol=2e-5)
 
@@ -171,14 +186,14 @@ def test_flash_key_padding_row_bias():
     scale = 1.0 / onp.sqrt(D)
 
     def loss_flash(q, k, v):
-        return jnp.sum(_flash2(q, k, v, bias, None, 0.0, scale, False,
+        return jnp.sum(_flash_bhtd(q, k, v, bias, None, 0.0, scale, False,
                                32, 32, False) ** 2)
 
     def loss_dense(q, k, v):
         return jnp.sum(_dense_reference(q, k, v, scale, False,
                                         bias=bias) ** 2)
 
-    out_f = _flash2(q, k, v, bias, None, 0.0, scale, False, 32, 32, False)
+    out_f = _flash_bhtd(q, k, v, bias, None, 0.0, scale, False, 32, 32, False)
     out_d = _dense_reference(q, k, v, scale, False, bias=bias)
     onp.testing.assert_allclose(onp.asarray(out_f), onp.asarray(out_d),
                                 rtol=2e-4, atol=2e-5)
@@ -199,7 +214,7 @@ def test_flash_row_bias_learned_grad():
     scale = 1.0 / onp.sqrt(D)
 
     def loss_flash(bias):
-        return jnp.sum(_flash2(q, q, q, bias, None, 0.0, scale, False,
+        return jnp.sum(_flash_bhtd(q, q, q, bias, None, 0.0, scale, False,
                                16, 16) ** 2)
 
     def loss_dense(bias):
@@ -230,9 +245,9 @@ def test_fused_backward_matches_twopass_and_dense(causal):
             lambda q, k, v: jnp.sum(fn(q, k, v) ** 2), argnums=(0, 1, 2))
 
     # block_k=256 >= T -> fused; block_k=64 -> two-pass (n_k=3)
-    gf = loss(lambda q, k, v: _flash2(q, k, v, None, None, 0.0, scale,
+    gf = loss(lambda q, k, v: _flash_bhtd(q, k, v, None, None, 0.0, scale,
                                       causal, 64, 256))(q, k, v)
-    gt = loss(lambda q, k, v: _flash2(q, k, v, None, None, 0.0, scale,
+    gt = loss(lambda q, k, v: _flash_bhtd(q, k, v, None, None, 0.0, scale,
                                       causal, 64, 64))(q, k, v)
     gd = loss(lambda q, k, v: _dense_reference(q, k, v, scale,
                                                causal))(q, k, v)
@@ -256,11 +271,71 @@ def test_fused_backward_bias_grad_matches_dense():
     scale = 1.0 / D ** 0.5
 
     gf = jax.grad(lambda b_: jnp.sum(
-        _flash2(q, q, q, b_, None, 0.0, scale, False, 48, 128) ** 2))(bias)
+        _flash_bhtd(q, q, q, b_, None, 0.0, scale, False, 48, 128) ** 2))(bias)
     gd = jax.grad(lambda b_: jnp.sum(
         _dense_reference(q, q, q, scale, False, b_) ** 2))(bias)
     onp.testing.assert_allclose(onp.asarray(gf), onp.asarray(gd),
                                 rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("heads,dim,group", [
+    (16, 64, 2), (20, 64, 2), (3, 64, 3), (16, 128, 1), (128, 128, 1),
+    (12, 32, 4), (2, 8, 2)])
+def test_head_group_is_the_fewest_heads_that_fill_whole_lane_tiles(
+        heads, dim, group):
+    assert head_group(heads, dim) == group
+
+
+@pytest.mark.parametrize("T", [128, 512])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("heads,dim", [(16, 64), (20, 64), (3, 64),
+                                       (16, 128)])
+def test_projection_layout_matches_dense(heads, dim, dtype, causal, T):
+    """The kernels fed as a model feeds them — (B, T, H·D) projections
+    seen as (B, T, H, D), a (B, 1, 1, Tk) key-padding bias — against the
+    dense reference, values and ``jax.grad``: pairs of 64-wide heads,
+    single 128-wide heads and a head count with no pair (one group of
+    all three)."""
+    B = 2
+    rng = onp.random.RandomState(heads * dim + T)
+    q, k, v, w = (jnp.asarray(rng.normal(0, 1, (B, T, heads * dim)), dtype)
+                  for _ in range(4))
+    keep = onp.ones((B, 1, 1, T), bool)
+    keep[1, :, :, -T // 4:] = False             # one row's last quarter
+    bias = jnp.asarray(onp.where(keep, 0.0, -1e9), dtype)
+
+    def split(a):
+        return a.reshape(B, T, heads, dim)
+
+    def flash(q, k, v):
+        return flash_attention(split(q), split(k), split(v), causal=causal,
+                               bias=bias, bias_grad=False
+                               ).reshape(B, T, heads * dim)
+
+    def dense(q, k, v):
+        t = lambda a: jnp.swapaxes(split(a), 1, 2)      # noqa: E731
+        out = _dense_reference(t(q), t(k), t(v), dim ** -0.5, causal, bias)
+        return jnp.swapaxes(out, 1, 2).reshape(B, T, heads * dim)
+
+    def vg(f):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: (f(q, k, v).astype(jnp.float32)
+                             * w.astype(jnp.float32)).sum(),
+            argnums=(0, 1, 2)))
+
+    # the file's own tolerances: float32 as the dense-parity cases above,
+    # bfloat16 as the public entry's
+    tol = (dict(rtol=5e-2, atol=5e-2) if dtype == jnp.bfloat16
+           else dict(rtol=3e-3, atol=1e-4))
+    as32 = lambda a: onp.asarray(a.astype(jnp.float32))  # noqa: E731
+    onp.testing.assert_allclose(as32(flash(q, k, v)), as32(dense(q, k, v)),
+                                **tol)
+    (_, gf), (_, gd) = vg(flash)(q, k, v), vg(dense)(q, k, v)
+    for a, b, name in zip(gf, gd, "qkv"):
+        assert a.shape == (B, T, heads * dim) and a.dtype == dtype
+        onp.testing.assert_allclose(as32(a), as32(b), err_msg=f"d{name}",
+                                    **tol)
 
 
 # ---------------------------------------------------------------------------
@@ -387,6 +462,39 @@ def one_v5e(monkeypatch):
     except Exception as e:      # noqa: BLE001 - whatever stops the describe
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+
+
+@pytest.mark.parametrize("shape,dtype,causal,block_q,grad,calls", [
+    ((16, 512, 16, 64), jnp.bfloat16, False, 512, True, 2),    # bert_large
+    ((1, 1024, 20, 64), jnp.float32, True, 256, False, 1),     # gpt2_774m
+    ((1, 1024, 16, 128), jnp.bfloat16, True, 256, False, 1),   # ouro_2_6b
+    ((1, 1024, 128, 128), jnp.bfloat16, False, 256, False, 1),  # command_a+
+])
+def test_flash_compiles_for_v5e_with_no_copy_of_a_projection(
+        one_v5e, shape, dtype, causal, block_q, grad, calls):
+    """At the cells' shapes, fed from (B, T, H·D) with the key-padding
+    bias: Mosaic takes the kernels (one forward, one fused backward),
+    and the compiled program neither copies nor transposes an array the
+    size of a projection on their way in or out (the parent's did 12
+    times a layer: CHANGES.md, PR 36)."""
+    import chip_smoke
+    B, T, H, D = shape
+    bias = jnp.zeros((B, 1, 1, T), dtype)
+
+    def fn(q, k, v):
+        split = lambda a: a.reshape(B, T, H, D)         # noqa: E731
+        return flash_attention(split(q), split(k), split(v), causal=causal,
+                               block_q=block_q, bias=bias, bias_grad=False
+                               ).reshape(B, T, H * D)
+
+    if grad:
+        hlo = _grad_hlo(fn, one_v5e.mesh, one_v5e.spec, (B, T, H * D),
+                        dtype)
+    else:
+        x = jax.ShapeDtypeStruct((B, T, H * D), dtype, sharding=one_v5e)
+        hlo = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert len(_kernel_calls(hlo)) == calls
+    assert chip_smoke.cache_sized_relayouts(hlo, B * T * H * D) == []
 
 
 def test_decode_attention_compiles_for_v5e_at_the_serving_cells_shapes(
