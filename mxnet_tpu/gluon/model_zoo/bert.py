@@ -14,6 +14,8 @@ from __future__ import annotations
 import math
 from typing import Any, List, Optional, Tuple
 
+import jax
+
 from ... import npx
 from ... import numpy as mxnp
 from ...ndarray.ndarray import NDArray
@@ -43,18 +45,25 @@ class BERTEncoderLayer(HybridBlock):
         self._dropout = dropout
 
     def forward(self, x: NDArray, mask: Optional[NDArray] = None) -> NDArray:
-        qkv = self.attn_qkv(x)  # (B, T, 3C)
-        q, k, v = mxnp.split(qkv, 3, axis=-1)
+        # the scopes of tracing.COMPONENTS; post-LN, so each LayerNorm
+        # is the epilogue of the projection before it
+        with jax.named_scope("attn/qkv"):
+            qkv = self.attn_qkv(x)  # (B, T, 3C)
+            q, k, v = mxnp.split(qkv, 3, axis=-1)
         att = npx.multi_head_attention(q, k, v, self._num_heads, mask=mask,
                                        dropout=self._dropout)
-        att = self.attn_out(att)
-        if self._dropout:
-            att = npx.dropout(att, self._dropout)
-        x = self.ln1(x + att)
-        ffn = self.ffn2(npx.gelu(self.ffn1(x)))
-        if self._dropout:
-            ffn = npx.dropout(ffn, self._dropout)
-        return self.ln2(x + ffn)
+        with jax.named_scope("attn/out"):
+            att = self.attn_out(att)
+            if self._dropout:
+                att = npx.dropout(att, self._dropout)
+            x = self.ln1(x + att)
+        with jax.named_scope("ffn/up"):
+            ffn = npx.gelu(self.ffn1(x))
+        with jax.named_scope("ffn/down"):
+            ffn = self.ffn2(ffn)
+            if self._dropout:
+                ffn = npx.dropout(ffn, self._dropout)
+            return self.ln2(x + ffn)
 
 
 class BERTEncoder(HybridBlock):
@@ -83,12 +92,13 @@ class BERTEncoder(HybridBlock):
                 (self._max_length, self._units))
         T = x.shape[1]
         from ...ndarray import ops
-        pos = ops.slice_axis(self.position_weight.data(), axis=0,
-                             begin=0, end=T)
-        x = x + pos.expand_dims(0)
-        x = self.ln(x)
-        if self._dropout:
-            x = npx.dropout(x, self._dropout)
+        with jax.named_scope("embed"):
+            pos = ops.slice_axis(self.position_weight.data(), axis=0,
+                                 begin=0, end=T)
+            x = x + pos.expand_dims(0)
+            x = self.ln(x)
+            if self._dropout:
+                x = npx.dropout(x, self._dropout)
         # activation checkpointing per layer under MXNET_REMAT
         from ..block import remat_stack
         return remat_stack(list(self.layers), x, mask,
@@ -151,27 +161,32 @@ class BERTModel(HybridBlock):
                 token_types: Optional[NDArray] = None,
                 valid_length: Optional[NDArray] = None,
                 masked_positions: Optional[NDArray] = None):
-        x = self.word_embed(inputs)
-        if token_types is not None:
-            x = x + self.token_type_embed(token_types)
-        mask = self._attention_mask(inputs, valid_length)
+        with jax.named_scope("embed"):
+            x = self.word_embed(inputs)
+            if token_types is not None:
+                x = x + self.token_type_embed(token_types)
+            mask = self._attention_mask(inputs, valid_length)
         seq = self.encoder(x, mask)
 
         outputs: List[Any] = [seq]
         if self.pooler is not None:
             from ...ndarray import ops
-            cls = ops.slice_axis(seq, axis=1, begin=0, end=1).squeeze(1)
-            outputs.append(self.pooler(cls))
+            with jax.named_scope("head"):
+                cls = ops.slice_axis(seq, axis=1, begin=0,
+                                     end=1).squeeze(1)
+                outputs.append(self.pooler(cls))
         if self.mlm_transform is not None and masked_positions is not None:
             if not self.mlm_bias.is_initialized:
                 self.mlm_bias._finish_deferred_init(self.mlm_bias.shape)
-            gathered = npx.take_positions(seq, masked_positions)
-            h = npx.gelu(self.mlm_transform(gathered))
-            h = self.mlm_ln(h)
-            logits = mxnp.dot(h.reshape(-1, self._units),
-                              self.word_embed.weight.data().T)
-            logits = logits + self.mlm_bias.data()
-            logits = logits.reshape(gathered.shape[0], gathered.shape[1], -1)
+            with jax.named_scope("head"):
+                gathered = npx.take_positions(seq, masked_positions)
+                h = npx.gelu(self.mlm_transform(gathered))
+                h = self.mlm_ln(h)
+                logits = mxnp.dot(h.reshape(-1, self._units),
+                                  self.word_embed.weight.data().T)
+                logits = logits + self.mlm_bias.data()
+                logits = logits.reshape(gathered.shape[0],
+                                        gathered.shape[1], -1)
             outputs.append(logits)
         return tuple(outputs) if len(outputs) > 1 else outputs[0]
 

@@ -213,8 +213,10 @@ def _f32(x):
     return x.astype(jnp.float32)
 
 
+@jax.named_scope("norm")
 def _ln(x, g, eps):
-    """Bias-free LayerNorm in float32, handed back in x's dtype."""
+    """Bias-free LayerNorm in float32, handed back in x's dtype.  ONE
+    feeds a layer's three branches, so it stands alone (``norm``)."""
     h = _f32(x)
     mean = jnp.mean(h, axis=-1, keepdims=True)
     var = jnp.var(h, axis=-1, keepdims=True)
@@ -254,6 +256,7 @@ def rope(x, pos, theta: float, pairing: str = "interleaved"):
     return (h * jnp.cos(angle) + partner * jnp.sin(angle)).astype(x.dtype)
 
 
+@jax.named_scope("attn/qkv")
 def qkv(p, h, pos, kind: str, cfg):
     """``h (T, w)`` -> q ``(T, heads, d)``, k and v ``(T, kv heads, d)``
     in h's dtype; q and k rotated at ``pos`` on a window layer, as the
@@ -277,6 +280,7 @@ def _use_flash(T: int) -> bool:
     return _use_pallas_len(T)
 
 
+@jax.named_scope("attn/core")
 def attention_seq(q, k, v, cfg, window: Optional[int]):
     """Causal grouped attention of T queries over the T rows before and
     at them (``window`` of them where given): (T, heads d) float32."""
@@ -303,6 +307,7 @@ def attention_seq(q, k, v, cfg, window: Optional[int]):
     return out.reshape(T, nq * d)
 
 
+@jax.named_scope("experts/shared")
 def shared_experts(p, h):
     """The mean of the always-on experts' outputs, (T, w) float32."""
     from ...parallel import moe as _moe
@@ -341,7 +346,8 @@ def forward_sequence(params, toks, t0, cfg):
     T = toks.shape[0]
     pos = jnp.arange(T)
     valid = pos < t0
-    x = params["embed"][toks]
+    with jax.named_scope("embed"):
+        x = params["embed"][toks]
     rows, loads = [], []
     for kind, p in zip(cfg["kinds"], params["layers"]):
         h = _ln(x, p["ln_g"], eps)
@@ -349,12 +355,16 @@ def forward_sequence(params, toks, t0, cfg):
         a = attention_seq(q, k, v, cfg,
                           cfg["window"] if kind == "window" else None)
         y, load = experts(p, h, cfg, valid)
-        x = x + (_mm(a.astype(h.dtype), p["out_w"]) + y).astype(x.dtype)
+        with jax.named_scope("attn/out"):
+            a = _mm(a.astype(h.dtype), p["out_w"])
+        x = x + (a + y).astype(x.dtype)
         rows.append((k, v))
         loads.append(load)
-    return _ln(x, params["lnf_g"], eps), rows, jnp.stack(loads)
+    with jax.named_scope("head"):
+        return _ln(x, params["lnf_g"], eps), rows, jnp.stack(loads)
 
 
+@jax.named_scope("head")
 def lm_logits(params, hidden, cfg):
     return cfg["logit_scale"] * _mm(hidden, params["embed"])
 

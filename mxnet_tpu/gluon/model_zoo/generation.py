@@ -94,18 +94,23 @@ def _block_prefill(p, x, nh: int, L: int, ga=(False, 1e-5)):
     the caches zero-padded to length L."""
     B, T, C = x.shape
     d = C // nh
-    h = _ln(x, p["ln1_g"], p["ln1_b"], eps)
-    qkv = h @ p["qkv_w"].T + p["qkv_b"]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    qh = q.reshape(B, T, nh, d)
-    kh = k.reshape(B, T, nh, d)
-    vh = v.reshape(B, T, nh, d)
-    out = jax.nn.dot_product_attention(qh, kh, vh, is_causal=True)
-    x = x + (out.reshape(B, T, C) @ p["out_w"].T + p["out_b"])
-    h = _ln(x, p["ln2_g"], p["ln2_b"], eps)
-    ffn = jax.nn.gelu(h @ p["f1_w"].T + p["f1_b"],
-                      approximate=gelu_approx)
-    x = x + (ffn @ p["f2_w"].T + p["f2_b"])
+    with jax.named_scope("attn/qkv"):
+        h = _ln(x, p["ln1_g"], p["ln1_b"], eps)
+        qkv = h @ p["qkv_w"].T + p["qkv_b"]
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        qh = q.reshape(B, T, nh, d)
+        kh = k.reshape(B, T, nh, d)
+        vh = v.reshape(B, T, nh, d)
+    with jax.named_scope("attn/core"):
+        out = jax.nn.dot_product_attention(qh, kh, vh, is_causal=True)
+    with jax.named_scope("attn/out"):
+        x = x + (out.reshape(B, T, C) @ p["out_w"].T + p["out_b"])
+    with jax.named_scope("ffn/up"):
+        h = _ln(x, p["ln2_g"], p["ln2_b"], eps)
+        ffn = jax.nn.gelu(h @ p["f1_w"].T + p["f1_b"],
+                          approximate=gelu_approx)
+    with jax.named_scope("ffn/down"):
+        x = x + (ffn @ p["f2_w"].T + p["f2_b"])
     pad = [(0, 0), (0, L - T), (0, 0), (0, 0)]
     return x, jnp.pad(kh, pad), jnp.pad(vh, pad)
 
@@ -117,24 +122,30 @@ def _block_step(p, x, ck, cv, pos, nh: int, ga=(False, 1e-5)):
     B, _, C = x.shape
     d = C // nh
     L = ck.shape[1]
-    h = _ln(x, p["ln1_g"], p["ln1_b"], eps)
-    qkv = h @ p["qkv_w"].T + p["qkv_b"]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    qh = q.reshape(B, 1, nh, d)
-    ck = lax.dynamic_update_slice_in_dim(ck, k.reshape(B, 1, nh, d),
-                                         pos, axis=1)
-    cv = lax.dynamic_update_slice_in_dim(cv, v.reshape(B, 1, nh, d),
-                                         pos, axis=1)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", qh, ck) / math.sqrt(d)
-    visible = jnp.arange(L) <= pos                  # static-shape mask
-    scores = jnp.where(visible[None, None, None, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, cv).reshape(B, 1, C)
-    x = x + (out @ p["out_w"].T + p["out_b"])
-    h = _ln(x, p["ln2_g"], p["ln2_b"], eps)
-    ffn = jax.nn.gelu(h @ p["f1_w"].T + p["f1_b"],
-                      approximate=gelu_approx)
-    x = x + (ffn @ p["f2_w"].T + p["f2_b"])
+    with jax.named_scope("attn/qkv"):
+        h = _ln(x, p["ln1_g"], p["ln1_b"], eps)
+        qkv = h @ p["qkv_w"].T + p["qkv_b"]
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        qh = q.reshape(B, 1, nh, d)
+    with jax.named_scope("cache/write"):
+        ck = lax.dynamic_update_slice_in_dim(ck, k.reshape(B, 1, nh, d),
+                                             pos, axis=1)
+        cv = lax.dynamic_update_slice_in_dim(cv, v.reshape(B, 1, nh, d),
+                                             pos, axis=1)
+    with jax.named_scope("attn/core"):
+        scores = jnp.einsum("bqhd,bkhd->bhqk", qh, ck) / math.sqrt(d)
+        visible = jnp.arange(L) <= pos              # static-shape mask
+        scores = jnp.where(visible[None, None, None, :], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhqk,bkhd->bqhd", probs, cv).reshape(B, 1, C)
+    with jax.named_scope("attn/out"):
+        x = x + (out @ p["out_w"].T + p["out_b"])
+    with jax.named_scope("ffn/up"):
+        h = _ln(x, p["ln2_g"], p["ln2_b"], eps)
+        ffn = jax.nn.gelu(h @ p["f1_w"].T + p["f1_b"],
+                          approximate=gelu_approx)
+    with jax.named_scope("ffn/down"):
+        x = x + (ffn @ p["f2_w"].T + p["f2_b"])
     return x, ck, cv
 
 

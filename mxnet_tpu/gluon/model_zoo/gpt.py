@@ -13,6 +13,8 @@ from __future__ import annotations
 
 from typing import Any, Optional
 
+import jax
+
 from ... import npx
 from ... import numpy as mxnp
 from ...ndarray.ndarray import NDArray
@@ -63,25 +65,34 @@ class GPTBlock(HybridBlock):
         self._dropout = dropout
 
     def forward(self, x: NDArray) -> NDArray:
-        h = self.ln1(x)
-        qkv = self.attn_qkv(h)
-        q, k, v = mxnp.split(qkv, 3, axis=-1)
+        # the scopes of tracing.COMPONENTS; pre-LN, so each LayerNorm
+        # is the prologue of the projection after it
+        with jax.named_scope("attn/qkv"):
+            h = self.ln1(x)
+            qkv = self.attn_qkv(h)
+            q, k, v = mxnp.split(qkv, 3, axis=-1)
         att = npx.multi_head_attention(q, k, v, self._num_heads,
                                        causal=True,
                                        dropout=self._dropout)
-        att = self.attn_out(att)
-        if self._dropout:
-            att = npx.dropout(att, self._dropout)
-        x = x + att
-        h = self.ln2(x)
+        with jax.named_scope("attn/out"):
+            att = self.attn_out(att)
+            if self._dropout:
+                att = npx.dropout(att, self._dropout)
+            x = x + att
+
+        def residual(ffn: NDArray) -> NDArray:
+            if self._dropout:
+                ffn = npx.dropout(ffn, self._dropout)
+            return x + ffn
+
         if self.moe is not None:
-            ffn = self.moe(h)
-        else:
-            ffn = self.ffn2(npx.gelu(self.ffn1(h),
-                                     approximate=self._gelu_approximate))
-        if self._dropout:
-            ffn = npx.dropout(ffn, self._dropout)
-        return x + ffn
+            with jax.named_scope("experts"):
+                return residual(self.moe(self.ln2(x)))
+        with jax.named_scope("ffn/up"):
+            ffn = npx.gelu(self.ffn1(self.ln2(x)),
+                           approximate=self._gelu_approximate)
+        with jax.named_scope("ffn/down"):
+            return residual(self.ffn2(ffn))
 
 
 class GPTModel(HybridBlock):
@@ -135,20 +146,22 @@ class GPTModel(HybridBlock):
         if not self.position_weight.is_initialized:
             self.position_weight._finish_deferred_init(
                 (self._max_length, self._units))
-        x = self.word_embed(tokens)
         from ...ndarray import ops
-        pos = ops.slice_axis(self.position_weight.data(), axis=0,
-                             begin=0, end=T)
-        x = x + pos.expand_dims(0)
-        if self._dropout:
-            x = npx.dropout(x, self._dropout)
+        with jax.named_scope("embed"):
+            x = self.word_embed(tokens)
+            pos = ops.slice_axis(self.position_weight.data(), axis=0,
+                                 begin=0, end=T)
+            x = x + pos.expand_dims(0)
+            if self._dropout:
+                x = npx.dropout(x, self._dropout)
         # activation checkpointing per block under MXNET_REMAT
         from ..block import remat_stack
         x = remat_stack(list(self.blocks), x, dropout=self._dropout)
-        x = self.ln_f(x)
-        # weight-tied LM head: logits = x @ E^T
-        w = self.word_embed.weight.data()
-        return mxnp.matmul(x, w.T)
+        with jax.named_scope("head"):
+            x = self.ln_f(x)
+            # weight-tied LM head: logits = x @ E^T
+            w = self.word_embed.weight.data()
+            return mxnp.matmul(x, w.T)
 
     def generate(self, tokens, max_new_tokens: int,
                  method: str = "greedy", temperature: float = 1.0,

@@ -198,6 +198,7 @@ def residual(x, branch, g, eps):
     return x + _rms(branch, g, eps).astype(x.dtype)
 
 
+@jax.named_scope("attn/qkv")
 def qkv(p, x, pos, cfg):
     """The attention branch up to its products: ``x (T, w)`` -> q, k, v
     ``(T, heads, d)`` in x's dtype, q and k rotated at ``pos``, as the
@@ -214,14 +215,18 @@ def finish(p, x, a, cfg):
     """The rest of a layer from the heads' reads ``a (T, heads d)``:
     the output projection and its norm, then the MLP between its two."""
     eps = cfg["rms_norm_eps"]
-    x = residual(x, _mm(a.astype(x.dtype), p["out_w"]), p["norm_g"][1],
-                 eps)
-    h = _rms(x, p["norm_g"][2], eps).astype(x.dtype)
-    gate, up = jnp.split(_mm(h, p["gate_up_w"]), 2, axis=-1)
-    m = _mm((jax.nn.silu(gate) * up).astype(x.dtype), p["down_w"])
-    return residual(x, m, p["norm_g"][3], eps)
+    with jax.named_scope("attn/out"):
+        x = residual(x, _mm(a.astype(x.dtype), p["out_w"]),
+                     p["norm_g"][1], eps)
+    with jax.named_scope("ffn/up"):
+        h = _rms(x, p["norm_g"][2], eps).astype(x.dtype)
+        gate, up = jnp.split(_mm(h, p["gate_up_w"]), 2, axis=-1)
+        h = (jax.nn.silu(gate) * up).astype(x.dtype)
+    with jax.named_scope("ffn/down"):
+        return residual(x, _mm(h, p["down_w"]), p["norm_g"][3], eps)
 
 
+@jax.named_scope("attn/core")
 def attention_seq(q, k, v):
     """Causal attention of T queries over the T rows before and at
     them, head ``n`` on head ``n``: (T, heads d) float32."""
@@ -238,6 +243,7 @@ def attention_seq(q, k, v):
                       preferred_element_type=jnp.float32).reshape(T, n * d)
 
 
+@jax.named_scope("norm")
 def loop_output(params, x, t, cfg):
     """What loop step ``t`` hands on: the final norm's output, which is
     ``z_t`` and the state step ``t + 1`` starts from."""
@@ -261,8 +267,9 @@ def forward_sequence(params, toks, cfg):
         x = loop_output(params, x, t, cfg)
         return x, (x, rows)
 
-    _, (z, (k, v)) = lax.scan(step, params["embed"][toks],
-                              jnp.arange(cfg["loop_steps"]))
+    with jax.named_scope("embed"):
+        x = params["embed"][toks]
+    _, (z, (k, v)) = lax.scan(step, x, jnp.arange(cfg["loop_steps"]))
     return z, k, v
 
 
@@ -292,6 +299,7 @@ def exit_step(probs, threshold: float):
                      last).astype(jnp.int32)
 
 
+@jax.named_scope("head")
 def lm_logits(params, hidden):
     """The untied head, float32."""
     return _mm(hidden, params["head"])

@@ -252,8 +252,11 @@ def _f32(x):
     return x.astype(jnp.float32)
 
 
+@jax.named_scope("norm")
 def _ln(x, g, b, eps):
-    """LayerNorm computed in float32, handed back in x's dtype."""
+    """LayerNorm computed in float32, handed back in x's dtype.  Its
+    scope counts only where it is called outside every other component
+    (tracing.COMPONENTS: the first word of a path is the component)."""
     h = _f32(x)
     mean = jnp.mean(h, axis=-1, keepdims=True)
     var = jnp.var(h, axis=-1, keepdims=True)
@@ -268,9 +271,11 @@ def _mm(x, w):
 
 
 def _mlp(p, x):
-    gate, up = jnp.split(_mm(x, p["mlp_w1"]), 2, axis=-1)
-    return _mm((up * jax.nn.silu(gate)).astype(x.dtype),
-               p["mlp_w2"]).astype(x.dtype)
+    with jax.named_scope("ffn/up"):
+        gate, up = jnp.split(_mm(x, p["mlp_w1"]), 2, axis=-1)
+        h = (up * jax.nn.silu(gate)).astype(x.dtype)
+    with jax.named_scope("ffn/down"):
+        return _mm(h, p["mlp_w2"]).astype(x.dtype)
 
 
 def _ssm_inputs(p, uc, cfg):
@@ -283,6 +288,7 @@ def _ssm_inputs(p, uc, cfg):
     return dt, dbc[..., r:r + n], dbc[..., r + n:]
 
 
+@jax.named_scope("ssm")
 def _mamba_seq(p, x, t0, cfg, unroll: int = 8):
     """Mamba-1 over a sequence padded to ``T`` whose real length is the
     traced ``t0``: returns (y (T, w), memory m (T, d_inner) float32,
@@ -341,27 +347,43 @@ def _diff_attn_seq(p, q, k, v, depth, cfg, window: Optional[int]):
     """Differential attention of T queries over the T rows before and
     at them (``window`` rows where given); q (T, w), k and v (T, kv)."""
     T = q.shape[0]
-    qh, kh, vh = _split_heads(q, k, v, cfg)
-    scores = jnp.einsum("tngjd,snjd->ngjts", qh, kh,
-                        preferred_element_type=jnp.float32) \
-        / math.sqrt(cfg["head_dim"])
-    t, s = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
-    keep = s <= t
-    if window is not None:
-        keep &= s > t - window
-    probs = jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
-    a = jnp.einsum("ngjts,sne->tngje", probs.astype(v.dtype), vh,
-                   preferred_element_type=jnp.float32)
-    out = _diff_combine(p, a, depth, cfg["layer_norm_eps"])
-    return _mm(out.reshape(T, -1).astype(q.dtype), p["out_w"]) \
-        + _f32(p["out_b"])
+    with jax.named_scope("attn/core"):
+        qh, kh, vh = _split_heads(q, k, v, cfg)
+        scores = jnp.einsum("tngjd,snjd->ngjts", qh, kh,
+                            preferred_element_type=jnp.float32) \
+            / math.sqrt(cfg["head_dim"])
+        t, s = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+        keep = s <= t
+        if window is not None:
+            keep &= s > t - window
+        probs = jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+        a = jnp.einsum("ngjts,sne->tngje", probs.astype(v.dtype), vh,
+                       preferred_element_type=jnp.float32)
+    with jax.named_scope("attn/out"):
+        out = _diff_combine(p, a, depth, cfg["layer_norm_eps"])
+        return _mm(out.reshape(T, -1).astype(q.dtype), p["out_w"]) \
+            + _f32(p["out_b"])
 
 
+@jax.named_scope("attn/qkv")
 def _qkv(p, x, cfg):
     w = cfg["units"]
     kv = cfg["num_kv_heads"] * cfg["head_dim"]
     qkv = (_mm(x, p["qkv_w"]) + _f32(p["qkv_b"])).astype(x.dtype)
     return qkv[..., :w], qkv[..., w:w + kv], qkv[..., w + kv:]
+
+
+@jax.named_scope("ssm")
+def _gmu(p, h, memory):
+    """A Gated Memory Unit: the last Mamba layer's memory, gated."""
+    gate = jax.nn.silu(_mm(h, p["in_w"]))
+    return _mm((memory * gate).astype(h.dtype), p["out_w"])
+
+
+@jax.named_scope("attn/qkv")
+def _cross_q(p, h):
+    """The query of a layer that reads the full layer's K and V."""
+    return (_mm(h, p["q_w"]) + _f32(p["q_b"])).astype(h.dtype)
 
 
 def forward_sequence(params, toks, t0, cfg):
@@ -371,7 +393,8 @@ def forward_sequence(params, toks, t0, cfg):
     state)``, ``("window" | "full", k, v)`` with all T rows, or
     ``None``."""
     eps = cfg["layer_norm_eps"]
-    x = params["embed"][toks]
+    with jax.named_scope("embed"):
+        x = params["embed"][toks]
     memory = shared = None
     cached: List[Any] = []
     for depth, (kind, p) in enumerate(zip(cfg["kinds"], params["layers"])):
@@ -381,10 +404,9 @@ def forward_sequence(params, toks, t0, cfg):
             y, memory, tail, state = _mamba_seq(p, h, t0, cfg)
             entry = ("mamba", tail, state)
         elif kind == "gmu":
-            gate = jax.nn.silu(_mm(h, p["in_w"]))
-            y = _mm((memory * gate).astype(h.dtype), p["out_w"])
+            y = _gmu(p, h, memory)
         elif kind == "cross":
-            q = (_mm(h, p["q_w"]) + _f32(p["q_b"])).astype(h.dtype)
+            q = _cross_q(p, h)
             y = _diff_attn_seq(p, q, *shared, depth, cfg, None)
         else:
             q, k, v = _qkv(p, h, cfg)
@@ -396,10 +418,12 @@ def forward_sequence(params, toks, t0, cfg):
         cached.append(entry)
         x = x + y.astype(x.dtype)
         x = x + _mlp(p, _ln(x, p["ln2_g"], p["ln2_b"], eps))
-    return _ln(x, params["lnf_g"], params["lnf_b"], eps), cached
+    with jax.named_scope("head"):
+        return _ln(x, params["lnf_g"], params["lnf_b"], eps), cached
 
 
 def forward_logits(params, toks, cfg):
     """(T,) token ids -> (T, vocab) float32 logits."""
     hidden, _ = forward_sequence(params, toks, toks.shape[0], cfg)
-    return _mm(hidden, params["embed"])
+    with jax.named_scope("head"):
+        return _mm(hidden, params["embed"])

@@ -458,6 +458,7 @@ class MetricsRegistry:
         _OP_CHILDREN.clear()
         _BULK_REASON_CHILDREN.clear()
         _BWD_SEG_CHILDREN.clear()
+        _BUILD_STAGE_CHILDREN.clear()
 
     def dump_json(self) -> Dict[str, Any]:
         with self._lock:
@@ -570,6 +571,14 @@ COMPILE_PERSISTENT_HITS = counter(
 COMPILE_SECONDS = histogram(
     "mxnet_compile_seconds",
     "Wall time of XLA backend compilations (jax.monitoring).")
+PROGRAM_BUILD_SECONDS = histogram(
+    "mxnet_program_build_seconds",
+    "Wall time of each stage of getting a compiled program, as jax "
+    "reports it: 'trace' (Python tracing to a jaxpr), 'lower' (jaxpr to "
+    "an MLIR module), 'compile' (the XLA backend) or 'load' (the same "
+    "call answered from jax's persistent cache). By program and role: "
+    "mxnet_tpu.tracing.programs().",
+    labels=("stage",))
 EXEC_CACHE_SIZE = gauge(
     "mxnet_exec_cache_size",
     "Entries in the per-op executable cache (ndarray.register).")
@@ -1040,6 +1049,9 @@ def inc_backward_segment(reason: str) -> None:
 # ---------------------------------------------------------------------------
 
 _JAX_HOOK = {"installed": False}
+# Hot-path cache for the build-stage histogram: jax reports every inner
+# jit of a program it traces, tens of thousands of events a large step.
+_BUILD_STAGE_CHILDREN: Dict[str, Any] = {}
 _HOOK_TLS = threading.local()
 
 
@@ -1056,14 +1068,30 @@ def _install_jax_hooks() -> None:
         if event == "/jax/compilation_cache/cache_hits":
             _HOOK_TLS.cache_hit = True
 
+    from . import tracing
+    stages = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+              "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+              "/jax/core/compile/backend_compile_duration": "compile"}
+
     def _on_duration(event: str, duration: float, **kw: Any) -> None:
-        if event.endswith("backend_compile_duration"):
+        stage = stages.get(event)
+        if stage is None:
+            return
+        if stage == "compile":
             if getattr(_HOOK_TLS, "cache_hit", False):
                 _HOOK_TLS.cache_hit = False
                 COMPILE_PERSISTENT_HITS.inc()
+                stage = "load"
             else:
                 COMPILE_MISSES.inc()
                 COMPILE_SECONDS.observe(duration)
+        child = _BUILD_STAGE_CHILDREN.get(stage)
+        if child is None:
+            child = _BUILD_STAGE_CHILDREN[stage] = \
+                PROGRAM_BUILD_SECONDS.labels(stage=stage)
+        child.observe(duration)
+        if "fun_name" in kw:
+            tracing.note_build(stage, str(kw["fun_name"]), duration)
 
     _mon.register_event_listener(_on_event)
     _mon.register_event_duration_secs_listener(_on_duration)

@@ -97,7 +97,7 @@ def dot_product_attention(query, key, value, mask=None,
     blk_q = _flash_block("Q", seq=inputs[0].shape[1])
     blk_k = _flash_block("K")
 
-    def impl(q, k, v, *rest):
+    def attend(q, k, v, *rest):
         rest = list(rest)
         seed = rest.pop() if train_rate > 0.0 else None
         bias = None
@@ -130,6 +130,10 @@ def dot_product_attention(query, key, value, mask=None,
             return jnp.swapaxes(out, 1, 2)
         return jax.nn.dot_product_attention(
             q, k, v, bias=bias, scale=sc, is_causal=cz)
+
+    def impl(*arrays):
+        with jax.named_scope("attn/core"):
+            return attend(*arrays)
 
     return invoke("dot_product_attention", impl, inputs)
 
@@ -330,7 +334,7 @@ def multi_head_attention(query, key, value, num_heads: int, mask=None,
     blk_q = _flash_block("Q", seq=inputs[0].shape[1])
     blk_k = _flash_block("K")
 
-    def impl(q, k, v, *rest):
+    def attend(q, k, v, *rest):
         rest = list(rest)
         seed = rest.pop() if train_rate > 0.0 else None
         B, Tq, C = q.shape
@@ -367,6 +371,10 @@ def multi_head_attention(query, key, value, num_heads: int, mask=None,
             out = jax.nn.dot_product_attention(qh, kh, vh, bias=bias,
                                                scale=sc, is_causal=cz)
         return out.reshape(B, Tq, C)
+
+    def impl(*arrays):
+        with jax.named_scope("attn/core"):
+            return attend(*arrays)
 
     return invoke("multi_head_attention", impl, inputs)
 

@@ -216,6 +216,7 @@ class MoEDense(HybridBlock):
 # the dropless layer: pure functions over (T, d) tokens
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("experts/route")
 def route(h, router_w, top_k: int, held, valid=None):
     """Sigmoid top-k routing over ALL the experts, for a caller that
     holds the experts ``held = (lo, hi)`` of them.
@@ -249,6 +250,7 @@ def _swiglu(gate_up, dtype):
     return (jax.nn.silu(gate) * up).astype(dtype)
 
 
+@jax.named_scope("experts/routed")
 def dense_experts(h, local, weights, w_in, w_out):
     """The held experts' part of the output as ONE batched product over
     all of them, every token through every held expert and weighted 0
@@ -288,6 +290,7 @@ def _gmm(rows, w, sizes):
         interpret=_interpret())
 
 
+@jax.named_scope("experts/routed")
 def grouped_experts(h, local, weights, load, w_in, w_out):
     """The same part by segments: the (token, choice) pairs sorted by
     held expert, the absent ones last, and one grouped product over the

@@ -304,7 +304,7 @@ class SPMDTrainer:
 
         donate = (0, 1) if self._donate else ()
         if not self._donate_inputs:
-            return jax.jit(step, donate_argnums=donate)
+            return self._program(step, donate)
         # batch args start at position 6; n_inputs data arrays plus
         # the label array.  Batch buffers rarely alias an output shape
         # (params/states/loss) — the donation win is the EARLY release
@@ -317,7 +317,15 @@ class SPMDTrainer:
         _warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
         donate = donate + tuple(range(6, 6 + n_inputs + 1))
-        return jax.jit(step, donate_argnums=donate)
+        return self._program(step, donate)
+
+    def _program(self, fn: Callable, donate: Tuple[int, ...]) -> Callable:
+        """The compiled step, with its line in ``tracing.programs()``."""
+        return _tracing.program(
+            fn, "train_step", type(self.block).__name__,
+            attrs={"optimizer": type(self.optimizer).__name__,
+                   "devices": int(self.mesh.size)},
+            donate_argnums=donate)
 
     def _build_step_body(self, n_inputs: int,
                          health_gate: bool = False) -> Callable:
@@ -356,14 +364,15 @@ class SPMDTrainer:
                     finally:
                         set_training(prev)
                     out = self._output_transform(out)
-                    loss = loss_fn(out, from_jax(labels))
-                    # loss is already MEAN-reduced here, so grads need no
-                    # 1/batch rescale (unlike the Trainer path, which
-                    # rescales summed per-sample grads)
-                    total = loss.mean()._data
-                    # MoE load-balancing terms raised during forward
-                    for a in aux_losses:
-                        total = total + a._data
+                    with jax.named_scope("loss"):
+                        loss = loss_fn(out, from_jax(labels))
+                        # loss is already MEAN-reduced here, so grads
+                        # need no 1/batch rescale (unlike the Trainer
+                        # path, which rescales summed per-sample grads)
+                        total = loss.mean()._data
+                        # MoE load-balancing terms raised during forward
+                        for a in aux_losses:
+                            total = total + a._data
                     # in-trace writes to non-differentiable state (BN
                     # running stats), read BEFORE _bind_params restores
                     from ..gluon.block import _collect_mutated
@@ -433,8 +442,9 @@ class SPMDTrainer:
                         new_params.append(w)
                         new_states.append(st)
                     else:
-                        nw, ns = opt_cls._step(w, g, st, lr, wd, t,
-                                               hp[i])
+                        with jax.named_scope("optim"):
+                            nw, ns = opt_cls._step(w, g, st, lr, wd, t,
+                                                   hp[i])
                         new_params.append(nw)
                         new_states.append(ns)
                 return new_params, new_states
@@ -479,8 +489,7 @@ class SPMDTrainer:
                 (keys, lrs, wds) + tuple(xs) + (ys,))
             return params, states, losses
 
-        donate = (0, 1) if self._donate else ()
-        return jax.jit(multi, donate_argnums=donate)
+        return self._program(multi, (0, 1) if self._donate else ())
 
     def _raw_step(self, n_inputs: int) -> Callable:
         """The unjitted single-step body (shared by step and multi-step)."""

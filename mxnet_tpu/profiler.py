@@ -10,7 +10,9 @@ surface ``python/mxnet/profiler.py`` (``set_config``/``set_state``/
 Design (tpu-first): ops are instrumented at the one dispatch point
 (``ndarray.register.invoke``); device-side detail comes from wrapping the
 XLA profiler (``start_xla_trace``/``stop_xla_trace`` → TensorBoard xplane,
-the TPU analog of the reference's NVTX emitter). Eager timings measure
+the TPU analog of the reference's NVTX emitter), which
+``device_summary`` reduces to device seconds by program role and by
+component of the model (``tracing.COMPONENTS``). Eager timings measure
 dispatch by default (the reference likewise measures engine-op execution,
 not python); set ``MXNET_PROFILER_SYNC=1`` to block per op and capture
 true device latency.
@@ -19,9 +21,10 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from .base import MXNetError, getenv, register_env
 
@@ -29,7 +32,8 @@ __all__ = ["set_config", "set_state", "start", "stop", "pause", "resume",
            "dump", "dumps", "reset", "state",
            "ProfileDomain", "ProfileTask", "ProfileEvent", "ProfileCounter",
            "ProfileFrame", "ProfileMarker", "scope",
-           "start_xla_trace", "stop_xla_trace"]
+           "start_xla_trace", "stop_xla_trace", "device_summary",
+           "summarize_planes"]
 
 register_env("MXNET_PROFILER_AUTOSTART", 0,
              "Start the profiler at import time (1 = on).")
@@ -335,6 +339,160 @@ def stop_xla_trace() -> Optional[str]:
     jax.profiler.stop_trace()
     d, _xla_trace_dir = _xla_trace_dir, None
     return d
+
+
+_DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+
+
+def device_summary(xplane_path: str, programs: Optional[List[Any]] = None,
+                   window: Optional[Tuple[int, int]] = None
+                   ) -> Dict[str, Any]:
+    """Reduce a device trace to seconds by program role and by component.
+
+    ``xplane_path`` is an ``.xplane.pb`` or a directory that holds one
+    (the log directory of ``start_xla_trace``: the newest is read).
+    Each "XLA Ops" event is looked up in the program whose "XLA
+    Modules" event encloses it on that device, so two programs that
+    both have a ``fusion.1`` are told apart; the instruction's
+    component comes from ``Program.scopes`` of ``programs`` (the
+    process's own ``tracing.programs()`` by default: a v5e trace's
+    events carry no ``op_name``, only the instruction's text), which
+    costs a lowering and a cache hit a compiled shape.  For a builder
+    after a traced run, never on a hot path.  ``window`` (start_ns,
+    end_ns on the trace's clock) cuts the events to it.
+    :func:`summarize_planes` describes the result."""
+    import glob
+    from jax.profiler import ProfileData
+    if os.path.isdir(xplane_path):
+        files = glob.glob(os.path.join(xplane_path, "**", "*.xplane.pb"),
+                          recursive=True)
+        if not files:
+            raise MXNetError(f"no .xplane.pb under {xplane_path}")
+        xplane_path = max(files, key=os.path.getmtime)
+    planes: Dict[str, Dict[str, List[Tuple[str, int, int]]]] = {}
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if _DEVICE_PLANE.match(plane.name):
+            planes[plane.name] = {
+                line.name: [(ev.name, int(ev.start_ns),
+                             int(ev.duration_ns)) for ev in line.events]
+                for line in plane.lines
+                if line.name in ("XLA Ops", "XLA Modules")}
+    return summarize_planes(planes, programs, window)
+
+
+def summarize_planes(planes: Dict[str, Dict[str, List[Tuple[str, int,
+                                                            int]]]],
+                     programs: Optional[List[Any]] = None,
+                     window: Optional[Tuple[int, int]] = None
+                     ) -> Dict[str, Any]:
+    """:func:`device_summary` on plain data, ``{"/device:TPU:0": {"XLA
+    Ops": [(instruction text or name, start_ns, dur_ns)], "XLA Modules":
+    [("jit__step(<fingerprint>)", start_ns, dur_ns)]}}``.
+
+    Returns seconds meaned over the devices: ``ops_s`` (every leaf
+    instruction: a ``while``, ``call`` or ``conditional`` encloses its
+    children on the line and is left out); ``by_role`` {role: seconds};
+    ``by_component`` {"ffn/up fwd": seconds, ..., "unscoped fwd": ...};
+    ``by_program`` {module: {"role", "runs", "seconds",
+    "by_component"}}; ``mixed_s``, the seconds in fusions whose
+    instructions span more than one component (booked by the fusion
+    rule of ``tracing.hlo_scopes``), and ``mixed_pct`` of ``ops_s``.
+    A module with several compiled shapes is read with the shape whose
+    instructions name most of what ran under that fingerprint."""
+    import bisect
+    from . import tracing
+    if programs is None:
+        programs = tracing.programs()
+    by_module: Dict[str, List[Any]] = {}
+    for prog in programs:
+        if prog.role is not None:
+            by_module.setdefault(prog.module, []).append(prog)
+    read: Dict[Tuple[int, int], Any] = {}
+
+    def scopes_for(module: str, names: Any) -> Tuple[Any, Any]:
+        """(scopes, mixed) of the compiled shape of ``module`` whose
+        instructions name most of ``names``: the newest first, and no
+        further than one that names them all."""
+        best: Tuple[Any, Any] = ({}, set())
+        for prog in by_module.get(module, []):
+            for i in reversed(range(len(prog.built()))):
+                if (id(prog), i) not in read:
+                    text = prog.hlo_text(i)
+                    read[(id(prog), i)] = None if text is None \
+                        else tracing.hlo_scopes(text)
+                found = read[(id(prog), i)]
+                if found is not None and (not best[0] or len(
+                        names & found[0].keys()) > len(
+                        names & best[0].keys())):
+                    best = found
+                if best[0] and names <= best[0].keys():
+                    return best
+        return best
+
+    def instruction(text: str) -> Tuple[str, str]:
+        """(name, opcode) of an "XLA Ops" event, which is named by the
+        instruction's whole text (or, in a test, by its name alone)."""
+        name, _, rest = text.partition(" = ")
+        return name.lstrip("%"), tracing._opcode(rest) if rest else ""
+
+    n = max(len(planes), 1)
+    lo, hi = window or (float("-inf"), float("inf"))
+    out: Dict[str, Any] = {"devices": len(planes), "ops_s": 0.0,
+                           "mixed_s": 0.0, "by_role": {},
+                           "by_component": {}, "by_program": {}}
+
+    def add(table: Dict[str, float], key: str, secs: float) -> None:
+        table[key] = table.get(key, 0.0) + secs
+
+    def program_row(module: str) -> Dict[str, Any]:
+        role = by_module[module][-1].role if module in by_module \
+            else "unregistered"
+        return out["by_program"].setdefault(module, {
+            "role": role, "runs": 0.0, "seconds": 0.0, "by_component": {}})
+
+    for lines in planes.values():
+        modules = sorted((s, s + d, name)
+                         for name, s, d in lines.get("XLA Modules", []))
+        starts = [m[0] for m in modules]
+        runs: Dict[str, List[Tuple[str, float]]] = {}
+        for text, s, d in lines.get("XLA Ops", []):
+            secs = (min(s + d, hi) - max(s, lo)) / 1e9
+            name, opcode = instruction(text)
+            if secs <= 0 or opcode in tracing._CONTROL:
+                continue
+            i = bisect.bisect_right(starts, s) - 1
+            run = modules[i][2] if i >= 0 and s < modules[i][1] else "-"
+            runs.setdefault(run, []).append((name, secs / n))
+        for run, events in runs.items():
+            module = run.split("(", 1)[0]
+            scopes, mixed = scopes_for(module, {
+                name for name, _ in events
+                if re.sub(r"\.\d+$", "", name) not in tracing._CONTROL})
+            row = program_row(module)
+            role = row["role"]
+            for name, secs in events:
+                # a while's name is a leaf's only by accident: the
+                # program's own text says which names are leaves
+                if scopes and name not in scopes and \
+                        re.sub(r"\.\d+$", "", name) in tracing._CONTROL:
+                    continue
+                comp, part, direction = scopes.get(
+                    name, (tracing.UNSCOPED, "", "fwd"))
+                key = f"{comp}/{part} {direction}" if part \
+                    else f"{comp} {direction}"
+                out["ops_s"] += secs
+                row["seconds"] += secs
+                add(row["by_component"], key, secs)
+                add(out["by_component"], key, secs)
+                add(out["by_role"], role, secs)
+                if name in mixed:
+                    out["mixed_s"] += secs
+        for s, e, name in modules:
+            if e > lo and s < hi:
+                program_row(name.split("(", 1)[0])["runs"] += 1.0 / n
+    out["mixed_pct"] = 100.0 * out["mixed_s"] / out["ops_s"] \
+        if out["ops_s"] else 0.0
+    return out
 
 
 if getenv("MXNET_PROFILER_AUTOSTART", 0):

@@ -46,6 +46,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as _np
 
+from .. import tracing as _tracing
 from .kv_cache import PagedKVCache
 from .model import DecodeModel, _sample_tokens, _select_one
 
@@ -70,19 +71,22 @@ def _slot_attention(p, q, ck, cv, pos, depth, cfg):
     S, L, d = q.shape[0], ck.shape[2], cfg["head_dim"]
     nkv = cfg["num_kv_heads"] // 2
     g = cfg["num_heads"] // cfg["num_kv_heads"]
-    # free views: d is whole sublane tiles (kv_cache module docstring)
-    scores = jnp.einsum("sngjd,snjdl->sngjl", q.reshape(S, nkv, g, 2, d),
-                        ck.reshape(S, nkv, 2, d, L),
-                        preferred_element_type=jnp.float32) / math.sqrt(d)
-    visible = jnp.arange(L)[None, :] <= pos[:, None]
-    probs = jax.nn.softmax(jnp.where(visible[:, None, None, None, :],
-                                     scores, -jnp.inf), axis=-1)
-    a = jnp.einsum("sngjl,snel->sngje", probs.astype(cv.dtype),
-                   cv.reshape(S, nkv, 2 * d, L),
-                   preferred_element_type=jnp.float32)
-    out = _pf._diff_combine(p, a, depth, cfg["layer_norm_eps"])
-    return _pf._mm(out.reshape(S, -1).astype(q.dtype), p["out_w"]) \
-        + _pf._f32(p["out_b"])
+    with jax.named_scope("attn/core"):
+        # free views: d is whole sublane tiles (kv_cache module docstring)
+        scores = jnp.einsum(
+            "sngjd,snjdl->sngjl", q.reshape(S, nkv, g, 2, d),
+            ck.reshape(S, nkv, 2, d, L),
+            preferred_element_type=jnp.float32) / math.sqrt(d)
+        visible = jnp.arange(L)[None, :] <= pos[:, None]
+        probs = jax.nn.softmax(jnp.where(visible[:, None, None, None, :],
+                                         scores, -jnp.inf), axis=-1)
+        a = jnp.einsum("sngjl,snel->sngje", probs.astype(cv.dtype),
+                       cv.reshape(S, nkv, 2 * d, L),
+                       preferred_element_type=jnp.float32)
+    with jax.named_scope("attn/out"):
+        out = _pf._diff_combine(p, a, depth, cfg["layer_norm_eps"])
+        return _pf._mm(out.reshape(S, -1).astype(q.dtype), p["out_w"]) \
+            + _pf._f32(p["out_b"])
 
 
 def _rows_attention(p, q, ck, cv, pos, depth, cfg):
@@ -91,12 +95,15 @@ def _rows_attention(p, q, ck, cv, pos, depth, cfg):
     position blocks up to ``pos[i]``'s are read and no other.  The
     window rings stay with the dense code: a ring is live in full from
     ``window - 1`` on."""
+    import jax
     from ..gluon.model_zoo import phi4flash as _pf
     from ..ops.pallas import decode_attention as _da
-    a = _da.paired_decode_attention(q, ck, cv, pos, cfg["head_dim"])
-    out = _pf._diff_combine(p, a, depth, cfg["layer_norm_eps"])
-    return _pf._mm(out.reshape(q.shape[0], -1).astype(q.dtype),
-                   p["out_w"]) + _pf._f32(p["out_b"])
+    with jax.named_scope("attn/core"):
+        a = _da.paired_decode_attention(q, ck, cv, pos, cfg["head_dim"])
+    with jax.named_scope("attn/out"):
+        out = _pf._diff_combine(p, a, depth, cfg["layer_norm_eps"])
+        return _pf._mm(out.reshape(q.shape[0], -1).astype(q.dtype),
+                       p["out_w"]) + _pf._f32(p["out_b"])
 
 
 def _slot_mamba(p, x, conv, ssm, cfg):
@@ -166,7 +173,9 @@ class HybridDecodeModel(DecodeModel):
             from jax import lax
             Lp = toks.shape[0]
             hidden, cached = _pf.forward_sequence(params, toks, t0, cfg)
-            h = lax.dynamic_slice_in_dim(hidden, t0 - 1, 1, axis=0)[0]
+            with jax.named_scope("head"):
+                h = lax.dynamic_slice_in_dim(hidden, t0 - 1, 1, axis=0)[0]
+                logits = _pf._mm(h, params["embed"])
             # ring column j holds the newest position < t0 that is
             # congruent to j; columns past t0 - 1 hold no position yet
             # and stay invisible until the step writes them
@@ -183,12 +192,13 @@ class HybridDecodeModel(DecodeModel):
                     state["conv"].append(a)
                     state["ssm"].append(b)
                 elif kind == "window":
-                    state["wk"].append(a[newest].T)
-                    state["wv"].append(b[newest].T)
+                    with jax.named_scope("cache/write"):
+                        state["wk"].append(a[newest].T)
+                        state["wv"].append(b[newest].T)
                 else:
                     ks.append(a.reshape(Lp, -1, cfg["head_dim"]))
                     vs.append(b.reshape(Lp, -1, cfg["head_dim"]))
-            return _pf._mm(h, params["embed"]), ks, vs, state
+            return logits, ks, vs, state
 
         def _step(params, ks, vs, state, toks, pos, seeds, bases, temps,
                   topks, topps, methods):
@@ -198,7 +208,8 @@ class HybridDecodeModel(DecodeModel):
             # window rings, conv tails and recurrence states
             from jax import lax
             eps = cfg["layer_norm_eps"]
-            x = params["embed"][toks]
+            with jax.named_scope("embed"):
+                x = params["embed"][toks]
             ring = pos % W
             seen_ring = jnp.minimum(pos, W - 1)
             new = {name: list(bufs) for name, bufs in state.items()}
@@ -209,38 +220,39 @@ class HybridDecodeModel(DecodeModel):
                 h = _pf._ln(x, p["ln1_g"], p["ln1_b"], eps)
                 i = nth[depth]      # among the layers of its cache kind
                 if kind == "mamba":
-                    y, memory, new["conv"][i], new["ssm"][i] = _slot_mamba(
-                        p, h, new["conv"][i], new["ssm"][i], cfg)
+                    with jax.named_scope("ssm"):
+                        y, memory, new["conv"][i], new["ssm"][i] = \
+                            _slot_mamba(p, h, new["conv"][i],
+                                        new["ssm"][i], cfg)
                 elif kind == "gmu":
-                    gate = jax.nn.silu(_pf._mm(h, p["in_w"]))
-                    y = _pf._mm((memory * gate).astype(h.dtype),
-                                p["out_w"])
+                    y = _pf._gmu(p, h, memory)
                 elif kind == "cross":
-                    q = (_pf._mm(h, p["q_w"])
-                         + _pf._f32(p["q_b"])).astype(h.dtype)
-                    y = _rows_attention(p, q, ks[0], vs[0], pos, depth,
-                                        cfg)
+                    y = _rows_attention(p, _pf._cross_q(p, h), ks[0],
+                                        vs[0], pos, depth, cfg)
                 else:
                     q, k, v = _pf._qkv(p, h, cfg)
                     # the token's K and V column of every slot, one
                     # in-place kernel call a layer: a ring's at pos % W,
                     # a row's at pos
                     if kind == "window":
-                        ck, cv = _cw.write_columns(
-                            (new["wk"][i], new["wv"][i]), (k, v), ring)
+                        with jax.named_scope("cache/write"):
+                            ck, cv = _cw.write_columns(
+                                (new["wk"][i], new["wv"][i]), (k, v), ring)
                         new["wk"][i], new["wv"][i] = ck, cv
                         y = _slot_attention(p, q, ck, cv, seen_ring,
                                             depth, cfg)
                     else:
-                        ck, cv = _cw.write_columns((ks[i], vs[i]), (k, v),
-                                                   pos)
+                        with jax.named_scope("cache/write"):
+                            ck, cv = _cw.write_columns((ks[i], vs[i]),
+                                                       (k, v), pos)
                         ks[i], vs[i] = ck, cv
                         y = _rows_attention(p, q, ck, cv, pos, depth, cfg)
                 x = x + y.astype(x.dtype)
                 x = x + _pf._mlp(p, _pf._ln(x, p["ln2_g"], p["ln2_b"],
                                             eps))
-            x = _pf._ln(x, params["lnf_g"], params["lnf_b"], eps)
-            logits = _pf._mm(x, params["embed"])
+            with jax.named_scope("head"):
+                x = _pf._ln(x, params["lnf_g"], params["lnf_b"], eps)
+                logits = _pf._mm(x, params["embed"])
 
             def _mixed(lg):
                 return _sample_tokens(lg, seeds, pos - bases, temps,
@@ -249,13 +261,16 @@ class HybridDecodeModel(DecodeModel):
             def _greedy(lg):
                 return jnp.argmax(lg, axis=-1).astype(jnp.int32)
 
-            next_tok = lax.cond(jnp.any(methods != 0), _mixed, _greedy,
-                                logits)
+            with jax.named_scope("sample"):
+                next_tok = lax.cond(jnp.any(methods != 0), _mixed,
+                                    _greedy, logits)
             return next_tok, ks, vs, new
 
-        self._prefill_fn = jax.jit(_prefill)
-        self._select_fn = jax.jit(_select_one)
-        self._step_fn = jax.jit(_step, donate_argnums=(1, 2, 3))
+        fam = self.family
+        self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
+        self._select_fn = _tracing.program(_select_one, "select", fam)
+        self._step_fn = _tracing.program(_step, "decode", fam,
+                                         donate_argnums=(1, 2, 3))
 
     @staticmethod
     def from_phi4flash(block: Any) -> "HybridDecodeModel":

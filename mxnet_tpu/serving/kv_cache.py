@@ -554,10 +554,12 @@ def _grow_rows(buf: Any, new_len: int) -> Any:
         import jax.numpy as jnp
 
         def grow(b, _L=int(new_len)):
-            return jnp.pad(b, ((0, 0),) * (b.ndim - 1)
-                           + ((0, _L - b.shape[-1]),))
+            with jax.named_scope("cache/grow"):
+                return jnp.pad(b, ((0, 0),) * (b.ndim - 1)
+                               + ((0, _L - b.shape[-1]),))
 
-        fn = _grow_jits[int(new_len)] = jax.jit(grow)
+        fn = _grow_jits[int(new_len)] = _tracing.program(
+            grow, "cache_resize", attrs={"rows": int(new_len)})
     return fn(buf)
 
 
@@ -589,8 +591,9 @@ def _make_write_rows():
             return lax.dynamic_update_slice(
                 b, jnp.expand_dims(r, -3).astype(b.dtype),
                 lead + (slot, _np.int32(0), start))
-        return [place(b, r) for b, r in zip(bufs, rows)]
-    return jax.jit(write, donate_argnums=(0,))
+        with jax.named_scope("cache/write"):
+            return [place(b, r) for b, r in zip(bufs, rows)]
+    return _tracing.program(write, "cache_write", donate_argnums=(0,))
 
 
 class _Lazy:
@@ -618,11 +621,12 @@ def _make_install_state():
         # slot axis.  The whole of the slot is replaced.  DONATED: the
         # window rings are as large as a bucket's rows and an
         # un-donated write would hold them twice
-        return jax.tree_util.tree_map(
-            lambda b, r: lax.dynamic_update_slice(
-                b, r[None].astype(b.dtype), (slot,) + (0,) * r.ndim),
-            bufs, new)
-    return jax.jit(install, donate_argnums=(0,))
+        with jax.named_scope("cache/write"):
+            return jax.tree_util.tree_map(
+                lambda b, r: lax.dynamic_update_slice(
+                    b, r[None].astype(b.dtype), (slot,) + (0,) * r.ndim),
+                bufs, new)
+    return _tracing.program(install, "cache_install", donate_argnums=(0,))
 
 
 _install_state_jit = _Lazy(_make_install_state)
@@ -639,9 +643,11 @@ def _shrink_rows(rows: List[Any], new_len: int) -> List[Any]:
         import jax
 
         def shrink(bs, _n=int(new_len)):
-            return [b[:_n] for b in bs]
+            with jax.named_scope("cache/grow"):
+                return [b[:_n] for b in bs]
 
-        fn = _shrink_jits[int(new_len)] = jax.jit(shrink)
+        fn = _shrink_jits[int(new_len)] = _tracing.program(
+            shrink, "cache_resize", attrs={"rows": int(new_len)})
     return fn(list(rows))
 
 

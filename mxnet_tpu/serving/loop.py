@@ -43,6 +43,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import numpy as _np
 
 from .. import metrics as _metrics
+from .. import tracing as _tracing
 from .kv_cache import PagedKVCache
 from .model import DecodeModel, _sample_tokens, _select_one
 
@@ -109,7 +110,8 @@ class LoopDecodeModel(DecodeModel):
             from jax import lax
             Lp = toks.shape[0]
             z, k, v = _ou.forward_sequence(params, toks, cfg)
-            h = lax.dynamic_slice_in_dim(z[-1], t0 - 1, 1, axis=0)[0]
+            with jax.named_scope("head"):
+                h = lax.dynamic_slice_in_dim(z[-1], t0 - 1, 1, axis=0)[0]
             return (_ou.lm_logits(params, h),
                     [k.reshape(steps * N, Lp, heads, d)],
                     [v.reshape(steps * N, Lp, heads, d)])
@@ -129,11 +131,13 @@ class LoopDecodeModel(DecodeModel):
                 q, k, v = _ou.qkv(p, x, pos, cfg)
                 # the token's K and V column of every slot into entry
                 # e, in place; then each slot's live rows of it
-                K, V = _cw.write_columns(
-                    (K, V), (k.reshape(S, heads * d),
-                             v.reshape(S, heads * d)), pos, entry=e)
-                a = _da.ragged_attention(q.reshape(S, heads, 1, d), K, V,
-                                         pos, scale, entry=e)
+                with jax.named_scope("cache/write"):
+                    K, V = _cw.write_columns(
+                        (K, V), (k.reshape(S, heads * d),
+                                 v.reshape(S, heads * d)), pos, entry=e)
+                with jax.named_scope("attn/core"):
+                    a = _da.ragged_attention(q.reshape(S, heads, 1, d), K,
+                                             V, pos, scale, entry=e)
                 return (_ou.finish(p, x, a.reshape(S, heads * d), cfg),
                         K, V), None
 
@@ -143,8 +147,10 @@ class LoopDecodeModel(DecodeModel):
                     (params["layers"], jnp.arange(N)))
                 return _ou.loop_output(params, x, t, cfg), K, V
 
-            x, K, V = lax.fori_loop(
-                0, steps, loop_step, (params["embed"][toks], ks[0], vs[0]))
+            with jax.named_scope("embed"):
+                x = params["embed"][toks]
+            x, K, V = lax.fori_loop(0, steps, loop_step,
+                                    (x, ks[0], vs[0]))
             logits = _ou.lm_logits(params, x)
 
             def _mixed(lg):
@@ -154,13 +160,16 @@ class LoopDecodeModel(DecodeModel):
             def _greedy(lg):
                 return jnp.argmax(lg, axis=-1).astype(jnp.int32)
 
-            next_tok = lax.cond(jnp.any(methods != 0), _mixed, _greedy,
-                                logits)
+            with jax.named_scope("sample"):
+                next_tok = lax.cond(jnp.any(methods != 0), _mixed,
+                                    _greedy, logits)
             return next_tok, [K], [V]
 
-        self._prefill_fn = jax.jit(_prefill)
-        self._select_fn = jax.jit(_select_one)
-        self._step_fn = jax.jit(_step, donate_argnums=(1, 2))
+        fam = self.family
+        self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
+        self._select_fn = _tracing.program(_select_one, "select", fam)
+        self._step_fn = _tracing.program(_step, "decode", fam,
+                                         donate_argnums=(1, 2))
 
     @staticmethod
     def from_ouro(block: Any) -> "LoopDecodeModel":

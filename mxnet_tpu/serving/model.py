@@ -274,6 +274,14 @@ def _sample_tokens(logits, seeds, ctrs, temps, topks, topps, methods):
     mirrors ``model_zoo.generation._select`` exactly — the zoo stays
     the host-side parity oracle (pinned in tests)."""
     import jax
+
+    with jax.named_scope("sample"):
+        return _sample_rows(logits, seeds, ctrs, temps, topks, topps,
+                            methods)
+
+
+def _sample_rows(logits, seeds, ctrs, temps, topks, topps, methods):
+    import jax
     import jax.numpy as jnp
 
     V = logits.shape[-1]
@@ -332,12 +340,13 @@ def _slot_block_step(p, x, ck, cv, pos, nh: int, ga):
     S, _, C = x.shape
     d = C // nh
     L = ck.shape[2]
-    h = _pure_ln(x, p["ln1_g"], p["ln1_b"], eps)
-    qkv = h @ p["qkv_w"].T + p["qkv_b"]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    qh = q.reshape(S, 1, nh, d)
-    kcol = k.reshape(S, C, 1)
-    vcol = v.reshape(S, C, 1)
+    with jax.named_scope("attn/qkv"):
+        h = _pure_ln(x, p["ln1_g"], p["ln1_b"], eps)
+        qkv = h @ p["qkv_w"].T + p["qkv_b"]
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        qh = q.reshape(S, 1, nh, d)
+        kcol = k.reshape(S, C, 1)
+        vcol = v.reshape(S, C, 1)
     # slot i writes its k/v column at ITS position pos[i]: one in-place
     # dynamic_update_slice a slot, NOT a scatter — a TPU scatter wants
     # its update window on the minor axes, so XLA would relayout the
@@ -346,35 +355,40 @@ def _slot_block_step(p, x, ck, cv, pos, nh: int, ga):
     # where the scatter dropped it; only a verify pass at the grid's
     # top gets there, for rows no emitted token reads (the submit-time
     # budget check)
-    for i in range(S):
-        # positions are never negative: without the flag every traced
-        # index gets a wrap-around select, slots x 2 x layers times a
-        # program, a second of tracing on every start
-        at = (i, 0, lax.index_in_dim(pos, i, keepdims=False))
-        ck = lax.dynamic_update_slice(
-            ck, lax.slice_in_dim(kcol, i, i + 1), at,
-            allow_negative_indices=False)
-        cv = lax.dynamic_update_slice(
-            cv, lax.slice_in_dim(vcol, i, i + 1), at,
-            allow_negative_indices=False)
-    # the heads' view of the buffers is free: d (64) is whole sublane
-    # tiles, so splitting C moves nothing
-    kh = ck.reshape(S, nh, d, L)
-    vh = cv.reshape(S, nh, d, L)
-    scores = jnp.einsum("sqhd,shdk->shqk", qh, kh) / _math.sqrt(d)
-    # slot i sees cache positions 0..pos[i] (its prompt + its decoded
-    # tokens); pad garbage beyond pos[i] stays invisible until the loop
-    # overwrites it position by position
-    visible = jnp.arange(L)[None, :] <= pos[:, None]          # (S, L)
-    scores = jnp.where(visible[:, None, None, :], scores,
-                       jnp.float32(-jnp.inf).astype(scores.dtype))
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("shqk,shdk->sqhd", probs, vh).reshape(S, 1, C)
-    x = x + (out @ p["out_w"].T + p["out_b"])
-    h = _pure_ln(x, p["ln2_g"], p["ln2_b"], eps)
-    ffn = jax.nn.gelu(h @ p["f1_w"].T + p["f1_b"],
-                      approximate=gelu_approx)
-    return x + (ffn @ p["f2_w"].T + p["f2_b"]), ck, cv
+    with jax.named_scope("cache/write"):
+        for i in range(S):
+            # positions are never negative: without the flag every traced
+            # index gets a wrap-around select, slots x 2 x layers times a
+            # program, a second of tracing on every start
+            at = (i, 0, lax.index_in_dim(pos, i, keepdims=False))
+            ck = lax.dynamic_update_slice(
+                ck, lax.slice_in_dim(kcol, i, i + 1), at,
+                allow_negative_indices=False)
+            cv = lax.dynamic_update_slice(
+                cv, lax.slice_in_dim(vcol, i, i + 1), at,
+                allow_negative_indices=False)
+    with jax.named_scope("attn/core"):
+        # the heads' view of the buffers is free: d (64) is whole sublane
+        # tiles, so splitting C moves nothing
+        kh = ck.reshape(S, nh, d, L)
+        vh = cv.reshape(S, nh, d, L)
+        scores = jnp.einsum("sqhd,shdk->shqk", qh, kh) / _math.sqrt(d)
+        # slot i sees cache positions 0..pos[i] (its prompt + its decoded
+        # tokens); pad garbage beyond pos[i] stays invisible until the loop
+        # overwrites it position by position
+        visible = jnp.arange(L)[None, :] <= pos[:, None]          # (S, L)
+        scores = jnp.where(visible[:, None, None, :], scores,
+                           jnp.float32(-jnp.inf).astype(scores.dtype))
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("shqk,shdk->sqhd", probs, vh).reshape(S, 1, C)
+    with jax.named_scope("attn/out"):
+        x = x + (out @ p["out_w"].T + p["out_b"])
+    with jax.named_scope("ffn/up"):
+        h = _pure_ln(x, p["ln2_g"], p["ln2_b"], eps)
+        ffn = jax.nn.gelu(h @ p["f1_w"].T + p["f1_b"],
+                          approximate=gelu_approx)
+    with jax.named_scope("ffn/down"):
+        return x + (ffn @ p["f2_w"].T + p["f2_b"]), ck, cv
 
 
 def _pure_ln(x, g, b, eps):
@@ -400,31 +414,36 @@ def _block_suffix(p, x, pk, pv, q, nh: int, ga):
     _, T, C = x.shape
     d = C // nh
     Pb = pk.shape[0]
-    h = _pure_ln(x, p["ln1_g"], p["ln1_b"], eps)
-    qkv = h @ p["qkv_w"].T + p["qkv_b"]
-    qq, kk, vv = jnp.split(qkv, 3, axis=-1)
-    qh = qq.reshape(T, nh, d)
-    kh = kk.reshape(T, nh, d)
-    vh = vv.reshape(T, nh, d)
-    k_all = jnp.concatenate([pk, kh], axis=0)       # (Pb + T, nh, d)
-    v_all = jnp.concatenate([pv, vh], axis=0)
-    scores = jnp.einsum("qhd,khd->hqk", qh, k_all) / _math.sqrt(d)
-    cols = jnp.arange(Pb + T)
-    # suffix position i (absolute q+i) sees: real prefix rows (< q)
-    # and suffix rows up to itself (causal); prefix pad garbage in
-    # q..Pb stays invisible
-    vis = (cols[None, :] < q) | (
-        (cols[None, :] >= Pb)
-        & (cols[None, :] - Pb <= jnp.arange(T)[:, None]))
-    scores = jnp.where(vis[None, :, :], scores,
-                       jnp.float32(-jnp.inf).astype(scores.dtype))
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("hqk,khd->qhd", probs, v_all).reshape(1, T, C)
-    x = x + (out @ p["out_w"].T + p["out_b"])
-    h = _pure_ln(x, p["ln2_g"], p["ln2_b"], eps)
-    ffn = jax.nn.gelu(h @ p["f1_w"].T + p["f1_b"],
-                      approximate=gelu_approx)
-    return x + (ffn @ p["f2_w"].T + p["f2_b"]), kh, vh
+    with jax.named_scope("attn/qkv"):
+        h = _pure_ln(x, p["ln1_g"], p["ln1_b"], eps)
+        qkv = h @ p["qkv_w"].T + p["qkv_b"]
+        qq, kk, vv = jnp.split(qkv, 3, axis=-1)
+        qh = qq.reshape(T, nh, d)
+        kh = kk.reshape(T, nh, d)
+        vh = vv.reshape(T, nh, d)
+    with jax.named_scope("attn/core"):
+        k_all = jnp.concatenate([pk, kh], axis=0)       # (Pb + T, nh, d)
+        v_all = jnp.concatenate([pv, vh], axis=0)
+        scores = jnp.einsum("qhd,khd->hqk", qh, k_all) / _math.sqrt(d)
+        cols = jnp.arange(Pb + T)
+        # suffix position i (absolute q+i) sees: real prefix rows (< q)
+        # and suffix rows up to itself (causal); prefix pad garbage in
+        # q..Pb stays invisible
+        vis = (cols[None, :] < q) | (
+            (cols[None, :] >= Pb)
+            & (cols[None, :] - Pb <= jnp.arange(T)[:, None]))
+        scores = jnp.where(vis[None, :, :], scores,
+                           jnp.float32(-jnp.inf).astype(scores.dtype))
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("hqk,khd->qhd", probs, v_all).reshape(1, T, C)
+    with jax.named_scope("attn/out"):
+        x = x + (out @ p["out_w"].T + p["out_b"])
+    with jax.named_scope("ffn/up"):
+        h = _pure_ln(x, p["ln2_g"], p["ln2_b"], eps)
+        ffn = jax.nn.gelu(h @ p["f1_w"].T + p["f1_b"],
+                          approximate=gelu_approx)
+    with jax.named_scope("ffn/down"):
+        return x + (ffn @ p["f2_w"].T + p["f2_b"]), kh, vh
 
 
 class DecodeModel:
@@ -489,15 +508,17 @@ class DecodeModel:
             from jax import lax
             from ..gluon.model_zoo.generation import _block_prefill
             Lp = toks.shape[0]
-            x = params["embed"][toks][None] + params["pos"][None, :Lp]
+            with jax.named_scope("embed"):
+                x = params["embed"][toks][None] + params["pos"][None, :Lp]
             ks, vs = [], []
             for p in params["blocks"]:
                 x, ck, cv = _block_prefill(p, x, nh, Lp, ga_s)
                 ks.append(ck[0])
                 vs.append(cv[0])
-            x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
-            h = lax.dynamic_slice_in_dim(x[0], t0 - 1, 1, axis=0)[0]
-            return h @ params["embed"].T, ks, vs
+            with jax.named_scope("head"):
+                x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
+                h = lax.dynamic_slice_in_dim(x[0], t0 - 1, 1, axis=0)[0]
+                return h @ params["embed"].T, ks, vs
 
         def _step(params, ks, vs, toks, pos, seeds, bases, temps,
                   topks, topps, methods):
@@ -512,15 +533,17 @@ class DecodeModel:
             # in-program (ctr = pos - base: base is the slot's
             # original prompt length minus its stream offset, minus
             # one) so no per-token host vector rides the hot loop
-            x = (params["embed"][toks][:, None, :]
-                 + params["pos"][pos][:, None, :])
+            with jax.named_scope("embed"):
+                x = (params["embed"][toks][:, None, :]
+                     + params["pos"][pos][:, None, :])
             new_ks, new_vs = [], []
             for p, ck, cv in zip(params["blocks"], ks, vs):
                 x, ck, cv = _slot_block_step(p, x, ck, cv, pos, nh, ga_s)
                 new_ks.append(ck)
                 new_vs.append(cv)
-            x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
-            logits = x[:, 0, :] @ params["embed"].T
+            with jax.named_scope("head"):
+                x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
+                logits = x[:, 0, :] @ params["embed"].T
 
             # token selection ON DEVICE (greedy argmax or the fused
             # temperature/top-k/top-p sampler under per-slot counter
@@ -542,8 +565,9 @@ class DecodeModel:
 
             from jax import lax
             import jax.numpy as jnp
-            next_tok = lax.cond(jnp.any(methods != 0), _mixed,
-                                _greedy, logits)
+            with jax.named_scope("sample"):
+                next_tok = lax.cond(jnp.any(methods != 0), _mixed,
+                                    _greedy, logits)
             return next_tok, new_ks, new_vs
 
         def _verify(params, ks, vs, toks, pos, seeds, bases, temps,
@@ -566,8 +590,9 @@ class DecodeModel:
             K1 = toks.shape[1]
             outs = []
             for j in range(K1):
-                x = (params["embed"][toks[:, j]][:, None, :]
-                     + params["pos"][pos + j][:, None, :])
+                with jax.named_scope("embed"):
+                    x = (params["embed"][toks[:, j]][:, None, :]
+                         + params["pos"][pos + j][:, None, :])
                 new_ks, new_vs = [], []
                 for p, ck, cv in zip(params["blocks"], ks, vs):
                     x, ck, cv = _slot_block_step(p, x, ck, cv, pos + j,
@@ -575,9 +600,10 @@ class DecodeModel:
                     new_ks.append(ck)
                     new_vs.append(cv)
                 ks, vs = new_ks, new_vs
-                x = _pure_ln(x, params["lnf_g"], params["lnf_b"],
-                             ga_s[1])
-                logits = x[:, 0, :] @ params["embed"].T
+                with jax.named_scope("head"):
+                    x = _pure_ln(x, params["lnf_g"], params["lnf_b"],
+                                 ga_s[1])
+                    logits = x[:, 0, :] @ params["embed"].T
 
                 def _mixed(lg, _j=j):
                     return _sample_tokens(lg, seeds, (pos + _j) - bases,
@@ -586,8 +612,9 @@ class DecodeModel:
                 def _greedy(lg):
                     return jnp.argmax(lg, axis=-1).astype(jnp.int32)
 
-                outs.append(lax.cond(jnp.any(methods != 0), _mixed,
-                                     _greedy, logits))
+                with jax.named_scope("sample"):
+                    outs.append(lax.cond(jnp.any(methods != 0), _mixed,
+                                         _greedy, logits))
             return jnp.stack(outs, axis=1), ks, vs
 
         def _prefill_sfx(params, pre_ks, pre_vs, toks, q, t0):
@@ -598,29 +625,35 @@ class DecodeModel:
             # last-real-suffix-token logits + the SUFFIX KV rows only
             from jax import lax
             Sb = toks.shape[0]
-            x = (params["embed"][toks][None]
-                 + lax.dynamic_slice_in_dim(params["pos"], q, Sb,
-                                            axis=0)[None])
+            with jax.named_scope("embed"):
+                x = (params["embed"][toks][None]
+                     + lax.dynamic_slice_in_dim(params["pos"], q, Sb,
+                                                axis=0)[None])
             ks_o, vs_o = [], []
             for p, pk, pv in zip(params["blocks"], pre_ks, pre_vs):
                 x, ck, cv = _block_suffix(p, x, pk, pv, q, nh, ga_s)
                 ks_o.append(ck)
                 vs_o.append(cv)
-            x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
-            h = lax.dynamic_slice_in_dim(x[0], t0 - 1, 1, axis=0)[0]
-            return h @ params["embed"].T, ks_o, vs_o
+            with jax.named_scope("head"):
+                x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
+                h = lax.dynamic_slice_in_dim(x[0], t0 - 1, 1, axis=0)[0]
+                return h @ params["embed"].T, ks_o, vs_o
 
-        self._prefill_fn = jax.jit(_prefill)
-        self._prefill_sfx_fn = jax.jit(_prefill_sfx)
-        self._select_fn = jax.jit(_select_one)
+        fam = self.family
+        self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
+        self._prefill_sfx_fn = _tracing.program(_prefill_sfx,
+                                                "prefill_suffix", fam)
+        self._select_fn = _tracing.program(_select_one, "select", fam)
         # the KV buffers are DONATED: XLA updates the resident cache in
         # place instead of allocating a fresh (S, h * d, L) per layer
         # every token
-        self._step_fn = jax.jit(_step, donate_argnums=(1, 2))
+        self._step_fn = _tracing.program(_step, "decode", fam,
+                                         donate_argnums=(1, 2))
         # same donation contract as _step: verify scatters k+1 rows
         # into the resident buffers in place; rejected rows are
         # invisible (visibility mask <= pos) until overwritten
-        self._verify_fn = jax.jit(_verify, donate_argnums=(1, 2))
+        self._verify_fn = _tracing.program(_verify, "verify", fam,
+                                           donate_argnums=(1, 2))
 
     # -- constructors -------------------------------------------------------
     @staticmethod

@@ -45,6 +45,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as _np
 
 from .. import metrics as _metrics
+from .. import tracing as _tracing
 from .kv_cache import PagedKVCache
 from .model import DecodeModel, _sample_tokens, _select_one
 
@@ -115,7 +116,8 @@ class MoEDecodeModel(DecodeModel):
             Lp = toks.shape[0]
             hidden, rows, load = _c2.forward_sequence(params, toks, t0,
                                                       cfg)
-            h = lax.dynamic_slice_in_dim(hidden, t0 - 1, 1, axis=0)[0]
+            with jax.named_scope("head"):
+                h = lax.dynamic_slice_in_dim(hidden, t0 - 1, 1, axis=0)[0]
             # ring column j holds the newest position < t0 that is
             # congruent to j; columns past t0 - 1 hold no position yet
             # and stay invisible until the step writes them
@@ -125,8 +127,9 @@ class MoEDecodeModel(DecodeModel):
             state: Dict[str, List[Any]] = {"wk": [], "wv": []}
             for kind, (k, v) in zip(kinds, rows):
                 if kind == "window":
-                    state["wk"].append(k.reshape(Lp, -1)[newest].T)
-                    state["wv"].append(v.reshape(Lp, -1)[newest].T)
+                    with jax.named_scope("cache/write"):
+                        state["wk"].append(k.reshape(Lp, -1)[newest].T)
+                        state["wv"].append(v.reshape(Lp, -1)[newest].T)
                 else:
                     ks.append(k)
                     vs.append(v)
@@ -143,7 +146,8 @@ class MoEDecodeModel(DecodeModel):
             from jax import lax
             eps = cfg["layer_norm_eps"]
             S = pos.shape[0]
-            x = params["embed"][toks[:S]]
+            with jax.named_scope("embed"):
+                x = params["embed"][toks[:S]]
             ring = pos % W
             seen_ring = jnp.minimum(pos, W - 1)
             new = {name: list(bufs) for name, bufs in state.items()}
@@ -155,25 +159,31 @@ class MoEDecodeModel(DecodeModel):
                 cols = (k.reshape(S, nkv * d), v.reshape(S, nkv * d))
                 # the token's K and V column of every slot, one in-place
                 # kernel call a layer: a ring's at pos % W, a row's at pos
-                if kind == "window":
-                    ck, cv = _cw.write_columns(
-                        (new["wk"][i], new["wv"][i]), cols, ring)
-                    new["wk"][i], new["wv"][i] = ck, cv
-                    seen = seen_ring
-                else:
-                    ck, cv = _cw.write_columns((ks[i], vs[i]), cols, pos)
-                    ks[i], vs[i] = ck, cv
-                    seen = pos
+                with jax.named_scope("cache/write"):
+                    if kind == "window":
+                        ck, cv = _cw.write_columns(
+                            (new["wk"][i], new["wv"][i]), cols, ring)
+                        new["wk"][i], new["wv"][i] = ck, cv
+                        seen = seen_ring
+                    else:
+                        ck, cv = _cw.write_columns((ks[i], vs[i]), cols,
+                                                   pos)
+                        ks[i], vs[i] = ck, cv
+                        seen = pos
                 # query head n reads K/V head n // g: the kernel's
                 # groups are the K/V heads, their g queries its rows
-                a = _da.ragged_attention(q.reshape(S, nkv, -1, d), ck, cv,
-                                         seen, scale)
+                with jax.named_scope("attn/core"):
+                    a = _da.ragged_attention(q.reshape(S, nkv, -1, d), ck,
+                                             cv, seen, scale)
                 y, load = _c2.experts(p, h, cfg, grouped=False)
                 loads.append(load)
-                x = x + (_c2._mm(a.reshape(S, -1).astype(h.dtype),
-                                 p["out_w"]) + y).astype(x.dtype)
-            x = _c2._ln(x, params["lnf_g"], eps)
-            logits = _c2.lm_logits(params, x, cfg)
+                with jax.named_scope("attn/out"):
+                    a = _c2._mm(a.reshape(S, -1).astype(h.dtype),
+                                p["out_w"])
+                x = x + (a + y).astype(x.dtype)
+            with jax.named_scope("head"):
+                x = _c2._ln(x, params["lnf_g"], eps)
+                logits = _c2.lm_logits(params, x, cfg)
 
             def _mixed(lg):
                 return _sample_tokens(lg, seeds, pos - bases, temps,
@@ -182,15 +192,18 @@ class MoEDecodeModel(DecodeModel):
             def _greedy(lg):
                 return jnp.argmax(lg, axis=-1).astype(jnp.int32)
 
-            next_tok = lax.cond(jnp.any(methods != 0), _mixed, _greedy,
-                                logits)
+            with jax.named_scope("sample"):
+                next_tok = lax.cond(jnp.any(methods != 0), _mixed,
+                                    _greedy, logits)
             out = jnp.concatenate([next_tok,
                                    jnp.stack(loads).reshape(-1)])
             return out, ks, vs, new
 
-        self._prefill_fn = jax.jit(_prefill)
-        self._select_fn = jax.jit(_select_one)
-        self._step_fn = jax.jit(_step, donate_argnums=(1, 2, 3))
+        fam = self.family
+        self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
+        self._select_fn = _tracing.program(_select_one, "select", fam)
+        self._step_fn = _tracing.program(_step, "decode", fam,
+                                         donate_argnums=(1, 2, 3))
 
     @staticmethod
     def from_cohere2moe(block: Any) -> "MoEDecodeModel":

@@ -67,13 +67,15 @@ def _chain_steps(params, ks, vs, toks, pos, seeds, bases, temps,
     ``pos + j - base``: exactly the key the target's verify pass uses
     for that position, so a draft whose logits match the target's
     proposes the target's own token (the accept rule's fixed point)."""
+    import jax
     from jax import lax
     import jax.numpy as jnp
     cur = toks
     outs = []
     for j in range(n_sub):
-        x = (params["embed"][cur][:, None, :]
-             + params["pos"][pos + j][:, None, :])
+        with jax.named_scope("embed"):
+            x = (params["embed"][cur][:, None, :]
+                 + params["pos"][pos + j][:, None, :])
         new_ks, new_vs = [], []
         for p, ck, cv in zip(params["blocks"], ks, vs):
             x, ck, cv = _slot_block_step(p, x, ck, cv, pos + j,
@@ -81,8 +83,9 @@ def _chain_steps(params, ks, vs, toks, pos, seeds, bases, temps,
             new_ks.append(ck)
             new_vs.append(cv)
         ks, vs = new_ks, new_vs
-        x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
-        logits = x[:, 0, :] @ params["embed"].T
+        with jax.named_scope("head"):
+            x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
+            logits = x[:, 0, :] @ params["embed"].T
 
         def _mixed(lg, _j=j):
             return _sample_tokens(lg, seeds, (pos + _j) - bases,
@@ -91,7 +94,9 @@ def _chain_steps(params, ks, vs, toks, pos, seeds, bases, temps,
         def _greedy(lg):
             return jnp.argmax(lg, axis=-1).astype(jnp.int32)
 
-        cur = lax.cond(jnp.any(methods != 0), _mixed, _greedy, logits)
+        with jax.named_scope("sample"):
+            cur = lax.cond(jnp.any(methods != 0), _mixed, _greedy,
+                           logits)
         outs.append(cur)
     return jnp.stack(outs, axis=1), ks, vs
 
@@ -186,7 +191,8 @@ class SelfSpeculativeDraft(DraftModel):
                                       topps, methods, n_sub, nh, ga_s)
             return outs
 
-        self._fn = jax.jit(_propose)
+        self._fn = _tracing.program(_propose, "draft", model.family,
+                                    attrs={"mode": self.mode})
 
     def _sub_params(self) -> dict:
         p = self.model.params
@@ -267,7 +273,9 @@ class IndependentDraft(DraftModel):
 
         # the draft cache's buffers are donated exactly like the
         # target step's: the chain updates them in place
-        self._fn = jax.jit(_propose, donate_argnums=(1, 2))
+        self._fn = _tracing.program(_propose, "draft", model.family,
+                                    attrs={"mode": self.mode},
+                                    donate_argnums=(1, 2))
 
     def admit(self, slot: int, tokens: _np.ndarray,
               prompt_buckets: Sequence[int]) -> None:

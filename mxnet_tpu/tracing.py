@@ -45,6 +45,15 @@ production:
   annotation is a flag read in the runtime.  Records keep
   ``time.perf_counter()`` seconds; retroactive intervals
   (:func:`record_span`) have no annotation.
+* **the device's work, named**: the pure functions the compiled
+  programs are traced from carry ``jax.named_scope`` paths out of one
+  vocabulary (:data:`COMPONENTS`), and every compiled program of the
+  serving and training paths is built through :func:`program`, which
+  is ``jax.jit`` plus a line in the program table: what the program is
+  (its ``role``), what each compiled shape cost to get
+  (``trace_s`` / ``lower_s`` / ``compile_s`` or ``load_s``, from jax's
+  own duration events) and, on demand only, which component each of
+  its device instructions belongs to (:meth:`Program.scopes`).
 
 Overhead contract: with ``MXNET_TRACE_SAMPLE=0`` tracing is fully off —
 ``span()`` returns a shared no-op after one flag read, and zero spans
@@ -60,9 +69,11 @@ import functools
 import itertools
 import os
 import random
+import re
 import threading
 import time
-from typing import Any, Callable, Dict, Iterator, List, Optional
+from typing import (Any, Callable, Dict, Iterator, List, Optional, Set,
+                    Tuple)
 
 from . import profiler as _prof
 from .base import getenv, register_env
@@ -71,7 +82,8 @@ __all__ = ["span", "child_span", "capture", "root_context", "attach",
            "current_context", "current_trace_id", "traceparent",
            "parse_traceparent", "record_span", "spans",
            "export_trace_events", "active_spans_tree", "configure",
-           "reset", "SpanContext"]
+           "reset", "SpanContext", "COMPONENTS", "ROLES", "Program",
+           "program", "programs", "hlo_scopes"]
 
 # ring capacity, a power of two: 90 s of the busiest measured producer
 # stay resident (the serving engine under chipbench's
@@ -156,10 +168,14 @@ def configure(sample: Optional[float] = None,
 
 
 def reset() -> None:
-    """Drop every recorded span (keeps the current configuration)."""
+    """Drop every recorded span and the program table with the jitted
+    callables it keeps (keeps the current configuration)."""
     rt = _RT
     rt.buf = [None] * rt.cap
     rt.seq = itertools.count()
+    with _PROGRAM_LOCK:
+        _PROGRAMS.clear()
+        _NEWEST.clear()
 
 
 class _TraceState:
@@ -543,7 +559,9 @@ def export_trace_events() -> Dict[str, Any]:
     """Chrome/Perfetto trace-event JSON — byte-shape identical to the
     profiler's :func:`mxnet_tpu.profiler.dump` payload and on the same
     clock epoch, so one ``chrome://tracing`` / Perfetto load can show a
-    profiler dump and this export side by side."""
+    profiler dump and this export side by side.  ``programs`` (a key
+    the viewers ignore) is the program table, :meth:`Program.describe`
+    of every line."""
     t0 = _prof._P.t0
     events: List[Dict[str, Any]] = [
         {"name": "process_name", "ph": "M", "pid": 0,
@@ -568,7 +586,8 @@ def export_trace_events() -> Dict[str, Any]:
             "dur": max(0.0, (rec["t_end"] - rec["t_begin"]) * 1e6),
             "pid": 0, "tid": rec["tid"], "args": args,
         })
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
+    return {"traceEvents": events, "displayTimeUnit": "ms",
+            "programs": [p.describe() for p in programs()]}
 
 
 def active_spans_tree() -> List[str]:
@@ -606,3 +625,494 @@ def active_spans_tree() -> List[str]:
         return lines
     except Exception:   # noqa: BLE001 - diagnostics must never raise
         return []
+
+
+# ---------------------------------------------------------------------------
+# the device's work: component scopes and the program table
+# ---------------------------------------------------------------------------
+
+# The vocabulary of the ``jax.named_scope`` paths in the code the
+# compiled programs are traced from: the first segment is the
+# component, an optional second the part.  A cache read that feeds
+# attention is ``attn/core``; ``norm`` is only for a norm that stands
+# outside the other components (a LayerNorm fused to a projection is
+# scoped with it); no path carries a layer index.  Gradient code needs
+# nothing: jax writes ``jvp(ffn)/up`` and ``transpose(jvp(ffn))/up``
+# around the same path.  tests/test_tracing.py holds every literal
+# under mxnet_tpu/ to this tuple.
+COMPONENTS = (
+    "embed",
+    "attn", "attn/qkv", "attn/core", "attn/out",
+    "ffn", "ffn/up", "ffn/down",
+    "experts", "experts/route", "experts/routed", "experts/shared",
+    "ssm",
+    "norm",
+    "cache", "cache/write", "cache/grow",
+    "head",
+    "sample",
+    "loss",
+    "optim",
+)
+UNSCOPED = "unscoped"
+
+# what a compiled program is for, as :func:`program` is told
+ROLES = ("train_step", "decode", "prefill", "prefill_suffix", "verify",
+         "draft", "select", "cache_write", "cache_install", "cache_resize")
+
+_STAGES = ("trace", "lower", "compile", "load")
+
+_PROGRAM_LOCK = threading.Lock()
+# (module, role, family) -> Program, in order of registration; a
+# function nobody registered is (module, None, None)
+_PROGRAMS: Dict[Tuple[str, Optional[str], Optional[str]], "Program"] = {}
+# module -> the registered Program of that name that was put in last:
+# where a build's events are booked when its trace-time body did not run
+_NEWEST: Dict[str, "Program"] = {}
+# the build this thread is in the middle of: ``current`` is (Program,
+# shape entry) from the trace-time body to the compile's end,
+# ``reading`` is true inside Program.hlo_text
+_BUILD = threading.local()
+
+
+def _module_name(fun_name: str) -> str:
+    """The name the device trace's "XLA Modules" line shows for what
+    jax's duration events call ``fun_name``: ``_step`` (the trace
+    event) and ``jit(_step)`` (lowering, compiling) are both
+    ``jit__step``."""
+    name = _MODULE_NAMES.get(fun_name)
+    if name is None:
+        m = re.fullmatch(r"(\w+)\((.*)\)", fun_name)
+        api, fn = (m[1], m[2]) if m else ("jit", fun_name)
+        name = _MODULE_NAMES[fun_name] = \
+            f"{api}_" + re.sub(r"[^\w.-]", "_", fn)
+    return name
+
+
+# jax reports a traced program's every inner jit (tens of thousands of
+# events a large step): the listener's path is a few dict reads
+_MODULE_NAMES: Dict[str, str] = {}
+
+
+def _abstract(x: Any) -> Any:
+    """What :meth:`Program.hlo_text` needs of one traced argument: shape,
+    dtype and the (names, sizes) of the mesh it came in on, None where
+    it came on none.  Anything without a shape is kept as it is (a
+    static argument)."""
+    if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+        return x
+    import jax
+    mesh = getattr(getattr(jax.typeof(x), "sharding", None), "mesh", None)
+    on = None if mesh is None or mesh.empty \
+        else (tuple(mesh.axis_names), tuple(mesh.axis_sizes))
+    return _Abstract(tuple(x.shape), x.dtype, on)
+
+
+class _Abstract:
+    __slots__ = ("shape", "dtype", "mesh")
+
+    def __init__(self, shape: Tuple[int, ...], dtype: Any,
+                 mesh: Optional[Tuple[Tuple[str, ...], Tuple[int, ...]]]
+                 ) -> None:
+        self.shape, self.dtype, self.mesh = shape, dtype, mesh
+
+    def __str__(self) -> str:
+        on = "@" + ",".join(self.mesh[0]) if self.mesh else ""
+        return f"{self.dtype}[{','.join(map(str, self.shape))}]{on}"
+
+
+def _signature(leaves: List[Any]) -> str:
+    """A compiled shape in a line: each distinct array type (``@`` the
+    axes of the mesh it came in on) with how many arguments have it,
+    static arguments by their repr."""
+    counts: Dict[str, int] = {}
+    for leaf in leaves:
+        key = str(leaf) if isinstance(leaf, _Abstract) else repr(leaf)
+        counts[key] = counts.get(key, 0) + 1
+    return ", ".join(k if n == 1 else f"{k} x{n}"
+                     for k, n in counts.items())
+
+
+class Program:
+    """One line of the program table: a compiled program's ``module``
+    (the name the device trace's "XLA Modules" line shows, e.g.
+    ``jit__step``), its ``role`` (one of :data:`ROLES`; None for a
+    function nobody registered), ``family``, the site's ``attrs``, and
+    ``shapes``: one dict a compiled shape in order of arrival, with
+    ``args`` (the shape in a line), the ``attrs`` of the instance that
+    built it where it has any, and the seconds jax reported for its
+    stages, ``trace_s``, ``lower_s`` and ``compile_s`` OR ``load_s``
+    (the executable came out of jax's persistent cache).  An
+    unregistered function has one entry for all its builds
+    (``builds`` counts them).  An entry with ``reading`` true was
+    built by :meth:`hlo_text` itself and is left out of every sum."""
+
+    __slots__ = ("module", "role", "family", "attrs", "shapes", "jitted")
+
+    def __init__(self, module: str, role: Optional[str] = None,
+                 family: Optional[str] = None,
+                 attrs: Optional[Dict[str, Any]] = None) -> None:
+        self.module, self.role, self.family = module, role, family
+        self.attrs = dict(attrs or {})
+        self.shapes: List[Dict[str, Any]] = []
+        self.jitted: Any = None
+
+    def describe(self) -> Dict[str, Any]:
+        """The line as plain data (what ``export_trace_events`` holds)."""
+        return {"module": self.module, "role": self.role,
+                "family": self.family, "attrs": dict(self.attrs),
+                "shapes": [{k: v for k, v in e.items()
+                            if not k.startswith("_")}
+                           for e in self.shapes]}
+
+    def built(self) -> List[Dict[str, Any]]:
+        """The shapes the program's callers built (not ``hlo_text``)."""
+        return [e for e in self.shapes if not e.get("reading")]
+
+    def seconds(self, *stages: str) -> float:
+        """Sum of ``stages`` (``"trace"``, ``"lower"``, ``"compile"``,
+        ``"load"``; all four where none is given) over :meth:`built`."""
+        return sum(e.get(f"{s}_s", 0.0) for e in self.built()
+                   for s in (stages or _STAGES))
+
+    # -- trace time ---------------------------------------------------------
+    def _begin(self, args: Tuple[Any, ...], kwargs: Dict[str, Any]) -> None:
+        """jax is tracing the program for a new shape on this thread."""
+        import jax
+        abstract = jax.tree_util.tree_map(_abstract, (args, kwargs))
+        entry: Dict[str, Any] = {
+            "args": _signature(jax.tree_util.tree_leaves(abstract)),
+            "_abstract": abstract}
+        if self.attrs:
+            entry["attrs"] = dict(self.attrs)
+        if getattr(_BUILD, "reading", False):
+            entry["reading"] = True
+        with _PROGRAM_LOCK:
+            # back into the table after a reset(); under a newer
+            # instance's line where one took the key since
+            line = _PROGRAMS.setdefault(
+                (self.module, self.role, self.family), self)
+            _NEWEST.setdefault(self.module, line)
+            if line is not self:
+                del entry["_abstract"]
+            line.shapes.append(entry)
+        _BUILD.current = (line, entry)
+
+    # -- from instruction to component, on demand ---------------------------
+    def hlo_text(self, shape: int = -1) -> Optional[str]:
+        """The optimized HLO text of :meth:`built` ``[shape]``: the
+        registered callable lowered and compiled again from the shapes
+        noted when it was traced, which jax answers from its caches (in
+        this process from memory, else a load from the persistent one).
+        Costs a program load at worst and runs nowhere unless asked:
+        not at construction, in warm-up or on a step.  None for an
+        unregistered function, for a shape inherited from an older
+        instance, and for a program traced over a mesh of more than one
+        device."""
+        built = self.built()
+        abstract = built[shape].get("_abstract") if built else None
+        if self.jitted is None or abstract is None:
+            return None
+        import jax
+        import numpy as _np
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+        meshes: Dict[Any, Any] = {}
+
+        def concrete(leaf: Any) -> Any:
+            if not isinstance(leaf, _Abstract):
+                return leaf
+            if leaf.mesh is None:
+                return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+            if leaf.mesh not in meshes:
+                names, sizes = leaf.mesh
+                # every sharding over one device lowers alike
+                meshes[leaf.mesh] = NamedSharding(Mesh(_np.asarray(
+                    jax.devices()[:1]).reshape(sizes), names),
+                    PartitionSpec())
+            return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                        sharding=meshes[leaf.mesh])
+
+        leaves, treedef = jax.tree_util.tree_flatten(abstract)
+        if any(isinstance(a, _Abstract) and a.mesh is not None
+               and _np.prod(a.mesh[1]) > 1 for a in leaves):
+            return None
+        args, kwargs = treedef.unflatten([concrete(a) for a in leaves])
+        _BUILD.reading = True
+        try:
+            return self.jitted.lower(*args, **kwargs).compile().as_text()
+        finally:
+            _BUILD.reading = False
+            _BUILD.current = None
+
+    def scopes(self, shape: int = -1
+               ) -> Optional[Dict[str, Tuple[str, str, str]]]:
+        """``{instruction name: (component, part, "fwd" | "bwd")}`` of
+        the leaf instructions of :meth:`hlo_text` (:func:`hlo_scopes`
+        has the rules); None where that is."""
+        text = self.hlo_text(shape)
+        return None if text is None else hlo_scopes(text)[0]
+
+
+def program(fn: Callable, role: str, family: Optional[str] = None,
+            attrs: Optional[Dict[str, Any]] = None,
+            **jit_kwargs: Any) -> Any:
+    """``jax.jit(fn, **jit_kwargs)`` with a line in the program table.
+
+    Returns what ``jax.jit`` returns, so a call takes jit's own path.
+    jit is handed ``fn`` under ``functools.wraps`` (the module's name,
+    the argument names and with them every cache key stay what they
+    were) with a body that runs only while jax traces, once a shape,
+    and notes there the arguments' shapes and that this thread is now
+    building this program.  The table keeps the jitted callable of the
+    LAST instance registered under (module, ``role``, ``family``), and
+    with it that model's closure, for :meth:`Program.hlo_text`; an
+    older instance's shapes and seconds stay in the line, its callable
+    does not.  :func:`reset` drops the table."""
+    if role not in ROLES:
+        raise ValueError(f"program role {role!r} is none of {ROLES}")
+    import jax
+    rec = Program(_module_name(fn.__name__), role, family, attrs)
+
+    @functools.wraps(fn)
+    def traced(*args: Any, **kwargs: Any) -> Any:
+        rec._begin(args, kwargs)
+        return fn(*args, **kwargs)
+
+    rec.jitted = jax.jit(traced, **jit_kwargs)
+    key = (rec.module, role, family)
+    with _PROGRAM_LOCK:
+        older = _PROGRAMS.get(key)
+        if older is not None:
+            rec.shapes = [{k: v for k, v in e.items() if k != "_abstract"}
+                          for e in older.shapes]
+        _PROGRAMS[key] = _NEWEST[rec.module] = rec
+    return rec.jitted
+
+
+def programs() -> List[Program]:
+    """The program table: every registered program in order of
+    registration, then a line (``role`` None) for each function jax
+    built that nobody registered."""
+    with _PROGRAM_LOCK:
+        found = list(_PROGRAMS.values())
+    return sorted(found, key=lambda p: p.role is None)
+
+
+def note_build(stage: str, fun_name: str, seconds: float) -> None:
+    """One stage of one build, as jax reported it to
+    ``metrics._install_jax_hooks``'s listener on the thread that built:
+    ``stage`` is ``"trace"``, ``"lower"``, ``"compile"`` or ``"load"``.
+    Books the seconds in the table and emits the stage as a
+    retroactive span ``program.<stage>`` under whatever span is open on
+    this thread (nothing, outside a trace).  The helpers jax traces
+    INSIDE a program (``_where``, ``_einsum``: thousands, microseconds
+    each) are booked but get no span."""
+    module = _module_name(fun_name)
+    reading = getattr(_BUILD, "reading", False)
+    current = getattr(_BUILD, "current", None)
+    done = stage in ("compile", "load")
+    with _PROGRAM_LOCK:
+        if current is not None and current[0].module == module:
+            rec, entry = current
+            if done:
+                _BUILD.current = None
+        elif module in _NEWEST:
+            # the trace-time body did not run: jax answered the trace
+            # from its cache, or lowers one trace a second time
+            rec = _NEWEST[module]
+            if reading:
+                entry = {"args": None, "reading": True}
+                rec.shapes.append(entry)
+                _BUILD.current = (rec, entry)
+            else:
+                if not rec.built():
+                    rec.shapes.append({"args": None})
+                entry = rec.built()[-1]
+        elif reading:
+            return
+        else:
+            rec = _PROGRAMS.setdefault((module, None, None),
+                                       Program(module))
+            if not rec.shapes:
+                rec.shapes.append({"args": None, "builds": 0})
+            entry = rec.shapes[0]
+            entry["builds"] += done
+        key = f"{stage}_s"
+        entry[key] = entry.get(key, 0.0) + seconds
+    if rec.role is not None or stage != "trace":
+        end = time.perf_counter()
+        record_span(f"program.{stage}", end - seconds, end,
+                    program=module, role=rec.role)
+
+
+# an instruction of the optimized HLO text: ``[ROOT] %name = <type>
+# opcode(operands), attributes``; a tuple's type holds spaces
+_HLO_HEADER = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
+_HLO_OPCODE = re.compile(r"^([\w\-]+)\(")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLED = re.compile(
+    r"\b(calls|body|condition|to_apply|true_computation|"
+    r"false_computation)=%?([\w.\-]+)")
+_HLO_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+# a segment jax writes for a jit inside the program: never a scope
+_JIT_SEGMENT = re.compile(r"\b(?:jit|pjit|pmap)\([^()]*\)")
+_TRANSFORM = re.compile(r"\w+\(")
+_FIRST_SEGMENTS = frozenset(c for c in COMPONENTS if "/" not in c)
+# instructions that only lead to other computations, and those that
+# cost the device nothing
+_CONTROL = ("while", "call", "conditional")
+_FREE = frozenset(("parameter", "constant", "tuple", "get-tuple-element",
+                   "bitcast", "after-all", "partition-id", "replica-id"))
+
+
+def _scope_of(op_name: str) -> Tuple[str, str, str]:
+    """(component, part, direction) of a ``metadata.op_name``: the first
+    vocabulary word of the path, the part where the next segment is one
+    of that component's, ``bwd`` where the path holds ``transpose(``."""
+    direction = "bwd" if "transpose(" in op_name else "fwd"
+    path = _TRANSFORM.sub("", _JIT_SEGMENT.sub("", op_name))
+    segments = path.replace(")", "").split("/")
+    for i, seg in enumerate(segments):
+        if seg in _FIRST_SEGMENTS:
+            part = segments[i + 1] if i + 1 < len(segments) else ""
+            if f"{seg}/{part}" not in COMPONENTS:
+                part = ""
+            return seg, part, direction
+    return UNSCOPED, "", direction
+
+
+def _opcode(rest: str) -> str:
+    """The opcode of an instruction's text after ``=``: what follows the
+    result type, which is one word or a parenthesised tuple."""
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                rest = rest[i + 1:].lstrip()
+                break
+    else:
+        rest = rest.split(" ", 1)[1] if " " in rest else ""
+    m = _HLO_OPCODE.match(rest)
+    return m[1] if m else ""
+
+
+def hlo_scopes(text: str) -> Tuple[Dict[str, Tuple[str, str, str]],
+                                   Set[str]]:
+    """From an optimized HLO module's text to ``({instruction name:
+    (component, part, "fwd" | "bwd")}, mixed)`` for the leaf
+    instructions of the ENTRY computation and of every computation a
+    ``while``, ``call`` or ``conditional`` leads to from it; ``mixed``
+    names the fusions whose instructions span more than one component.
+
+    The rules: the component is the first word of :data:`COMPONENTS` in
+    the instruction's ``metadata.op_name`` (:func:`_scope_of`); a
+    ``fusion`` that holds a ``dot`` or a ``convolution`` takes THAT
+    instruction's path (the optimizer's update fused behind a
+    weight-gradient matmul counts with the matmul), else its root's,
+    and where the root carries no word (a multi-output fusion's bare
+    tuple), the one component its instructions name, if they name one;
+    ``while``, ``call`` and ``conditional`` are not leaves (the trace
+    shows them around their own children); an instruction whose path has
+    no vocabulary word is ``unscoped``; one with no metadata at all (the
+    compiler's own: a weight's prefetch and the wait for it, a layout
+    copy) takes the scope of the first scoped instruction that waits
+    for it, up to four steps on."""
+    computations: Dict[str, List[Tuple[str, str, str, bool]]] = {}
+    entry, body = None, None
+    for line in text.splitlines():
+        if body is None:
+            m = _HLO_HEADER.match(line)
+            if m:
+                body = computations.setdefault(m[2], [])
+                if m[1]:
+                    entry = m[2]
+        elif line.startswith("}"):
+            body = None
+        else:
+            m = _HLO_INSTRUCTION.match(line)
+            if m:
+                body.append((m[2], _opcode(m[3]), m[3], bool(m[1])))
+
+    def called(rest: str) -> List[str]:
+        names = [m[2] for m in _HLO_CALLED.finditer(rest)]
+        for m in _HLO_BRANCHES.finditer(rest):
+            names += [n.strip().lstrip("%") for n in m[1].split(",")]
+        return [n for n in names if n in computations]
+
+    def op_name(rest: str) -> str:
+        m = _HLO_OP_NAME.search(rest)
+        return m[1] if m else ""
+
+    def inside(comp: str, seen: Set[str]) -> List[Tuple[str, str, bool]]:
+        """(opcode, op_name, is root) of a fused computation's
+        instructions, those of the computations it calls first."""
+        out: List[Tuple[str, str, bool]] = []
+        if comp in seen:
+            return out
+        seen.add(comp)
+        for _, opcode, rest, root in computations[comp]:
+            if opcode == "fusion":
+                for sub in called(rest):
+                    out += [(o, n, False) for o, n, _ in inside(sub, seen)]
+            out.append((opcode, op_name(rest), root))
+        return out
+
+    scopes: Dict[str, Tuple[str, str, str]] = {}
+    mixed: Set[str] = set()
+    visited: Set[str] = set()
+
+    def walk(comp: str) -> None:
+        if comp in visited:
+            return
+        visited.add(comp)
+        bare: List[str] = []
+        for name, opcode, rest, _ in computations[comp]:
+            if opcode in _CONTROL:
+                for sub in called(rest):
+                    walk(sub)
+                continue
+            if opcode in _FREE or name in scopes:
+                continue
+            path = op_name(rest)
+            subs = called(rest) if "calls=" in rest else []
+            if subs:
+                held = [x for sub in subs for x in inside(sub, set())]
+                matmul = next((n for o, n, _ in held
+                               if o in ("dot", "convolution") and n), None)
+                root = next((n for _, n, r in held if r and n), None)
+                path = matmul or root or path
+                named = {_scope_of(n)[:2]: n for _, n, _ in held if n
+                         and _scope_of(n)[0] != UNSCOPED}
+                if len({c for c, _ in named}) > 1:
+                    mixed.add(name)
+                elif named and _scope_of(path)[0] == UNSCOPED:
+                    path = next(iter(named.values()))
+            scopes[name] = _scope_of(path)
+            if not path:
+                bare.append(name)
+        if bare:
+            # what the compiler put in (a weight's prefetch, a layout
+            # copy): the scope of the first scoped instruction that
+            # waits for it
+            users: Dict[str, List[str]] = {}
+            for name, _, rest, _ in computations[comp]:
+                for operand in set(_HLO_OPERAND.findall(rest)):
+                    users.setdefault(operand, []).append(name)
+            for name in bare:
+                front, seen = [name], {name}
+                for _ in range(4):
+                    front = [u for n in front for u in users.get(n, [])
+                             if u not in seen and not seen.add(u)]
+                    found = next((scopes[u] for u in front
+                                  if scopes.get(u, (UNSCOPED,))[0]
+                                  != UNSCOPED), None)
+                    if found or not front:
+                        break
+                if found:
+                    scopes[name] = found
+
+    if entry is not None:
+        walk(entry)
+    return scopes, mixed

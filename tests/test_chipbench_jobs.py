@@ -31,7 +31,8 @@ TINY = (
      "device_idle_pct.serve", "queue_wait_p95_ms", "admission_ms",
      "decode_dispatch_ms", "engine_host_ms", "idle_pct.decode_call",
      "idle_pct.admission", "idle_pct.engine_host", "cache_bytes_per_slot",
-     "state_install_ms", "decode_hbm_pct", "decode_ahead_pct"],
+     "state_install_ms", "decode_hbm_pct", "decode_ahead_pct",
+     "program_trace_lower_s", "program_load_s", "decode_device_ms"],
     {"arch": {"vocab": 503, "width": 64, "kv_heads": 2, "head_dim": 16,
               "window": 8, "d_inner": 128, "d_state": 16, "d_conv": 4,
               "mamba_layers": 3, "window_layers": 2, "full_layers": 1,
@@ -112,7 +113,10 @@ LOOP_TINY = (
      "traffic": {"rate_per_s": 20.0, "ramp_s": 0.5,
                  "prompt": {"median": 24, "sigma": 0.5, "min": 8, "max": 64},
                  "output": {"median": 8, "sigma": 0.5, "min": 4, "max": 16},
-                 "at_window_end": "drain", "drain_s": 20.0},
+                 "at_window_end": "drain",
+                 # patience, not speed: beside five other workers the
+                 # interpret-mode loop has taken 40 s for these requests
+                 "drain_s": 120.0},
      # forced: a slot from a short prompt and one installed from the
      # reference (longer than the traffic's longest prompt) that crosses
      # the ragged kernel's 512-position block
@@ -175,6 +179,18 @@ def _end_to_end(tmp_path, monkeypatch, cell, trace):
     return {k: m["value"] for k, m in line["metrics"].items()}
 
 
+def _family_programs(family):
+    """After a rehearsed job: the table lists every program the family's
+    engine built with a role, each with its stages' seconds."""
+    from mxnet_tpu import tracing
+    mine = [p for p in tracing.programs()
+            if p.role and p.family in (family, None) and p.built()]
+    roles = {p.role for p in mine}
+    assert {"decode", "prefill", "select", "cache_write"} <= roles, roles
+    assert all(p.seconds("trace", "lower") > 0 for p in mine)
+    return roles
+
+
 @pytest.mark.parametrize("trace", [0, 1])
 def test_new_job_end_to_end_at_a_tiny_size(tmp_path, monkeypatch, trace):
     value = _end_to_end(tmp_path, monkeypatch, CELL, trace)
@@ -190,6 +206,21 @@ def test_new_job_end_to_end_at_a_tiny_size(tmp_path, monkeypatch, trace):
     per_slot = value["cache_bytes_per_slot"]
     fixed = 2 * 2 * 32 * 8 * 4 + 3 * 128 * (16 + 3) * 4
     assert (per_slot - fixed) / (2 * 32 * 4) in (64, 128, 256)
+    # the program table's readers: the family's own programs, found by
+    # role; the reference's layer programs and the eager initialisers
+    # are in the table too (role None) and in neither sum
+    from mxnet_tpu import tracing
+    with_role = [p for p in tracing.programs() if p.role]
+    assert {"decode", "prefill", "select", "cache_write",
+            "cache_install"} <= {p.role for p in with_role}
+    assert 0 < value["program_trace_lower_s"] == pytest.approx(
+        sum(p.seconds("trace", "lower") for p in with_role))
+    assert 0 < value["program_load_s"] == pytest.approx(
+        sum(p.seconds("compile", "load") for p in with_role))
+    others = sum(p.seconds() for p in tracing.programs() if not p.role)
+    assert others > 0
+    # the synthetic trace ran jit__step once for half the window
+    assert value["decode_device_ms"] == pytest.approx(0.5e3 * 0.5, rel=0.2)
 
 
 def _check_on_a_tiny_model(tmp_path, monkeypatch, cell=CELL):
@@ -362,6 +393,7 @@ def test_the_moe_job_end_to_end_at_a_tiny_size(tmp_path, monkeypatch,
     # three rings of 8 and the rows at some bucket, 32 channels, float32
     per_slot = value["cache_bytes_per_slot"]
     assert (per_slot - 3 * 2 * 32 * 8 * 4) / (2 * 32 * 4) in (64, 128, 256)
+    assert "cache_install" in _family_programs("cohere2moe")
 
 
 def test_the_float8_control_is_refused_by_the_moe_jobs_verdict(
@@ -597,6 +629,7 @@ def test_the_loop_job_end_to_end_at_a_tiny_size(tmp_path, monkeypatch,
     assert value["cache_bytes_per_slot"] == 9 * 2 * 64 * 4 * 1024
     # every slot sits in the first of the bucket's two blocks
     assert value["rows_read_pct"] == 50.0
+    _family_programs("loop")
 
 
 def test_the_float8_control_is_refused_by_the_loop_jobs_verdict(
@@ -714,10 +747,13 @@ def test_the_loop_cell_resolves_from_the_real_benchmark():
     assert found["chips"] == 1 and found["cell"]["engine"] == {
         "max_slots": 5, "kv_buckets": [1024], "prefix_slots": 0,
         "queue_limit": 100000, "max_tokens": 752}
-    # the sixteen serving readers ISSUE 35 lists, the two it adds, and
-    # warmup_s, which every cell reports; not state_install_ms: a slot
-    # of this family holds rows alone
-    assert len(listed) == 16 + 2 + 1 and "state_install_ms" not in listed
+    # the sixteen serving readers ISSUE 35 lists, the two it adds,
+    # warmup_s, which every cell reports, and three of ISSUE 37 (two of
+    # set-up, the decode program's device time); not state_install_ms:
+    # a slot of this family holds rows alone; not admission_device_ms:
+    # a 4 s stretch of this cell often holds no admission
+    assert len(listed) == 16 + 2 + 1 + 3 \
+        and not {"state_install_ms", "admission_device_ms"} & set(listed)
     assert {"loop_passes_per_token", "decode_attn_roofline_pct",
             "decode_hbm_pct", "rows_read_pct", "cache_bytes_per_slot"} \
         <= set(listed)
@@ -751,3 +787,155 @@ def test_the_loop_cell_resolves_from_the_real_benchmark():
     # the longest request of the mix fits the one bucket
     assert mix["prompt"]["max"] + mix["output"]["max"] \
         <= found["cell"]["engine"]["kv_buckets"][0]
+
+
+# ---------------------------------------------------------------------------
+# the readers of the program's table (chipbench/harness/program_table.py)
+# on a table and reductions made by hand
+# ---------------------------------------------------------------------------
+
+NEW_READERS = ("program_trace_lower_s", "program_load_s",
+               "decode_device_ms", "admission_device_ms",
+               "device_ms_per_step.attn", "device_ms_per_step.ffn",
+               "device_ms_per_step.optim", "device_ms_per_step.other",
+               "device_unscoped_pct")
+
+
+def _reader(name):
+    return run._load_module(os.path.join(ROOT, "chipbench", "metrics",
+                                         name + ".py"))
+
+
+def _hand_table(monkeypatch, scopes=None):
+    """A table as the program would hold it after a warm start: the
+    family's programs with a role, the reference's without, one entry
+    that a reading of the HLO caused."""
+    from chipbench.harness import program_table
+    from mxnet_tpu import tracing
+
+    class Step(tracing.Program):
+        def scopes(self, shape=-1):
+            return scopes
+
+    def rec(cls, module, role, *shapes):
+        p = cls(module, role, "toy")
+        p.shapes = list(shapes)
+        return p
+
+    table = [
+        rec(Step, "jit_step", "train_step",
+            {"args": "a", "trace_s": 2.0, "lower_s": 1.0, "load_s": 4.0},
+            {"args": "a", "trace_s": 9.0, "lower_s": 9.0, "load_s": 9.0,
+             "reading": True}),
+        rec(tracing.Program, "jit__step", "decode",
+            {"args": "b", "trace_s": 0.5, "lower_s": 0.25,
+             "compile_s": 8.0}),
+        rec(tracing.Program, "jit__prefill", "prefill",
+            {"args": "c", "trace_s": 0.25, "lower_s": 0.125,
+             "load_s": 1.0}),
+        rec(tracing.Program, "jit_write", "cache_write"),
+        rec(tracing.Program, "jit_install", "cache_install"),
+        rec(tracing.Program, "jit__select_one", "select"),
+        rec(tracing.Program, "jit_ref_logits", None,
+            {"args": None, "builds": 1, "trace_s": 100.0,
+             "compile_s": 100.0}),
+    ]
+    monkeypatch.setattr(program_table, "table", lambda: table)
+    return table
+
+
+def _hand_ctx(**reduction):
+    return {"reduction": reduction or None,
+            "readings": {"traced_steps": 4}}
+
+
+def test_the_setup_readers_sum_the_roles_and_leave_the_reading_out(
+        monkeypatch):
+    _hand_table(monkeypatch)
+    ctx = _hand_ctx()
+    assert _reader("program_trace_lower_s").read(ctx) == pytest.approx(
+        2.0 + 1.0 + 0.5 + 0.25 + 0.25 + 0.125)
+    assert _reader("program_load_s").read(ctx) == pytest.approx(
+        4.0 + 8.0 + 1.0)
+
+
+def test_the_serving_readers_find_their_programs_by_role(monkeypatch):
+    _hand_table(monkeypatch)
+    ctx = _hand_ctx(ops={}, programs={
+        "jit__step": [0.8, 40.0], "jit__prefill": [0.09, 3.0],
+        "jit_write": [0.006, 3.0], "jit_install": [0.003, 3.0],
+        "jit__select_one": [0.0009, 3.0], "jit_ref_logits": [5.0, 1.0]})
+    assert _reader("decode_device_ms").read(ctx) == pytest.approx(20.0)
+    assert _reader("admission_device_ms").read(ctx) == pytest.approx(
+        1e3 * (0.09 + 0.006 + 0.003 + 0.0009) / 3.0)
+    # a stretch without an admission has nothing to divide by
+    ctx = _hand_ctx(ops={}, programs={"jit__step": [0.8, 40.0]})
+    assert _reader("admission_device_ms").read(ctx) is None
+
+
+def test_the_training_readers_add_up_to_the_scoped_ops_seconds(
+        monkeypatch):
+    scopes = {
+        "fusion.1": ("ffn", "up", "bwd"), "fusion.2": ("ffn", "down", "fwd"),
+        "custom-call.3": ("attn", "core", "fwd"),
+        "fusion.4": ("attn", "qkv", "fwd"), "fusion.5": ("optim", "", "fwd"),
+        "fusion.6": ("head", "", "fwd"), "fusion.7": ("loss", "", "bwd"),
+        "fusion.8": ("norm", "", "fwd"), "copy.9": ("unscoped", "", "fwd"),
+    }
+    _hand_table(monkeypatch, scopes)
+    ops = {"fusion.1": [0.40, 4], "fusion.2": [0.20, 4],
+           "custom-call.3[tpu_custom_call]": [0.12, 4],
+           "fusion.4": [0.08, 4], "fusion.5": [0.04, 4],
+           "fusion.6": [0.02, 4], "fusion.7": [0.01, 4],
+           "fusion.8": [0.01, 4], "copy.9": [0.03, 4],
+           "fusion.77": [0.01, 1],          # not the step's: unscoped
+           "while.3": [0.5, 4]}             # around its children: left out
+    ctx = _hand_ctx(ops=ops, programs={"jit_step": [0.95, 4.0],
+                                       "jit_convert": [0.002, 1.0]})
+    got = {c: _reader(f"device_ms_per_step.{c}").read(ctx)
+           for c in ("attn", "ffn", "optim", "other")}
+    assert got == pytest.approx({"attn": 50.0, "ffn": 150.0, "optim": 10.0,
+                                 "other": 10.0})
+    scoped = sum(s for n, (s, _) in ops.items()
+                 if n not in ("copy.9", "fusion.77", "while.3"))
+    assert sum(got.values()) * 4 / 1e3 == pytest.approx(scoped)
+    # unscoped + what the unregistered jit_convert may have added, over
+    # all leaf ops' seconds
+    assert _reader("device_unscoped_pct").read(ctx) == pytest.approx(
+        100.0 * (0.03 + 0.01 + 0.002) / (scoped + 0.04))
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_a_reader_given_the_parents_shape_of_things_returns_none(
+        monkeypatch, name):
+    """The parent has no program table (``tracing.programs``): every
+    new reader leaves its metric out and does not raise, traced or
+    not."""
+    from mxnet_tpu import tracing
+    monkeypatch.delattr(tracing, "programs")
+    ctx = _hand_ctx(ops={"fusion.1": [0.4, 4]},
+                    programs={"jit__step": [0.8, 40.0],
+                              "jit__prefill": [0.09, 3.0]})
+    assert _reader(name).read(ctx) is None
+    assert _reader(name).read(_hand_ctx()) is None
+
+
+def test_benchmark_json_lists_the_new_readers_for_their_cells():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    assert [m["name"] for m in bench["per_layer"]][-9:] == list(NEW_READERS)
+    cells = [w["name"] for w in bench["workloads"]]
+    serve = [c for c in cells if ".serve_" in c]
+    for name in NEW_READERS:
+        mod, entry = _reader(name), entries[name]
+        assert (entry["unit"], entry["layer"], entry["moves"],
+                entry["source"], entry["better"]) == (
+            mod.UNIT, mod.LAYER, mod.MOVES, mod.SOURCE, "lower")
+        want = cells if name.startswith("program_") else serve \
+            if name.endswith("_device_ms") else ["bert_large.train_mlm512"]
+        if name == "admission_device_ms":
+            # 0.3 admissions a second: one traced stretch in three of
+            # the looped cell holds none, so the cell does not list it
+            want = [c for c in serve if c != "ouro_2_6b.serve_math"]
+        assert entry["workloads"] == want

@@ -577,6 +577,26 @@ def test_hybrid_step_compiles_for_v5e_and_reads_the_rows_in_place(one_v5e):
     of a ring or of layer 17's K or V rows on the way into or out of
     them (``chip_smoke.cache_sized_relayouts``, PR 27's check)."""
     import chip_smoke
+    hlo, S, kv, L, cfg = _hybrid_step(one_v5e)
+    calls = _kernel_calls(hlo)
+    assert calls.count("write_columns") == 9 and len(calls) == 8 + 9
+    assert _column_updates(hlo, S, kv) == []
+    assert chip_smoke.cache_sized_relayouts(hlo, S * kv * L) == []
+    assert chip_smoke.cache_sized_relayouts(
+        hlo, S * kv * cfg["window"]) == []
+
+
+# whole programs compiled for the described v5e, kept for the module:
+# --dist loadfile keeps this file on one worker, so a minute-long
+# compile runs once whichever tests read it
+_COMPILED = {}
+
+
+def _hybrid_step(one_v5e):
+    """(optimized HLO, S, kv, L, cfg) of the hybrid family's decode step
+    at the cell's shapes."""
+    if "hybrid" in _COMPILED:
+        return _COMPILED["hybrid"]
     from mxnet_tpu.gluon.model_zoo import phi4flash as pf
     from mxnet_tpu.serving.hybrid import CACHE_KIND, HybridDecodeModel
     S, L = 64, 4096
@@ -602,12 +622,8 @@ def test_hybrid_step_compiles_for_v5e_and_reads_the_rows_in_place(one_v5e):
     i32, f32 = arg((S,), jnp.int32), arg((S,), jnp.float32)
     hlo = model._step_fn.lower(params, rows, rows, state, i32, i32, i32,
                                i32, f32, i32, f32, i32).compile().as_text()
-    calls = _kernel_calls(hlo)
-    assert calls.count("write_columns") == 9 and len(calls) == 8 + 9
-    assert _column_updates(hlo, S, kv) == []
-    assert chip_smoke.cache_sized_relayouts(hlo, S * kv * L) == []
-    assert chip_smoke.cache_sized_relayouts(
-        hlo, S * kv * cfg["window"]) == []
+    _COMPILED["hybrid"] = (hlo, S, kv, L, cfg)
+    return _COMPILED["hybrid"]
 
 
 # ---------------------------------------------------------------------------
@@ -650,6 +666,23 @@ def _described_moe_model(one_v5e):
         cfg, arg
 
 
+def _moe_step(one_v5e):
+    """(compiled decode step, S, kv, L) of one chip's share of Command
+    A+ at the cell's shapes."""
+    if "moe" not in _COMPILED:
+        model, params, cfg, arg = _described_moe_model(one_v5e)
+        S, L, W = 48, 4096, cfg["window"]
+        kv = cfg["num_kv_heads"] * cfg["head_dim"]
+        rows = [arg((S, kv, L), jnp.bfloat16)]
+        ring = [arg((S, kv, W), jnp.bfloat16)] * 3
+        i32, f32 = arg((S,), jnp.int32), arg((S,), jnp.float32)
+        toks = arg((S + model.n_layers * model.held,), jnp.int32)
+        _COMPILED["moe"] = (model._step_fn.lower(
+            params, rows, rows, {"wk": ring, "wv": ring}, toks, i32, i32,
+            i32, f32, i32, f32, i32).compile(), S, kv, L)
+    return _COMPILED["moe"]
+
+
 @pytest.mark.slow
 def test_moe_step_compiles_for_v5e_and_reads_every_cache_in_place(one_v5e):
     """The whole decode step of one chip's share of Command A+, 48
@@ -659,16 +692,7 @@ def test_moe_step_compiles_for_v5e_and_reads_every_cache_in_place(one_v5e):
     size of a cache on the way into or out of them, and temporaries far
     under a cache."""
     import chip_smoke
-    model, params, cfg, arg = _described_moe_model(one_v5e)
-    S, L, W = 48, 4096, cfg["window"]
-    kv = cfg["num_kv_heads"] * cfg["head_dim"]
-    rows = [arg((S, kv, L), jnp.bfloat16)]
-    ring = [arg((S, kv, W), jnp.bfloat16)] * 3
-    i32, f32 = arg((S,), jnp.int32), arg((S,), jnp.float32)
-    toks = arg((S + model.n_layers * model.held,), jnp.int32)
-    compiled = model._step_fn.lower(
-        params, rows, rows, {"wk": ring, "wv": ring}, toks, i32, i32, i32,
-        f32, i32, f32, i32).compile()
+    compiled, S, kv, L = _moe_step(one_v5e)
     hlo = compiled.as_text()
     calls = _kernel_calls(hlo)
     assert calls.count("write_columns") == 4 and len(calls) == 4 + 4
@@ -780,6 +804,20 @@ def _described_loop_model(one_v5e):
                            "aot"), params, arg
 
 
+def _loop_step(one_v5e):
+    """(compiled decode step, S, L, E, C) of the published Ouro-2.6B at
+    the cell's shapes."""
+    if "loop" not in _COMPILED:
+        model, params, arg = _described_loop_model(one_v5e)
+        S, L, E, C = 5, 1024, 192, 2048
+        rows = [arg((E, S, C, L), jnp.bfloat16)]
+        i32, f32 = arg((S,), jnp.int32), arg((S,), jnp.float32)
+        _COMPILED["loop"] = (model._step_fn.lower(
+            params, rows, rows, i32, i32, i32, i32, f32, i32, f32,
+            i32).compile(), S, L, E, C)
+    return _COMPILED["loop"]
+
+
 @pytest.mark.slow
 def test_loop_step_compiles_for_v5e_as_a_loop_with_the_cache_in_place(
         one_v5e):
@@ -790,13 +828,7 @@ def test_loop_step_compiles_for_v5e_as_a_loop_with_the_cache_in_place(
     ``dynamic-slice`` is fused into the product that reads them), and
     temporaries of a few MB."""
     import chip_smoke
-    model, params, arg = _described_loop_model(one_v5e)
-    S, L, E, C = 5, 1024, 192, 2048
-    rows = [arg((E, S, C, L), jnp.bfloat16)]
-    i32, f32 = arg((S,), jnp.int32), arg((S,), jnp.float32)
-    compiled = model._step_fn.lower(
-        params, rows, rows, i32, i32, i32, i32, f32, i32, f32,
-        i32).compile()
+    compiled, S, L, E, C = _loop_step(one_v5e)
     hlo = compiled.as_text()
     assert sorted(_kernel_calls(hlo)) == ["ragged_attention",
                                           "write_columns"]
@@ -820,3 +852,89 @@ def test_loop_prefill_compiles_for_v5e_as_a_loop_with_flash_at_1024(
     assert hlo.count("tpu_custom_call") >= 1
     assert len(re.findall(r" while\(", hlo)) == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+# ---------------------------------------------------------------------------
+# from instruction to component (tracing.hlo_scopes) on the optimized v5e
+# text of the programs above: what the per-layer readers and
+# profiler.device_summary rest on
+# ---------------------------------------------------------------------------
+
+def _matmul_fusions(hlo):
+    """The ENTRY-level and loop-body ``fusion`` instructions of an
+    optimized HLO module whose fused computation holds a ``dot`` or a
+    ``convolution``, found without tracing.hlo_scopes: by the text."""
+    holds, fused, found, name = set(), set(), [], None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if m:
+            name = m.group(1)
+            continue
+        if re.search(r" (dot|convolution)\(", line):
+            holds.add(name)
+        m = re.match(r"^\s+(?:ROOT )?%([\w.\-]+) = .* fusion\(.*"
+                     r"calls=%([\w.\-]+)", line)
+        if m:
+            fused.add(m.group(2))
+            found.append((name, m.group(1), m.group(2)))
+    # a fusion inside a fused computation is no leaf
+    return [n for comp, n, called in found
+            if called in holds and comp not in fused]
+
+
+def _flash_step_hlo(one_v5e, monkeypatch):
+    """BERT-large's attention forward + backward through the op the zoo
+    calls (``npx.multi_head_attention``), b16 x 512, 16 heads of 64."""
+    if "flash" not in _COMPILED:
+        from mxnet_tpu.ndarray.ndarray import from_jax
+        from mxnet_tpu.ops import transformer
+        monkeypatch.setattr(transformer, "_use_pallas_len",
+                            lambda T: T >= 512)
+
+        def fn(q, k, v):
+            return transformer.multi_head_attention(
+                from_jax(q), from_jax(k), from_jax(v), 16)._data
+
+        _COMPILED["flash"] = _grad_hlo(fn, one_v5e.mesh, one_v5e.spec,
+                                       (16, 512, 1024), jnp.bfloat16)
+    return _COMPILED["flash"]
+
+
+@pytest.mark.parametrize("program", ["flash", "hybrid", "moe", "loop"])
+def test_every_matmul_and_kernel_of_a_v5e_program_resolves_to_a_component(
+        one_v5e, monkeypatch, program):
+    """On the optimized text of the hybrid, sparse-expert and looped
+    families' whole decode steps and of BERT's attention forward +
+    backward: every leaf fusion that holds a dot or a convolution and
+    every Mosaic call has a component; the ragged read is attn/core, the
+    column write cache/write, and the flash backward kernel bwd."""
+    from mxnet_tpu import tracing
+    hlo = {"flash": lambda: _flash_step_hlo(one_v5e, monkeypatch),
+           "hybrid": lambda: _hybrid_step(one_v5e)[0],
+           "moe": lambda: _moe_step(one_v5e)[0].as_text(),
+           "loop": lambda: _loop_step(one_v5e)[0].as_text()}[program]()
+    scopes, _ = tracing.hlo_scopes(hlo)
+    fusions = _matmul_fusions(hlo)
+    assert fusions or program == "flash"
+    unresolved = [n for n in fusions
+                  if scopes.get(n, ("unscoped",))[0] == "unscoped"]
+    assert unresolved == [], unresolved[:5]
+    kernels = [m.group(1) for m in re.finditer(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        hlo)]
+    assert kernels and all(k in scopes for k in kernels)
+    by_kind = {}
+    for k in kernels:
+        by_kind.setdefault(re.sub(r"\.\d+$", "", k), set()).add(scopes[k])
+    if program == "flash":
+        assert sorted(by_kind.values(), key=str) == [
+            {("attn", "core", "bwd")}, {("attn", "core", "fwd")}]
+        return
+    assert by_kind["ragged_attention"] == {("attn", "core", "fwd")}
+    assert by_kind["write_columns"] == {("cache", "write", "fwd")}
+    # the loops are around their children, not leaves beside them
+    assert not any(re.match(r"while(\.\d+)?$", n) for n in scopes)
+    comps = {c for c, _, _ in scopes.values()}
+    assert {"attn", "cache", "head", "sample", "embed"} <= comps
+    assert {"hybrid": {"ssm", "ffn", "norm"}, "moe": {"experts", "norm"},
+            "loop": {"ffn", "norm"}}[program] <= comps

@@ -490,6 +490,37 @@ def _dense_trainer():
     return tr, batch
 
 
+def test_the_train_step_is_in_the_program_table_and_its_scopes_read():
+    """SPMDTrainer's compiled step has a line of role ``train_step``;
+    its instructions resolve to ``loss`` and ``optim`` (a Dense net has
+    no other component), read by lowering the step again from the
+    shapes noted when it was traced, the parameters on the one-device
+    mesh they came in on."""
+    from mxnet_tpu import tracing
+    tracing.reset()
+    tr, batch = _dense_trainer()
+    for i in range(3):
+        tr.step(*batch(i))
+    rec, = [p for p in tracing.programs() if p.role == "train_step"]
+    assert rec.module == "jit_step" and rec.attrs["devices"] >= 1
+    assert rec.attrs["optimizer"] and rec.family
+    built, seconds = len(rec.built()), rec.seconds()
+    assert built >= 1 and rec.seconds("trace", "lower") > 0
+    scopes = rec.scopes()
+    if rec.attrs["devices"] > 1:
+        assert scopes is None       # a real mesh: until a cell wants it
+        return
+    comps = {c for c, _, _ in scopes.values()}
+    assert {"loss", "optim"} <= comps <= {"loss", "optim", "unscoped"}
+    assert {d for c, _, d in scopes.values() if c == "loss"} == {
+        "fwd", "bwd"}
+    # what the reading built (the batch came in committed to the
+    # device, which the noted shapes cannot say, so jax lowers again
+    # and, where it has a persistent cache, loads) is in no sum
+    assert len(rec.built()) == built and rec.seconds() == seconds
+    assert all(e.get("reading") for e in rec.shapes[built:])
+
+
 def test_step_spans_for_a_bare_loop_and_inside_fit():
     """A caller's own loop over trainer.step() records spmd.step >
     step.place, step.dispatch; inside fit() the same three nest under
@@ -506,9 +537,19 @@ def test_step_spans_for_a_bare_loop_and_inside_fit():
             assert root["parent_id"] == ""
             kids = [r for r in tracing.spans(root["trace_id"])
                     if r is not root]
+            # a step that builds its program also holds the build's
+            # stages, under the dispatch that caused them
+            built = [k for k in kids if k["name"].startswith("program.")]
+            kids = [k for k in kids if k not in built]
             assert sorted(k["name"] for k in kids) == ["step.dispatch",
                                                        "step.place"]
             assert all(k["parent_id"] == root["span_id"] for k in kids)
+            dispatch, = [k for k in kids if k["name"] == "step.dispatch"]
+            assert all(b["parent_id"] == dispatch["span_id"]
+                       for b in built)
+        first = {r["name"] for r in tracing.spans(roots[0]["trace_id"])
+                 if r["attrs"].get("role") == "train_step"}
+        assert {"program.trace", "program.lower"} < first
 
         tracing.reset()
         tr.fit(batch, 5)
