@@ -30,6 +30,13 @@ as much again to write and to read as the tile itself.
 
 Buffers that share ``at`` (a layer's K and V) go through one call.  On
 the CPU the kernel runs in interpret mode (``attention._interpret``).
+
+The hybrid and the sparse-expert families' decode steps call it.  The
+looped family's pass makes the same move inside its one call
+(``decode_attention.append_and_attend``: the tile that takes the column
+is the read's last block, so it is fetched once); the stacked form here
+(``entry=``) is what that call's written stacks are held to, bit for
+bit.
 """
 from __future__ import annotations
 

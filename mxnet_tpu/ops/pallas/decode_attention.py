@@ -29,6 +29,15 @@ queries ``q[s, n] (R, C)`` meet channels ``n C .. (n + 1) C`` of K and
 of V.  A family packs its heads into that shape (:func:`paired_queries`
 is the differential-attention family's packing).  On the CPU the kernel
 runs in interpret mode (``attention._interpret``).
+
+A looped family's pass WRITES the token's column into the rows it then
+reads, entry after entry of a stacked cache, 192 times a step on calls
+a twentieth the size: :func:`append_and_attend` is that pass in one
+call, the same block step (``_attend``) under a walk of its own: the
+rows stay in HBM and the kernel copies a slot's ``pos // B + 1`` live
+blocks itself (no idle steps, so a smaller ``B``: ``append_block``),
+selects the new column into the last of them in VMEM and copies the
+tile that took it back.
 """
 from __future__ import annotations
 
@@ -41,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import attention as _attention
+from .column_write import LANES, _WORD, _words
 
 # positions a block.  Fixed from chip measurements (PERF.md section 6,
 # PR 31); a bucket shorter than this is read as one block.
@@ -50,71 +60,204 @@ _SUBLANES = 8
 
 
 def row_block(length: int) -> int:
-    """Positions a block of a cache whose rows are ``length`` long."""
+    """Positions a block of ``ragged_attention``'s grid over rows
+    ``length`` long."""
     return min(ROW_BLOCK, int(length))
 
 
-def blocks_read(pos, length: int):
-    """``(read, all)``: the position blocks a call at per-slot positions
+def append_block(length: int) -> int:
+    """Positions a block of ``append_and_attend``'s copy loop over rows
+    ``length`` long: an eighth of them, from one tile column to
+    ``ROW_BLOCK``.  The loop has no idle steps to pay for, so its block
+    follows the rows: a slot's are a third of the bucket on average and
+    the last block fetched is half dead, which a larger block pays in
+    bytes and a smaller one in fixed cost a block (~0.6 us; PERF.md
+    section 6, PR 38: 128 and 256 tie at 1024, 512 loses 20 %)."""
+    length = int(length)
+    return min(length, max(LANES, min(ROW_BLOCK, length // 8)))
+
+
+def blocks_read(pos, length: int, block=None):
+    """``(read, all)``: the position blocks of ``block`` positions
+    (``row_block(length)`` unless said) a call at per-slot positions
     ``pos`` (a host vector) fetches, and those a dense read of every
     slot's ``length`` rows would."""
-    block = row_block(length)
+    block = block or row_block(length)
     return (int((pos // block + 1).sum()),
             int(pos.shape[0]) * (int(length) // block))
+
+
+def _attend(q_ref, rows, seen, scale, m_sc, l_sc, acc_sc):
+    """One position block into every group's online softmax:
+    ``rows(n)`` is group ``n``'s ``(C, block)`` K and V of it, ``seen
+    (1, block)`` the columns that count (None: all of them)."""
+    for n in range(acc_sc.shape[0]):
+        kn, vn = rows(n)
+        prec = _attention._prec(kn.dtype)
+        sc = jax.lax.dot_general(
+            q_ref[0, n], kn, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=prec) * scale
+        if seen is not None:
+            sc = jnp.where(seen, sc, _attention._NEG_INF)
+            vn = jnp.where(seen, vn, jnp.zeros_like(vn))
+        m_prev = m_sc[n]
+        m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)
+        l_sc[n] = alpha * l_sc[n] + p.sum(axis=-1, keepdims=True)
+        acc_sc[n] = alpha * acc_sc[n] + jax.lax.dot_general(
+            p.astype(vn.dtype), vn, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec)
+        m_sc[n] = m_new
+
+
+def _set(m_sc, l_sc, acc_sc):
+    m_sc[...] = jnp.full_like(m_sc, _attention._NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+
+
+def _seen(j, block, pos):
+    """``(1, block)``: the columns of block ``j`` up to ``pos``."""
+    return j * block + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block), 1) <= pos
 
 
 def _kernel(*refs, block, scale):
     # refs: pos, the entry where the rows are stacked, then q, k, v, o
     # and the scratch
     pos_ref = refs[0]
-    q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc = refs[-7:]
+    q_ref, k_ref, v_ref, o_ref, *stats = refs[-7:]
     s, i = pl.program_id(0), pl.program_id(1)
     pos = pos_ref[s]
     final = pl.num_programs(1) - 1
     # the block this step walks; negative on the slot's idle steps
     j = i - (final - pos // block)
-    G, R, C = acc_sc.shape
-    prec = _attention._prec(k_ref.dtype)
+    C = stats[2].shape[2]
+
+    def rows(n):
+        return (k_ref[0, n * C:(n + 1) * C, :],
+                v_ref[0, n * C:(n + 1) * C, :])
 
     @pl.when(i == 0)
     def _init():
-        m_sc[...] = jnp.full_like(m_sc, _attention._NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
-
-    def _block(masked):
-        if masked:
-            col = j * block + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block), 1)
-            seen = col <= pos
-        for n in range(G):
-            kn = k_ref[0, n * C:(n + 1) * C, :]
-            vn = v_ref[0, n * C:(n + 1) * C, :]
-            sc = jax.lax.dot_general(
-                q_ref[0, n], kn, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=prec) * scale
-            if masked:
-                sc = jnp.where(seen, sc, _attention._NEG_INF)
-                vn = jnp.where(seen, vn, jnp.zeros_like(vn))
-            m_prev = m_sc[n]
-            m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(sc - m_new)
-            l_sc[n] = alpha * l_sc[n] + p.sum(axis=-1, keepdims=True)
-            acc_sc[n] = alpha * acc_sc[n] + jax.lax.dot_general(
-                p.astype(vn.dtype), vn, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32, precision=prec)
-            m_sc[n] = m_new
+        _set(*stats)
 
     @pl.when(jnp.logical_and(j >= 0, i < final))
     def _interior():
-        _block(False)
+        _attend(q_ref, rows, None, scale, *stats)
 
     @pl.when(i == final)
     def _last():
-        _block(True)
-        o_ref[0] = acc_sc[...] / l_sc[...]
+        _attend(q_ref, rows, _seen(j, block, pos), scale, *stats)
+        o_ref[0] = stats[2][...] / stats[1][...]
+
+
+def _append_kernel(pos_ref, entry_ref, q_ref, kc_ref, vc_ref, k_hbm, v_hbm,
+                   o_ref, ko_hbm, vo_hbm, kbuf, vbuf, sem, out_sem, count,
+                   *stats, block, tile, scale):
+    s, S = pl.program_id(0), pl.num_programs(0)
+    e = entry_ref[0]
+    pos = pos_ref[s]
+    last = pos // block
+    C = stats[2].shape[2]
+    sides = ((k_hbm, kbuf, kc_ref, ko_hbm), (v_hbm, vbuf, vc_ref, vo_hbm))
+
+    def fetch(slot, j, b):
+        """Block ``j`` of ``slot``'s rows into buffer ``b``, K and V."""
+        return [pltpu.make_async_copy(
+            hbm.at[e, slot, :, pl.ds(pl.multiple_of(j * block, block),
+                                     block)],
+            buf.at[b], sem.at[b, i])
+            for i, (hbm, buf, _, _) in enumerate(sides)]
+
+    def tile_out(b, t):
+        """Tile column ``t`` of buffer ``b``, the one that holds ``pos``,
+        back to the cache."""
+        at = pl.multiple_of((pos // tile) * tile, tile)
+        return [pltpu.make_async_copy(
+            buf.at[b, :, t * tile:(t + 1) * tile],
+            out.at[e, s, :, pl.ds(at, tile)], out_sem.at[i])
+            for i, (_, buf, _, out) in enumerate(sides)]
+
+    @pl.when(s == 0)
+    def _first():
+        count[0] = 0
+        for c in fetch(0, 0, 0):
+            c.start()
+
+    _set(*stats)
+    # the buffers alternate over the call's blocks, not the slot's
+    base = count[0]
+
+    def arrive(j):
+        """Waits for block ``j`` of this slot, with the one after it
+        (the next slot's first behind this slot's last) on its way into
+        the other buffer; the buffer it is in."""
+        b = (base + j) % 2
+
+        @pl.when(j < last)
+        def _next_block():
+            for c in fetch(s, j + 1, 1 - b):
+                c.start()
+
+        @pl.when(jnp.logical_and(j == last, s + 1 < S))
+        def _next_slot():
+            for c in fetch(s + 1, 0, 1 - b):
+                c.start()
+
+        for c in fetch(s, j, b):
+            c.wait()
+        return b
+
+    def rows_in(b):
+        return lambda n: (kbuf[b, n * C:(n + 1) * C, :],
+                          vbuf[b, n * C:(n + 1) * C, :])
+
+    def interior(j, carry):
+        _attend(q_ref, rows_in(arrive(j)), None, scale, *stats)
+        return carry
+
+    jax.lax.fori_loop(0, last, interior, 0)
+    b = arrive(last)
+
+    # column_write's move, on the tile column of the block that holds
+    # pos: the slot's words, one a lane, down the tile's sublanes in
+    # every lane, and the select keeps lane pos % tile of them.  In the
+    # buffer itself: the block is attended as the cache will hold it,
+    # and the tile goes back from there while it is
+    for t in range(block // tile):
+        @pl.when(pos % block // tile == t)
+        def _write():
+            for _, buf, col_ref, _ in sides:
+                words = pltpu.bitcast(buf[b, :, t * tile:(t + 1) * tile],
+                                      _WORD)
+                col = jnp.broadcast_to(col_ref[0], words.shape[::-1]).T
+                hit = jax.lax.broadcasted_iota(
+                    jnp.int32, words.shape, 1) == pos % tile
+                buf[b, :, t * tile:(t + 1) * tile] = pltpu.bitcast(
+                    jnp.where(hit, col, words), buf.dtype)
+            for c in tile_out(b, t):
+                c.start()
+    _attend(q_ref, rows_in(b), _seen(last, block, pos), scale, *stats)
+    o_ref[0] = stats[2][...] / stats[1][...]
+    count[0] = base + last + 1
+    # before the next slot's second block may land in this buffer
+    for c in tile_out(b, 0):
+        c.wait()
+
+
+def _padded_queries(q):
+    """``q (S, G, R, C)`` with ``R`` padded to whole sublanes."""
+    pad = -q.shape[2] % _SUBLANES
+    return jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else q
+
+
+def _stats(G, Rp, C):
+    return [pltpu.VMEM((G, Rp, 1), jnp.float32),
+            pltpu.VMEM((G, Rp, 1), jnp.float32),
+            pltpu.VMEM((G, Rp, C), jnp.float32)]
 
 
 def ragged_attention(q, k_rows, v_rows, pos, scale: float, entry=None):
@@ -139,10 +282,8 @@ def ragged_attention(q, k_rows, v_rows, pos, scale: float, entry=None):
     if L % block:
         raise ValueError(f"rows of {L} positions are not whole blocks "
                          f"of {block}")
-    pad = -R % _SUBLANES
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    Rp = R + pad
+    q = _padded_queries(q)
+    Rp = q.shape[2]
     steps = L // block
 
     def rows_at(s, i, pos_ref, *entry_ref):
@@ -164,11 +305,7 @@ def ragged_attention(q, k_rows, v_rows, pos, scale: float, entry=None):
             ],
             out_specs=pl.BlockSpec((1, G, Rp, C),
                                    lambda s, i, *p: (s, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, Rp, 1), jnp.float32),
-                pltpu.VMEM((G, Rp, 1), jnp.float32),
-                pltpu.VMEM((G, Rp, C), jnp.float32),
-            ]),
+            scratch_shapes=_stats(G, Rp, C)),
         out_shape=jax.ShapeDtypeStruct((S, G, Rp, C), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
@@ -176,6 +313,84 @@ def ragged_attention(q, k_rows, v_rows, pos, scale: float, entry=None):
         name="ragged_attention",
     )(*prefetch, q, k_rows, v_rows)
     return out[:, :, :R]
+
+
+def append_and_attend(q, k_rows, v_rows, k_new, v_new, pos, scale: float,
+                      entry):
+    """A pass of a looped family in ONE call: every slot's new K and V
+    column written into entry ``entry`` of the STACKED rows
+    ``(E, S, G C, L)`` at ``pos[s]``, and ``ragged_attention`` over that
+    entry as written.  ``q (S, G, R, C)``; ``k_new`` / ``v_new
+    (S, G C)`` in the rows' dtype, lane-dense as the projection leaves
+    them; ``pos (S,)`` int32, clamped into ``0 .. L - 1`` as
+    ``write_columns`` clamps it (a free slot rides at 0); ``entry`` an
+    int32 scalar, traced.  Returns ``(attention (S, G, R, C) float32,
+    k_rows, v_rows)``, the stacks aliased to their operands.
+
+    The rows stay in HBM (no block spec) and the kernel, a grid over
+    slots, copies a slot's live blocks ``0 .. pos[s] // B`` itself
+    (``B = append_block(L)``), two buffers a side, the next block (the
+    next slot's first behind a slot's last) on its way while one is
+    attended: ``pos // B + 1`` copies a slot and no idle step.  On a
+    slot's last block, which holds ``pos[s]``, the new column is
+    selected into its lane IN the buffer (``column_write``'s move:
+    32-bit words, no arithmetic), so the block is attended bit for bit
+    as the cache will hold it and the result is what write-then-read
+    gives; the 128-position tile column of the buffer that holds the
+    lane is then copied back to the stack, the only bytes the call
+    writes there, fetched once.  Every other element of both stacks is
+    never touched."""
+    S, G, R, C = q.shape
+    L = k_rows.shape[-1]
+    if k_rows.ndim != 4:
+        raise ValueError(f"rows {k_rows.shape}: stacked (E, S, channels, "
+                         "L) with the entry to write and read")
+    for rows, new in ((k_rows, k_new), (v_rows, v_new)):
+        if rows.shape[1:] != (S, G * C, L) or new.shape != (S, G * C) \
+                or new.dtype != rows.dtype:
+            raise ValueError(f"column {new.shape} {new.dtype} does not "
+                             f"fit rows {rows.shape} {rows.dtype} of "
+                             f"{S} slots and {G * C} channels")
+    block = append_block(L)
+    tile = min(LANES, block)
+    if L % block:
+        raise ValueError(f"rows of {L} positions are not whole blocks "
+                         f"of {block}")
+    q = _padded_queries(q)
+    Rp = q.shape[2]
+    words = [_words(new) for new in (k_new, v_new)]
+    pos = jnp.clip(pos.astype(jnp.int32), 0, L - 1)
+    here = lambda s, *p: (s, 0, 0, 0)                    # noqa: E731
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    out, k_rows, v_rows = pl.pallas_call(
+        functools.partial(_append_kernel, block=block, tile=tile,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((1, G, Rp, C), here)] + [
+                pl.BlockSpec((1, 1, w.shape[2]), lambda s, *p: (s, 0, 0))
+                for w in words] + [anywhere] * 2,
+            out_specs=[pl.BlockSpec((1, G, Rp, C), here)] + [anywhere] * 2,
+            scratch_shapes=[
+                pltpu.VMEM((2, G * C, block), k_rows.dtype),
+                pltpu.VMEM((2, G * C, block), v_rows.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32)] + _stats(G, Rp, C)),
+        out_shape=[jax.ShapeDtypeStruct((S, G, Rp, C), jnp.float32),
+                   jax.ShapeDtypeStruct(k_rows.shape, k_rows.dtype),
+                   jax.ShapeDtypeStruct(v_rows.shape, v_rows.dtype)],
+        # behind pos and entry: q, the two columns, then K and V
+        input_output_aliases={5: 1, 6: 2},
+        # the buffers alternate from slot to slot: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_attention._interpret(),
+        name="ragged_attention",
+    )(pos, jnp.asarray(entry, jnp.int32).reshape(1), q, *words,
+      k_rows, v_rows)
+    return out[:, :, :R], k_rows, v_rows
 
 
 def paired_queries(q, pairs: int, head_dim: int):

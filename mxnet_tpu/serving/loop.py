@@ -13,11 +13,13 @@ them from inside a loop.
 Both programs are LOOPS, not ``entries`` unrolled bodies: a
 ``fori_loop`` over the loop steps around a ``scan`` over the layers'
 stacked weights.  The decode step carries K and V through both; pass
-``(t, l)`` writes the token's column of every slot into its entry in
-place (``ops.pallas.column_write`` with the entry scalar-prefetched) and
-reads the slots' live rows of that entry through the ragged kernel
-(``ops.pallas.decode_attention``, likewise), so no entry is ever sliced
-out of the stack or copied.  Prefill is one program a prompt bucket from
+``(t, l)`` makes ONE kernel call on them
+(``ops.pallas.decode_attention.append_and_attend``, the entry
+scalar-prefetched): it walks the slots' live rows of the entry with the
+token's column of every slot in place, and leaves that column written
+in the stack, aliased to its operand, so no entry is ever sliced out of
+the stack or copied and the tile that takes the column is fetched once.
+Prefill is one program a prompt bucket from
 the zoo's sequence function; its rows go into the slot through the
 cache's donated admission write, all entries in one call.
 
@@ -80,7 +82,6 @@ class LoopDecodeModel(DecodeModel):
         import jax
         import jax.numpy as jnp
         from ..gluon.model_zoo import ouro as _ou
-        from ..ops.pallas import column_write as _cw
         from ..ops.pallas import decode_attention as _da
         self.params = params
         self.cfg = cfg
@@ -129,15 +130,13 @@ class LoopDecodeModel(DecodeModel):
                 p, l = xs
                 e = _entry(t, l, N)
                 q, k, v = _ou.qkv(p, x, pos, cfg)
-                # the token's K and V column of every slot into entry
-                # e, in place; then each slot's live rows of it
-                with jax.named_scope("cache/write"):
-                    K, V = _cw.write_columns(
-                        (K, V), (k.reshape(S, heads * d),
-                                 v.reshape(S, heads * d)), pos, entry=e)
+                # each slot's live rows of entry e with the token's K
+                # and V column in place, and the column left written
                 with jax.named_scope("attn/core"):
-                    a = _da.ragged_attention(q.reshape(S, heads, 1, d), K,
-                                             V, pos, scale, entry=e)
+                    a, K, V = _da.append_and_attend(
+                        q.reshape(S, heads, 1, d), K, V,
+                        k.reshape(S, heads * d), v.reshape(S, heads * d),
+                        pos, scale, e)
                 return (_ou.finish(p, x, a.reshape(S, heads * d), cfg),
                         K, V), None
 
@@ -188,9 +187,11 @@ class LoopDecodeModel(DecodeModel):
 
     def row_blocks(self, positions: _np.ndarray,
                    bucket: int) -> Tuple[int, int]:
-        """Every entry is read by the same extent."""
+        """Every entry is read by the same extent, in the blocks of
+        the appended walk."""
         from ..ops.pallas import decode_attention as _da
-        read, every = _da.blocks_read(_np.asarray(positions), bucket)
+        read, every = _da.blocks_read(_np.asarray(positions), bucket,
+                                      _da.append_block(bucket))
         return self.entries * read, self.entries * every
 
     def dispatch(self, cache: Any, tokens: Any, positions: _np.ndarray,

@@ -627,8 +627,9 @@ def test_the_loop_job_end_to_end_at_a_tiny_size(tmp_path, monkeypatch,
     assert 0 < value["decode_hbm_pct"] and 0 < value["decode_attn_roofline_pct"]
     # 9 entries of 4 heads x 16, K and V, float32, one bucket of 1024
     assert value["cache_bytes_per_slot"] == 9 * 2 * 64 * 4 * 1024
-    # every slot sits in the first of the bucket's two blocks
-    assert value["rows_read_pct"] == 50.0
+    # every slot sits in the first of the bucket's eight blocks (the
+    # appended walk's: 128 positions of 1024)
+    assert value["rows_read_pct"] == 12.5
     _family_programs("loop")
 
 

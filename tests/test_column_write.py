@@ -196,3 +196,35 @@ def test_a_stack_without_its_entry_is_refused():
     with pytest.raises(ValueError, match="stacked"):
         cw.write_columns((buf[0],), (col,), jnp.zeros(3, jnp.int32),
                          jnp.int32(0))
+
+
+# ---------------------------------------------------------------------------
+# the same move inside the looped family's one call a pass
+# (decode_attention.append_and_attend): the written stacks against this
+# kernel's; the attention it returns is tests/test_decode_attention.py's
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("entry", [0, 3])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_the_appended_walk_moves_the_column_as_this_kernel_does(dtype,
+                                                                entry):
+    """Columns of every bit pattern (NaNs, infinities, subnormals)
+    through ``append_and_attend``: both stacks come out bit for bit as
+    ``write_columns`` leaves them, first and last lane of a tile, the
+    rows' last column and a position past it (clamped) among them."""
+    from mxnet_tpu.ops.pallas import decode_attention as da
+    E, S, G, C, L = 4, 5, 2, CHANNELS // 2, 1024
+    rng = onp.random.default_rng(entry + 7)
+    at = jnp.asarray([0, 127, 128, L - 1, L + 9], jnp.int32)
+    kb = jnp.asarray(rng.normal(size=(E, S, CHANNELS, L)), dtype)
+    vb = jnp.asarray(rng.normal(size=(E, S, CHANNELS, L)), dtype)
+    kc = _random_bits(rng, (S, CHANNELS), dtype)
+    vc = _random_bits(rng, (S, CHANNELS), dtype)
+    q = jnp.zeros((S, G, 1, C), dtype)
+    _, got_k, got_v = jax.jit(da.append_and_attend, static_argnums=6)(
+        q, kb, vb, kc, vc, at, 1.0, jnp.int32(entry))
+    want_k, want_v = cw.write_columns((kb, vb), (kc, vc), at,
+                                      jnp.int32(entry))
+    onp.testing.assert_array_equal(_bits(got_k), _bits(want_k))
+    onp.testing.assert_array_equal(_bits(got_v), _bits(want_v))

@@ -15,6 +15,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import metrics, serving, tracing
 from mxnet_tpu.gluon.model_zoo import phi4flash as pf
+from mxnet_tpu.ops.pallas import column_write as cw
 from mxnet_tpu.ops.pallas import decode_attention as da
 
 BLOCK = 16
@@ -118,6 +119,135 @@ def test_rows_that_are_not_whole_blocks_are_refused():
         da.paired_decode_attention(q, ck[:, :, :BLOCK + 8],
                                    cv[:, :, :BLOCK + 8],
                                    jnp.zeros((6,), jnp.int32), D)
+
+
+# ---------------------------------------------------------------------------
+# the appended walk (a looped family's pass in one call): the column
+# written inside the read
+# ---------------------------------------------------------------------------
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+# (rows, the block the walk takes of them): every size append_block
+# can return, and rows shorter than a tile column, which are one block
+WALKS = [(1024, 128), (2048, 256), (4096, 512), (64, 64)]
+
+
+@pytest.fixture
+def real_blocks(monkeypatch):
+    # the appended walk's block is an eighth of the rows between a tile
+    # column and the grid's block: at the sizes of the cells
+    monkeypatch.setattr(da, "ROW_BLOCK", 512)
+
+
+@pytest.mark.parametrize("L,B", WALKS)
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 1e-3)],
+                         ids=["float32", "bfloat16"])
+def test_append_and_attend_is_write_columns_then_ragged_attention(
+        real_blocks, monkeypatch, L, B, dtype, tol):
+    """The one call against the pair it replaced, on the same operands:
+    the attention to float32 round-off where the pair walks the call's
+    own blocks of ``B`` (the same arithmetic in the same order) and to
+    the rows' round-off where it walks its grid's 512 (bfloat16
+    probabilities are rounded under another running maximum); both
+    stacks bit for bit what the column write leaves, so every other
+    entry and every tile the walk did not write untouched.  Slots at 0,
+    at the last column of a block, the first of the next, the rows'
+    last, a free slot riding at 0, one inside."""
+    assert da.append_block(L) == B
+    E, G, R, C, entry = 3, 2, 1, 16, 1
+    pos = np.asarray([0, B - 1, B % L, L - 1, 0, (B + 5) % L], np.int32)
+    S = len(pos)
+    rng = np.random.default_rng(L)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), dtype)  # noqa
+    q, kn, vn = draw(S, G, R, C), draw(S, G * C), draw(S, G * C)
+    K, V = draw(E, S, G * C, L), draw(E, S, G * C, L)
+    at, e = jnp.asarray(pos), jnp.int32(entry)
+    got, gk, gv = jax.jit(da.append_and_attend, static_argnums=6)(
+        q, K, V, kn, vn, at, 0.25, e)
+    wk, wv = cw.write_columns((K, V), (kn, vn), at, e)
+    want = da.ragged_attention(q, wk, wv, at, 0.25, e)
+    monkeypatch.setattr(da, "ROW_BLOCK", B)
+    same = da.ragged_attention(q, wk, wv, at, 0.25, e)
+    assert got.shape == (S, G, R, C) and got.dtype == jnp.float32
+    top = float(jnp.abs(want).max())
+    assert float(jnp.abs(got - same).max()) <= 2e-6 * top
+    assert float(jnp.abs(got - want).max()) <= tol * top
+    for g, w, before, new in ((gk, wk, K, kn), (gv, wv, V, vn)):
+        assert g.shape == before.shape and g.dtype == before.dtype
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+        changed = np.asarray(_bits(g) != _bits(before))
+        # only entry `entry`, and there only column pos[s] of slot s
+        assert not changed[[0, 2]].any()
+        for s in range(S):
+            assert not np.delete(changed[entry, s], pos[s], axis=1).any()
+            np.testing.assert_array_equal(
+                _bits(g[entry, s, :, pos[s]]), _bits(new[s]))
+
+
+@pytest.mark.parametrize("L,B", WALKS)
+def test_blocks_read_of_the_appended_walk_equal_a_hand_count(real_blocks,
+                                                             L, B):
+    pos = np.asarray([0, B - 1, B % L, L - 1, 0, (B + 5) % L])
+    read, every = da.blocks_read(pos, L, da.append_block(L))
+    live = [1, 1, 2, L // B, 1, 2] if L > B else [1] * 6
+    assert (read, every) == (sum(live), 6 * (L // B))
+    # the grid's block, unless said: what the 3-D callers count
+    assert da.blocks_read(pos, L) == (
+        int((pos // min(512, L) + 1).sum()), 6 * (L // min(512, L)))
+    assert da.row_block(4096) == 512 and da.row_block(L) == min(512, L)
+
+
+def test_the_appended_walk_inside_a_loop_writes_and_reads_every_entry():
+    """The entry is a traced loop index, as in the looped family's
+    decode step: pass e attends entry e with its column in place, hands
+    its result on as the next pass's column, and the carried stacks end
+    with every entry's column written."""
+    E, S, G, C, L = 4, 3, 2, 16, 256
+    rng = np.random.default_rng(3)
+    K = jnp.asarray(rng.normal(size=(E, S, G * C, L)), jnp.float32)
+    V = jnp.asarray(rng.normal(size=(E, S, G * C, L)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(S, G * C)), jnp.float32)
+    pos = jnp.asarray([0, 127, 200], jnp.int32)
+
+    def one(e, carry):
+        K, V, x = carry
+        a, K, V = da.append_and_attend(x.reshape(S, G, 1, C), K, V, x, -x,
+                                       pos, 0.25, e)
+        return K, V, a.reshape(S, G * C)
+
+    def pair(e, carry):
+        K, V, x = carry
+        K, V = cw.write_columns((K, V), (x, -x), pos, e)
+        a = da.ragged_attention(x.reshape(S, G, 1, C), K, V, pos, 0.25, e)
+        return K, V, a.reshape(S, G * C)
+
+    got = jax.jit(lambda *c: jax.lax.fori_loop(0, E, one, c))(K, V, x)
+    want = jax.jit(lambda *c: jax.lax.fori_loop(0, E, pair, c))(K, V, x)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(g - w).max()) <= 2e-6 * float(jnp.abs(w).max())
+    assert float(jnp.abs(got[0] - K).max()) > 0
+
+
+@pytest.mark.parametrize("what,match", [
+    ("plain rows", "stacked"), ("narrow column", "does not fit"),
+    ("other dtype", "does not fit"), ("part block", "whole blocks")])
+def test_append_and_attend_refuses_operands_that_do_not_fit(what, match):
+    E, S, G, C, L = 2, 3, 2, 16, 256
+    q = jnp.zeros((S, G, 1, C))
+    K = jnp.zeros((E, S, G * C, L))
+    new = jnp.zeros((S, G * C))
+    pos = jnp.zeros((S,), jnp.int32)
+    args = {"plain rows": (q, K[0], K[0], new, new),
+            "narrow column": (q, K, K, new[:, :C], new),
+            "other dtype": (q, K, K, new.astype(jnp.bfloat16), new),
+            "part block": (q, K[..., :200], K[..., :200], new, new)}[what]
+    with pytest.raises(ValueError, match=match):
+        da.append_and_attend(*args, pos, 0.25, jnp.int32(0))
 
 
 # ---------------------------------------------------------------------------

@@ -718,8 +718,9 @@ def test_moe_prefill_compiles_for_v5e_with_flash_and_the_grouped_matmul(
 
 
 # ---------------------------------------------------------------------------
-# the looped (Ouro) family (serving/loop.py): both kernels on a STACKED
-# cache, the entry scalar-prefetched, from inside a loop
+# the looped (Ouro) family (serving/loop.py): ONE kernel call a pass on a
+# STACKED cache, the entry scalar-prefetched, from inside a loop; the
+# stacked forms of the two kernels it replaced are the pair it is held to
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("entry", [0, 1, 3])
@@ -746,42 +747,53 @@ def test_stacked_decode_attention_equals_the_plain_form_entry_by_entry(
         da.ragged_attention(q, ck, cv, pos, 0.25)
 
 
-def _loop_of_passes(E, S, G, C, L):
-    """Both kernels as the looped family's decode step calls them: a
-    ``fori_loop`` over the entries with the stacked K and V carried."""
+def _loop_of_passes(E, S, G, C, L, form):
+    """A looped family's passes, a ``fori_loop`` over the entries with
+    the stacked K and V carried: ``form`` ``"one call"`` as its decode
+    step makes them, ``"pair"`` the column write then the ragged read
+    that the one call replaced (PR 35's step)."""
     from mxnet_tpu.ops.pallas import column_write as cw
     from mxnet_tpu.ops.pallas import decode_attention as da
 
     def step(K, V, x, pos):
         def body(e, carry):
             K, V, x = carry
-            K, V = cw.write_columns((K, V), (x, x), pos, entry=e)
-            a = da.ragged_attention(x.reshape(S, G, 1, C), K, V, pos,
-                                    C ** -0.5, entry=e)
+            q = x.reshape(S, G, 1, C)
+            if form == "pair":
+                K, V = cw.write_columns((K, V), (x, x), pos, entry=e)
+                a = da.ragged_attention(q, K, V, pos, C ** -0.5, entry=e)
+            else:
+                a, K, V = da.append_and_attend(q, K, V, x, x, pos,
+                                               C ** -0.5, e)
             return K, V, a.reshape(S, G * C).astype(x.dtype)
         return jax.lax.fori_loop(0, E, body, (K, V, x))
     return step
 
 
+@pytest.mark.parametrize("form,calls", [
+    ("one call", ["ragged_attention"]),
+    ("pair", ["ragged_attention", "write_columns"])])
 def test_the_stacked_kernels_compile_for_v5e_in_a_loop_at_the_cells_shapes(
-        one_v5e):
+        one_v5e, form, calls):
     """192 entries, 5 slots, 16 heads of 128, the 1024 bucket, bfloat16
-    (ouro_2_6b.serve_math): Mosaic takes both leading-axis forms, the
-    program holds ONE call of each inside one ``while``, the two stacks
-    (8.05 GB) are aliased whole to the results, and nothing the size of
-    an entry (or of the stack) is copied, sliced out or relaid."""
+    (ouro_2_6b.serve_math): Mosaic takes the one call a pass (the rows
+    left in HBM, its own copies of 128-position blocks, the tile copied
+    back into the aliased stack) and the pair of leading-axis forms it
+    replaced; the program holds ONE Mosaic call (one of each for the
+    pair) inside one ``while``, the two stacks (8.05 GB) are aliased
+    whole to the results, and nothing the size of an entry (or of the
+    stack) is copied, sliced out or relaid."""
     import chip_smoke
     E, S, G, C, L = 192, 5, 16, 128, 1024
     arg = lambda shape, dt: jax.ShapeDtypeStruct(      # noqa: E731
         shape, dt, sharding=one_v5e)
     stack = arg((E, S, G * C, L), jnp.bfloat16)
-    compiled = jax.jit(_loop_of_passes(E, S, G, C, L),
+    compiled = jax.jit(_loop_of_passes(E, S, G, C, L, form),
                        donate_argnums=(0, 1)).lower(
         stack, stack, arg((S, G * C), jnp.bfloat16),
         arg((S,), jnp.int32)).compile()
     hlo = compiled.as_text()
-    assert sorted(_kernel_calls(hlo)) == ["ragged_attention",
-                                          "write_columns"]
+    assert sorted(_kernel_calls(hlo)) == calls
     assert len(re.findall(r" while\(", hlo)) == 1
     for size in (S * G * C * L, E * S * G * C * L):
         assert chip_smoke.cache_sized_relayouts(hlo, size) == []
@@ -822,16 +834,16 @@ def _loop_step(one_v5e):
 def test_loop_step_compiles_for_v5e_as_a_loop_with_the_cache_in_place(
         one_v5e):
     """The whole decode step of the published Ouro-2.6B, 5 slots on the
-    1024 bucket (about half a minute): two Mosaic calls and two
-    ``while`` loops for 192 layer passes, the stacked K and V aliased to
-    the results, no copy of an entry or of a layer's weights (their
+    1024 bucket (about half a minute): ONE Mosaic call (the read that
+    writes the column; no ``write_columns`` beside it) and two ``while``
+    loops for 192 layer passes, the stacked K and V aliased to the
+    results, no copy of an entry or of a layer's weights (their
     ``dynamic-slice`` is fused into the product that reads them), and
     temporaries of a few MB."""
     import chip_smoke
     compiled, S, L, E, C = _loop_step(one_v5e)
     hlo = compiled.as_text()
-    assert sorted(_kernel_calls(hlo)) == ["ragged_attention",
-                                          "write_columns"]
+    assert _kernel_calls(hlo) == ["ragged_attention"]
     assert len(re.findall(r" while\(", hlo)) == 2
     for size in (S * C * L, E * S * C * L):
         assert chip_smoke.cache_sized_relayouts(hlo, size) == []
@@ -931,10 +943,13 @@ def test_every_matmul_and_kernel_of_a_v5e_program_resolves_to_a_component(
             {("attn", "core", "bwd")}, {("attn", "core", "fwd")}]
         return
     assert by_kind["ragged_attention"] == {("attn", "core", "fwd")}
-    assert by_kind["write_columns"] == {("cache", "write", "fwd")}
+    # the looped family's read writes the column itself
+    assert by_kind.get("write_columns") == (
+        None if program == "loop" else {("cache", "write", "fwd")})
     # the loops are around their children, not leaves beside them
     assert not any(re.match(r"while(\.\d+)?$", n) for n in scopes)
     comps = {c for c, _, _ in scopes.values()}
-    assert {"attn", "cache", "head", "sample", "embed"} <= comps
+    assert {"attn", "head", "sample", "embed"} <= comps
+    assert "cache" in comps or program == "loop"
     assert {"hybrid": {"ssm", "ffn", "norm"}, "moe": {"experts", "norm"},
             "loop": {"ffn", "norm"}}[program] <= comps

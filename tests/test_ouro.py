@@ -296,6 +296,24 @@ def test_a_batch_at_different_positions_across_a_block_boundary(model,
     assert worst < TOL and moved == 0
 
 
+def test_slots_on_either_side_of_an_edge_of_the_walks_block(model, params):
+    """The one call a pass walks blocks of 128 positions of the 1024
+    bucket: a slot whose column lands in the last lane of a block, one
+    in the first lane of the next, one that crosses the edge during the
+    steps and one that crosses the next edge; every entry of every slot
+    is the reference's, the tile that took the column included."""
+    from mxnet_tpu.ops.pallas import decode_attention as da
+    assert da.append_block(1024) == 128
+    prompts = [prompt(127, 40), prompt(128, 41), prompt(122, 42),
+               prompt(250, 43)]
+    seqs, answers, cache = decode_through_the_cache(model, prompts, 10)
+    assert cache.bucket == 1024 and list(cache.positions) == [
+        137, 138, 132, 260]
+    worst, moved = worst_against_the_reference(
+        model, params, seqs, prompts, answers, cache)
+    assert worst < TOL and moved == 0
+
+
 def test_the_rows_grow_through_the_bucket_grid(model, params):
     prompts = [prompt(60, 24), prompt(5, 25)]
     m0 = metrics.value("mxnet_gen_kv_migrations_total")
@@ -334,12 +352,14 @@ def _step_jaxpr(model, S=3, L=128):
 
 def test_the_decode_program_is_a_loop_over_the_stacked_cache(model):
     """One loop over the loop steps around one over the layers (both
-    ``scan`` in the jaxpr, ``while`` in the HLO), with the two kernels
-    called once each inside (not 9 x 2 unrolled), and no value of one
-    entry's shape anywhere: the kernels take the whole stack and the
-    entry's index."""
+    ``scan`` in the jaxpr, ``while`` in the HLO), with the ONE kernel
+    that writes the column and reads the rows called once inside (not 9
+    unrolled, and no write beside it), and no value of one entry's
+    shape anywhere: the kernel takes the whole stack and the entry's
+    index."""
     text = _step_jaxpr(model)
-    assert text.count("pallas_call[") == 2 and text.count("scan[") == 2
+    assert text.count("pallas_call[") == 1 and text.count("scan[") == 2
+    assert "name=ragged_attention" in text
     assert "f32[9,3,64,128]" in text and "f32[3,64,128]" not in text
 
 
@@ -567,10 +587,13 @@ def test_the_cache_is_one_stacked_buffer_a_side(model):
         == d["bytes"]["rows"]
     assert [b.shape for b in cache._k + cache._v] == [(ENTRIES, 4, C, 64)] * 2
     assert cache.k(4).shape == (4, C, 64)
-    # every entry is read by each slot's extent: blocks of 512
+    # every entry is read by each slot's extent, in the appended
+    # walk's blocks: an eighth of the bucket, 256 of 2048
     pos = np.array([0, 600, 1000, 2000])
-    assert model.row_blocks(pos, 2048) == (ENTRIES * (1 + 2 + 2 + 4),
-                                           ENTRIES * 4 * 4)
+    assert model.row_blocks(pos, 2048) == (ENTRIES * (1 + 3 + 4 + 8),
+                                           ENTRIES * 4 * 8)
+    assert model.row_blocks(pos[:3], 1024) == (ENTRIES * (1 + 5 + 8),
+                                               ENTRIES * 3 * 8)
 
 
 @pytest.mark.parametrize("kwargs,match", [
