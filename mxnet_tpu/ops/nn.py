@@ -21,6 +21,7 @@ from typing import Any, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .._tape import is_training
@@ -66,6 +67,47 @@ def _pair(v, n):
 # contrib gelu; python gluon.nn.activations)
 # ---------------------------------------------------------------------------
 
+@jax.custom_jvp
+def _erf_gelu(x):
+    return jax.nn.gelu(x, approximate=False)
+
+
+@_erf_gelu.defjvp
+def _erf_gelu_jvp(primals, tangents):
+    """The exact GELU ``x·Φ(x)`` with ``Φ`` evaluated once for the value
+    and the derivative ``Φ(x) + x·φ(x)`` together. Left to autodiff, XLA
+    keeps only ``x`` and recomputes the erfc wherever the value or the
+    derivative is consumed: in the prologue of the next matmul, of its
+    weight gradient and in the epilogue of its input gradient, each then
+    bound by the vector unit. Here the value (at ``x``'s dtype) and the
+    derivative (at least float32) are formed in one place and stored,
+    and the backward is one multiply."""
+    (x,), (t,) = primals, tangents
+    wide = jnp.promote_types(x.dtype, jnp.float32)
+    # jax.nn.gelu's own operations and roundings, so y is its value bit
+    # for bit: 0.5·x·erfc(z), z = -x·√½ at x's dtype
+    sqrt_half = np.sqrt(0.5).astype(x.dtype)
+    z = (-x * sqrt_half).astype(wide)
+    erfc = lax.erfc(z)
+    xw = x.astype(wide)
+    y = (0.5 * xw * erfc.astype(x.dtype).astype(wide)).astype(x.dtype)
+    # d/dz erfc(z) = -2/√π·exp(-z²): exp(-z²) is the erfc's own, shared
+    dy = 0.5 * erfc + xw * jnp.exp(-(z * z)) * (
+        float(sqrt_half) / np.sqrt(np.pi))
+    # stored as they are: fused into their consumers they would be
+    # recomputed in each of them
+    y, dy = lax.optimization_barrier((y, dy))
+    return y, (t.astype(wide) * dy).astype(t.dtype)
+
+
+def _gelu(x, approximate: bool = False):
+    """The one GELU of the op library: the tanh form, or the exact form
+    (``x·Φ(x)``) with its own derivative rule."""
+    if approximate:
+        return jax.nn.gelu(x, approximate=True)
+    return _erf_gelu(x)
+
+
 _ACT_FNS = {
     "relu": jax.nn.relu,
     "sigmoid": jax.nn.sigmoid,
@@ -74,7 +116,7 @@ _ACT_FNS = {
     "softrelu": jax.nn.softplus,
     "softplus": jax.nn.softplus,
     "softsign": jax.nn.soft_sign,
-    "gelu": jax.nn.gelu,
+    "gelu": _gelu,
     "silu": jax.nn.silu,
     "swish": jax.nn.silu,
     "mish": jax.nn.mish,
@@ -100,7 +142,7 @@ def leaky_relu(data, slope: float = 0.25, act_type: str = "leaky"):
     if act_type == "elu":
         return elu(data, s)
     if act_type == "gelu":
-        return invoke("gelu", jax.nn.gelu, (_as_nd(data),))
+        return gelu(data)
     if act_type == "selu":
         return selu(data)
     raise ValueError(f"unknown leaky_relu act_type {act_type}")
@@ -123,8 +165,7 @@ def selu(data):
 
 def gelu(data, approximate: bool = False):
     ap = approximate
-    return invoke("gelu", lambda x: jax.nn.gelu(x, approximate=ap),
-                  (_as_nd(data),))
+    return invoke("gelu", lambda x: _gelu(x, ap), (_as_nd(data),))
 
 
 def silu(data):

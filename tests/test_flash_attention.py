@@ -497,6 +497,85 @@ def test_flash_compiles_for_v5e_with_no_copy_of_a_projection(
     assert chip_smoke.cache_sized_relayouts(hlo, B * T * H * D) == []
 
 
+def _fusions_holding(hlo, op):
+    """``{fusion name: [the op_name metadata of its convolutions]}`` for
+    every fusion of an optimized HLO module whose fused computation (or
+    one it calls) holds an instruction that jax lowered from ``op``
+    (its metadata's op_name ends in ``/<op>``)."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+
+    def lines(name, seen=()):
+        for line in comps.get(name, []):
+            yield line
+            for sub in re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", line):
+                if sub not in seen:
+                    yield from lines(sub, seen + (name,))
+
+    found = {}
+    for body in comps.values():
+        for line in body:
+            m = re.match(r"\s*%([\w.\-]+) = .* fusion\(.*calls=%([\w.\-]+)",
+                         line)
+            if not m:
+                continue
+            inner = list(lines(m.group(2)))
+            if any(re.search(rf'op_name="[^"]*/{op}"', s) for s in inner):
+                found[m.group(1)] = [
+                    re.search(r'op_name="([^"]*)"', s).group(1)
+                    for s in inner if " convolution(" in s]
+    return found
+
+
+def test_bert_layer_step_compiles_for_v5e_with_one_gelu_evaluation(
+        one_v5e, monkeypatch):
+    """The zoo's BERT layer (post-LN, exact GELU, bfloat16) forward and
+    backward at bert_large.train_mlm512's shapes: the erfc of the exact
+    GELU is evaluated in ONE fusion, FFN1's forward, which also forms
+    its derivative; FFN2's forward, FFN2's backward to its input and
+    dW2 read what it stored.  The parent's step held the erfc in six
+    fusions of the layer: those three matmul fusions (each then bound by
+    the vector unit, 41-45 % of its matmul's peak on the chip), FFN1's
+    forward (for a sign mask) and two elementwise fusions."""
+    from mxnet_tpu import _tape
+    from mxnet_tpu.gluon.block import _bind_params
+    from mxnet_tpu.gluon.model_zoo.bert import BERTEncoderLayer
+    from mxnet_tpu.ndarray.ndarray import from_jax
+    monkeypatch.setenv("MXNET_ATTENTION_USE_PALLAS", "1")
+    B, T, C, F, H = 16, 512, 1024, 4096, 16
+    layer = BERTEncoderLayer(C, F, H, dropout=0.0)
+    layer.initialize()
+    layer.cast("bfloat16")
+    params = list(layer.collect_params().values())
+    arg = lambda shape: jax.ShapeDtypeStruct(      # noqa: E731
+        shape, jnp.bfloat16, sharding=one_v5e)
+
+    def loss(pa, x):
+        with _bind_params(params, pa):
+            prev = _tape.set_training(True)
+            try:
+                y = layer.forward(from_jax(x),
+                                  from_jax(jnp.ones((B, 1, 1, T), bool)))
+            finally:
+                _tape.set_training(prev)
+        return y._data.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        [arg(p.shape) for p in params], arg((B, T, C))).compile().as_text()
+    erfc = _fusions_holding(hlo, "erfc")
+    assert len(erfc) == 1, sorted(erfc)
+    (convs,) = erfc.values()
+    assert len(convs) == 1 and "ffn/up" in convs[0] \
+        and "transpose" not in convs[0], convs
+
+
 def test_decode_attention_compiles_for_v5e_at_the_serving_cells_shapes(
         one_v5e):
     """64 slots, 1280 K/V channels, the 4096 bucket, bfloat16, the
