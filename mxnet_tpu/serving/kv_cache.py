@@ -232,7 +232,9 @@ class PagedKVCache:
             # a retirement; stacked rows are 8 GB to zero and upload
             return
         # what is held goes before what replaces it is made: stacked
-        # rows are most of a chip
+        # rows are most of a chip, and two buckets of a wide family's
+        # rows are more than it has
+        del held
         self._k = self._v = []
         # device_put COMMITS the buffers: a jitted call keys its cache
         # on input committed-ness, so fresh uncommitted zeros would
@@ -442,8 +444,15 @@ class PagedKVCache:
     def grow(self, new_bucket: int) -> None:
         if new_bucket <= self.bucket:
             return
-        self._k = [_grow_rows(k, new_bucket) for k in self._k]
-        self._v = [_grow_rows(v, new_bucket) for v in self._v]
+        # a buffer at a time, each old one given back before the next
+        # new one is made: dispatched together, every pad's result is
+        # allocated while every old buffer is still held, and the two
+        # buckets' rows side by side are more than a wide family's chip
+        # has beside its weights
+        for bufs in (self._k, self._v):
+            for i in range(len(bufs)):
+                bufs[i] = _grow_rows(bufs[i], new_bucket)
+                bufs[i].block_until_ready()
         self.bucket = new_bucket
         _metrics.GEN_KV_MIGRATIONS_TOTAL.inc()
         _metrics.GEN_KV_BUCKET_LEN.set(new_bucket)

@@ -659,16 +659,19 @@ class DecodeModel:
     @staticmethod
     def from_block(block: Any) -> "DecodeModel":
         """Build from a live zoo LM (weights as currently
-        initialized/loaded).  Four families are served: a ``GPTModel``
+        initialized/loaded).  Five families are served: a ``GPTModel``
         with dense FFNs (one built with ``moe_experts`` keeps
         ``MoEDense``'s capacity mask, which drops tokens, and is
         refused), a ``Phi4FlashModel`` (``serving.hybrid``), a
         ``Cohere2MoEModel`` (``serving.moe``: dropless top-k experts, of
-        which the model holds a share) and an ``OuroModel``
+        which the model holds a share), an ``OuroModel``
         (``serving.loop``: one stack of layers run several times a
-        token), each of the last three through its own subclass."""
+        token) and an ``OlmoHybridModel`` (``serving.delta``: delta-rule
+        layers beside full attention), each of the last four through
+        its own subclass."""
         from ..gluon.model_zoo.cohere2moe import Cohere2MoEModel
         from ..gluon.model_zoo.generation import _collect
+        from ..gluon.model_zoo.olmo_hybrid import OlmoHybridModel
         from ..gluon.model_zoo.ouro import OuroModel
         from ..gluon.model_zoo.phi4flash import Phi4FlashModel
         if isinstance(block, OuroModel):
@@ -680,11 +683,15 @@ class DecodeModel:
         if isinstance(block, Cohere2MoEModel):
             from .moe import MoEDecodeModel
             return MoEDecodeModel.from_cohere2moe(block)
+        if isinstance(block, OlmoHybridModel):
+            from .delta import DeltaDecodeModel
+            return DeltaDecodeModel.from_olmo_hybrid(block)
         if not hasattr(block, "blocks") or not hasattr(block,
                                                        "word_embed"):
             raise MXNetError(
                 f"DecodeModel serves decoder-only zoo LMs (GPTModel, "
-                f"Phi4FlashModel, Cohere2MoEModel, OuroModel); got "
+                f"Phi4FlashModel, Cohere2MoEModel, OuroModel, "
+                f"OlmoHybridModel); got "
                 f"{type(block).__name__}")
         params = _collect(block)
         ga = (params.pop("gelu_approx"), params.pop("ln_eps"))

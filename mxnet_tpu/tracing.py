@@ -646,6 +646,7 @@ COMPONENTS = (
     "ffn", "ffn/up", "ffn/down",
     "experts", "experts/route", "experts/routed", "experts/shared",
     "ssm",
+    "delta", "delta/conv", "delta/chunk", "delta/state",
     "norm",
     "cache", "cache/write", "cache/grow",
     "head",

@@ -760,8 +760,10 @@ def test_the_loop_cell_resolves_from_the_real_benchmark():
         <= set(listed)
     assert lastline.cell_metrics(bench, cell, 0) == {
         "setup_s": "s", "serve_tokens_per_s": "tokens/s"}
-    assert len(bench["workloads"]) == 5 \
-        and all(w["chips"] == 1 for w in bench["workloads"])
+    # the cell's own entry: one chip (what other cells the benchmark
+    # holds, and on how many chips, is theirs to say)
+    assert next(w for w in bench["workloads"]
+                if w["name"] == cell)["chips"] == 1
     mix = found["cell"]["traffic"]
     assert (mix["prompt"], mix["output"], mix["ramp_s"],
             mix["at_window_end"]) == (
@@ -925,7 +927,10 @@ def test_benchmark_json_lists_the_new_readers_for_their_cells():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-9:] == list(NEW_READERS)
+    # all there, in their order, wherever later metrics put them
+    names = [m["name"] for m in bench["per_layer"]]
+    assert set(NEW_READERS) <= set(names)
+    assert [n for n in names if n in NEW_READERS] == list(NEW_READERS)
     cells = [w["name"] for w in bench["workloads"]]
     serve = [c for c in cells if ".serve_" in c]
     for name in NEW_READERS:

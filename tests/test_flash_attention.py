@@ -647,6 +647,28 @@ def test_column_write_compiles_for_v5e_in_place_at_the_cells_shapes(
     assert memory.temp_size_in_bytes < 2 ** 20
 
 
+def test_delta_step_compiles_for_v5e_in_place_at_the_cells_shape(one_v5e):
+    """The delta-rule state kernel at the Olmo Hybrid cell's shape (40
+    slots, 30 heads of 96 x 192, float32): Mosaic takes it (a head's k
+    and q selected down the sublanes into the lanes it owns, 384-lane
+    units), the donated state IS the result, and nothing the size of
+    the state is copied on the way in or out."""
+    import chip_smoke
+    from mxnet_tpu.ops.pallas import delta_step as ds
+    S, H, dk, dv = 40, 30, 96, 192
+    arg = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_v5e)
+    compiled = jax.jit(ds.delta_step, donate_argnums=(0,)).lower(
+        arg((S, dk, H * dv)), arg((S, H, dk)), arg((S, H, dk)),
+        arg((S, H, dv)), arg((S, H)), arg((S, H))).compile()
+    hlo = compiled.as_text()
+    assert _kernel_calls(hlo) == ["delta_step"]
+    assert chip_smoke.cache_sized_relayouts(hlo, S * dk * H * dv) == []
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == S * dk * H * dv * 4
+    assert memory.temp_size_in_bytes < 2 ** 22
+
+
 @pytest.mark.slow
 def test_hybrid_step_compiles_for_v5e_and_reads_the_rows_in_place(one_v5e):
     """The whole decode step of the published Phi-4-mini-flash, 64

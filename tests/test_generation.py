@@ -121,6 +121,57 @@ def test_kv_cache_slots_and_buckets():
         c.ensure_capacity(40)                # past the top bucket
 
 
+def test_a_new_bucket_is_allocated_after_the_old_rows_are_given_back(
+        monkeypatch):
+    """Warm-up and the shrink of an emptied cache reallocate the rows at
+    another bucket: no buffer of the old bucket may still be alive when
+    the first of the new one is made (two buckets' rows of a wide family
+    do not fit the chip beside its weights)."""
+    import weakref
+    import jax
+    c = PagedKVCache(n_layers=2, n_heads=2, head_dim=4, max_slots=3,
+                     buckets=(8, 16))
+    old = [weakref.ref(b) for b in c._k + c._v]
+    alive = []
+    put = jax.device_put
+
+    def counting_put(*args, **kwargs):
+        alive.append(sum(r() is not None for r in old))
+        return put(*args, **kwargs)
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    c.bucket = 16
+    c._alloc_buffers(16)
+    assert alive and max(alive) == 0
+    assert c.k(0).shape == (3, 2 * 4, 16)
+
+
+def test_a_grow_lets_each_old_buffer_go_as_its_successor_is_made(
+        monkeypatch):
+    """A live migration pads the rows a buffer at a time: when the i-th
+    new buffer is made, only the old buffers not yet padded are alive,
+    so the rows never stand at both buckets at once."""
+    import weakref
+    from mxnet_tpu.serving import kv_cache
+    c = PagedKVCache(n_layers=2, n_heads=2, head_dim=4, max_slots=3,
+                     buckets=(8, 16))
+    c.alloc()
+    c.positions[0] = 8
+    c._k[0] = c._k[0].at[0, 1, 3].set(5.0)
+    old = [weakref.ref(b) for b in c._k + c._v]
+    alive = []
+    grow_rows = kv_cache._grow_rows
+
+    def counting(buf, new_len):
+        alive.append(sum(r() is not None for r in old))
+        return grow_rows(buf, new_len)
+    monkeypatch.setattr(kv_cache, "_grow_rows", counting)
+    assert c.ensure_capacity(9) and c.bucket == 16
+    assert alive == [4, 3, 2, 1]
+    assert all(r() is None for r in old)
+    assert c.k(0).shape == (3, 2 * 4, 16)
+    assert float(c.k(0)[0, 1, 3]) == 5.0 and float(c.k(0)[0, 1, 8]) == 0.0
+
+
 def _resident_rows(c, slot):
     """A slot's rows as callers hand them in: per layer (L, heads, d)
     K then V, whatever axis order the cache keeps them in."""

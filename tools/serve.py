@@ -18,6 +18,8 @@ bucket, and answers on a stdlib HTTP server:
         --max-slots 48 --kv-buckets 1024,2048,4096    # sparse experts, bfloat16
     python tools/serve.py --generate --zoo-ouro ouro_2_6b \
         --max-slots 5 --kv-buckets 1024       # looped LM (48 layers x 4), bfloat16
+    python tools/serve.py --generate --zoo-olmo-hybrid olmo_hybrid_7b_stage \
+        --max-slots 40 --kv-buckets 1024,2048  # delta-rule hybrid, bfloat16
 
     curl -s localhost:8080/v1/inference -d '{"instances": [[...]]}'
     curl -sN localhost:8080/v1/generate \
@@ -30,9 +32,10 @@ Knobs default from the MXNET_SERVING_* env tier, plus MXNET_GEN_* for
 traced batch size; export with ``dynamic_batch=True`` for the full
 bucket grid.  --generate serves a LIVE decoder LM (zoo GPT in float32,
 with --zoo-phi4flash the Phi-4-mini-flash hybrid, with
---zoo-cohere2moe one chip's share of Command A+ or with --zoo-ouro the
-looped Ouro-2.6B in bfloat16, for all three of which speculation and
-the prefix cache are refused; optionally with --gpt-params weights)
+--zoo-cohere2moe one chip's share of Command A+, with --zoo-ouro the
+looped Ouro-2.6B or with --zoo-olmo-hybrid a 16-layer stage of
+Olmo-Hybrid-7B in bfloat16, for all four of which speculation and the
+prefix cache are refused; optionally with --gpt-params weights)
 through the resident decode loop.
 
 Resilience (docs/serving.md#resilience): --replicas N hosts N worker
@@ -113,8 +116,8 @@ def main(argv=None) -> None:
                          "random unless --gpt-params is given)")
     ap.add_argument("--gpt-params", default=None,
                     help="a .params file to load into the --zoo-gpt, "
-                         "--zoo-phi4flash, --zoo-cohere2moe or "
-                         "--zoo-ouro model before serving")
+                         "--zoo-phi4flash, --zoo-cohere2moe, --zoo-ouro "
+                         "or --zoo-olmo-hybrid model before serving")
     ap.add_argument("--zoo-phi4flash", default=None,
                     choices=("phi4_mini_flash", "tiny"),
                     help="serve the Phi-4-mini-flash hybrid family "
@@ -153,6 +156,19 @@ def main(argv=None) -> None:
                          "3-layer x 3-step float32 one for the CPU.  "
                          "Speculation and the prefix cache are refused "
                          "for it")
+    ap.add_argument("--zoo-olmo-hybrid", default=None,
+                    choices=("olmo_hybrid_7b_stage", "tiny"),
+                    help="serve the Olmo Hybrid family for --generate "
+                         "instead of --zoo-gpt: Gated DeltaNet layers "
+                         "(a float32 matrix state a head, updated in "
+                         "place by one kernel call a layer) beside full "
+                         "multi-head attention without position "
+                         "embedding.  olmo_hybrid_7b_stage is one of two "
+                         "16-layer pipeline stages of the published "
+                         "7.43 B model (4.10 B with the embedding and "
+                         "the head) in bfloat16, 'tiny' one float32 "
+                         "period for the CPU.  Speculation and the "
+                         "prefix cache are refused for it")
     ap.add_argument("--max-slots", type=int, default=None,
                     help="decode slots for --generate "
                          "(MXNET_GEN_MAX_SLOTS)")
@@ -289,16 +305,19 @@ def _serve_generate(args, serving) -> None:
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo.gpt import GPTModel, get_gpt
     from mxnet_tpu.gluon.model_zoo.cohere2moe import get_cohere2moe
+    from mxnet_tpu.gluon.model_zoo.olmo_hybrid import get_olmo_hybrid
     from mxnet_tpu.gluon.model_zoo.ouro import get_ouro
     from mxnet_tpu.gluon.model_zoo.phi4flash import get_phi4flash
 
     mx.random.seed(0)
-    declared = args.zoo_phi4flash or args.zoo_cohere2moe or args.zoo_ouro
+    declared = args.zoo_phi4flash or args.zoo_cohere2moe or args.zoo_ouro \
+        or args.zoo_olmo_hybrid
     if declared:
         # the families that declare every shape; bfloat16 as published
         # but for the CPU size
         get = get_phi4flash if args.zoo_phi4flash else \
-            get_cohere2moe if args.zoo_cohere2moe else get_ouro
+            get_cohere2moe if args.zoo_cohere2moe else \
+            get_ouro if args.zoo_ouro else get_olmo_hybrid
         net = get(declared,
                   dtype="float32" if declared == "tiny" else "bfloat16")
         # inference only: a gradient buffer beside each of billions of
