@@ -29,7 +29,8 @@ as it is and its OUTPUT is normed before it is added::
 
 The head is untied.  Over a sequence the delta rule is evaluated in
 chunks of ``chunk`` positions (:func:`delta_chunked`, the WY form: the
-chunk's writes solved in one unit lower-triangular system, float32);
+chunk's writes from the inverse of one unit lower-triangular matrix,
+built by block merges, float32);
 one token a slot at a time it is ``ops.pallas.delta_step``.
 
 The math lives here once, as pure functions over a parameter pytree
@@ -57,7 +58,7 @@ from ..parameter import Parameter
 from .cohere2moe import _f32, _mm, _use_flash
 
 __all__ = ["OlmoHybridModel", "get_olmo_hybrid", "layer_kinds",
-           "forward_logits", "delta_chunked"]
+           "forward_logits", "delta_chunked", "unit_lower_inverse"]
 
 # the published config's names of the two kinds of layer
 KIND_OF_TYPE = {"linear_attention": "linear", "full_attention": "full"}
@@ -345,6 +346,43 @@ def conv_step(conv_in, state, w):
     return jax.nn.silu(out), taps[:, 1:]
 
 
+def _mm_f32(spec, a, b):
+    """``einsum`` of float32 operands at precision "highest"."""
+    return jnp.einsum(spec, a, b, precision=_HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+def unit_lower_inverse(A):
+    """``(I + A)^-1`` for a strictly lower-triangular ``A (..., C, C)``
+    float32, any ``C``, by block merges: the inverse's diagonal blocks
+    double in size a level at a time from 1 x 1 blocks of 1, two
+    neighbours whose inverses are ``T11`` and ``T22`` merging into
+    ``[[T11, 0], [-T22 A21 T11, T22]]``.  That is forward substitution
+    a block at a time: no power of ``A`` is formed, so nothing large
+    cancels (a power series ``(I - A)(I + A^2)...`` does, at write
+    strengths near 2 and keys that share a direction).  A level is two
+    products of whole ``C x C`` matrices on the MXU, ``T - T X T`` with
+    ``X`` the merging blocks' lower-left quadrants of ``A``: ``T`` is
+    block-diagonal, so the only entries the products make are those
+    quadrants' ``T22 A21 T11``, summed over the same terms as the blocks
+    alone.  ``ceil(log2 C)`` levels (the first, onto blocks of 1, needs
+    no product), float32 at precision "highest"."""
+    i = jnp.arange(A.shape[-1])
+
+    def lower_left(s):      # of the blocks of 2s, halves of s
+        return ((i[:, None] // s == i[None, :] // s + 1)
+                & (i[None, :] // s % 2 == 0))
+
+    T = jnp.eye(A.shape[-1], dtype=A.dtype) - jnp.where(lower_left(1), A, 0.0)
+    s = 2
+    while s < A.shape[-1]:
+        X = jnp.where(lower_left(s), A, 0.0)
+        T = T - _mm_f32("...tj,...jk->...tk",
+                        _mm_f32("...tj,...jk->...tk", T, X), T)
+        s *= 2
+    return T
+
+
 @jax.named_scope("delta/chunk")
 def delta_chunked(q, k, v, g, beta, chunk: int):
     """The delta rule over a sequence, ``chunk`` positions at a time:
@@ -359,11 +397,14 @@ def delta_chunked(q, k, v, g, beta, chunk: int):
     = beta_t (G_t / G_j) k_t . k_j`` for ``j < t``.  So ``U = T (beta
     V) - T (beta G K) S_0``, ``o_t = G_t S_0^T q_t + sum_{j<=t} (G_t /
     G_j) (q_t . k_j) u_j`` and the chunk ends at ``G_C S_0 + sum_j
-    (G_C / G_j) k_j u_j^T``: the solve and the products that do not
-    need ``S_0`` are made for every chunk at once, then a scan over the
-    chunks carries the state.  Every product is float32 at precision
-    "highest".  A position with ``beta`` 0 and ``g`` 0 leaves the state
-    as it was: padding past a prompt's end does."""
+    (G_C / G_j) k_j u_j^T``: ``T = (I + A)^-1`` (block merges on the
+    MXU, :func:`unit_lower_inverse`; no triangular solve, which the
+    TPU's compiler runs as sequential work on the chunks' diagonal
+    blocks), its products and the others that do not need ``S_0`` are
+    made for every chunk at once, then a scan over the chunks carries
+    the state.  Every product is float32 at precision "highest".  A
+    position with ``beta`` 0 and ``g`` 0 leaves the state as it was:
+    padding past a prompt's end does."""
     T, H, dk = q.shape
     dv = v.shape[-1]
     pad = -T % chunk
@@ -385,16 +426,12 @@ def delta_chunked(q, k, v, g, beta, chunk: int):
                               dec[..., :, None] - dec[..., None, :], 0.0))
     ratio = jnp.where(on_or_below, ratio, 0.0)          # G_t / G_j, j <= t
 
-    def mm(spec, a, b):
-        return jnp.einsum(spec, a, b, precision=_HIGHEST,
-                          preferred_element_type=jnp.float32)
-
+    mm = _mm_f32
     A = jnp.where(below, beta[..., :, None] * mm("nhtd,nhjd->nhtj", k, k)
                   * ratio, 0.0)
     rhs = jnp.concatenate([beta[..., None] * v,
                            (beta * jnp.exp(dec))[..., None] * k], axis=-1)
-    sol = lax.linalg.triangular_solve(A, rhs, left_side=True, lower=True,
-                                      unit_diagonal=True)
+    sol = mm("nhtj,nhje->nhte", unit_lower_inverse(A), rhs)
     u, w = sol[..., :dv], sol[..., dv:]
     qk = mm("nhtd,nhjd->nhtj", q, k) * ratio
     qd = q * jnp.exp(dec)[..., None]

@@ -49,8 +49,9 @@ __all__ = ["DeltaDecodeModel"]
 
 CACHE_KIND = {"linear": "state", "full": "rows"}
 # prompts are prefilled whole, one program a bucket: the full layers'
-# (heads, T, T) scores below 512 positions and the chunks' solves bound
-# it; a chunked prefill over the cache would lift it (ROADMAP)
+# (heads, T, T) scores below 512 positions bound it (the delta rule's
+# chunks are products, their inverses block merges, linear in T); a
+# chunked prefill over the cache would lift it (ROADMAP)
 MAX_PROMPT = 1024
 # the delta rule's chunk
 MIN_PROMPT_BUCKET = 64

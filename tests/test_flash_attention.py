@@ -669,6 +669,30 @@ def test_delta_step_compiles_for_v5e_in_place_at_the_cells_shape(one_v5e):
     assert memory.temp_size_in_bytes < 2 ** 22
 
 
+def test_the_chunked_delta_rule_compiles_for_v5e_with_no_triangular_solve(
+        one_v5e):
+    """The Olmo Hybrid cell's prefill of 512 positions (30 heads, keys of
+    96, values of 192, chunks of 64): the chunks' writes are products,
+    and the compiled program holds no triangular solve, neither as
+    XLA's op nor as the sequential custom call the TPU compiler expands
+    one into (which ``lax.linalg.triangular_solve`` at the chunks' shape
+    is shown to hold, so the names looked for are the compiler's)."""
+    from jax import lax
+    from mxnet_tpu.gluon.model_zoo import olmo_hybrid as oh
+    T, H, dk, dv, C = 512, 30, 96, 192, 64
+    arg = lambda *shape: jax.ShapeDtypeStruct(      # noqa: E731
+        shape, jnp.float32, sharding=one_v5e)
+    hlo = jax.jit(oh.delta_chunked, static_argnames="chunk").lower(
+        arg(T, H, dk), arg(T, H, dk), arg(T, H, dv), arg(T, H), arg(T, H),
+        chunk=C).compile().as_text()
+    assert "InvertDiagBlocksLowerTriangular" not in hlo
+    assert "triangular-solve" not in hlo
+    solve = jax.jit(lambda a, b: lax.linalg.triangular_solve(
+        a, b, left_side=True, lower=True, unit_diagonal=True)).lower(
+        arg(T // C, H, C, C), arg(T // C, H, C, dk + dv)).compile().as_text()
+    assert "InvertDiagBlocksLowerTriangular" in solve
+
+
 @pytest.mark.slow
 def test_hybrid_step_compiles_for_v5e_and_reads_the_rows_in_place(one_v5e):
     """The whole decode step of the published Phi-4-mini-flash, 64

@@ -121,28 +121,95 @@ def _sequential(q, k, v, g, beta):
     return np.stack(out), S
 
 
-@pytest.mark.parametrize("T", [1, 63, 64, 65, 200])
-def test_the_chunked_rule_is_the_sequential_recurrence(T):
-    """WY chunks of 64 against one rank-one update a token, with write
+@pytest.mark.parametrize("T,dk,dv,chunk,shared", [
+    (1, 16, 24, 64, False), (63, 16, 24, 64, False),
+    (64, 16, 24, 64, False), (65, 16, 24, 64, False),
+    (200, 16, 24, 64, False),
+    (40, 16, 24, 16, False),            # the tiny size's chunk
+    (200, 96, 192, 64, False), (512, 96, 192, 64, False),  # published
+    (200, 16, 24, 64, True), (512, 96, 192, 64, True),
+])
+def test_the_chunked_rule_is_the_sequential_recurrence(T, dk, dv, chunk,
+                                                       shared):
+    """WY chunks against one rank-one update a token, with write
     strengths above 1 (``beta`` in (0, 2)) and strong decays among
-    them, and a state that grows over several chunks."""
+    them, and a state that grows over several chunks.  ``shared``: every
+    key leans on one direction (weight 0.99) and ``beta`` is in (1.95,
+    2), so ``A``'s entries are near 2 and its powers grow as binomials:
+    a form of the chunk's inverse that adds powers of ``A`` cancels them
+    away in float32 and fails here, where forward substitution holds."""
     rng = np.random.default_rng(T)
-    h, dk, dv = 3, 16, 24
+    h = 3
 
     def unit(x):
         return x / np.linalg.norm(x, axis=-1, keepdims=True)
 
     q = unit(rng.normal(size=(T, h, dk))) / np.sqrt(dk)
     k = unit(rng.normal(size=(T, h, dk)))
+    if shared:
+        k = unit(0.99 * unit(rng.normal(size=(h, dk))) + 0.01 * k)
     v = rng.normal(size=(T, h, dv))
     g = -rng.exponential(0.3, size=(T, h))
     g[:, 0] *= 20                   # one head forgets fast
-    beta = rng.uniform(0.0, 2.0, size=(T, h))
+    beta = rng.uniform(1.95, 2.0, size=(T, h)) if shared \
+        else rng.uniform(0.0, 2.0, size=(T, h))
     assert (beta > 1).any()
     o, S = oh.delta_chunked(*(jnp.asarray(a, jnp.float32)
-                              for a in (q, k, v, g, beta)), chunk=64)
+                              for a in (q, k, v, g, beta)), chunk=chunk)
     want_o, want_S = _sequential(q, k, v, g, beta)
     assert _rel(o, want_o) < 1e-5 and _rel(S, want_S) < 1e-5
+
+
+@pytest.mark.parametrize("C", [1, 2, 5, 16, 24, 64])
+def test_the_chunk_inverse_is_the_inverse(C):
+    """``unit_lower_inverse`` against float64's inverse of ``I + A`` for
+    a chunk's ``A`` (unit keys, write strengths in (0, 2), decays), at
+    the two chunks the family's sizes hold (16, 64), at sizes that are
+    no power of two (the last merge's halves differ) and at 1 (no
+    merge)."""
+    rng = np.random.default_rng(C)
+    k = rng.normal(size=(2, 3, C, 8))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    beta = rng.uniform(0.0, 2.0, size=(2, 3, C))
+    dec = np.cumsum(-rng.exponential(0.1, size=(2, 3, C)), axis=-1)
+    A = np.tril(beta[..., None] * np.einsum("...td,...jd->...tj", k, k)
+                * np.exp(dec[..., :, None] - dec[..., None, :]), -1)
+    got = oh.unit_lower_inverse(jnp.asarray(A, jnp.float32))
+    assert got.shape == A.shape and got.dtype == jnp.float32
+    assert _rel(got, np.linalg.inv(np.eye(C) + A)) < 1e-5
+
+
+def _primitives(jaxpr):
+    """The names of every primitive in ``jaxpr``, sub-jaxprs too."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_the_chunked_rule_calls_no_triangular_solve(chunk):
+    """The chunk's writes come from products (``unit_lower_inverse``),
+    not from ``lax.linalg.triangular_solve``, which the TPU compiler
+    expands into a sequential ``InvertDiagBlocksLowerTriangular``; the
+    v5e compile at the cell's shape is in tests/test_flash_attention.py."""
+    T, h, dk, dv = 100, 2, 8, 48
+    args = [jnp.zeros((T, h, d), jnp.float32) for d in (dk, dk, dv)]
+    args += [jnp.zeros((T, h), jnp.float32)] * 2
+    jaxpr = jax.make_jaxpr(lambda *a: oh.delta_chunked(*a, chunk=chunk))(
+        *args)
+    names = _primitives(jaxpr.jaxpr)
+    assert "dot_general" in names and "triangular_solve" not in names
+
+
+def test_the_prefill_program_calls_no_triangular_solve(model):
+    toks = jnp.zeros(64, jnp.int32)
+    jaxpr = jax.make_jaxpr(model._prefill_fn)(model.params, toks,
+                                              jnp.int32(37))
+    names = _primitives(jaxpr.jaxpr)
+    assert "dot_general" in names and "triangular_solve" not in names
 
 
 def test_padding_past_the_real_length_leaves_the_state(params, net):
