@@ -746,9 +746,10 @@ GEN_STEP_FALLBACKS_TOTAL = counter(
     "mxnet_gen_step_fallbacks_total",
     "Decode steps launched the serial way (previous tokens read back "
     "first), by what stood in the way of launching ahead: idle "
-    "(nothing was in flight), finish (a resident sequence ended or "
-    "was about to), admit (a free slot and a waiting request), cancel, "
-    "spec (a speculating iteration). With "
+    "(nothing was in flight), finish (a resident stream ended outside "
+    "the loop, a slot at the top bucket's last row, or every sequence "
+    "in flight ended with its token), cancel, spec (a speculating "
+    "iteration). With "
     "mxnet_gen_steps_ahead_total it counts every step.",
     labels=("reason",))
 GEN_ROW_BLOCKS_READ_TOTAL = counter(
@@ -800,6 +801,13 @@ GEN_DISCARDED_TOKENS_TOTAL = counter(
 GEN_ADMISSIONS_TOTAL = counter(
     "mxnet_gen_admissions_total",
     "Generation requests admitted into a decode slot (prefill ran).")
+GEN_ADMISSIONS_AHEAD_TOTAL = counter(
+    "mxnet_gen_admissions_ahead_total",
+    "Admissions queued on the device behind the decode step in flight: "
+    "prefill, row write and first-token select dispatched with no host "
+    "read, the first token put into that step's token array and read "
+    "back with it. Over mxnet_gen_admissions_total it is the share of "
+    "admissions that did not drain the decode loop.")
 GEN_RETIREMENTS_TOTAL = counter(
     "mxnet_gen_retirements_total",
     "Generation sequences retired from their slot, by reason: eos / "

@@ -23,15 +23,18 @@ PAPERS.md) on top of the pieces PRs 2-5 built:
   per-prompt-bucket program) runs for the newcomers, their KV rows are
   written into free slots, and the very next iteration decodes old and
   new sequences together — no resident sequence ever stalls or changes
-  its tokens because of an arrival;
+  its tokens because of an arrival.  With a step in flight the whole
+  admission is queued on the device behind it, with no host read: the
+  first token joins that step's token array and is read back with it;
 * **retirement is per-step**: a sequence that reaches its max-tokens
   budget frees its slot in the iteration that reads its last token,
   one that emits EOS an iteration later (the next step was launched
   before the EOS was read; its token for the slot is discarded);
 * **one step is in flight**: the loop launches step N+1 from step N's
   token array on the device before it reads N's tokens back, wherever
-  the host knows N+1's inputs without them, so dispatch, emit and
-  bookkeeping run under the device's step and not between steps;
+  the host knows N+1's inputs without them, so dispatch, emit,
+  bookkeeping and admissions run under the device's step and not
+  between steps;
 * **tokens stream out as they exist**: each iteration's (S,) token
   readback is pushed into per-request :class:`TokenStream` queues the
   HTTP layer drains as chunked responses.
@@ -416,18 +419,24 @@ def make_recovery_request(req: GenRequest) -> GenRequest:
 
 class _InFlight:
     """The plain decode step the engine has launched and not read
-    back: its (S,) token array, still on the device; the slot table it
-    was launched over (which no retirement or admission changes before
-    it is read); the positions it wrote at; and the moment ``since``
-    which the loop has been paying for it (its dispatch, or, for a
-    step launched ahead, the readback of the step before it)."""
+    back: its (S,) token array, still on the device; ``active``, the
+    slots whose tokens in it are read for a stream (the step's own,
+    less those retired before it is read, whose tokens are nobody's,
+    and the admissions queued behind it); ``first``, those admissions,
+    whose first token the array holds at their slot, each with the
+    prefill pass to read what the family says of it (None where the
+    prefill was read at once); the positions the step wrote at; and
+    the moment ``since`` which the loop has been paying for it (its
+    dispatch, or, for a step launched ahead, the readback of the step
+    before it)."""
 
-    __slots__ = ("tokens", "active", "pos", "since")
+    __slots__ = ("tokens", "active", "first", "pos", "since")
 
     def __init__(self, tokens: Any, active: Dict[int, "GenRequest"],
                  pos: _np.ndarray, since: float) -> None:
         self.tokens = tokens
         self.active = active
+        self.first: Dict[int, Any] = {}
         self.pos = pos
         self.since = since
 
@@ -450,20 +459,26 @@ class GenerationEngine:
     already knows the next step's inputs, the quantum launches step N+1
     (fed by the token array step N returns, still on the device, at
     its positions plus one) and only then reads step N's tokens back,
-    emits them and bookkeeps, all under the device's N+1.  Otherwise it
-    falls back to the serial order: read N back, emit, retire finished
-    sequences, admit newcomers into freed slots (prefill), launch N+1
-    from the host's tokens.  It falls back for the quantum in which a
-    resident sequence ends or is about to (budget, top bucket, an EOS
-    read last time), a consumer cancelled, a slot is free and a request
-    waits, any resident request speculates, or nothing is in flight;
-    it decides that from its slot table, with no knob.
+    emits them, retires the sequences that ended and admits newcomers
+    into free slots, all under the device's N+1: each admission's
+    prefill, row write and first-token select are queued behind N+1
+    and its first token is put into N+1's token array on the device,
+    so the next quantum launches N+2 from it and reads the residents'
+    tokens and the first tokens back together.  A sequence whose
+    stream ends with its token in N (its budget) rides N+1 as a free
+    slot does.  Otherwise the quantum falls back to the serial order:
+    read N back, emit, retire, admit (the first token read at once),
+    launch N+1 from the host's tokens.  It falls back where nothing is
+    in flight, a consumer cancelled, a resident stream ended outside
+    the loop, a slot sits at the top bucket's last row, or a resident
+    request speculates; it decides that from its slot table, with no
+    knob.
 
     An EOS therefore LAGS by one step: the host reads it after the
     next step was launched, discards that step's token for the slot
     (never streamed, never counted as generated) and retires the slot
-    one iteration later.  Every stream's tokens, finish reason and
-    indexes are those of the serial order, greedy and sampled.
+    at once.  Every stream's tokens, finish reason and indexes are
+    those of the serial order, greedy and sampled.
     ``cache.positions`` advances when a step is LAUNCHED (it is then
     the next write index whether or not the token has been read).
 
@@ -811,12 +826,12 @@ class GenerationEngine:
         quanta.  Where the host already knows the next step's inputs
         (the token array the step in flight will return, still on the
         device; its positions plus one) the quantum RUNS AHEAD: launch
-        step N+1, then read step N's tokens back, emit them and
-        bookkeep, all under the device's N+1.  Otherwise it falls back
-        to the serial order, which is the one every quantum had before:
-        read N back, emit, retire, admit, launch N+1 from the host's
-        ``_last_tok``.  :meth:`_fallback_reason` decides, each quantum,
-        from the slot table alone."""
+        step N+1, then read step N's tokens back, emit them, retire and
+        admit, all under the device's N+1, the admissions queued behind
+        it with no host read.  Otherwise it falls back to the serial
+        order: read N back, emit, retire, admit, launch N+1 from the
+        host's ``_last_tok``.  :meth:`_fallback_reason` decides, each
+        quantum, from the slot table alone."""
         self._iter += 1
         log: Dict[str, Any] = {"iter": self._iter, "admitted": [],
                                "retired": [], "decoded": []}
@@ -838,51 +853,49 @@ class GenerationEngine:
             with _tracing.span("engine.iteration", iter=self._iter,
                                tokens=0) as isp:
                 reason = self._fallback_reason(flight)
-                ahead = None
                 if flight is not None:
                     decoding = True
                     if reason is None:
-                        ahead = self._launch(flight.active, flight, None)
+                        self._flight = self._launch(flight.active, flight,
+                                                    None)
                     next_tok = self._collect(flight)
                     decoding = False
-                    if ahead is not None:
+                    if self._flight is not None:
                         # it ran under the wait above: the loop pays
                         # for it from here
-                        ahead.since = time.perf_counter()
-                    self._flight = ahead
+                        self._flight.since = time.perf_counter()
                     self._emit_step(isp, flight.active, log,
-                                    next_tok=next_tok, wrote_at=flight.pos)
-                if ahead is not None:
-                    active = flight.active
-                    # the queue is still visited: with no slot to give,
-                    # it sheds what waited past its deadline
-                    self.scheduler.pop_admissions(0)
-                else:
-                    self._retire_finished(log)
-                    self._admit_pending(log)
-                    active = victims = self.scheduler.active()
-                    if not active:
-                        worked = bool(flight is not None or log["admitted"]
-                                      or log["retired"])
-                        self.cache.publish_live_bytes()
-                        self.cache.reset_if_empty()
-                        if self._draft is not None:
-                            self._draft.reset_if_empty()
-                    elif not self._speculating(active):
-                        decoding = True
-                        self._flight = self._launch(active, None, reason)
-                    elif flight is None:
-                        # a speculating quantum is wholly serial:
-                        # accepted lengths, hence positions, depend on
-                        # its tokens
-                        decoding = True
-                        spec = self._decode_spec(active)
-                        decoding = False
-                        self._emit_step(isp, active, log, spec=spec)
-                        self.cache.publish_live_bytes()
-                    # else: this quantum emitted a plain step and then
-                    # admitted a speculating request; the next drafts
+                                    next_tok=next_tok, wrote_at=flight.pos,
+                                    first=flight.first)
+                self._retire_finished(log)
+                self._admit_pending(log)
+                active = victims = self.scheduler.active()
+                if not active:
+                    worked = bool(flight is not None or log["admitted"]
+                                  or log["retired"])
+                    self.cache.publish_live_bytes()
+                    self.cache.reset_if_empty()
+                    if self._draft is not None:
+                        self._draft.reset_if_empty()
+                elif self._flight is None \
+                        and not self._speculating(active):
+                    # where the quantum could have run ahead, every
+                    # sequence in flight ended with its token
+                    decoding = True
+                    self._flight = self._launch(active, None,
+                                                reason or "finish")
+                elif flight is None:
+                    # a speculating quantum is wholly serial: accepted
+                    # lengths, hence positions, depend on its tokens
+                    decoding = True
+                    spec = self._decode_spec(active)
                     decoding = False
+                    self._emit_step(isp, active, log, spec=spec)
+                    self.cache.publish_live_bytes()
+                # else: this quantum launched ahead (its admissions
+                # queued behind that step), or emitted a plain step and
+                # then admitted a speculating request; the next drafts
+                decoding = False
                 _metrics.GEN_SLOTS_ACTIVE.set(len(active))
                 isp.set_attr(slots=len(active),
                              admitted=len(log["admitted"]),
@@ -913,25 +926,27 @@ class GenerationEngine:
         host knows without the tokens in flight: ``idle`` nothing is in
         flight (the first step over a batch, or the one after a decode
         fault, whose victims the retirement counter has); ``cancel`` a
-        resident consumer gave up; ``finish`` a resident stream has
-        ended (an EOS read one step late, a failure) or ends with the
-        token in flight (its budget, or the last row of the top
-        bucket); ``admit`` a slot is free and the queue is not empty.
-        A speculating quantum leaves nothing in flight, so it reads
+        resident consumer gave up; ``finish`` a resident stream ended
+        outside the loop (a failure), or a slot's next write would be
+        past the top bucket's last row; ``spec`` a resident request
+        speculates (one admitted behind the step in flight).  A
+        speculating quantum leaves nothing in flight, so it reads
         ``idle`` here and is counted ``spec`` where it is launched.
-        Growing the rows is no reason: ``_launch`` queues the
-        migration behind the step in flight like any other program."""
+        Neither an admission nor a budget that ends with the token in
+        flight is a reason: the one is queued behind the next step, the
+        other's slot rides it as a free one.  Nor is growing the rows:
+        ``_launch`` queues the migration behind the step in flight like
+        any other program."""
         if flight is None:
             return "idle"
         top = self.grid[-1]
         for slot, req in flight.active.items():
             if req.stream.finished:
                 return "cancel" if req.is_cancelled() else "finish"
-            if req.emitted + 1 >= req.max_new_tokens \
-                    or int(flight.pos[slot]) + 1 >= top:
+            if int(self.cache.positions[slot]) >= top:
                 return "finish"
-        if len(self.scheduler) and len(flight.active) < self.max_slots:
-            return "admit"
+        if self._speculating(flight.active):
+            return "spec"
         return None
 
     def _speculating(self, active: Dict[int, "GenRequest"]) -> bool:
@@ -940,30 +955,43 @@ class GenerationEngine:
 
     def _launch(self, active: Dict[int, "GenRequest"],
                 after: Optional["_InFlight"], reason: Optional[str]
-                ) -> "_InFlight":
+                ) -> Optional["_InFlight"]:
         """Dispatch one plain decode step over every slot and leave it
-        in flight.  ``after`` is the step in flight whose un-read
-        tokens feed this one (running ahead); without it the host's
-        ``_last_tok`` is uploaded, and ``reason`` says why.
-        ``cache.positions`` advances HERE, at dispatch: the step writes
-        each live slot's column at its position, so the next write
-        index is one on whether or not the token has been read (the
-        capacity check and a launch ahead both need that)."""
-        self.cache.ensure_capacity(self.cache.needed_capacity())
-        pos = _np.maximum(self.cache.positions, 0).astype(_np.int32)
+        in flight; None where no sequence of ``active`` needs it.
+        ``after`` is the step in flight whose un-read tokens feed this
+        one (running ahead): a sequence whose stream ends with its
+        token in there (its budget, a first token included) rides this
+        step as a free slot does, at position 0 with its token
+        nobody's.  Without ``after`` the host's ``_last_tok`` is
+        uploaded, and ``reason`` says why.  ``cache.positions``
+        advances HERE, at dispatch: the step writes each live slot's
+        column at its position, so the next write index is one on
+        whether or not the token has been read (the capacity check and
+        a launch ahead both need that)."""
+        if after is not None:
+            going = [s for s, r in active.items()
+                     if r.emitted + 1 < r.max_new_tokens]
+        else:
+            going = [s for s, r in active.items() if not r.stream.finished]
+        if not going:
+            return None
+        self.cache.ensure_capacity(
+            int(self.cache.positions[going].max()) + 1)
+        pos = _np.zeros((self.max_slots,), _np.int32)
+        pos[going] = self.cache.positions[going]
         if self._samp_dev is None:
             self._samp_dev = self.model.device_sampling(self._samp)
         t = time.perf_counter()
         toks = self.model.dispatch(
             self.cache, after.tokens if after is not None
             else self._last_tok, pos, self._samp_dev)
-        self.cache.positions[list(active)] += 1
+        self.cache.positions[going] += 1
         self.cache.publish_live_bytes()
         if after is not None:
             _metrics.GEN_STEPS_AHEAD_TOTAL.inc()
         else:
             _metrics.GEN_STEP_FALLBACKS_TOTAL.labels(reason=reason).inc()
-        return _InFlight(toks, active, pos, t)
+        return _InFlight(toks, {s: active[s] for s in going}, pos, t)
 
     def _collect(self, flight: "_InFlight") -> _np.ndarray:
         """Wait for the step in flight; its (S,) tokens on the host.
@@ -1033,20 +1061,30 @@ class GenerationEngine:
         consumers release their slot here too.  The producer-side
         `finished` flag, NOT `done`: a finished sequence must free its
         slot even while its consumer is still draining buffered
-        tokens."""
+        tokens.  A slot retired with a token in the step in flight (its
+        EOS was read after that step was launched) leaves the step's
+        slot table: that token is discarded, never read."""
+        flight, n_discarded = self._flight, 0
         for slot, req in self.scheduler.active().items():
             if req.stream.finished or req.is_cancelled():
+                if flight is not None \
+                        and flight.active.pop(slot, None) is not None:
+                    n_discarded += 1
                 self._retire(slot, req,
                              req.stream.finish_reason or "cancelled")
                 log["retired"].append(slot)
+        if n_discarded:
+            _metrics.GEN_DISCARDED_TOKENS_TOTAL.inc(n_discarded)
 
     def _admit_pending(self, log: Dict[str, Any]) -> None:
         """Admit into free slots (prefill, one compiled program per
         prompt bucket).  Always visit the queue — with zero free slots
         pop_admissions(0) admits nothing but STILL sheds queued
         requests whose deadline passed ("no slot freed within the
-        deadline" is the generation overload signal).  Mid-admission
-        requests ride self._in_admission so a worker death during
+        deadline" is the generation overload signal).  With a step in
+        flight each admission is queued behind it (``engine.prefill``
+        says ``ahead=1``).  Mid-admission requests ride
+        self._in_admission so a worker death during
         prefill still evacuates them (they are in neither the queue nor
         the slot table), and the scheduler's mid-admission count keeps
         drain polls honest."""
@@ -1060,7 +1098,8 @@ class GenerationEngine:
                 # span errored, which tail-upgrades the whole trace
                 with _tracing.attach(req.trace), _tracing.child_span(
                         "engine.prefill", request_id=req.request_id,
-                        prompt=int(req.tokens.size)):
+                        prompt=int(req.tokens.size),
+                        ahead=int(self._flight is not None)):
                     slot = self._admit(req)
             except Exception as e:   # noqa: BLE001 - a poisoned
                 # prompt (or an injected prefill fault) fails ONLY
@@ -1124,19 +1163,24 @@ class GenerationEngine:
 
     def _emit(self, active: Dict[int, "GenRequest"], log: Dict[str, Any],
               next_tok: Any = None, wrote_at: Any = None,
+              first: Optional[Dict[int, Any]] = None,
               spec: Optional[Tuple[Any, ...]] = None) -> int:
         """Hand each slot's new token(s) to its stream, mark finished
         sequences (they retire at the next retire phase), count;
-        returns the tokens streamed.  A plain step brings ``next_tok``
-        and the positions it ``wrote_at``; a speculative iteration
-        ``spec`` = ``(verified, candidates, speculating slots, k)``.
+        returns the decode-step tokens streamed.  A plain step brings
+        ``next_tok``, the positions it ``wrote_at`` and the admissions
+        queued behind it, whose ``first`` tokens it holds; a
+        speculative iteration ``spec`` = ``(verified, candidates,
+        speculating slots, k)``.
 
         A plain step's token for a stream that had already ended when
-        it was read is DISCARDED: the step was launched before the host
-        had read the EOS of the one before it (or the consumer gave up
-        meanwhile).  It reaches no stream and no token counter but
+        it was read (the consumer gave up meanwhile) is DISCARDED: it
+        reaches no stream and no token counter but
         ``mxnet_gen_discarded_tokens_total``; the slot retires in this
-        quantum and is installed anew, whole, at its next admission."""
+        quantum and is installed anew, whole, at its next admission.
+        (A stream whose EOS is read after the next step was launched
+        retires before that step is read, its token discarded there:
+        :meth:`_retire_finished`.)"""
         iter_tid = _tracing.current_trace_id()
         use_spec = spec is not None
         if use_spec:
@@ -1212,6 +1256,9 @@ class GenerationEngine:
                     n_discarded += 1
                     continue
                 tok = int(next_tok[slot])
+                if first and slot in first:
+                    self._first_token(slot, req, tok, first[slot])
+                    continue
                 self._last_tok[slot] = tok
                 _metrics.GEN_SAMPLED_TOKENS_TOTAL.labels(
                     method=req.method).inc()
@@ -1230,8 +1277,8 @@ class GenerationEngine:
                   else int(wrote_at[slot]) + 1) >= self.grid[-1]:
                 finished = "length"
             if finished:
-                # mark done now; the slot frees at the next iteration's
-                # retire phase (keeps this loop allocation-free)
+                # mark done now; the slot frees at the next retire
+                # phase (keeps this loop allocation-free)
                 req.stream.close(finished)
         if it_proposed:
             self._spec_proposed += it_proposed
@@ -1319,15 +1366,24 @@ class GenerationEngine:
         prompt whose prefix is resident COPIES the shared rows into
         the slot (one fused row-write over every layer) and prefills only the
         suffix — or nothing at all for an identical prompt.  Either
-        way the pass emits the FIRST generated token (TTFT ends
-        here)."""
+        way the pass selects the FIRST generated token (TTFT ends
+        where it is read).
+
+        Nothing here reads the device where the prefix cache is off:
+        the prefill's logits stay on the device, and the row write,
+        the state install and the select are dispatched behind it.
+        With a step in flight the selected token is put into that
+        step's token array at the slot, and the quantum that reads the
+        array streams it (:meth:`_first_token`); with none it is read
+        and streamed here."""
         from .. import faults as _faults
         _faults.maybe_fault("serving.execute", phase="prefill",
                             prompt=int(req.tokens.size))
         slot = self.cache.alloc()
         if slot is None:                     # caller checked free_slots
             raise MXNetError("no free decode slot (admission race)")
-        entry = None
+        entry = pre = None
+        flight = self._flight
         try:
             t0 = int(req.tokens.size)
             cacheable = (self.cache.prefix.slots > 0
@@ -1352,9 +1408,14 @@ class GenerationEngine:
                 _metrics.GEN_PREFIX_HITS_TOTAL.inc()
             else:
                 pb = round_up_bucket(t0, self.prompt_buckets)
-                # a family with fixed-size state hands it back fourth
-                logits, ks, vs, *state = self.model.prefill(req.tokens,
-                                                            pb)
+                if cacheable:
+                    # the entry keeps the logits on the host
+                    logits, ks, vs, *state = self.model.prefill(
+                        req.tokens, pb)
+                else:
+                    pre = self.model.dispatch_prefill(req.tokens, pb)
+                    logits, (ks, vs, *state) = pre.logits, pre.held
+                # a family with fixed-size state hands it back third
                 self.cache.write_prompt(slot, ks, vs, t0,
                                         state=state[0] if state else None)
                 if cacheable:
@@ -1363,9 +1424,11 @@ class GenerationEngine:
             # first token through the same fused sampler as the step
             # (key = fold_in(PRNGKey(seed), offset)): one key stream
             # per request no matter which program emits which token
-            first = self.model.select(
+            first = self.model.dispatch_select(
                 logits, req.seed, req.offset, req.temperature,
-                req.top_k, req.top_p, METHOD_CODES[req.method])
+                req.top_k, req.top_p, METHOD_CODES[req.method],
+                into=flight.tokens if flight is not None else None,
+                slot=slot)
             if req.speculative and self._draft is not None:
                 # the draft follows the same prompt: its cache rows
                 # mirror this slot from the first iteration on
@@ -1381,7 +1444,6 @@ class GenerationEngine:
                 self.cache.prefix.unpin(entry.key)
         self.scheduler.activate(slot, req)
         req.slot = slot
-        self._last_tok[slot] = first
         # arm the slot's sampling lane.  The counter base makes the
         # in-program key counter (pos - base) equal the token's
         # absolute stream index: at the request's decode step number e
@@ -1396,8 +1458,28 @@ class GenerationEngine:
         self._samp[4][slot] = req.top_p
         self._samp[5][slot] = METHOD_CODES[req.method]
         self._samp_dev = None        # lanes changed: remirror once
+        _metrics.GEN_ADMISSIONS_TOTAL.inc()
+        if flight is not None:
+            # read back with the step in flight, whose array it joined
+            flight.tokens = first
+            flight.active[slot] = req
+            flight.first[slot] = pre
+            _metrics.GEN_ADMISSIONS_AHEAD_TOTAL.inc()
+        else:
+            self._first_token(slot, req, int(first), pre)
+        return slot
+
+    def _first_token(self, slot: int, req: GenRequest, tok: int,
+                     pre: Any) -> None:
+        """Stream an admission's first token, read back now (TTFT ends
+        here), and what its prefill ``pre`` says (where it was
+        dispatched, not read at once).  An EOS or a budget of one ends
+        the stream with it."""
+        if pre is not None:
+            self.model.read_prefill(pre)
+        self._last_tok[slot] = tok
         req.t_first = time.monotonic()
-        req.stream.put(first, index=req.offset)
+        req.stream.put(tok, index=req.offset)
         req.emitted = 1
         _metrics.GEN_SAMPLED_TOKENS_TOTAL.labels(
             method=req.method).inc()
@@ -1406,17 +1488,15 @@ class GenerationEngine:
             exemplar=req.trace.trace_id if req.trace is not None
             else None)
         _metrics.GEN_TOKENS_TOTAL.labels(phase="prefill").inc()
-        _metrics.GEN_ADMISSIONS_TOTAL.inc()
         if req.recover_t0 is not None:
             # recovery ends when the resurrected sequence streams again
             _metrics.SERVING_RECOVERY_SECONDS.observe(
                 req.t_first - req.recover_t0)
             req.recover_t0 = None
-        if req.eos_token is not None and first == int(req.eos_token):
+        if req.eos_token is not None and tok == int(req.eos_token):
             req.stream.close("eos")
         elif req.emitted >= req.max_new_tokens:
             req.stream.close("length")
-        return slot
 
     def _retire(self, slot: int, req: GenRequest, reason: str) -> None:
         self.scheduler.release(slot)

@@ -48,7 +48,7 @@ import numpy as _np
 
 from .. import tracing as _tracing
 from .kv_cache import PagedKVCache
-from .model import DecodeModel, _sample_tokens, _select_one
+from .model import DecodeModel, _first_token_programs, _sample_tokens
 
 __all__ = ["HybridDecodeModel"]
 
@@ -268,7 +268,7 @@ class HybridDecodeModel(DecodeModel):
 
         fam = self.family
         self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
-        self._select_fn = _tracing.program(_select_one, "select", fam)
+        self._select_fn, self._put_fn = _first_token_programs(fam)
         self._step_fn = _tracing.program(_step, "decode", fam,
                                          donate_argnums=(1, 2, 3))
 

@@ -37,7 +37,7 @@ from .. import metrics as _metrics
 from .. import tracing as _tracing
 from .batching import BUCKET_COMPILES, BucketPolicy, INFER_SECONDS
 
-__all__ = ["ServedModel", "DecodeModel", "load_served"]
+__all__ = ["ServedModel", "DecodeModel", "Prefill", "load_served"]
 
 
 def _sig_str(shapes: Sequence[Tuple[int, ...]]) -> str:
@@ -322,6 +322,38 @@ def _select_one(logits, seed, ctr, temp, topk, topp, method):
     return _sample_tokens(
         logits[None], seed[None], ctr[None], temp[None],
         topk[None], topp[None], method[None])[0]
+
+
+def _put_token(tokens, slot, token):
+    """A step's token array with ``token`` at ``slot``: an admission's
+    first token joins the array the next step is fed from, on the
+    device (a family that hands more back behind the tokens keeps it)."""
+    from jax import lax
+    return lax.dynamic_update_slice(
+        tokens, token.astype(tokens.dtype)[None], (slot,))
+
+
+def _first_token_programs(family: str) -> Tuple[Any, Any]:
+    """A family's first-token programs: the selector over prefill
+    logits and the put of its token into a step's token array."""
+    return (_tracing.program(_select_one, "select", family),
+            _tracing.program(_put_token, "select", family))
+
+
+class Prefill:
+    """A prompt pass dispatched and not waited for
+    (:meth:`DecodeModel.dispatch_prefill`): its last-token ``logits``
+    (V,) and what it hands on (``held``: the K/V rows, and a family's
+    state) still on the device.  What the family says of the pass
+    (``said``, on its ``model.prefill`` ``span``) is read to the host
+    by :meth:`DecodeModel.read_prefill`, once the first token is."""
+
+    __slots__ = ("logits", "held", "said", "span")
+
+    def __init__(self, logits: Any, held: List[Any], said: Any,
+                 span: Any) -> None:
+        self.logits, self.held, self.said, self.span = \
+            logits, held, said, span
 
 
 def _slot_block_step(p, x, ck, cv, pos, nh: int, ga):
@@ -643,7 +675,7 @@ class DecodeModel:
         self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
         self._prefill_sfx_fn = _tracing.program(_prefill_sfx,
                                                 "prefill_suffix", fam)
-        self._select_fn = _tracing.program(_select_one, "select", fam)
+        self._select_fn, self._put_fn = _first_token_programs(fam)
         # the KV buffers are DONATED: XLA updates the resident cache in
         # place instead of allocating a fresh (S, h * d, L) per layer
         # every token
@@ -731,6 +763,27 @@ class DecodeModel:
         (bucket_len, nh, d)) and, for a family whose slots hold more
         than rows, fourth what ``PagedKVCache.write_prompt(state=)``
         installs beside them."""
+        logits, held, _, _ = self._run_prefill(tokens, bucket_len, True)
+        return (logits, *held)
+
+    def dispatch_prefill(self, tokens: _np.ndarray,
+                         bucket_len: int) -> Prefill:
+        """:meth:`prefill`'s pass, dispatched and not waited for: the
+        logits stay on the device for :meth:`dispatch_select`, the rows
+        (and state) go to ``write_prompt`` as they are, and nothing is
+        read to the host until :meth:`read_prefill`.  The
+        ``model.prefill`` span and ``mxnet_gen_step_seconds{phase=
+        "prefill"}`` then time the dispatch alone."""
+        return Prefill(*self._run_prefill(tokens, bucket_len, False))
+
+    def read_prefill(self, pre: Prefill) -> None:
+        """Read what the family says of a dispatched pass to the host
+        and onto its ``model.prefill`` span (the sparse-expert family's
+        load, as :meth:`prefill` says it)."""
+        self._say_prefill(pre.span, pre.said)
+
+    def _run_prefill(self, tokens: _np.ndarray, bucket_len: int,
+                     read: bool) -> Tuple[Any, List[Any], Any, Any]:
         import jax.numpy as jnp
         toks = _np.asarray(tokens, _np.int32).reshape(-1)
         t0 = toks.shape[0]
@@ -748,19 +801,25 @@ class DecodeModel:
             t = time.perf_counter()
             logits, *held = self._prefill_fn(
                 self.params, jnp.asarray(padded), _np.int32(t0))
-            held = self._prefill_extras(span, held)
-            out = _np.asarray(logits)
+            held, said = self._prefill_extras(held)
+            if read:
+                self._say_prefill(span, said)
+                logits = _np.asarray(logits)
             dt = time.perf_counter() - t
         _metrics.GEN_STEP_SECONDS.labels(phase="prefill").observe(
             dt, exemplar=_tracing.current_trace_id())
-        return (out, *held)
+        return logits, held, said, span
 
     # what a family's programs hand back or take beside the GPT family's,
     # where it has any (serving.moe: the held experts' load)
-    def _prefill_extras(self, span: Any, held: List[Any]) -> List[Any]:
+    def _prefill_extras(self, held: List[Any]) -> Tuple[List[Any], Any]:
         """``held``, what the prefill program returned after the logits,
-        without what is said on ``span`` and not handed on."""
-        return held
+        split into what is handed on and what is only said."""
+        return held, None
+
+    def _say_prefill(self, span: Any, said: Any) -> None:
+        """Read ``said`` (:meth:`_prefill_extras`) and say it on
+        ``span``."""
 
     def _step_tokens(self, tokens: _np.ndarray) -> _np.ndarray:
         """The host's (S,) last tokens as the step program takes them."""
@@ -979,16 +1038,33 @@ class DecodeModel:
         sequence's token at index ``i`` is identical whether the
         prefill or the decode step emitted it (the resurrection
         replay-from-transcript contract extends to sampling)."""
+        return int(self.dispatch_select(logits, seed, counter,
+                                        temperature, top_k, top_p, method))
+
+    def dispatch_select(self, logits: Any, seed: int, counter: int,
+                        temperature: float, top_k: int, top_p: float,
+                        method: int, into: Any = None,
+                        slot: int = 0) -> Any:
+        """:meth:`select`, dispatched and not read: the token stays on
+        the device.  ``into`` is a step's token array, still on the
+        device: the return is then that array with the token at
+        ``slot`` (one small program), for the next step to be fed
+        from and for :meth:`collect` to read with the step's own."""
+        import jax
         import jax.numpy as jnp
-        # logits keep the model dtype: the step's sampler sees the
-        # same representation, so the two paths stay bit-identical
         with _tracing.child_span("model.select"):
+            # logits keep the model dtype: the step's sampler sees the
+            # same representation, so the two paths stay bit-identical
             tok = self._select_fn(
                 jnp.asarray(logits),
                 _np.int32(seed), _np.int32(counter),
                 _np.float32(temperature), _np.int32(top_k),
                 _np.float32(top_p), _np.int32(method))
-            return int(tok)
+            if into is None:
+                return tok
+            # committed where the array is, as a step's results are
+            return self._put_fn(into, _np.int32(slot),
+                                jax.device_put(tok, into.sharding))
 
     def warmup(self, cache: Any, prompt_buckets: Sequence[int],
                suffix_pairs: bool = True) -> int:
@@ -1009,6 +1085,17 @@ class DecodeModel:
         self.select(_np.zeros((self.vocab_size,), self.logits_dtype),
                     seed=0, counter=0, temperature=1.0, top_k=1,
                     top_p=1.0, method=0)
+        n += 1
+        # the engine's admission chain: the logits stay on the device,
+        # the token joins a step's token array there (committed to the
+        # cache's device, as a step's results are)
+        pre = self.dispatch_prefill(_np.zeros((1,), _np.int32),
+                                    int(min(prompt_buckets)))
+        _np.asarray(self.dispatch_select(
+            pre.logits, 0, 0, 1.0, 1, 1.0, 0,
+            into=jax.device_put(self._step_tokens(
+                _np.zeros((cache.max_slots,), _np.int32)), cache.device)))
+        self.read_prefill(pre)
         n += 1
         if suffix_pairs:
             dev = cache.device

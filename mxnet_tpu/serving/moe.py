@@ -29,7 +29,10 @@ choices that fell on each held expert of each layer, over every slot
 the program ran (a free slot rides along at token 0 and is routed like
 any other: the load is the grouped product's, not the requests').
 ``collect`` splits it, says it on the ``model.step.readback`` span and
-moves the ``mxnet_gen_expert_*`` counters.
+moves the ``mxnet_gen_expert_*`` counters.  A prefill's load, over its
+real tokens, goes on its ``model.prefill`` span: ``prefill`` reads it
+at once, and a pass the engine dispatched (``dispatch_prefill``) has it
+read by ``read_prefill`` with the admission's first token.
 
 A ring cannot be rewound and a shared prefix would have to carry the
 rings at its end: ``supports_rollback`` is False, so
@@ -47,7 +50,7 @@ import numpy as _np
 from .. import metrics as _metrics
 from .. import tracing as _tracing
 from .kv_cache import PagedKVCache
-from .model import DecodeModel, _sample_tokens, _select_one
+from .model import DecodeModel, _first_token_programs, _sample_tokens
 
 __all__ = ["MoEDecodeModel"]
 
@@ -201,7 +204,7 @@ class MoEDecodeModel(DecodeModel):
 
         fam = self.family
         self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
-        self._select_fn = _tracing.program(_select_one, "select", fam)
+        self._select_fn, self._put_fn = _first_token_programs(fam)
         self._step_fn = _tracing.program(_step, "decode", fam,
                                          donate_argnums=(1, 2, 3))
 
@@ -239,12 +242,14 @@ class MoEDecodeModel(DecodeModel):
 
     # -- execution: DecodeModel's prefill, dispatch and collect, with the
     # load taken out of what the programs hand back ---------------------
-    def _prefill_extras(self, span: Any, held: List[Any]) -> List[Any]:
+    def _prefill_extras(self, held: List[Any]) -> Tuple[List[Any], Any]:
         *held, load = held
+        return held, load
+
+    def _say_prefill(self, span: Any, load: Any) -> None:
         load = self.last_prefill_load = _np.asarray(load)
         span.set_attr(expert_assignments=int(load.sum()),
                       experts_hit=int((load > 0).sum()))
-        return held
 
     def _step_tokens(self, tokens: _np.ndarray) -> _np.ndarray:
         out = _np.zeros((len(tokens) + self.n_layers * self.held,),

@@ -79,6 +79,9 @@ class StepCounters:
         out.update(
             ahead=metrics.value("mxnet_gen_steps_ahead_total"),
             discarded=metrics.value("mxnet_gen_discarded_tokens_total"),
+            admissions=metrics.value("mxnet_gen_admissions_total"),
+            admissions_ahead=metrics.value(
+                "mxnet_gen_admissions_ahead_total"),
             iterations=metrics.value("mxnet_gen_iterations_total"),
             decode_tokens=metrics.value("mxnet_gen_tokens_total",
                                         phase="decode"),

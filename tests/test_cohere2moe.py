@@ -475,6 +475,38 @@ def test_prefill_says_the_load_of_the_real_tokens(model, params):
     assert span["attrs"]["family"] == "cohere2moe"
 
 
+def test_every_admission_says_its_load_through_the_engine(model, params):
+    """Admissions queued behind a step read the prefill's load back with
+    their first token: every admission's ``model.prefill`` span still
+    says ``expert_assignments`` and ``experts_hit``, equal to the hand
+    count from the reference's router scores.  ``prefill`` called
+    directly still hands back numpy logits and sets
+    ``last_prefill_load`` (what the benchmark's correctness job
+    reads)."""
+    mix = [dict(prompt=prompt(11, 80), max_new_tokens=12, at=0),
+           dict(prompt=prompt(7, 81), max_new_tokens=3, at=0),
+           dict(prompt=prompt(19, 82), max_new_tokens=4, at=2),
+           dict(prompt=prompt(5, 83), max_new_tokens=3, at=3)]
+    engine = new_engine(model, max_slots=2)
+    counted = StepCounters()
+    t0 = time.perf_counter()
+    run_staggered(engine, mix)
+    assert counted.moved()["admissions_ahead"] >= 1
+    said = sorted((s for s in tracing.spans() if s["name"] == "model.prefill"
+                   and s["t_begin"] >= t0), key=lambda s: s["t_begin"])
+    assert len(said) == len(mix)
+    wants = []
+    for s, r in zip(said, mix):      # the queue admits in arrival order
+        _, _, scores = ref.hidden_states(params, r["prompt"], model.cfg)
+        want = np.stack([held_choices(x, model.cfg).sum(0) for x in scores])
+        assert s["attrs"]["expert_assignments"] == want.sum() > 0
+        assert s["attrs"]["experts_hit"] == (want > 0).sum()
+        wants.append(want)
+    logits, *_ = model.prefill(mix[0]["prompt"], 64)
+    assert isinstance(logits, np.ndarray) and logits.shape == (VOCAB,)
+    np.testing.assert_array_equal(model.last_prefill_load, wants[0])
+
+
 def test_a_steps_load_equals_a_hand_count(model, params):
     """Two slots: one live, one free (it rides along at token 0,
     position 0 and is routed like any other).  The step's load is, by
@@ -593,6 +625,8 @@ def test_one_step_in_flight_changes_no_transcript(model, sampled):
         assert (s.result(), s.finish_reason) == serial_transcript(
             model, engine, r["prompt"], **kw)
     assert moved["ahead"] >= 25 and moved["discarded"] == 0
+    assert moved["admissions_ahead"] >= 1
+    assert moved["admit"] == moved["finish"] == 0
     assert moved["ahead"] + counted.fallbacks() == moved["iterations"]
     check_log(engine, streams)
 
@@ -662,7 +696,7 @@ def test_the_other_families_are_untouched():
     toks = np.arange(3, dtype=np.int32)
     assert plain._step_tokens(toks) is toks
     assert plain._read_step(None, toks) is toks
-    assert plain._prefill_extras(None, [1, 2]) == [1, 2]
+    assert plain._prefill_extras([1, 2]) == ([1, 2], None)
     assert plain.row_blocks(np.zeros(2, np.int32), 64) is None
     with pytest.raises(MXNetError, match="Cohere2MoEModel"):
         serving.DecodeModel.from_block(mx.gluon.nn.Dense(4))

@@ -389,8 +389,9 @@ STAGGERED = [
 def test_transcripts_equal_the_serial_step_loop(decode_model):
     """Greedy: every stream's tokens and finish reason equal the same
     request decoded alone by ``DecodeModel.step``, launch-wait-read; the
-    run really ran ahead, and really fell back for its admissions and
-    finishes.  (Sampled lanes: test_gen_sampling; the hybrid family:
+    run really ran ahead, through its finishes and through the
+    admissions that found a step in flight, which were queued behind
+    it.  (Sampled lanes: test_gen_sampling; the hybrid family:
     test_phi4flash.)"""
     eng = _engine(decode_model)
     counted = StepCounters()
@@ -403,8 +404,13 @@ def test_transcripts_equal_the_serial_step_loop(decode_model):
         assert (s.result(timeout=5), s.finish_reason) == want
     moved = counted.moved()
     assert moved["ahead"] >= 25
-    assert moved["finish"] >= 2 and moved["admit"] >= 1 \
-        and moved["idle"] >= 1
+    # the first launch is serial; no admission or finish stops the loop
+    assert moved["idle"] >= 1
+    assert moved["admit"] == moved["finish"] == 0
+    # the two that arrive together find nothing in flight; the other
+    # three are queued behind a step
+    assert (moved["admissions"], moved["admissions_ahead"]) == (5, 3)
+    assert moved["ahead"] + counted.fallbacks() == moved["iterations"]
     assert moved["discarded"] == 0          # nothing ended on an EOS
     check_log(eng, streams)
 
@@ -427,6 +433,11 @@ def test_every_step_is_counted_ahead_or_fallen_back(decode_model, traced):
     assert len(launches) == moved["iterations"]
     assert all(r["attrs"]["ahead"] in (0, 1) for r in launches)
     assert sum(r["attrs"]["ahead"] for r in launches) == moved["ahead"]
+    # every admission says whether it was queued behind a step
+    admissions = [r for r in traced.spans() if r["name"] == "engine.prefill"]
+    assert len(admissions) == moved["admissions"] == len(STAGGERED)
+    assert sum(r["attrs"]["ahead"] for r in admissions) \
+        == moved["admissions_ahead"] >= 1
     # what the token counters say is what the clients got
     delivered = sum(len(s.result(timeout=5)) for s in streams)
     assert moved["decode_tokens"] + moved["prefill_tokens"] == delivered
@@ -493,6 +504,205 @@ def test_cancel_with_a_step_in_flight(decode_model):
         decode_model, eng, onp.arange(1, 8, dtype="int32"), 12)[0]
     assert sc.result(timeout=5) == serial_transcript(
         decode_model, eng, PROMPT_B, 5)[0]
+
+
+# ---------------------------------------------------------------------------
+# admissions queued behind the step in flight
+# ---------------------------------------------------------------------------
+
+def _decoding(eng, prompt=PROMPT_A, n=20, quanta=3):
+    """One request decoding in a two-slot engine, a step in flight and
+    the other slot free."""
+    s = eng.submit(prompt, max_new_tokens=n)
+    for _ in range(quanta):
+        eng.run_iteration()
+    assert eng._flight is not None and eng.cache.free_slots() == [1]
+    return s
+
+
+def test_a_budget_of_one_is_streamed_and_never_decoded(decode_model,
+                                                       monkeypatch):
+    """A request for one token, admitted behind a step: its token is
+    the first token, read with that step's, and no step is launched
+    over its slot."""
+    eng = _engine(decode_model)
+    sa = _decoding(eng)
+    launched = []
+    real = eng.model.dispatch
+
+    def spy(cache, tokens, positions, *rest):
+        launched.append(onp.array(positions))
+        return real(cache, tokens, positions, *rest)
+
+    monkeypatch.setattr(eng.model, "dispatch", spy)
+    counted = StepCounters()
+    sb = eng.submit(PROMPT_B, max_new_tokens=1)
+    _drain(eng, sa, sb)
+    assert (sb.result(timeout=5), sb.finish_reason) == serial_transcript(
+        decode_model, eng, PROMPT_B, 1)
+    assert (sa.result(timeout=5), sa.finish_reason) == serial_transcript(
+        decode_model, eng, PROMPT_A, 20)
+    moved = counted.moved()
+    assert moved["admissions_ahead"] == 1 and moved["discarded"] == 0
+    assert moved["admit"] == moved["finish"] == 0
+    # slot 1 rode every launch as a free slot does
+    assert launched and all(p[1] == 0 for p in launched)
+    assert moved["prefill_tokens"] == 1
+    check_log(eng, [sa, sb])
+
+
+def test_an_eos_on_the_first_token_lags_one_step(decode_model):
+    """An admission queued behind a step whose first token is its EOS:
+    the host reads it after the next step was launched over the slot,
+    so that step's token for it is discarded (the one-step EOS lag),
+    and the resident decodes as if alone."""
+    eng = _engine(decode_model)
+    eos = serial_transcript(decode_model, eng, PROMPT_B, 6)[0][0]
+    sa = _decoding(eng)
+    counted = StepCounters()
+    sb = eng.submit(PROMPT_B, max_new_tokens=6, eos_token=eos)
+    _drain(eng, sa, sb)
+    assert (sb.result(timeout=5), sb.finish_reason) == ([eos], "eos")
+    assert (sa.result(timeout=5), sa.finish_reason) == serial_transcript(
+        decode_model, eng, PROMPT_A, 20)
+    moved = counted.moved()
+    assert moved["admissions_ahead"] == 1 and moved["discarded"] == 1
+    assert moved["decode_tokens"] + moved["prefill_tokens"] \
+        == len(sa.tokens) - 3 + 1
+    check_log(eng, [sa, sb])
+
+
+def test_an_admission_grows_the_rows_behind_a_step(decode_model):
+    """A prompt longer than the rows' bucket, admitted with a step in
+    flight: the rows grow behind that step, the prompt's rows are
+    written into the grown buffers, and both streams equal the serial
+    loop's."""
+    eng = _engine(decode_model)
+    sa = _decoding(eng, PROMPT_B, 10)
+    assert eng.cache.bucket == 16
+    long_p = onp.arange(3, 23, dtype="int32")      # 20 rows: bucket 32
+    m0 = metrics.value("mxnet_gen_kv_migrations_total")
+    counted = StepCounters()
+    sb = eng.submit(long_p, max_new_tokens=8)
+    eng.run_iteration()
+    assert counted.moved()["admissions_ahead"] == 1
+    assert eng.cache.bucket == 32
+    assert metrics.value("mxnet_gen_kv_migrations_total") == m0 + 1
+    assert sb.tokens == []                  # read with the next step
+    _drain(eng, sa, sb)
+    assert (sa.result(timeout=5), sa.finish_reason) == serial_transcript(
+        decode_model, eng, PROMPT_B, 10)
+    assert (sb.result(timeout=5), sb.finish_reason) == serial_transcript(
+        decode_model, eng, long_p, 8)
+    assert counted.fallbacks() == 0
+
+
+def test_an_admitting_quantum_launches_first_and_reads_nothing_of_it(
+        decode_model, traced, monkeypatch):
+    """The order of a quantum that admits behind a step: launch N+1
+    (``ahead=1``), read N back, then the admission (``engine.prefill``
+    ``ahead=1``: prefill, row write and the select into N+1's token
+    array), with no readback and no read of the prefill or its first
+    token before the next quantum's launch; that quantum reads the
+    first token with N+1's."""
+    eng = _engine(decode_model)
+    sa = _decoding(eng, n=12)
+    calls = []
+    for name in ("dispatch", "collect", "dispatch_prefill",
+                 "dispatch_select", "read_prefill", "prefill", "select"):
+        def spy(*a, _name=name, _real=getattr(eng.model, name), **kw):
+            calls.append(_name if _name != "dispatch_select"
+                         else (_name, kw.get("into") is not None))
+            return _real(*a, **kw)
+        monkeypatch.setattr(eng.model, name, spy)
+    traced.reset()
+    sb = eng.submit(PROMPT_B, max_new_tokens=4)
+    eng.run_iteration()
+    assert calls == ["dispatch", "collect", "dispatch_prefill",
+                     ("dispatch_select", True)]
+    assert sb.tokens == [] and list(eng._flight.first) == [1]
+    del calls[:]
+    eng.run_iteration()
+    assert calls == ["dispatch", "collect", "read_prefill"]
+    assert len(sb.tokens) == 1
+    recs = traced.spans()
+    iters = sorted((r for r in recs if r["name"] == "engine.iteration"),
+                   key=lambda r: r["t_begin"])
+    assert len(iters) == 2
+    admit = [r for r in recs if r["name"] == "engine.prefill"]
+    assert len(admit) == 1 and admit[0]["attrs"]["ahead"] == 1
+    assert _inside(admit[0], iters[0])
+    for r in recs:
+        if r["name"] in ("model.prefill", "kv.write_prompt",
+                         "model.select"):
+            assert _inside(r, admit[0]), r["name"]
+    for it in iters:
+        order = sorted((r for r in recs if _inside(r, it) and r["name"]
+                        in ("model.step.dispatch", "model.step.readback",
+                            "engine.prefill")), key=lambda r: r["t_begin"])
+        names = [r["name"] for r in order]
+        assert names[:2] == ["model.step.dispatch", "model.step.readback"]
+        assert order[0]["attrs"]["ahead"] == 1
+        assert names[2:] == (["engine.prefill"] if it is iters[0] else [])
+    _drain(eng, sa, sb)
+    assert (sb.result(timeout=5), sb.finish_reason) == serial_transcript(
+        decode_model, eng, PROMPT_B, 4)
+
+
+def test_nothing_compiles_after_the_warm_up():
+    """A staggered run, sampled lanes and admissions behind a step
+    included, compiles nothing after ``warmup()``: the first-token
+    program that puts a token into a step's array is warmed with the
+    rest of the grid.  A model of its own, so no other test's
+    compiles count."""
+    from mxnet_tpu import tracing
+    from mxnet_tpu.gluon.model_zoo.gpt import GPTModel
+    mx.random.seed(3)
+    net = GPTModel(vocab_size=61, num_layers=1, units=16, hidden_size=24,
+                   num_heads=2, max_length=64, dropout=0.0)
+    net.initialize(mx.init.Normal(1.0))
+    net(mx.np.zeros((1, 4), dtype="int32"))
+    eng = _engine(DecodeModel.from_block(net))
+    lanes = [dict(method="top_k", top_k=5, seed=1), dict(method="greedy"),
+             dict(method="sample", temperature=0.9, seed=2),
+             dict(method="top_p", top_p=0.8, seed=3), dict()]
+    mix = [dict(r, **lane) for r, lane in zip(STAGGERED, lanes)]
+
+    def compiled():
+        return (metrics.value("mxnet_compile_misses_total")
+                + metrics.value("mxnet_compile_persistent_hits_total"),
+                {p.module: len(p.built()) for p in tracing.programs()
+                 if p.family == "gpt"})
+
+    before = compiled()
+    counted = StepCounters()
+    run_staggered(eng, mix)
+    assert counted.moved()["admissions_ahead"] >= 1
+    assert compiled() == before
+
+
+def test_a_prefill_fault_behind_a_step_fails_only_its_request(decode_model):
+    """The injected prefill fault of an admission queued behind a step
+    fails that request alone: the step in flight is read next time and
+    the resident decodes on as if alone."""
+    eng = _engine(decode_model)
+    sa = _decoding(eng)
+    counted = StepCounters()
+    sb = eng.submit(PROMPT_B, max_new_tokens=4)
+    # hit 1 is the readback of the step in flight, hit 2 the admission
+    with faults.fault_plan("serving.execute:after=1:times=1"):
+        assert eng.run_iteration()
+    with pytest.raises(mx.MXNetError, match="injected"):
+        sb.result(timeout=5)
+    moved = counted.moved()
+    assert moved["ahead"] == 1 and moved["admissions_ahead"] == 0
+    assert eng._flight is not None and eng.cache.free_slots() == [1]
+    sc = eng.submit(PROMPT_B, max_new_tokens=4)
+    _drain(eng, sa, sc)
+    assert (sa.result(timeout=5), sa.finish_reason) == serial_transcript(
+        decode_model, eng, PROMPT_A, 20)
+    assert sc.result(timeout=5) == serial_transcript(
+        decode_model, eng, PROMPT_B, 4)[0]
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,8 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 import reference_olmo_hybrid as ref                           # noqa: E402
+from serial_decode import (StepCounters, check_log,           # noqa: E402
+                           run_staggered, serial_transcript)
 
 VOCAB, H, DK, DV = 97, 2, 8, 48
 TOL = 2e-5      # float32 system against the float32 reference
@@ -354,6 +356,50 @@ def test_alone_equals_in_a_full_batch(model):
     while engine.run_iteration():
         pass
     assert (np.asarray(streams[0].result()) == alone).all()
+
+
+def _prompt(n, seed):
+    return np.random.default_rng(seed).integers(0, VOCAB, n).astype(np.int32)
+
+
+DELTA_MIX = [
+    dict(prompt=_prompt(11, 50), max_new_tokens=30, at=0),
+    dict(prompt=_prompt(3, 51), max_new_tokens=6, at=0),
+    dict(prompt=_prompt(20, 52), max_new_tokens=12, at=2),
+    dict(prompt=_prompt(5, 53), max_new_tokens=4, at=5),
+    dict(prompt=_prompt(40, 54), max_new_tokens=30, at=26),
+]
+LANES = [dict(method="top_k", top_k=7, temperature=0.8, seed=11),
+         dict(method="greedy"),
+         dict(method="sample", temperature=1.3, seed=2 ** 31 - 5),
+         dict(method="top_p", top_p=0.85, seed=3),
+         dict(method="top_k", top_k=3, seed=4)]
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+def test_one_step_in_flight_changes_no_transcript(model, sampled):
+    """The engine launches step N+1 from step N's token array before it
+    reads N back, and queues admissions behind it; every stream still
+    equals the request decoded alone by ``model.step``,
+    launch-wait-read: matrix state, conv inputs and rows, greedy and
+    with sampling lanes in the same batch."""
+    mix = [dict(r, **(lane if sampled else {}))
+           for r, lane in zip(DELTA_MIX, LANES)]
+    engine = serving.GenerationEngine(model, max_slots=2,
+                                      kv_buckets=(64, 128), prefix_slots=0,
+                                      max_tokens=64)
+    counted = StepCounters()
+    streams = run_staggered(engine, mix)
+    moved = counted.moved()
+    for s, r in zip(streams, mix):
+        kw = {k: v for k, v in r.items() if k not in ("at", "prompt")}
+        assert (s.result(), s.finish_reason) == serial_transcript(
+            model, engine, r["prompt"], **kw)
+    assert moved["ahead"] >= 25 and moved["discarded"] == 0
+    assert moved["admissions_ahead"] >= 1
+    assert moved["admit"] == moved["finish"] == 0
+    assert moved["ahead"] + counted.fallbacks() == moved["iterations"]
+    check_log(engine, streams)
 
 
 def test_the_decode_step_calls_the_kernels_once_a_layer(model):

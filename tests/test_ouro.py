@@ -571,6 +571,8 @@ def test_one_step_in_flight_changes_no_transcript(model, sampled):
         assert (s.result(), s.finish_reason) == serial_transcript(
             model, engine, r["prompt"], **kw)
     assert moved["ahead"] >= 25 and moved["discarded"] == 0
+    assert moved["admissions_ahead"] >= 1
+    assert moved["admit"] == moved["finish"] == 0
     assert moved["ahead"] + counted.fallbacks() == moved["iterations"]
     check_log(engine, streams)
 

@@ -269,7 +269,10 @@ def test_one_step_in_flight_changes_no_transcript(model, sampled):
         assert (s.result(), s.finish_reason) == serial_transcript(
             model, engine, r["prompt"], **kw)
     assert moved["ahead"] >= 25 and moved["discarded"] == 0
-    assert moved["finish"] >= 2 and moved["admit"] >= 1
+    # admissions behind a step in flight; no admission or finish
+    # stops the loop
+    assert moved["admissions_ahead"] >= 1
+    assert moved["admit"] == moved["finish"] == 0
     assert moved["ahead"] + counted.fallbacks() == moved["iterations"]
     check_log(engine, streams)
 

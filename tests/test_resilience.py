@@ -273,11 +273,11 @@ def test_fault_at_the_late_readback_hits_exactly_the_steps_in_flight(
     for _ in range(5):
         eng.run_iteration()
     if moment == "at_a_fall_back":
-        # B's budget ends with the token in flight, which the host
-        # counts itself: the next quantum reads before it launches
-        for req in eng.scheduler.active().values():
+        # B's slot sits at the top bucket's last row, which the host
+        # sees itself: the next quantum reads before it launches
+        for slot, req in eng.scheduler.active().items():
             if req.stream is streams[1]:
-                req.max_new_tokens = req.emitted + 1
+                eng.cache.positions[slot] = eng.grid[-1]
     assert eng._flight is not None and len(eng.scheduler) == 1
     had = [list(s.tokens) for s in streams]
     counted = StepCounters()
