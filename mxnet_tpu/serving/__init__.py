@@ -74,6 +74,9 @@ box.
 from .batching import (BucketPolicy, DynamicBatcher, OverloadError,
                        Request, SlotScheduler)
 from .model import DecodeModel, ServedModel, load_served
+# each decode family registers the zoo class it serves with
+# DecodeModel.from_block
+from . import delta, gpt, hybrid, loop, moe                  # noqa: F401
 from .kv_cache import PagedKVCache, PrefixCache
 from .generation import GenerationEngine, StreamTimeout, TokenStream
 from .replica import ReplicaSupervisor

@@ -36,14 +36,12 @@ raise.
 from __future__ import annotations
 
 import math
-import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as _np
 
-from .. import tracing as _tracing
 from .kv_cache import PagedKVCache
-from .model import DecodeModel, _first_token_programs, _sample_tokens
+from .model import DecodeModel
 
 __all__ = ["DeltaDecodeModel"]
 
@@ -64,6 +62,8 @@ class DeltaDecodeModel(DecodeModel):
     cache's buffers."""
 
     family = "olmo_hybrid"
+    zoo = "mxnet_tpu.gluon.model_zoo.olmo_hybrid.OlmoHybridModel"
+    cache_buffers = 3
     max_prompt = MAX_PROMPT
     min_prompt_bucket = MIN_PROMPT_BUCKET
     supports_rollback = False
@@ -72,29 +72,14 @@ class DeltaDecodeModel(DecodeModel):
         "also hold the delta rule's matrix state and a conv's inputs, "
         "of which no snapshot is taken yet")
 
-    def __init__(self, params: Any, cfg: Dict[str, Any], max_length: int,
-                 name: str) -> None:
-        # not DecodeModel.__init__: that builds the GPT programs
+    def _model_fns(self) -> Tuple[Any, Any]:
         import jax
         import jax.numpy as jnp
         from ..gluon.model_zoo import olmo_hybrid as _oh
         from ..ops.pallas import column_write as _cw
         from ..ops.pallas import decode_attention as _da
         from ..ops.pallas import delta_step as _ds
-        self.params = params
-        self.cfg = cfg
-        self.kinds = list(cfg["kinds"])
-        self.max_length = int(max_length)
-        self.name = name
-        self.vocab_size, self.units = params["embed"].shape
-        self.num_heads = int(cfg["num_heads"])
-        self.head_dim = int(cfg["head_dim"])
-        self.n_layers = len(self.kinds)
-        self.dtype = params["embed"].dtype
-        self.logits_dtype = jnp.dtype(jnp.float32)
-        self._seen_lock = threading.Lock()
-        self._seen: set = set()
-        kinds = self.kinds
+        cfg, kinds = self.cfg, self.kinds
         heads, d = self.num_heads, self.head_dim
         scale = 1.0 / math.sqrt(d)
         nth = [sum(k == kind for k in kinds[:i])
@@ -118,13 +103,11 @@ class DeltaDecodeModel(DecodeModel):
                     vs.append(b)
             return _oh.lm_logits(params, h, cfg)[0], ks, vs, state
 
-        def _step(params, ks, vs, state, toks, pos, seeds, bases, temps,
-                  topks, topps, methods):
-            # the GPT step's contract (model.DecodeModel._step): toks,
-            # pos (S,), free slots ride along at pos 0, the sampler in
-            # the program.  ks/vs: the full layers' rows; state: the
-            # linear layers' conv inputs and matrix states
-            from jax import lax
+        def _logits(params, ks, vs, state, toks, pos):
+            # the step's contract (model.DecodeModel._step): toks, pos
+            # (S,), free slots ride along at pos 0.  ks/vs: the full
+            # layers' rows; state: the linear layers' conv inputs and
+            # matrix states
             S = pos.shape[0]
             with jax.named_scope("embed"):
                 x = params["embed"][toks]
@@ -157,31 +140,9 @@ class DeltaDecodeModel(DecodeModel):
                                                  ck, cv, pos, scale)
                     x = _oh.full_out(p, a.reshape(S, heads * d), x, cfg)
                 x = _oh.mlp(p, x, cfg)
-            logits = _oh.lm_logits(params, x, cfg)
+            return _oh.lm_logits(params, x, cfg), ks, vs, new
 
-            def _mixed(lg):
-                return _sample_tokens(lg, seeds, pos - bases, temps,
-                                      topks, topps, methods)
-
-            def _greedy(lg):
-                return jnp.argmax(lg, axis=-1).astype(jnp.int32)
-
-            with jax.named_scope("sample"):
-                next_tok = lax.cond(jnp.any(methods != 0), _mixed,
-                                    _greedy, logits)
-            return next_tok, ks, vs, new
-
-        fam = self.family
-        self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
-        self._select_fn, self._put_fn = _first_token_programs(fam)
-        self._step_fn = _tracing.program(_step, "decode", fam,
-                                         donate_argnums=(1, 2, 3))
-
-    @staticmethod
-    def from_olmo_hybrid(block: Any) -> "DeltaDecodeModel":
-        from ..gluon.model_zoo.olmo_hybrid import _collect
-        return DeltaDecodeModel(_collect(block), dict(block.config),
-                                block._max_length, type(block).__name__)
+        return _prefill, _logits
 
     # -- what the engine asks of a family -----------------------------------
     def state_shapes(self) -> Dict[str, Tuple[int, ...]]:
@@ -209,12 +170,6 @@ class DeltaDecodeModel(DecodeModel):
         read, every = _da.blocks_read(_np.asarray(positions), bucket)
         n = self.kinds.count("full")
         return n * read, n * every
-
-    def verify(self, *args: Any, **kwargs: Any) -> _np.ndarray:
-        raise self.no_rollback("speculative verification")
-
-    def prefill_suffix(self, *args: Any, **kwargs: Any) -> Any:
-        raise self.no_rollback("suffix prefill over a shared prefix")
 
     def describe(self) -> Dict[str, Any]:
         out = super().describe()

@@ -41,14 +41,12 @@ raise.
 from __future__ import annotations
 
 import math
-import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as _np
 
-from .. import tracing as _tracing
 from .kv_cache import PagedKVCache
-from .model import DecodeModel, _first_token_programs, _sample_tokens
+from .model import DecodeModel
 
 __all__ = ["HybridDecodeModel"]
 
@@ -133,6 +131,8 @@ class HybridDecodeModel(DecodeModel):
     the per-slot state carried as one pytree of the cache's buffers."""
 
     family = "phi4flash"
+    zoo = "mxnet_tpu.gluon.model_zoo.phi4flash.Phi4FlashModel"
+    cache_buffers = 3
     max_prompt = MAX_PROMPT
     min_prompt_bucket = MIN_PROMPT_BUCKET
     supports_rollback = False
@@ -141,26 +141,12 @@ class HybridDecodeModel(DecodeModel):
         "also hold recurrent state and window rings, of which no "
         "snapshot is taken yet")
 
-    def __init__(self, params: Any, cfg: Dict[str, Any], max_length: int,
-                 name: str) -> None:
-        # not DecodeModel.__init__: that builds the GPT programs
+    def _model_fns(self) -> Tuple[Any, Any]:
         import jax
         import jax.numpy as jnp
         from ..gluon.model_zoo import phi4flash as _pf
         from ..ops.pallas import column_write as _cw
-        self.params = params
-        self.cfg = cfg
-        self.kinds = list(cfg["kinds"])
-        self.max_length = int(max_length)
-        self.name = name
-        self.vocab_size, self.units = params["embed"].shape
-        self.num_heads = int(cfg["num_heads"])
-        self.head_dim = int(cfg["head_dim"])
-        self.n_layers = len(self.kinds)
-        self.dtype = params["embed"].dtype
-        self.logits_dtype = jnp.dtype(jnp.float32)
-        self._seen_lock = threading.Lock()
-        self._seen: set = set()
+        cfg = self.cfg
         W = int(cfg["window"])
         kinds = self.kinds
         nth = [sum(CACHE_KIND[k] == CACHE_KIND[kind] for k in kinds[:i])
@@ -200,13 +186,11 @@ class HybridDecodeModel(DecodeModel):
                     vs.append(b.reshape(Lp, -1, cfg["head_dim"]))
             return logits, ks, vs, state
 
-        def _step(params, ks, vs, state, toks, pos, seeds, bases, temps,
-                  topks, topps, methods):
-            # the GPT step's contract (model.DecodeModel._step): toks,
-            # pos (S,), free slots ride along at pos 0, the sampler in
-            # the program.  ks/vs: the full layer's rows; state: the
-            # window rings, conv tails and recurrence states
-            from jax import lax
+        def _logits(params, ks, vs, state, toks, pos):
+            # the step's contract (model.DecodeModel._step): toks, pos
+            # (S,), free slots ride along at pos 0.  ks/vs: the full
+            # layer's rows; state: the window rings, conv tails and
+            # recurrence states
             eps = cfg["layer_norm_eps"]
             with jax.named_scope("embed"):
                 x = params["embed"][toks]
@@ -253,30 +237,9 @@ class HybridDecodeModel(DecodeModel):
             with jax.named_scope("head"):
                 x = _pf._ln(x, params["lnf_g"], params["lnf_b"], eps)
                 logits = _pf._mm(x, params["embed"])
+            return logits, ks, vs, new
 
-            def _mixed(lg):
-                return _sample_tokens(lg, seeds, pos - bases, temps,
-                                      topks, topps, methods)
-
-            def _greedy(lg):
-                return jnp.argmax(lg, axis=-1).astype(jnp.int32)
-
-            with jax.named_scope("sample"):
-                next_tok = lax.cond(jnp.any(methods != 0), _mixed,
-                                    _greedy, logits)
-            return next_tok, ks, vs, new
-
-        fam = self.family
-        self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
-        self._select_fn, self._put_fn = _first_token_programs(fam)
-        self._step_fn = _tracing.program(_step, "decode", fam,
-                                         donate_argnums=(1, 2, 3))
-
-    @staticmethod
-    def from_phi4flash(block: Any) -> "HybridDecodeModel":
-        from ..gluon.model_zoo.phi4flash import _collect
-        return HybridDecodeModel(_collect(block), dict(block.config),
-                                 block._max_length, type(block).__name__)
+        return _prefill, _logits
 
     # -- what the engine asks of a family -----------------------------------
     def make_cache(self, max_slots: int, buckets: Sequence[int],
@@ -296,14 +259,6 @@ class HybridDecodeModel(DecodeModel):
                    bucket: int) -> Tuple[int, int]:
         from ..ops.pallas import decode_attention as _da
         return _da.blocks_read(_np.asarray(positions), bucket)
-
-    # -- execution: DecodeModel's prefill and step, which hand a family's
-    # extra results through (the fixed-size kinds ride fourth) ----------
-    def verify(self, *args: Any, **kwargs: Any) -> _np.ndarray:
-        raise self.no_rollback("speculative verification")
-
-    def prefill_suffix(self, *args: Any, **kwargs: Any) -> Any:
-        raise self.no_rollback("suffix prefill over a shared prefix")
 
     def describe(self) -> Dict[str, Any]:
         out = super().describe()

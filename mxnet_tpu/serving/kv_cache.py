@@ -27,7 +27,7 @@ This cache provides that shape discipline:
   (``ops.pallas.column_write``, PR 33: the 128-position tile column
   that holds the slot's position is read, one lane of it replaced,
   and written back), the GPT family's step
-  (``model._slot_block_step``) still with one
+  (``gpt._slot_block_step``) still with one
   ``dynamic_update_slice`` a slot and buffer, ~3.5 us each whatever
   it moves.  Heads and ``d`` share ONE axis: a column is then whole
   sublane tiles of one 2-D block, and the per-slot updates compile to

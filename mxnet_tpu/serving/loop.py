@@ -39,15 +39,13 @@ refuses speculation and the prefix cache, and ``verify`` /
 from __future__ import annotations
 
 import math
-import threading
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as _np
 
 from .. import metrics as _metrics
-from .. import tracing as _tracing
 from .kv_cache import PagedKVCache
-from .model import DecodeModel, _first_token_programs, _sample_tokens
+from .model import DecodeModel
 
 __all__ = ["LoopDecodeModel"]
 
@@ -68,6 +66,7 @@ class LoopDecodeModel(DecodeModel):
     ``warmup``)."""
 
     family = "loop"
+    zoo = "mxnet_tpu.gluon.model_zoo.ouro.OuroModel"
     max_prompt = MAX_PROMPT
     min_prompt_bucket = MIN_PROMPT_BUCKET
     supports_rollback = False
@@ -78,28 +77,19 @@ class LoopDecodeModel(DecodeModel):
 
     def __init__(self, params: Any, cfg: Dict[str, Any], max_length: int,
                  name: str) -> None:
-        # not DecodeModel.__init__: that builds the GPT programs
-        import jax
-        import jax.numpy as jnp
-        from ..gluon.model_zoo import ouro as _ou
-        from ..ops.pallas import decode_attention as _da
-        self.params = params
-        self.cfg = cfg
-        self.max_length = int(max_length)
-        self.name = name
-        self.vocab_size, self.units = params["embed"].shape
-        self.num_heads = int(cfg["num_heads"])
-        self.head_dim = int(cfg["head_dim"])
-        self.n_layers = int(cfg["num_layers"])
+        super().__init__(params, cfg, max_length, name)
         self.loop_steps = int(cfg["loop_steps"])
         self.entries = self.loop_steps * self.n_layers
         self.span_attrs = {"loop_steps": self.loop_steps,
                            "layer_passes": self.entries}
-        self.dtype = params["embed"].dtype
-        self.logits_dtype = jnp.dtype(jnp.float32)
-        self._seen_lock = threading.Lock()
-        self._seen: set = set()
-        steps, N = self.loop_steps, self.n_layers
+
+    def _model_fns(self) -> Tuple[Any, Any]:
+        import jax
+        import jax.numpy as jnp
+        from ..gluon.model_zoo import ouro as _ou
+        from ..ops.pallas import decode_attention as _da
+        cfg = self.cfg
+        steps, N = int(cfg["loop_steps"]), self.n_layers
         heads, d = self.num_heads, self.head_dim
         scale = 1.0 / math.sqrt(d)
 
@@ -117,11 +107,10 @@ class LoopDecodeModel(DecodeModel):
                     [k.reshape(steps * N, Lp, heads, d)],
                     [v.reshape(steps * N, Lp, heads, d)])
 
-        def _step(params, ks, vs, toks, pos, seeds, bases, temps,
-                  topks, topps, methods):
-            # the GPT step's contract (model.DecodeModel._step): pos
-            # (S,), free slots ride along at pos 0, the sampler in the
-            # program.  ks/vs: the one stacked K and V
+        def _logits(params, ks, vs, toks, pos):
+            # the step's contract (model.DecodeModel._step): pos (S,),
+            # free slots ride along at pos 0.  ks/vs: the one stacked K
+            # and V
             from jax import lax
             S = pos.shape[0]
 
@@ -150,31 +139,9 @@ class LoopDecodeModel(DecodeModel):
                 x = params["embed"][toks]
             x, K, V = lax.fori_loop(0, steps, loop_step,
                                     (x, ks[0], vs[0]))
-            logits = _ou.lm_logits(params, x)
+            return _ou.lm_logits(params, x), [K], [V]
 
-            def _mixed(lg):
-                return _sample_tokens(lg, seeds, pos - bases, temps,
-                                      topks, topps, methods)
-
-            def _greedy(lg):
-                return jnp.argmax(lg, axis=-1).astype(jnp.int32)
-
-            with jax.named_scope("sample"):
-                next_tok = lax.cond(jnp.any(methods != 0), _mixed,
-                                    _greedy, logits)
-            return next_tok, [K], [V]
-
-        fam = self.family
-        self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
-        self._select_fn, self._put_fn = _first_token_programs(fam)
-        self._step_fn = _tracing.program(_step, "decode", fam,
-                                         donate_argnums=(1, 2))
-
-    @staticmethod
-    def from_ouro(block: Any) -> "LoopDecodeModel":
-        from ..gluon.model_zoo.ouro import _collect
-        return LoopDecodeModel(_collect(block), dict(block.config),
-                               block._max_length, type(block).__name__)
+        return _prefill, _logits
 
     # -- what the engine asks of a family -----------------------------------
     def make_cache(self, max_slots: int, buckets: Sequence[int],
@@ -198,12 +165,6 @@ class LoopDecodeModel(DecodeModel):
                  sampling: Optional[Sequence[Any]] = None) -> Any:
         _metrics.GEN_LOOP_STEPS_TOTAL.inc(self.loop_steps)
         return super().dispatch(cache, tokens, positions, sampling)
-
-    def verify(self, *args: Any, **kwargs: Any) -> _np.ndarray:
-        raise self.no_rollback("speculative verification")
-
-    def prefill_suffix(self, *args: Any, **kwargs: Any) -> Any:
-        raise self.no_rollback("suffix prefill over a shared prefix")
 
     def describe(self) -> Dict[str, Any]:
         out = super().describe()

@@ -24,6 +24,7 @@ of it to startup.
 """
 from __future__ import annotations
 
+import inspect
 import json
 import os
 import threading
@@ -340,6 +341,32 @@ def _first_token_programs(family: str) -> Tuple[Any, Any]:
             _tracing.program(_put_token, "select", family))
 
 
+def _select_tokens(logits, seeds, pos, bases, temps, topks, topps, methods,
+                   j=None):
+    """Each slot's token from its (S, V) ``logits`` inside a program:
+    greedy argmax, or the fused sampler under the key counter ``pos -
+    base`` (``pos + j - base`` for column ``j`` of a multi-token pass).
+    The sampler rides behind a runtime ``lax.cond``: an all-greedy
+    iteration (the default traffic) runs only the argmax branch, so
+    sampling support costs nothing until a slot samples, and it stays
+    ONE executable, so greedy tokens are bit-identical whichever branch
+    the batch selects (argmax is comparison-only)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def _mixed(lg):
+        at = pos if j is None else pos + j
+        return _sample_tokens(lg, seeds, at - bases, temps, topks, topps,
+                              methods)
+
+    def _greedy(lg):
+        return jnp.argmax(lg, axis=-1).astype(jnp.int32)
+
+    with jax.named_scope("sample"):
+        return lax.cond(jnp.any(methods != 0), _mixed, _greedy, logits)
+
+
 class Prefill:
     """A prompt pass dispatched and not waited for
     (:meth:`DecodeModel.dispatch_prefill`): its last-token ``logits``
@@ -356,390 +383,142 @@ class Prefill:
             logits, held, said, span
 
 
-def _slot_block_step(p, x, ck, cv, pos, nh: int, ga):
-    """One decode token for EVERY slot: ``x`` (S, 1, C), caches
-    (S, C, L) — heads and head dim on one axis, positions last: the
-    order the device stores them in (``kv_cache`` module docstring) —
-    ``pos`` (S,) int32: the per-slot-position variant of
-    ``model_zoo.generation._block_step`` (which shares one scalar
-    position across the batch; continuous batching cannot)."""
-    import math as _math
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    gelu_approx, eps = ga
-    S, _, C = x.shape
-    d = C // nh
-    L = ck.shape[2]
-    with jax.named_scope("attn/qkv"):
-        h = _pure_ln(x, p["ln1_g"], p["ln1_b"], eps)
-        qkv = h @ p["qkv_w"].T + p["qkv_b"]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        qh = q.reshape(S, 1, nh, d)
-        kcol = k.reshape(S, C, 1)
-        vcol = v.reshape(S, C, 1)
-    # slot i writes its k/v column at ITS position pos[i]: one in-place
-    # dynamic_update_slice a slot, NOT a scatter — a TPU scatter wants
-    # its update window on the minor axes, so XLA would relayout the
-    # whole buffer to [S][L][C] and back around it, every layer, every
-    # token (PERF.md PR 27).  A position past L-1 clamps onto row L-1
-    # where the scatter dropped it; only a verify pass at the grid's
-    # top gets there, for rows no emitted token reads (the submit-time
-    # budget check)
-    with jax.named_scope("cache/write"):
-        for i in range(S):
-            # positions are never negative: without the flag every traced
-            # index gets a wrap-around select, slots x 2 x layers times a
-            # program, a second of tracing on every start
-            at = (i, 0, lax.index_in_dim(pos, i, keepdims=False))
-            ck = lax.dynamic_update_slice(
-                ck, lax.slice_in_dim(kcol, i, i + 1), at,
-                allow_negative_indices=False)
-            cv = lax.dynamic_update_slice(
-                cv, lax.slice_in_dim(vcol, i, i + 1), at,
-                allow_negative_indices=False)
-    with jax.named_scope("attn/core"):
-        # the heads' view of the buffers is free: d (64) is whole sublane
-        # tiles, so splitting C moves nothing
-        kh = ck.reshape(S, nh, d, L)
-        vh = cv.reshape(S, nh, d, L)
-        scores = jnp.einsum("sqhd,shdk->shqk", qh, kh) / _math.sqrt(d)
-        # slot i sees cache positions 0..pos[i] (its prompt + its decoded
-        # tokens); pad garbage beyond pos[i] stays invisible until the loop
-        # overwrites it position by position
-        visible = jnp.arange(L)[None, :] <= pos[:, None]          # (S, L)
-        scores = jnp.where(visible[:, None, None, :], scores,
-                           jnp.float32(-jnp.inf).astype(scores.dtype))
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("shqk,shdk->sqhd", probs, vh).reshape(S, 1, C)
-    with jax.named_scope("attn/out"):
-        x = x + (out @ p["out_w"].T + p["out_b"])
-    with jax.named_scope("ffn/up"):
-        h = _pure_ln(x, p["ln2_g"], p["ln2_b"], eps)
-        ffn = jax.nn.gelu(h @ p["f1_w"].T + p["f1_b"],
-                          approximate=gelu_approx)
-    with jax.named_scope("ffn/down"):
-        return x + (ffn @ p["f2_w"].T + p["f2_b"]), ck, cv
-
-
-def _pure_ln(x, g, b, eps):
-    import jax.numpy as jnp
-    from jax import lax
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.var(x, axis=-1, keepdims=True)
-    return (x - mean) * lax.rsqrt(var + eps) * g + b
-
-
-def _block_suffix(p, x, pk, pv, q, nh: int, ga):
-    """Causal pass over a prompt SUFFIX against resident prefix KV:
-    ``x`` (1, Sb, C) embeds suffix tokens at absolute positions
-    ``q..q+Sb``, ``pk``/``pv`` (Pb, nh, d) hold the shared prefix's
-    rows (valid through traced ``q``; pad garbage past it is masked).
-    Returns (x_out, suffix ck/cv (Sb, nh, d)) — the prefix rows are
-    already in the cache, only the suffix rows are new."""
-    import math as _math
-    import jax
-    import jax.numpy as jnp
-
-    gelu_approx, eps = ga
-    _, T, C = x.shape
-    d = C // nh
-    Pb = pk.shape[0]
-    with jax.named_scope("attn/qkv"):
-        h = _pure_ln(x, p["ln1_g"], p["ln1_b"], eps)
-        qkv = h @ p["qkv_w"].T + p["qkv_b"]
-        qq, kk, vv = jnp.split(qkv, 3, axis=-1)
-        qh = qq.reshape(T, nh, d)
-        kh = kk.reshape(T, nh, d)
-        vh = vv.reshape(T, nh, d)
-    with jax.named_scope("attn/core"):
-        k_all = jnp.concatenate([pk, kh], axis=0)       # (Pb + T, nh, d)
-        v_all = jnp.concatenate([pv, vh], axis=0)
-        scores = jnp.einsum("qhd,khd->hqk", qh, k_all) / _math.sqrt(d)
-        cols = jnp.arange(Pb + T)
-        # suffix position i (absolute q+i) sees: real prefix rows (< q)
-        # and suffix rows up to itself (causal); prefix pad garbage in
-        # q..Pb stays invisible
-        vis = (cols[None, :] < q) | (
-            (cols[None, :] >= Pb)
-            & (cols[None, :] - Pb <= jnp.arange(T)[:, None]))
-        scores = jnp.where(vis[None, :, :], scores,
-                           jnp.float32(-jnp.inf).astype(scores.dtype))
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("hqk,khd->qhd", probs, v_all).reshape(1, T, C)
-    with jax.named_scope("attn/out"):
-        x = x + (out @ p["out_w"].T + p["out_b"])
-    with jax.named_scope("ffn/up"):
-        h = _pure_ln(x, p["ln2_g"], p["ln2_b"], eps)
-        ffn = jax.nn.gelu(h @ p["f1_w"].T + p["f1_b"],
-                          approximate=gelu_approx)
-    with jax.named_scope("ffn/down"):
-        return x + (ffn @ p["f2_w"].T + p["f2_b"]), kh, vh
-
-
 class DecodeModel:
-    """The decode-capable serving path: a stateful
-    ``(params, kv_cache, positions) -> next tokens`` step over slot
-    rows, compiled ONCE per KV capacity bucket, plus a per-prompt-bucket
-    prefill — the two programs the continuous-batching
-    :class:`~mxnet_tpu.serving.generation.GenerationEngine` runs
-    resident.
+    """The decode surface the continuous-batching
+    :class:`~mxnet_tpu.serving.generation.GenerationEngine` drives, the
+    same for every family: a stateful ``(params, cache, positions) ->
+    next tokens`` step over slot rows, compiled ONCE per KV capacity
+    bucket, plus a per-prompt-bucket prefill, the first-token selector
+    and the put of that token into a step's token array.
 
-    Built from a live :class:`~mxnet_tpu.gluon.model_zoo.gpt.GPTModel`
-    (the zoo's decoder-only family): parameters are extracted once into
-    a pure pytree (``model_zoo.generation._collect``) and the decode
-    math mirrors the zoo's KV-cache step, extended to per-slot
-    positions.  Compile accounting rides the SAME per-bucket counter as
-    the one-shot path (``mxnet_serving_bucket_compiles_total``, labels
-    ``decode:SxL`` / ``prefill:Lp``), so warmup moves every compile to
-    startup and the smoke gate can pin "0 after warmup".
-
-    A zoo family with another kind of layer is a subclass
-    (``serving.hybrid.HybridDecodeModel``) that ``from_block`` picks by
-    the block's type; the class attributes below are what the engine
-    reads of a family.
+    A family is a subclass in its own module (``serving.gpt``,
+    ``serving.hybrid``, ``serving.moe``, ``serving.loop``,
+    ``serving.delta``) that names the zoo class it serves (``zoo``) and
+    supplies its model math (:meth:`_model_fns`: a prefill and a step
+    that ends at the logits), its cache (``make_cache``) and
+    :meth:`row_blocks`; this class selects the tokens inside the step,
+    registers the programs, accounts and times them on the spans and
+    refuses what a family without rollback cannot do.  Parameters are a
+    pure pytree extracted once from the live block (its zoo module's
+    ``_collect``).  Compile accounting rides the SAME per-bucket counter
+    as the one-shot path (``mxnet_serving_bucket_compiles_total``,
+    labels ``decode:SxL`` / ``prefill:Lp``), so warmup moves every
+    compile to startup and the smoke gate can pin "0 after warmup".
     """
 
-    family = "gpt"
+    # the family's name on spans, in the program table and in refusals
+    family = ""
+    # the zoo class the family serves, ``module.Class``; ``from_block``
+    # reads the table of families by it
+    zoo: Optional[str] = None
+    _families: Dict[str, type] = {}
+    # the buffer lists of the cache's ``buffers()`` the step takes and
+    # hands back (donated): K and V rows, and the fixed-size kinds where
+    # the cache holds any
+    cache_buffers = 2
     # longest prompt one prefill program takes (None: the top KV bucket)
     # and the smallest bucket prompts are padded to
     max_prompt: Optional[int] = None
     min_prompt_bucket = 8
     # whether a slot can be rewound (speculation) and its rows shared
     # (prefix cache), and where not, why: the end of every refusal
-    supports_rollback = True
+    supports_rollback = False
     no_rollback_why = ""
     # what a family says of its programs on the ``model.prefill`` and
     # ``model.step.dispatch`` spans beside what every family says
     span_attrs: Dict[str, Any] = {}
 
-    def __init__(self, params: Any, num_heads: int, ga: Tuple[Any, Any],
-                 max_length: int, name: str) -> None:
-        import jax
+    def __init_subclass__(cls, **kwargs: Any) -> None:
+        super().__init_subclass__(**kwargs)
+        if cls.zoo is not None:
+            DecodeModel._families[cls.zoo] = cls
 
+    def __init__(self, params: Any, cfg: Dict[str, Any], max_length: int,
+                 name: str) -> None:
         self.params = params
-        self.num_heads = int(num_heads)
-        self.ga = (bool(ga[0]), float(ga[1]))
+        self.cfg = cfg
+        self.kinds = list(cfg.get("kinds", ()))
         self.max_length = int(max_length)
         self.name = name
         self.vocab_size, self.units = params["embed"].shape
-        self.head_dim = self.units // self.num_heads
-        self.n_layers = len(params["blocks"])
-        self.dtype = params["blocks"][0]["qkv_w"].dtype
-        self.logits_dtype = self.dtype
+        self.num_heads = int(cfg["num_heads"])
+        self.head_dim = int(cfg["head_dim"])
+        self.n_layers = len(self.kinds) or int(cfg["num_layers"])
+        self.dtype = params["embed"].dtype
+        self.logits_dtype = _np.dtype(_np.float32)
         self._seen_lock = threading.Lock()
         self._seen: set = set()
-        nh, ga_s = self.num_heads, self.ga
+        prefill, logits_step = self._model_fns()
+        n, output = self.cache_buffers, self._step_output
 
-        def _prefill(params, toks, t0):
-            # toks (Lp,) int32 (pad tokens after t0), t0 traced scalar;
-            # returns (last-real-token logits (V,), ks/vs lists of
-            # (Lp, nh, d)) — garbage pad KV past t0 is masked by the
-            # decode position mask until overwritten
-            from jax import lax
-            from ..gluon.model_zoo.generation import _block_prefill
-            Lp = toks.shape[0]
-            with jax.named_scope("embed"):
-                x = params["embed"][toks][None] + params["pos"][None, :Lp]
-            ks, vs = [], []
-            for p in params["blocks"]:
-                x, ck, cv = _block_prefill(p, x, nh, Lp, ga_s)
-                ks.append(ck[0])
-                vs.append(cv[0])
-            with jax.named_scope("head"):
-                x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
-                h = lax.dynamic_slice_in_dim(x[0], t0 - 1, 1, axis=0)[0]
-                return h @ params["embed"].T, ks, vs
+        def _step(params, *args):
+            # args: the cache's buffer lists, then toks (S,) the last
+            # token of each slot, pos (S,) its write position (free
+            # slots ride along at 0, their outputs ignored on the host)
+            # and the (S,) sampling vectors.  The sampling vectors are
+            # traced operands that change only at admission, so
+            # per-request parameters never recompile the step and the
+            # engine reuses their device mirrors; the key counter is
+            # derived in-program (pos - base: base is the slot's prompt
+            # length minus its stream offset, minus one), so no
+            # per-token host vector rides the loop.  The host reads
+            # back the (S,) tokens, never (S, V) logits
+            toks, pos, seeds, bases, temps, topks, topps, methods = args[n:]
+            logits, *out = logits_step(params, *args[:n], toks, pos)
+            tok = _select_tokens(logits, seeds, pos, bases, temps, topks,
+                                 topps, methods)
+            return (output(tok, *out[n:]), *out[:n])
 
-        def _step(params, ks, vs, toks, pos, seeds, bases, temps,
-                  topks, topps, methods):
-            # toks (S,) int32 last emitted per slot, pos (S,) int32
-            # write positions; free slots ride along with pos=0 and
-            # their outputs are ignored on the host.  The sampling
-            # vectors (seed/base/temperature/top-k/top-p/method, all
-            # (S,)) are traced operands: per-request parameter changes
-            # never recompile the step — and they change only at
-            # admission, so the engine reuses their device mirrors
-            # across iterations.  The key COUNTER is derived
-            # in-program (ctr = pos - base: base is the slot's
-            # original prompt length minus its stream offset, minus
-            # one) so no per-token host vector rides the hot loop
-            with jax.named_scope("embed"):
-                x = (params["embed"][toks][:, None, :]
-                     + params["pos"][pos][:, None, :])
-            new_ks, new_vs = [], []
-            for p, ck, cv in zip(params["blocks"], ks, vs):
-                x, ck, cv = _slot_block_step(p, x, ck, cv, pos, nh, ga_s)
-                new_ks.append(ck)
-                new_vs.append(cv)
-            with jax.named_scope("head"):
-                x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
-                logits = x[:, 0, :] @ params["embed"].T
-
-            # token selection ON DEVICE (greedy argmax or the fused
-            # temperature/top-k/top-p sampler under per-slot counter
-            # keys): the host reads back (S,) int32 per iteration,
-            # never (S, V) logits.  The sampler rides behind a
-            # runtime lax.cond: an all-greedy iteration (the default
-            # traffic) executes only the argmax branch, so sampling
-            # support costs nothing until a slot actually samples —
-            # and it stays ONE executable, so greedy tokens are
-            # bit-identical whichever branch the batch composition
-            # selects (argmax is comparison-only, no FP reassociation)
-            def _mixed(lg):
-                return _sample_tokens(lg, seeds, pos - bases, temps,
-                                      topks, topps, methods)
-
-            def _greedy(lg):
-                import jax.numpy as jnp
-                return jnp.argmax(lg, axis=-1).astype(jnp.int32)
-
-            from jax import lax
-            import jax.numpy as jnp
-            with jax.named_scope("sample"):
-                next_tok = lax.cond(jnp.any(methods != 0), _mixed,
-                                    _greedy, logits)
-            return next_tok, new_ks, new_vs
-
-        def _verify(params, ks, vs, toks, pos, seeds, bases, temps,
-                    topks, topps, methods):
-            # speculative verification: toks (S, K1) int32 — column 0
-            # is each slot's last emitted token, columns 1.. the draft
-            # proposals; pos (S,) the write position of column 0.  The
-            # program is K1 UNROLLED repetitions of the single-token
-            # step (same ``_slot_block_step`` math, same shapes per
-            # sub-step, same lax.cond'd sampler), each scattering its
-            # K/V at pos+j and sampling under counter pos+j-base — so
-            # the token this pass computes at any position is
-            # BIT-IDENTICAL to what the sequential one-token step
-            # would have computed there (the byte-identical-streams
-            # contract CI pins).  Inputs past the accepted prefix feed
-            # garbage forward; the host discards those columns and
-            # rolls their KV rows back (PagedKVCache.truncate)
-            from jax import lax
-            import jax.numpy as jnp
-            K1 = toks.shape[1]
-            outs = []
-            for j in range(K1):
-                with jax.named_scope("embed"):
-                    x = (params["embed"][toks[:, j]][:, None, :]
-                         + params["pos"][pos + j][:, None, :])
-                new_ks, new_vs = [], []
-                for p, ck, cv in zip(params["blocks"], ks, vs):
-                    x, ck, cv = _slot_block_step(p, x, ck, cv, pos + j,
-                                                 nh, ga_s)
-                    new_ks.append(ck)
-                    new_vs.append(cv)
-                ks, vs = new_ks, new_vs
-                with jax.named_scope("head"):
-                    x = _pure_ln(x, params["lnf_g"], params["lnf_b"],
-                                 ga_s[1])
-                    logits = x[:, 0, :] @ params["embed"].T
-
-                def _mixed(lg, _j=j):
-                    return _sample_tokens(lg, seeds, (pos + _j) - bases,
-                                          temps, topks, topps, methods)
-
-                def _greedy(lg):
-                    return jnp.argmax(lg, axis=-1).astype(jnp.int32)
-
-                with jax.named_scope("sample"):
-                    outs.append(lax.cond(jnp.any(methods != 0), _mixed,
-                                         _greedy, logits))
-            return jnp.stack(outs, axis=1), ks, vs
-
-        def _prefill_sfx(params, pre_ks, pre_vs, toks, q, t0):
-            # suffix pass for shared-prefix admissions: pre_ks/pre_vs
-            # are the resident prefix rows (Pb, nh, d) per layer, toks
-            # (Sb,) the pad-bucketed suffix, q the traced real prefix
-            # length, t0 the traced real suffix length.  Returns the
-            # last-real-suffix-token logits + the SUFFIX KV rows only
-            from jax import lax
-            Sb = toks.shape[0]
-            with jax.named_scope("embed"):
-                x = (params["embed"][toks][None]
-                     + lax.dynamic_slice_in_dim(params["pos"], q, Sb,
-                                                axis=0)[None])
-            ks_o, vs_o = [], []
-            for p, pk, pv in zip(params["blocks"], pre_ks, pre_vs):
-                x, ck, cv = _block_suffix(p, x, pk, pv, q, nh, ga_s)
-                ks_o.append(ck)
-                vs_o.append(cv)
-            with jax.named_scope("head"):
-                x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
-                h = lax.dynamic_slice_in_dim(x[0], t0 - 1, 1, axis=0)[0]
-                return h @ params["embed"].T, ks_o, vs_o
-
+        # jax names the program's parameters after these
+        _step.__signature__ = inspect.Signature([
+            inspect.Parameter(a, inspect.Parameter.POSITIONAL_OR_KEYWORD)
+            for a in ("params", *("ks", "vs", "state")[:n], "toks", "pos",
+                      "seeds", "bases", "temps", "topks", "topps",
+                      "methods")])
         fam = self.family
-        self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
-        self._prefill_sfx_fn = _tracing.program(_prefill_sfx,
-                                                "prefill_suffix", fam)
+        self._prefill_fn = _tracing.program(prefill, "prefill", fam)
         self._select_fn, self._put_fn = _first_token_programs(fam)
-        # the KV buffers are DONATED: XLA updates the resident cache in
-        # place instead of allocating a fresh (S, h * d, L) per layer
-        # every token
-        self._step_fn = _tracing.program(_step, "decode", fam,
-                                         donate_argnums=(1, 2))
-        # same donation contract as _step: verify scatters k+1 rows
-        # into the resident buffers in place; rejected rows are
-        # invisible (visibility mask <= pos) until overwritten
-        self._verify_fn = _tracing.program(_verify, "verify", fam,
-                                           donate_argnums=(1, 2))
+        # every buffer list is DONATED: XLA updates the resident cache
+        # in place instead of allocating it afresh every token
+        self._step_fn = _tracing.program(
+            _step, "decode", fam, donate_argnums=tuple(range(1, n + 1)))
+
+    def _model_fns(self) -> Tuple[Any, Any]:
+        """The family's model math over ``self.params``' pytree:
+        ``prefill(params, toks, t0) -> (logits (V,), *held)`` over a
+        prompt padded past its traced real length ``t0`` (``held`` is
+        what ``PagedKVCache.write_prompt`` installs), and the step
+        ``(params, *buffers, toks, pos) -> (logits (S, V),
+        *new_buffers, *extra)``, ``extra`` going to
+        :meth:`_step_output`."""
+        raise NotImplementedError(f"{type(self).__name__} names no model")
 
     # -- constructors -------------------------------------------------------
-    @staticmethod
-    def from_block(block: Any) -> "DecodeModel":
+    @classmethod
+    def from_block(cls, block: Any) -> "DecodeModel":
         """Build from a live zoo LM (weights as currently
-        initialized/loaded).  Five families are served: a ``GPTModel``
-        with dense FFNs (one built with ``moe_experts`` keeps
-        ``MoEDense``'s capacity mask, which drops tokens, and is
-        refused), a ``Phi4FlashModel`` (``serving.hybrid``), a
-        ``Cohere2MoEModel`` (``serving.moe``: dropless top-k experts, of
-        which the model holds a share), an ``OuroModel``
-        (``serving.loop``: one stack of layers run several times a
-        token) and an ``OlmoHybridModel`` (``serving.delta``: delta-rule
-        layers beside full attention), each of the last four through
-        its own subclass."""
-        from ..gluon.model_zoo.cohere2moe import Cohere2MoEModel
-        from ..gluon.model_zoo.generation import _collect
-        from ..gluon.model_zoo.olmo_hybrid import OlmoHybridModel
-        from ..gluon.model_zoo.ouro import OuroModel
-        from ..gluon.model_zoo.phi4flash import Phi4FlashModel
-        if isinstance(block, OuroModel):
-            from .loop import LoopDecodeModel
-            return LoopDecodeModel.from_ouro(block)
-        if isinstance(block, Phi4FlashModel):
-            from .hybrid import HybridDecodeModel
-            return HybridDecodeModel.from_phi4flash(block)
-        if isinstance(block, Cohere2MoEModel):
-            from .moe import MoEDecodeModel
-            return MoEDecodeModel.from_cohere2moe(block)
-        if isinstance(block, OlmoHybridModel):
-            from .delta import DeltaDecodeModel
-            return DeltaDecodeModel.from_olmo_hybrid(block)
-        if not hasattr(block, "blocks") or not hasattr(block,
-                                                       "word_embed"):
+        initialized/loaded).  On this class the family is the one whose
+        ``zoo`` class the block is an instance of; on a family it is
+        that family."""
+        if cls is DecodeModel:
+            served = DecodeModel._families
+            for klass in type(block).__mro__:
+                family = served.get(f"{klass.__module__}.{klass.__name__}")
+                if family is not None:
+                    return family.from_block(block)
             raise MXNetError(
-                f"DecodeModel serves decoder-only zoo LMs (GPTModel, "
-                f"Phi4FlashModel, Cohere2MoEModel, OuroModel, "
-                f"OlmoHybridModel); got "
-                f"{type(block).__name__}")
-        params = _collect(block)
-        ga = (params.pop("gelu_approx"), params.pop("ln_eps"))
-        nh = next(iter(block.blocks._children.values()))._num_heads
-        return DecodeModel(params, nh, ga, block._max_length,
-                           type(block).__name__)
+                "DecodeModel serves decoder-only zoo LMs ("
+                + ", ".join(sorted(z.rpartition(".")[2] for z in served))
+                + f"); got {type(block).__name__}")
+        params, cfg = cls._collect(block)
+        return cls(params, cfg, block._max_length, type(block).__name__)
 
-    def make_cache(self, max_slots: int, buckets: Sequence[int],
-                   prefix_slots: Optional[int] = None,
-                   prefix: Any = None) -> Any:
-        """The slot cache this family decodes over."""
-        from .kv_cache import PagedKVCache
-        return PagedKVCache(
-            self.n_layers, self.num_heads, self.head_dim, max_slots,
-            buckets=buckets, dtype=self.dtype, prefix=prefix,
-            prefix_slots=prefix_slots)
+    @classmethod
+    def _collect(cls, block: Any) -> Tuple[Any, Dict[str, Any]]:
+        """The block's weights as one pytree, and its config."""
+        import importlib
+        zoo = importlib.import_module(cls.zoo.rpartition(".")[0])
+        return zoo._collect(block), dict(block.config)
 
     def no_rollback(self, what: str) -> MXNetError:
         """The refusal of ``what`` for a family that cannot rewind or
@@ -810,8 +589,8 @@ class DecodeModel:
             dt, exemplar=_tracing.current_trace_id())
         return logits, held, said, span
 
-    # what a family's programs hand back or take beside the GPT family's,
-    # where it has any (serving.moe: the held experts' load)
+    # what a family's programs hand back or take beside the tokens and
+    # the buffers, where it has any (serving.moe: the held experts' load)
     def _prefill_extras(self, held: List[Any]) -> Tuple[List[Any], Any]:
         """``held``, what the prefill program returned after the logits,
         split into what is handed on and what is only said."""
@@ -823,6 +602,11 @@ class DecodeModel:
 
     def _step_tokens(self, tokens: _np.ndarray) -> _np.ndarray:
         """The host's (S,) last tokens as the step program takes them."""
+        return tokens
+
+    def _step_output(self, tokens: Any, *extra: Any) -> Any:
+        """What the step program hands back before the buffers: the (S,)
+        tokens and, behind them, the ``extra`` of the family's step."""
         return tokens
 
     def _read_step(self, span: Any, out: _np.ndarray) -> _np.ndarray:
@@ -953,81 +737,15 @@ class DecodeModel:
                 exemplar=_tracing.current_trace_id())
             return out
 
-    def verify(self, cache: Any, tokens: _np.ndarray,
-               positions: _np.ndarray,
-               sampling: Optional[Sequence[Any]] = None
-               ) -> _np.ndarray:
-        """One speculative verification pass over every slot:
-        ``tokens`` is (S, k+1) int32 — column 0 each slot's last
-        emitted token, columns 1.. the k draft proposals — and the
-        return is the (S, k+1) int32 target tokens for those
-        positions, each bit-identical to what ``step`` would have
-        produced sequentially (same kernel math, same counter-PRNG
-        lanes).  The cache's buffers gain k+1 rows per slot starting
-        at ``positions``; the caller owns acceptance and rolls back
-        rejected rows via ``cache.truncate``.  One compiled program
-        per (S, bucket, k+1) triple."""
-        import jax
-        import jax.numpy as jnp
-        S = cache.max_slots
-        toks = _np.asarray(tokens, _np.int32)
-        if toks.ndim != 2 or toks.shape[0] != S or toks.shape[1] < 2:
-            raise MXNetError(
-                f"verify wants an (S, k+1) token matrix with k >= 1; "
-                f"got shape {toks.shape} for {S} slots")
-        if sampling is None:
-            sampling = self.greedy_sampling(S)
-        if not isinstance(sampling[0], jax.Array):
-            sampling = self.device_sampling(sampling)
-        seeds, bases, temps, topks, topps, methods = sampling
-        self._account(f"verify:{S}x{cache.bucket}x{toks.shape[1]}")
-        with _tracing.child_span("model.verify", slots=S,
-                                 bucket=cache.bucket):
-            t = time.perf_counter()
-            out_toks, new_ks, new_vs = self._verify_fn(
-                self.params, cache._k, cache._v,
-                jnp.asarray(toks),
-                jnp.asarray(_np.asarray(positions, _np.int32)),
-                seeds, bases, temps, topks, topps, methods)
-            cache.replace(new_ks, new_vs)
-            out = _np.asarray(out_toks)
-            dt = time.perf_counter() - t
-        _metrics.GEN_STEP_SECONDS.labels(phase="verify").observe(
-            dt, exemplar=_tracing.current_trace_id())
-        return out
+    # a family that can rewind a slot and share its rows has programs
+    # for these two; for any other the refusal says why not
+    def verify(self, *args: Any, **kwargs: Any) -> _np.ndarray:
+        """One speculative verification pass over every slot."""
+        raise self.no_rollback("speculative verification")
 
-    def prefill_suffix(self, tokens: _np.ndarray, prefix_ks: List[Any],
-                       prefix_vs: List[Any], q: int, bucket_len: int
-                       ) -> Tuple[_np.ndarray, List[Any], List[Any]]:
-        """Run the prompt pass over only the SUFFIX ``tokens`` (real
-        positions ``q..q+len``) against resident prefix K/V rows —
-        the shared-prefix admission path.  Returns (last-real-token
-        logits (V,) numpy, per-layer suffix ks/vs (bucket_len, nh,
-        d)).  One compiled program per (prefix bucket, suffix bucket)
-        pair; ``q`` and the real suffix length are traced operands."""
-        import jax.numpy as jnp
-        toks = _np.asarray(tokens, _np.int32).reshape(-1)
-        t0 = toks.shape[0]
-        if t0 < 1:
-            raise MXNetError("empty prompt suffix")
-        if bucket_len < t0:
-            raise MXNetError(
-                f"suffix length {t0} exceeds its bucket {bucket_len}")
-        padded = _np.zeros((bucket_len,), _np.int32)
-        padded[:t0] = toks
-        Pb = int(prefix_ks[0].shape[0])
-        self._account(f"prefill_sfx:{Pb}x{bucket_len}")
-        with _tracing.child_span("model.prefill", bucket=bucket_len,
-                                 family=self.family):
-            t = time.perf_counter()
-            logits, ks, vs = self._prefill_sfx_fn(
-                self.params, list(prefix_ks), list(prefix_vs),
-                jnp.asarray(padded), _np.int32(q), _np.int32(t0))
-            out = _np.asarray(logits)
-            dt = time.perf_counter() - t
-        _metrics.GEN_STEP_SECONDS.labels(phase="prefill").observe(
-            dt, exemplar=_tracing.current_trace_id())
-        return out, ks, vs
+    def prefill_suffix(self, *args: Any, **kwargs: Any) -> Any:
+        """A prompt pass over a suffix against resident prefix rows."""
+        raise self.no_rollback("suffix prefill over a shared prefix")
 
     def select(self, logits: _np.ndarray, seed: int, counter: int,
                temperature: float, top_k: int, top_p: float,
@@ -1069,12 +787,11 @@ class DecodeModel:
     def warmup(self, cache: Any, prompt_buckets: Sequence[int],
                suffix_pairs: bool = True) -> int:
         """Pre-compile the full program grid: one prefill per prompt
-        bucket, one suffix prefill per (prefix bucket, suffix bucket)
-        pair (the shared-prefix admission path; skipped when the
-        prefix cache is disabled), the first-token selector, and one
-        decode step per KV capacity bucket (run on the cache's own
-        buffer shapes).  After this, traffic confined to the grids
-        never compiles."""
+        bucket, the first-token selector and its put, and one decode
+        step per KV capacity bucket (run on the cache's own buffer
+        shapes); a family with a suffix prefill adds its pairs where
+        ``suffix_pairs`` (the prefix cache is enabled).  After this,
+        traffic confined to the grids never compiles."""
         import jax
         n = 0
         for pb in prompt_buckets:
@@ -1097,28 +814,6 @@ class DecodeModel:
                 _np.zeros((cache.max_slots,), _np.int32)), cache.device)))
         self.read_prefill(pre)
         n += 1
-        if suffix_pairs:
-            dev = cache.device
-            top = max(int(pb) for pb in prompt_buckets)
-            rows = {int(pb): [jax.device_put(
-                _np.zeros((int(pb), self.num_heads, self.head_dim),
-                          self.dtype), dev)
-                for _ in range(self.n_layers)]
-                for pb in prompt_buckets}
-            for Pb in prompt_buckets:
-                for Sb in prompt_buckets:
-                    if int(Pb) + int(Sb) > top:
-                        # unreachable at runtime: entries store
-                        # bucket-aligned prefixes (Pb == q) and the
-                        # admission capacity rule bounds q + Sb by the
-                        # top prompt bucket — compiling these pairs
-                        # would only inflate warmup and the persistent
-                        # cache
-                        continue
-                    self.prefill_suffix(
-                        _np.zeros((1,), _np.int32), rows[int(Pb)],
-                        rows[int(Pb)], q=1, bucket_len=int(Sb))
-                    n += 1
         S = cache.max_slots
         toks = _np.zeros((S,), _np.int32)
         pos = _np.zeros((S,), _np.int32)

@@ -42,15 +42,13 @@ rings at its end: ``supports_rollback`` is False, so
 from __future__ import annotations
 
 import math
-import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as _np
 
 from .. import metrics as _metrics
-from .. import tracing as _tracing
 from .kv_cache import PagedKVCache
-from .model import DecodeModel, _first_token_programs, _sample_tokens
+from .model import DecodeModel
 
 __all__ = ["MoEDecodeModel"]
 
@@ -68,6 +66,8 @@ class MoEDecodeModel(DecodeModel):
     ``warmup``)."""
 
     family = "cohere2moe"
+    zoo = "mxnet_tpu.gluon.model_zoo.cohere2moe.Cohere2MoEModel"
+    cache_buffers = 3
     max_prompt = MAX_PROMPT
     min_prompt_bucket = MIN_PROMPT_BUCKET
     supports_rollback = False
@@ -78,31 +78,21 @@ class MoEDecodeModel(DecodeModel):
 
     def __init__(self, params: Any, cfg: Dict[str, Any], max_length: int,
                  name: str) -> None:
-        # not DecodeModel.__init__: that builds the GPT programs
-        import jax
-        import jax.numpy as jnp
-        from ..gluon.model_zoo import cohere2moe as _c2
-        from ..ops.pallas import column_write as _cw
-        from ..ops.pallas import decode_attention as _da
-        self.params = params
-        self.cfg = cfg
-        self.kinds = list(cfg["kinds"])
-        self.max_length = int(max_length)
-        self.name = name
-        self.vocab_size, self.units = params["embed"].shape
-        self.num_heads = int(cfg["num_heads"])
-        self.head_dim = int(cfg["head_dim"])
-        self.n_layers = len(self.kinds)
-        self.dtype = params["embed"].dtype
-        self.logits_dtype = jnp.dtype(jnp.float32)
-        self._seen_lock = threading.Lock()
-        self._seen: set = set()
+        super().__init__(params, cfg, max_length, name)
         lo, hi = cfg["experts_held"]
         self.held = hi - lo
         # the (layers, held) load of the step, and of the prefill, read
         # last: for a caller that drives the programs itself (a check)
         self.last_load: Optional[_np.ndarray] = None
         self.last_prefill_load: Optional[_np.ndarray] = None
+
+    def _model_fns(self) -> Tuple[Any, Any]:
+        import jax
+        import jax.numpy as jnp
+        from ..gluon.model_zoo import cohere2moe as _c2
+        from ..ops.pallas import column_write as _cw
+        from ..ops.pallas import decode_attention as _da
+        cfg = self.cfg
         W = int(cfg["window"])
         kinds = self.kinds
         nkv, d = int(cfg["num_kv_heads"]), self.head_dim
@@ -138,15 +128,13 @@ class MoEDecodeModel(DecodeModel):
                     vs.append(v)
             return _c2.lm_logits(params, h, cfg), ks, vs, state, load
 
-        def _step(params, ks, vs, state, toks, pos, seeds, bases, temps,
-                  topks, topps, methods):
-            # the GPT step's contract (model.DecodeModel._step): pos
-            # (S,), free slots ride along at pos 0, the sampler in the
-            # program.  toks is the (S + layers x held,) array this
-            # program returned last (or the host's tokens padded to it):
-            # the S tokens lead.  ks/vs: the full layers' rows; state:
-            # the window layers' rings
-            from jax import lax
+        def _logits(params, ks, vs, state, toks, pos):
+            # the step's contract (model.DecodeModel._step): pos (S,),
+            # free slots ride along at pos 0.  toks is the (S + layers x
+            # held,) array this program returned last (or the host's
+            # tokens padded to it): the S tokens lead.  ks/vs: the full
+            # layers' rows; state: the window layers' rings.  The
+            # layers' loads ride behind (:meth:`_step_output`)
             eps = cfg["layer_norm_eps"]
             S = pos.shape[0]
             with jax.named_scope("embed"):
@@ -187,32 +175,9 @@ class MoEDecodeModel(DecodeModel):
             with jax.named_scope("head"):
                 x = _c2._ln(x, params["lnf_g"], eps)
                 logits = _c2.lm_logits(params, x, cfg)
+            return logits, ks, vs, new, loads
 
-            def _mixed(lg):
-                return _sample_tokens(lg, seeds, pos - bases, temps,
-                                      topks, topps, methods)
-
-            def _greedy(lg):
-                return jnp.argmax(lg, axis=-1).astype(jnp.int32)
-
-            with jax.named_scope("sample"):
-                next_tok = lax.cond(jnp.any(methods != 0), _mixed,
-                                    _greedy, logits)
-            out = jnp.concatenate([next_tok,
-                                   jnp.stack(loads).reshape(-1)])
-            return out, ks, vs, new
-
-        fam = self.family
-        self._prefill_fn = _tracing.program(_prefill, "prefill", fam)
-        self._select_fn, self._put_fn = _first_token_programs(fam)
-        self._step_fn = _tracing.program(_step, "decode", fam,
-                                         donate_argnums=(1, 2, 3))
-
-    @staticmethod
-    def from_cohere2moe(block: Any) -> "MoEDecodeModel":
-        from ..gluon.model_zoo.cohere2moe import _collect
-        return MoEDecodeModel(_collect(block), dict(block.config),
-                              block._max_length, type(block).__name__)
+        return _prefill, _logits
 
     # -- what the engine asks of a family -----------------------------------
     def make_cache(self, max_slots: int, buckets: Sequence[int],
@@ -251,6 +216,10 @@ class MoEDecodeModel(DecodeModel):
         span.set_attr(expert_assignments=int(load.sum()),
                       experts_hit=int((load > 0).sum()))
 
+    def _step_output(self, tokens: Any, loads: List[Any]) -> Any:
+        import jax.numpy as jnp
+        return jnp.concatenate([tokens, jnp.stack(loads).reshape(-1)])
+
     def _step_tokens(self, tokens: _np.ndarray) -> _np.ndarray:
         out = _np.zeros((len(tokens) + self.n_layers * self.held,),
                         _np.int32)
@@ -270,12 +239,6 @@ class MoEDecodeModel(DecodeModel):
         _metrics.GEN_EXPERTS_HIT_TOTAL.inc(hit)
         _metrics.GEN_EXPERT_SLOTS_TOTAL.inc(int(load.size))
         return out[:S]
-
-    def verify(self, *args: Any, **kwargs: Any) -> _np.ndarray:
-        raise self.no_rollback("speculative verification")
-
-    def prefill_suffix(self, *args: Any, **kwargs: Any) -> Any:
-        raise self.no_rollback("suffix prefill over a shared prefix")
 
     def describe(self) -> Dict[str, Any]:
         out = super().describe()

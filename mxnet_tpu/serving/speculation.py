@@ -44,7 +44,7 @@ Two draft flavors:
 from __future__ import annotations
 
 import time
-from typing import Any, List, Optional, Sequence
+from typing import Any, Optional, Sequence
 
 import numpy as _np
 
@@ -52,8 +52,8 @@ from ..base import MXNetError
 from .. import metrics as _metrics
 from .. import tracing as _tracing
 from .kv_cache import PagedKVCache, round_up_bucket
-from .model import DecodeModel, _pure_ln, _sample_tokens, \
-    _slot_block_step
+from .gpt import _logits_step
+from .model import DecodeModel, _select_tokens
 
 __all__ = ["DraftModel", "SelfSpeculativeDraft", "IndependentDraft",
            "make_draft"]
@@ -67,36 +67,13 @@ def _chain_steps(params, ks, vs, toks, pos, seeds, bases, temps,
     ``pos + j - base``: exactly the key the target's verify pass uses
     for that position, so a draft whose logits match the target's
     proposes the target's own token (the accept rule's fixed point)."""
-    import jax
-    from jax import lax
     import jax.numpy as jnp
     cur = toks
     outs = []
     for j in range(n_sub):
-        with jax.named_scope("embed"):
-            x = (params["embed"][cur][:, None, :]
-                 + params["pos"][pos + j][:, None, :])
-        new_ks, new_vs = [], []
-        for p, ck, cv in zip(params["blocks"], ks, vs):
-            x, ck, cv = _slot_block_step(p, x, ck, cv, pos + j,
-                                         nh, ga_s)
-            new_ks.append(ck)
-            new_vs.append(cv)
-        ks, vs = new_ks, new_vs
-        with jax.named_scope("head"):
-            x = _pure_ln(x, params["lnf_g"], params["lnf_b"], ga_s[1])
-            logits = x[:, 0, :] @ params["embed"].T
-
-        def _mixed(lg, _j=j):
-            return _sample_tokens(lg, seeds, (pos + _j) - bases,
-                                  temps, topks, topps, methods)
-
-        def _greedy(lg):
-            return jnp.argmax(lg, axis=-1).astype(jnp.int32)
-
-        with jax.named_scope("sample"):
-            cur = lax.cond(jnp.any(methods != 0), _mixed, _greedy,
-                           logits)
+        logits, ks, vs = _logits_step(params, ks, vs, cur, pos, nh, ga_s, j)
+        cur = _select_tokens(logits, seeds, pos, bases, temps, topks,
+                             topps, methods, j)
         outs.append(cur)
     return jnp.stack(outs, axis=1), ks, vs
 

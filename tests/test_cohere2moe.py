@@ -25,6 +25,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 import reference_cohere2moe as ref                           # noqa: E402
+from decode_families import tiny_cohere2moe, tiny_gpt        # noqa: E402
 from serial_decode import (StepCounters, check_log,          # noqa: E402
                            run_staggered, serial_transcript)
 
@@ -34,11 +35,7 @@ TOL = 2e-5      # float32 system against the float32 reference
 
 @pytest.fixture(scope="module")
 def net():
-    mx.random.seed(7)
-    net = c2.get_cohere2moe("tiny")
-    net.collect_params().setattr("grad_req", "null")
-    net.initialize()
-    return net
+    return tiny_cohere2moe()
 
 
 @pytest.fixture(scope="module")
@@ -656,43 +653,14 @@ def test_cache_and_blocks_by_kind(model):
     assert ring[0] == 1 + 2 + 8 + 8 and rows[0] == 1 + 2 + 8 + 10
 
 
-@pytest.mark.parametrize("kwargs,match", [
-    (dict(spec_mode="self"), "spec_mode"),
-    (dict(prefix_slots=2), "prefix_slots"),
-    (dict(prefix_cache=serving.PrefixCache(2)), "prefix_slots"),
-])
-def test_speculation_and_prefix_cache_are_refused_by_name(model, kwargs,
-                                                          match):
-    with pytest.raises(MXNetError, match=match) as e:
-        new_engine(model, **dict(dict(prefix_slots=None), **kwargs))
-    assert "cohere2moe" in str(e.value)
-
-
-def test_rollback_entry_points_raise(model):
-    for call in (model.verify, model.prefill_suffix):
-        with pytest.raises(MXNetError, match="cohere2moe"):
-            call()
-
-
-def test_a_prompt_past_the_prefill_limit_is_refused_at_submit(model):
-    engine = new_engine(model, kv_buckets=(64, 128, 2048))
-    assert engine.prompt_buckets == (64, 128, 256, 512, 1024)
-    with pytest.raises(MXNetError, match="prefills in one program"):
-        engine.submit(prompt(1025), max_new_tokens=4)
-
-
 def test_the_other_families_are_untouched():
-    """A GPT block is still the base class with its own programs and
-    takes and returns plain (S,) token vectors; a GPT block with
-    ``MoEDense`` inside is refused with the families that are served."""
+    """A GPT block is its own family with its own programs and takes
+    and returns plain (S,) token vectors; a GPT block with ``MoEDense``
+    inside is refused with the families that are served."""
     from mxnet_tpu.gluon.model_zoo.gpt import GPTModel
-    gpt = GPTModel(vocab_size=VOCAB, num_layers=2, units=64,
-                   hidden_size=128, num_heads=4, max_length=128,
-                   dropout=0.0)
-    gpt.initialize()
-    gpt(mx.np.zeros((1, 4), dtype="int32"))
-    plain = serving.DecodeModel.from_block(gpt)
-    assert type(plain) is serving.DecodeModel and plain.family == "gpt"
+    plain = serving.DecodeModel.from_block(tiny_gpt(VOCAB))
+    assert type(plain) is serving.gpt.GPTDecodeModel \
+        and plain.family == "gpt"
     toks = np.arange(3, dtype=np.int32)
     assert plain._step_tokens(toks) is toks
     assert plain._read_step(None, toks) is toks

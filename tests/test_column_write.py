@@ -16,7 +16,7 @@ CHANNELS = 16
 def loop_of_updates(buf, cols, at):
     """The plain reference: one in-place update a slot, as the decode
     steps wrote their columns before the kernel (and as the GPT
-    family's ``model._slot_block_step`` still does)."""
+    family's ``serving.gpt._slot_block_step`` still does)."""
     for i in range(buf.shape[0]):
         buf = lax.dynamic_update_slice(
             buf, lax.slice_in_dim(cols, i, i + 1),

@@ -15,7 +15,6 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import serving, tracing
-from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo import olmo_hybrid as oh
 from mxnet_tpu.ops.pallas import delta_step as ds
 from mxnet_tpu.serving import delta, kv_cache
@@ -25,6 +24,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 import reference_olmo_hybrid as ref                           # noqa: E402
+from decode_families import tiny_olmo_hybrid                  # noqa: E402
 from serial_decode import (StepCounters, check_log,           # noqa: E402
                            run_staggered, serial_transcript)
 
@@ -32,33 +32,9 @@ VOCAB, H, DK, DV = 97, 2, 8, 48
 TOL = 2e-5      # float32 system against the float32 reference
 
 
-def _loud(params):
-    """The seeded weights with norm gains and decays that a wrong norm
-    or a wrong decay cannot hide behind (gains of 1 are what the
-    initialiser leaves)."""
-    rng = np.random.default_rng(5)
-
-    def gains(a):
-        return jnp.asarray(rng.uniform(0.5, 1.5, a.shape), jnp.float32)
-
-    layers = [{k: gains(v) if k.endswith("_g") else v for k, v in p.items()}
-              for p in params["layers"]]
-    return dict(params, layers=layers, lnf_g=gains(params["lnf_g"]))
-
-
 @pytest.fixture(scope="module")
 def net():
-    mx.random.seed(7)
-    net = oh.get_olmo_hybrid("tiny")
-    net.collect_params().setattr("grad_req", "null")
-    net.initialize()
-    loud = _loud(oh._collect(net))
-    for i, layer in enumerate(net.layers._children.values()):
-        for name, a in loud["layers"][i].items():
-            if name.endswith("_g"):
-                getattr(layer, name).set_data(mx.nd.array(np.asarray(a)))
-    net.ln_f_g.set_data(mx.nd.array(np.asarray(loud["lnf_g"])))
-    return net
+    return tiny_olmo_hybrid()
 
 
 @pytest.fixture(scope="module")
@@ -425,24 +401,6 @@ def test_the_decode_step_calls_the_kernels_once_a_layer(model):
     assert names.count("ragged_attention") == 1
     assert {p.role for p in tracing.programs()
             if p.family == "olmo_hybrid"} >= {"decode", "prefill", "select"}
-
-
-@pytest.mark.parametrize("kwargs,what", [
-    ({"spec_mode": "self"}, "spec_mode"),
-    ({"prefix_slots": 4}, "prefix_slots"),
-])
-def test_speculation_and_prefix_cache_are_refused_by_name(model, kwargs,
-                                                          what):
-    with pytest.raises(MXNetError, match=f"{what}.*olmo_hybrid family"):
-        serving.GenerationEngine(model, **dict(
-            dict(max_slots=2, kv_buckets=(64,), prefix_slots=0), **kwargs))
-
-
-def test_rollback_entry_points_raise(model):
-    with pytest.raises(MXNetError, match="olmo_hybrid family"):
-        model.verify()
-    with pytest.raises(MXNetError, match="olmo_hybrid family"):
-        model.prefill_suffix()
 
 
 def test_from_block_picks_the_family(model):

@@ -15,7 +15,6 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import metrics, serving, tracing
-from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo import cohere2moe as c2
 from mxnet_tpu.gluon.model_zoo import ouro
 from mxnet_tpu.serving import kv_cache, loop
@@ -25,6 +24,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 import reference_ouro as ref                                  # noqa: E402
+from decode_families import tiny_gpt, tiny_ouro               # noqa: E402
 from serial_decode import (StepCounters, check_log,           # noqa: E402
                            run_staggered, serial_transcript)
 
@@ -33,31 +33,9 @@ ENTRIES, C = STEPS * LAYERS, HEADS * D
 TOL = 2e-5      # float32 system against the float32 reference
 
 
-def _loud(params):
-    """The seeded weights with gains, a gate and a bias that a wrong
-    norm or a wrong exit rule cannot hide behind (gains of 1 and a zero
-    gate are what the initialiser leaves)."""
-    rng = np.random.default_rng(5)
-    layers = dict(params["layers"], norm_g=jnp.asarray(
-        rng.uniform(0.5, 1.5, params["layers"]["norm_g"].shape),
-        jnp.float32))
-    return dict(params, layers=layers, gate_w=params["gate_w"] * 20,
-                gate_b=jnp.asarray([0.3], jnp.float32),
-                lnf_g=jnp.asarray(rng.uniform(0.5, 1.5, (64,)), jnp.float32))
-
-
 @pytest.fixture(scope="module")
 def net():
-    mx.random.seed(7)
-    net = ouro.get_ouro("tiny")
-    net.collect_params().setattr("grad_req", "null")
-    net.initialize()
-    loud = _loud(ouro._collect(net))
-    net.norm_g.set_data(mx.nd.array(np.asarray(loud["layers"]["norm_g"])))
-    net.ln_f_g.set_data(mx.nd.array(np.asarray(loud["lnf_g"])))
-    net.gate_w.set_data(mx.nd.array(np.asarray(loud["gate_w"])))
-    net.gate_b.set_data(mx.nd.array(np.asarray(loud["gate_b"])))
-    return net
+    return tiny_ouro()
 
 
 @pytest.fixture(scope="module")
@@ -491,14 +469,9 @@ def test_spans_and_the_counter_equal_a_hand_count(model):
 
 
 def test_a_family_without_a_loop_says_none_of_it():
-    from mxnet_tpu.gluon.model_zoo.gpt import GPTModel
-    gpt = GPTModel(vocab_size=VOCAB, num_layers=2, units=64,
-                   hidden_size=128, num_heads=4, max_length=128,
-                   dropout=0.0)
-    gpt.initialize()
-    gpt(mx.np.zeros((1, 4), dtype="int32"))
-    plain = serving.DecodeModel.from_block(gpt)
-    assert type(plain) is serving.DecodeModel and plain.span_attrs == {}
+    plain = serving.DecodeModel.from_block(tiny_gpt(VOCAB))
+    assert type(plain) is serving.gpt.GPTDecodeModel \
+        and plain.span_attrs == {}
     before = metrics.value("mxnet_gen_loop_steps_total")
     engine = serving.GenerationEngine(plain, max_slots=2, kv_buckets=(64,),
                                       prefix_slots=0, max_tokens=8)
@@ -596,32 +569,6 @@ def test_the_cache_is_one_stacked_buffer_a_side(model):
                                            ENTRIES * 4 * 8)
     assert model.row_blocks(pos[:3], 1024) == (ENTRIES * (1 + 5 + 8),
                                                ENTRIES * 3 * 8)
-
-
-@pytest.mark.parametrize("kwargs,match", [
-    (dict(spec_mode="self"), "spec_mode"),
-    (dict(prefix_slots=2), "prefix_slots"),
-    (dict(prefix_cache=serving.PrefixCache(2)), "prefix_slots"),
-])
-def test_speculation_and_prefix_cache_are_refused_by_name(model, kwargs,
-                                                          match):
-    with pytest.raises(MXNetError, match=match) as e:
-        new_engine(model, **dict(dict(prefix_slots=None), **kwargs))
-    assert "loop family" in str(e.value) \
-        and "not written yet" in str(e.value)
-
-
-def test_rollback_entry_points_raise(model):
-    for call in (model.verify, model.prefill_suffix):
-        with pytest.raises(MXNetError, match="loop family"):
-            call()
-
-
-def test_a_prompt_past_the_prefill_limit_is_refused_at_submit(model):
-    engine = new_engine(model, kv_buckets=(64, 128, 2048))
-    assert engine.prompt_buckets == (64, 128, 256, 512, 1024)
-    with pytest.raises(MXNetError, match="prefills in one program"):
-        engine.submit(prompt(1025), max_new_tokens=4)
 
 
 def test_the_step_bytes_by_hand():

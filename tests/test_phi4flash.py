@@ -21,6 +21,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 import reference_phi4flash as ref                           # noqa: E402
+from decode_families import tiny_gpt, tiny_phi4flash        # noqa: E402
 from serial_decode import (StepCounters, check_log,         # noqa: E402
                            run_staggered, serial_transcript)
 
@@ -30,11 +31,7 @@ TOL = 2e-5      # float32 system against the float32 reference
 
 @pytest.fixture(scope="module")
 def net():
-    mx.random.seed(7)
-    net = pf.get_phi4flash("tiny")
-    net.collect_params().setattr("grad_req", "null")
-    net.initialize()
-    return net
+    return tiny_phi4flash()
 
 
 @pytest.fixture(scope="module")
@@ -358,31 +355,6 @@ def test_live_bytes_cap_the_window_rows(model):
     run_all(engine)
 
 
-@pytest.mark.parametrize("kwargs,match", [
-    (dict(spec_mode="self"), "spec_mode"),
-    (dict(prefix_slots=2), "prefix_slots"),
-    (dict(prefix_cache=serving.PrefixCache(2)), "prefix_slots"),
-])
-def test_speculation_and_prefix_cache_are_refused_by_name(model, kwargs,
-                                                          match):
-    with pytest.raises(MXNetError, match=match) as e:
-        new_engine(model, **dict(dict(prefix_slots=None), **kwargs))
-    assert "phi4flash" in str(e.value)
-
-
-def test_rollback_entry_points_raise(model):
-    for call in (model.verify, model.prefill_suffix):
-        with pytest.raises(MXNetError, match="phi4flash"):
-            call()
-
-
-def test_a_prompt_past_the_prefill_limit_is_refused_at_submit(model):
-    engine = new_engine(model, kv_buckets=(64, 128, 2048))
-    assert engine.prompt_buckets == (64, 128, 256, 512, 1024)
-    with pytest.raises(MXNetError, match="prefills in one program"):
-        engine.submit(prompt(1025), max_new_tokens=4)
-
-
 def test_an_admission_must_install_every_kind(model):
     engine = new_engine(model)
     logits, ks, vs, state = model.prefill(prompt(5), 64)
@@ -391,14 +363,9 @@ def test_an_admission_must_install_every_kind(model):
 
 
 def test_the_gpt_family_is_untouched():
-    from mxnet_tpu.gluon.model_zoo.gpt import GPTModel
-    gpt = GPTModel(vocab_size=VOCAB, num_layers=2, units=64,
-                   hidden_size=128, num_heads=4, max_length=128,
-                   dropout=0.0)
-    gpt.initialize()
-    gpt(mx.np.zeros((1, 4), dtype="int32"))
-    model = serving.DecodeModel.from_block(gpt)
-    assert type(model) is serving.DecodeModel and model.family == "gpt"
+    model = serving.DecodeModel.from_block(tiny_gpt(VOCAB))
+    assert type(model) is serving.gpt.GPTDecodeModel \
+        and model.family == "gpt"
     engine = serving.GenerationEngine(model, max_slots=2,
                                       kv_buckets=(64, 128), prefix_slots=2)
     assert engine.cache.describe()["kinds"] == {"rows": 2}

@@ -500,7 +500,7 @@ def test_every_named_scope_in_the_package_is_in_the_vocabulary():
                  "gluon/model_zoo/cohere2moe.py", "parallel/moe.py",
                  "gluon/model_zoo/ouro.py", "serving/model.py",
                  "serving/hybrid.py", "serving/moe.py", "serving/loop.py",
-                 "serving/kv_cache.py", "serving/speculation.py",
+                 "serving/kv_cache.py", "serving/gpt.py",
                  "parallel/spmd.py"):
         assert site in found, site
     # first segments and parts hang together
